@@ -1,0 +1,541 @@
+"""chip_smoke.py — the quickest proof that mxtpu still starts on the chip.
+
+One process drives both halves of the hot path once, through the entry
+points a user and ``bench.py`` call, at the widths ``bench.py`` runs the
+repo's llama at (``GATE_CONFIGS["llama_509m*"]``): the mesh trainer for
+a few steps, then a paged ``ServeEngine`` behind the HTTP gateway for a
+dozen requests. The weights are random, made from a seed. Each phase
+prints one ``PASS``/``FAIL`` line with its set-up (compile) seconds
+apart from its run seconds; those seconds are set-up facts, not
+metrics. Any ``FAIL`` or exception ends the run non-zero, and so does
+a JAX that finds no TPU: there is no flag that lets it pass without
+one. The last line of standard output is the result,
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Run it alone: a chip belongs to one process at a time.
+
+    python chip_smoke.py
+"""
+from __future__ import annotations
+
+import contextlib
+import faulthandler
+import json
+import os
+import re
+import sys
+import threading
+import time
+import traceback
+from dataclasses import replace
+
+# bench.py's llama widths (bench_llama, bench_llama_serve): 509M
+# parameters, sized for one v5e chip
+WIDTHS = dict(vocab_size=32000, dim=2048, n_layers=8, n_heads=16,
+              n_kv_heads=8, hidden_dim=5632)
+# (prompt length, new tokens, temperature): four program shapes for the
+# per-request reference, three requests of each. The prompt lengths are
+# no multiple of the page size, so a prompt's last page is partial and
+# registering it runs the copy_page program.
+SERVE_SHAPES = ((50, 24, 0.0), (100, 40, 0.7), (200, 16, 0.0),
+                (200, 64, 0.7))
+SERVE_ENGINE = dict(max_slots=8, max_len=768, min_bucket=64)
+
+
+def _compiles() -> int:
+    """Programs jax has built or fetched from its cache in this
+    process, as the repo's compile listener counts them."""
+    from mxtpu import telemetry
+    return int(telemetry.registry().value("jax_compile_total"))
+
+
+# -- device -----------------------------------------------------------------
+def phase_device(cache_dir):
+    """jax must see TPUs of a kind the peaks table holds, and the
+    compile listener must be counting. Nothing here has a default."""
+    import importlib.metadata as md
+    import jax
+    import jaxlib
+    from mxtpu import telemetry
+    from mxtpu.telemetry import perfscope
+
+    t0 = time.perf_counter()
+    devs = jax.devices()
+    d0 = devs[0]
+    if d0.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU: jax.devices() reports {len(devs)} "
+            f"{d0.platform!r} device(s) (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r}); "
+            "chip_smoke.py only passes on a chip")
+    spec = perfscope.spec_for(d0.device_kind)     # unknown kind raises
+    if spec.kind == "cpu":
+        raise RuntimeError(f"peaks table maps {d0.device_kind!r} to "
+                           "its CPU row")
+    if not (telemetry.enabled()
+            and telemetry.install_compile_listener()):
+        raise RuntimeError("the compile listener is not installed; "
+                           "'no compile in the window' would be "
+                           "trivially true")
+    return {"setup_s": time.perf_counter() - t0, "run_s": 0.0,
+            "platform": d0.platform, "device_kind": d0.device_kind,
+            "count": len(devs), "peaks": spec.kind,
+            "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": md.version("libtpu"), "compile_cache": cache_dir}
+
+
+# -- context ----------------------------------------------------------------
+def phase_context(ctx, n=8192, chain=24):
+    """The README's typical use against a real ``mx.tpu()``, and the
+    fence check: a chain of large matmuls timed to
+    ``block_until_ready`` (``NDArray.wait_to_read``), to
+    ``mx.nd.waitall`` and to a scalar read-back must agree, and none
+    may beat the chip's peak."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import mxtpu as mx
+    from mxtpu import autograd, gluon
+    from mxtpu.gluon import nn
+    from mxtpu.telemetry import perfscope
+
+    t0 = time.perf_counter()
+    dev = ctx.jax_device()
+    rng = np.random.default_rng(0)
+    x_np = rng.standard_normal((64, 32)).astype(np.float32)
+    y_np = rng.standard_normal((64, 4)).astype(np.float32)
+    x = mx.nd.array(x_np, ctx=ctx)
+    assert x.context == ctx and x._data.devices() == {dev}, x.context
+    hop = x.as_in_context(mx.cpu()).as_in_context(ctx) \
+        .as_in_context(mx.cpu())
+    assert hop.context == mx.cpu()
+    np.testing.assert_array_equal(hop.asnumpy(), x_np)
+    y = mx.nd.array(y_np, ctx=ctx)
+
+    def train(hybridize):
+        net = nn.HybridSequential()
+        with net.name_scope():
+            net.add(nn.Dense(64, activation="tanh", in_units=32),
+                    nn.Dense(4, in_units=64))
+        mx.random.seed(7)
+        net.initialize(ctx=ctx)
+        if hybridize:
+            net.hybridize()
+        tr = gluon.Trainer(net.collect_params(), "sgd",
+                           {"learning_rate": 0.1})
+        losses = []
+        for _ in range(4):
+            with autograd.record():
+                loss = ((net(x) - y) ** 2).mean()
+            loss.backward()
+            tr.step(1)
+            losses.append(float(loss.asscalar()))
+        assert net[0].weight.data().context == ctx
+        return losses
+
+    eager, hybrid = train(False), train(True)
+    assert all(np.isfinite(eager)) and eager[-1] < eager[0], eager
+    # one XLA program against op-by-op dispatch: the same numbers up to
+    # the backend's default matmul precision
+    np.testing.assert_allclose(hybrid, eager, rtol=2e-2)
+    setup_s = time.perf_counter() - t0
+
+    # the fence check
+    t0 = time.perf_counter()
+    a = jax.device_put(
+        (rng.standard_normal((n, n)) / np.sqrt(n)).astype(np.float32),
+        dev).astype(jnp.bfloat16)
+    step = jax.jit(lambda y, a: y @ a)
+    first = jax.jit(lambda y: y[0, 0].astype(jnp.float32))
+    float(first(step(a, a)))                      # compile both
+
+    def timed(fence):
+        y = a
+        t = time.perf_counter()
+        for _ in range(chain):
+            y = step(y, a)
+        fence(y)
+        return time.perf_counter() - t
+
+    def waitall(y):
+        mx.nd.waitall()
+        assert y.is_ready(), "waitall returned before the chain ended"
+
+    t_read = min(timed(lambda y: float(first(y))) for _ in range(2))
+    t_ready = min(timed(lambda y: mx.nd.from_jax(y).wait_to_read())
+                  for _ in range(2))
+    t_all = min(timed(waitall) for _ in range(2))
+    floor = chain * 2 * n ** 3 / perfscope.spec_for(
+        dev.device_kind).peak_flops
+    for t in (t_read, t_ready, t_all):
+        assert t >= floor and abs(t - t_read) <= 0.25 * t_read, \
+            (t_read, t_ready, t_all, floor)
+    return {"setup_s": setup_s, "run_s": time.perf_counter() - t0,
+            "eager_vs_hybrid_max_rel": float(np.max(np.abs(
+                np.array(hybrid) / np.array(eager) - 1))),
+            "scalar_readback_ms": round(1e3 * t_read, 1),
+            "block_until_ready_ms": round(1e3 * t_ready, 1),
+            "waitall_ms": round(1e3 * t_all, 1),
+            "peak_floor_ms": round(1e3 * floor, 1)}
+
+
+# -- train ------------------------------------------------------------------
+def _shard_report(tree, mesh):
+    """Bytes of ``tree`` each device of ``mesh`` holds, and the tree's
+    global bytes — state that is spread holds a fraction everywhere."""
+    import jax
+    per_dev = {d.id: 0 for d in mesh.devices.flat}
+    total = 0
+    for leaf in jax.tree.leaves(tree):
+        total += leaf.nbytes
+        for s in leaf.addressable_shards:
+            per_dev[s.device.id] += s.data.nbytes
+    return per_dev, total
+
+
+def phase_train(cfg, batch, seq, steps, mesh_axes=None,
+                expect_attn="pallas"):
+    """What ``bench_llama`` builds — mesh, ``init_state``,
+    ``make_train_step`` over ``llama.loss_fn`` with adamw — stepped on
+    one repeated batch. ``expect_attn`` names the attention the
+    compiled step must hold: ``pallas`` (the Mosaic custom call),
+    ``blockwise`` (the scan, off-TPU) or ``ring`` (collective
+    permutes)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+    from mxtpu.models import llama
+    from mxtpu.parallel import mesh as pmesh, step as pstep
+
+    t0 = time.perf_counter()
+    mesh = pmesh.create_mesh(**(mesh_axes or {"dp": -1}))
+    rules = llama.sharding_rules(cfg)
+    tx = optax.adamw(3e-4)
+    state = pstep.init_state(
+        llama.init_params(cfg, jax.random.PRNGKey(0)), tx, mesh, rules)
+    train_step = pstep.make_train_step(
+        llama.loss_fn(cfg, mesh=mesh), tx, mesh, rules)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (batch, seq)), jnp.int32)
+    batch_d = {"tokens": tokens}
+    c0 = _compiles()
+    state, loss = train_step(state, batch_d)
+    losses = [float(jax.device_get(loss))]
+    c1 = _compiles()
+    assert c1 > c0, "the compile listener did not see the step compile"
+    setup_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    for _ in range(steps - 1):
+        state, loss = train_step(state, batch_d)
+        losses.append(float(jax.device_get(loss)))
+    run_s = time.perf_counter() - t0
+    assert _compiles() == c1, \
+        f"{_compiles() - c1} compile(s) after the first step"
+    assert all(np.isfinite(losses)), losses
+    assert losses[-1] < losses[0], f"loss did not fall: {losses}"
+
+    # which attention the program holds, read off the program itself
+    text = train_step._jitted.lower(state, batch_d, None) \
+        .as_text(debug_info=True)
+    kernels = re.findall(
+        r"custom_call @tpu_custom_call\(.*?: \((tensor<[^>]*>)", text)
+    held = ("pallas" if kernels
+            else "ring" if "collective_permute" in text
+            else "blockwise" if "flash_attention_blockwise" in text
+            else "other")
+    assert held == expect_attn, (
+        f"the compiled step holds {held!r} attention, not "
+        f"{expect_attn!r}")
+    if held == "pallas":
+        assert "flash_attention_pallas" in text
+
+    info = {"setup_s": setup_s, "run_s": run_s,
+            "mesh": {a: n for a, n in mesh.shape.items() if n > 1}
+            or {"dp": 1},
+            "loss": [round(l, 3) for l in (losses[0], losses[-1])],
+            "steps": steps, "attention": held}
+    if kernels:
+        info["mosaic_calls"] = len(kernels)
+        info["kernel_operand"] = kernels[0]
+    if mesh.size > 1:
+        per_dev, total = _shard_report(state.params, mesh)
+        info["param_bytes_per_device"] = per_dev
+        info["param_bytes"] = total
+        if mesh_axes:           # a model-parallel layout: truly spread
+            assert all(0 < b <= 0.6 * total
+                       for b in per_dev.values()), (per_dev, total)
+    return info
+
+
+# -- serve ------------------------------------------------------------------
+def make_jobs(vocab, shapes, per_shape=3, shared_prefix=128, seed=0):
+    """The request list: ``per_shape`` requests of each (prompt length,
+    new tokens, temperature), with random prompts from ``seed``. The
+    second request of the last greedy shape repeats the first one's
+    leading ``shared_prefix`` tokens and waits for it to finish, so its
+    admission must hit the prefix cache."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    jobs = []
+    for plen, mnew, temp in shapes:
+        for _ in range(per_shape):
+            jobs.append({"prompt": rng.integers(0, vocab, plen).tolist(),
+                         "mnew": mnew, "temperature": temp,
+                         "seed": len(jobs), "after": None, "shared": 0})
+    greedy = [i for i, j in enumerate(jobs) if j["temperature"] == 0.0
+              and len(j["prompt"]) > shared_prefix]
+    first, second = greedy[-per_shape], greedy[-per_shape + 1]
+    jobs[second]["prompt"][:shared_prefix] = \
+        jobs[first]["prompt"][:shared_prefix]
+    jobs[second].update(after=first, shared=shared_prefix)
+    return jobs
+
+
+def _references(cfg, params, jobs):
+    """Each job's stream from per-request ``llama.generate`` — the
+    contract every serve test asserts against."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxtpu.models import llama
+    fns, out = {}, []
+    for job in jobs:
+        key = (job["mnew"], job["temperature"])
+        if key not in fns:
+            fns[key] = jax.jit(lambda p, t, r, k=key: llama.generate(
+                cfg, p, t, k[0], temperature=k[1], rng=r))
+        toks = fns[key](params, jnp.asarray(job["prompt"], jnp.int32)[None],
+                        jax.random.PRNGKey(job["seed"]))
+        out.append(np.asarray(toks)[0, len(job["prompt"]):].tolist())
+    return out
+
+
+@contextlib.contextmanager
+def _matmul_precision(precision):
+    """jax's default matmul precision, process-wide: the config's own
+    context manager is per thread, and the engine traces on its own."""
+    import jax
+    prev = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", precision)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_default_matmul_precision", prev)
+
+
+def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
+    """The serving half of :func:`phase_serve`: gateway up, warm-up,
+    the jobs, the checks, gateway down. Set-up counts from ``t0``.
+    Returns (info, streams)."""
+    import numpy as np
+    from mxtpu.serve import ServeEngine
+    from mxtpu.serve.engine import bucket_for
+    from mxtpu.serve.gateway import Gateway, GatewayClient
+
+    # a cold compile may outlast the supervisor's default stall
+    # threshold; a replica restarted mid-compile never finishes one
+    gw = Gateway(lambda: ServeEngine(
+        cfg, params, paged=True, prefix_cache=True, mesh=mesh,
+        **engine_kw),
+        n_replicas=1, queue_max=4 * len(jobs),
+        supervisor_opts={"stall_s": 900.0, "warmup_s": 900.0})
+    try:
+        port = gw.start_http(port=0)
+        engine = gw.backend.replicas()[0].engine
+        min_bucket, max_len = engine.min_bucket, engine.max_len
+
+        def ask(job):
+            return GatewayClient("127.0.0.1", port, timeout=900).generate(
+                job["prompt"], job["mnew"], seed=job["seed"],
+                temperature=job["temperature"])
+
+        # warm-up: every prefill bucket the jobs use (a prefix hit
+        # prefills only the suffix), the decode program and copy_page,
+        # one request at a time
+        buckets = sorted({bucket_for(len(j["prompt"]) - j["shared"],
+                                     min_bucket, max_len) for j in jobs})
+        wrng = np.random.default_rng(1)
+        warm_s = {}
+        for b in buckets:
+            tw = time.perf_counter()
+            rec = ask({"prompt": wrng.integers(
+                           0, cfg.vocab_size,
+                           min(b, max_len - 1) - 1).tolist(),
+                       "mnew": 2, "temperature": 0.7, "seed": 10 ** 6 + b})
+            assert rec["status"] == 200 and len(rec["tokens"]) == 2, rec
+            warm_s[b] = round(time.perf_counter() - tw, 1)
+        bound = engine.n_buckets + 2        # + decode + copy_page
+        compiled = engine.compile_count
+        assert engine.n_buckets == len(buckets), (engine.n_buckets, buckets)
+        assert compiled <= bound, (compiled, bound)
+        c_warm = _compiles()
+        setup_s = time.perf_counter() - t0
+
+        t0 = time.perf_counter()
+        results = [None] * len(jobs)
+        done = [threading.Event() for _ in jobs]
+
+        def fire(i):
+            try:
+                if jobs[i]["after"] is not None:
+                    done[jobs[i]["after"]].wait(900)
+                results[i] = ask(jobs[i])
+            finally:
+                done[i].set()
+
+        threads = [threading.Thread(target=fire, args=(i,))
+                   for i in range(len(jobs))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(900)
+        assert not any(t.is_alive() for t in threads), "a client hung"
+        run_s = time.perf_counter() - t0
+        for job, rec in zip(jobs, results):
+            assert rec is not None and rec["status"] == 200, rec
+            assert len(rec["tokens"]) == job["mnew"] and \
+                rec["reason"] == "complete", (job["mnew"], rec)
+        assert engine.compile_count == compiled, \
+            (engine.compile_count, compiled)
+        assert _compiles() == c_warm, \
+            f"{_compiles() - c_warm} compile(s) after warm-up"
+        kv = engine.kv_cache_stats()
+        assert kv["prefix_hits"] >= 1, kv
+        client = GatewayClient("127.0.0.1", port)
+        status, health = client.get_json("/healthz")
+        assert status == 200 and health["status"] == "ok", health
+        status, metrics = client.get_text("/metrics")
+        assert status == 200 and "serve_" in metrics, metrics[:200]
+
+        info = {"setup_s": setup_s, "run_s": run_s,
+                "requests": len(jobs),
+                "tokens": sum(j["mnew"] for j in jobs),
+                "buckets": buckets, "warmup_s": warm_s,
+                "compiles": compiled, "compile_bound": bound,
+                "prefix_hits": kv["prefix_hits"],
+                "cow_forks": kv["cow_forks"]}
+        if mesh is not None:
+            per_dev, total = _shard_report(engine._kv, mesh)
+            assert all(0 < b < total for b in per_dev.values()), per_dev
+            info.update(mesh={a: n for a, n in mesh.shape.items()
+                              if n > 1},
+                        kv_spec=str(engine._kv["k"].sharding.spec),
+                        kv_bytes_per_device=per_dev, kv_bytes=total)
+    finally:
+        gw.close()
+    return info, [r["tokens"] for r in results]
+
+
+def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
+                must_match=False, **engine_kw):
+    """A paged ``ServeEngine`` (prefix cache on) behind
+    ``Gateway.start_http``, asked ``jobs`` by ``GatewayClient`` threads
+    of this process. Every request must come back 200 and whole, the
+    engine must stay inside its own compile bound with nothing compiled
+    after warm-up, and ``/healthz`` and ``/metrics`` must answer. Then
+    the streams are compared, token for token, with ``compare_to`` or —
+    when that is None — with per-request ``llama.generate``; how many
+    agree is returned, and asserted only under ``must_match`` (what
+    holds on the chip: float32 at highest precision; bf16 streams part
+    from ``generate`` at near-ties). ``precision`` is jax's default
+    matmul precision for the phase; ``engine_kw`` shapes the engine."""
+    import jax
+    import numpy as np
+    from mxtpu.models import llama
+    from mxtpu.parallel.sharding import shard_pytree
+
+    against = ("llama.generate" if compare_to is None
+               else "the one-device engine")
+    t0 = time.perf_counter()
+    with _matmul_precision(precision):
+        params = llama.init_params(cfg, jax.random.PRNGKey(0))
+        if mesh is not None:
+            params = shard_pytree(params, mesh, llama.sharding_rules(cfg))
+        info, streams = _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0)
+        if compare_to is None:
+            compare_to = _references(cfg, params, jobs)
+    parted = {}
+    for i, (got, want) in enumerate(zip(streams, compare_to)):
+        if got != want:
+            parted[i] = next(k for k, (a, b) in enumerate(zip(got, want))
+                             if a != b)
+    info.update(dtype=np.dtype(cfg.dtype).name,
+                precision=precision or "default", compared_with=against,
+                identical=f"{len(jobs) - len(parted)}/{len(jobs)}",
+                first_difference_at=parted)
+    assert not (must_match and parted), \
+        f"streams parted from {against} at {parted}"
+    return info, streams
+
+
+# -- the run ----------------------------------------------------------------
+def _run(name, fn, *args, **kw):
+    """One phase: PASS line with its facts, or FAIL and a non-zero
+    exit."""
+    print(f"---- {name}", flush=True)
+    try:
+        out = fn(*args, **kw)
+    except BaseException:
+        traceback.print_exc()
+        print(f"FAIL {name}", flush=True)
+        raise SystemExit(1)
+    info, rest = out if isinstance(out, tuple) else (out, None)
+    facts = " ".join(f"{k}={json.dumps(v)}" for k, v in info.items()
+                     if k not in ("setup_s", "run_s"))
+    print(f"PASS {name} setup_s={info['setup_s']:.1f} "
+          f"run_s={info['run_s']:.1f} {facts}", flush=True)
+    return rest
+
+
+def main():
+    # a hang must end inside the driver's limit, with every stack shown
+    faulthandler.dump_traceback_later(1150, exit=True)
+    import jax
+    import jax.numpy as jnp
+    import mxtpu as mx
+    from mxtpu import runtime
+    from mxtpu.models import llama
+    from mxtpu.parallel import mesh as pmesh
+
+    cache_dir = runtime.use_compile_cache()     # before anything compiles
+    _run("device", phase_device, cache_dir)
+    _run("context", phase_context, mx.tpu())
+
+    seq = 2048
+    train_cfg = llama.LlamaConfig(
+        **WIDTHS, max_seq_len=seq, attn_impl="flash", remat=True,
+        remat_policy="dots_no_batch")
+    _run("train", phase_train, train_cfg, 4, seq, 5)
+
+    serve_cfg = llama.LlamaConfig(
+        **WIDTHS, max_seq_len=SERVE_ENGINE["max_len"], remat=False)
+    jobs = make_jobs(serve_cfg.vocab_size, SERVE_SHAPES)
+    streams = _run("serve", phase_serve, serve_cfg, jobs, **SERVE_ENGINE)
+    _run("serve_f32", phase_serve,
+         replace(serve_cfg, dtype=jnp.float32), jobs, **SERVE_ENGINE,
+         precision="highest", must_match=True)
+
+    if jax.device_count() >= 4:
+        # the same two phases over a mesh with more than one
+        # non-trivial axis: state spread, not parked on device 0
+        _run("train_fsdp2_tp2", phase_train, train_cfg, 4, seq, 3,
+             mesh_axes={"dp": -1, "fsdp": 2, "tp": 2})
+        _run("train_fsdp2_sp2_ring", phase_train,
+             replace(train_cfg, attn_impl="ring"), 4, seq, 3,
+             mesh_axes={"dp": -1, "fsdp": 2, "sp": 2},
+             expect_attn="ring")
+        _run("serve_tp4", phase_serve, serve_cfg, jobs, **SERVE_ENGINE,
+             mesh=pmesh.create_mesh(dp=-1, tp=4), compare_to=streams)
+
+    faulthandler.cancel_dump_traceback_later()
+    d0 = jax.devices()[0]
+    print(json.dumps({"ok": True, "device": {
+        "platform": d0.platform, "kind": d0.device_kind,
+        "count": len(jax.devices())}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -19,10 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import numpy as np
 
 SMOKE = bool(int(os.environ.get("MXTPU_SMOKE", "0")))

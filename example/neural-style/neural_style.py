@@ -21,8 +21,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 # the convergence bar below is a numerics assertion: on TPU the default
 # matmul precision (bf16 passes) raises the loss floor enough to miss
 # it — pin full f32 accumulation so CPU and chip walk the same

@@ -589,10 +589,7 @@ class HybridBlock(Block):
             outs = out if isinstance(out, (list, tuple)) else (out,)
             return tuple(o._data for o in outs)
 
-        # `from jax import export` (not jax.export attribute access):
-        # on older jax the submodule exists but is lazily registered
-        from jax import export as _jax_export
-        exp = _jax_export.export(jax.jit(infer))(*[x._data for x in ex])
+        exp = jax.export.export(jax.jit(infer))(*[x._data for x in ex])
         out_path = path if path.endswith(".stablehlo") else \
             path + ".stablehlo"
         with open(out_path, "wb") as f:

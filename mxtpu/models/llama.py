@@ -38,7 +38,7 @@ from ..ops.attention import (flash_attention, dense_attention,
                              ring_attention, ulysses_attention,
                              slot_decode_attention,
                              paged_decode_attention)
-from ..parallel.sharding import ShardingRules, constrain
+from ..parallel.sharding import ShardingRules, _filter_spec, constrain
 from ..parallel.sharding import mcon as _mcon
 
 __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
@@ -240,24 +240,34 @@ def apply_rope(x, cos, sin):
 
 def _attention(cfg: LlamaConfig, q, k, v, mesh: Optional[Mesh]):
     sp_ok = mesh is not None and "sp" in mesh.axis_names
-    if cfg.attn_impl in ("ring", "ulysses") and not sp_ok:
-        raise ValueError(
-            f"attn_impl={cfg.attn_impl!r} needs a mesh with an 'sp' "
-            "axis (got mesh="
-            f"{None if mesh is None else mesh.axis_names}); pass "
-            "mesh= to forward/loss_fn or use 'flash'")
-    if cfg.attn_impl in ("ring", "ulysses") and sp_ok:
+    if cfg.attn_impl in ("ring", "ulysses"):
+        if not sp_ok:
+            raise ValueError(
+                f"attn_impl={cfg.attn_impl!r} needs a mesh with an "
+                "'sp' axis (got mesh="
+                f"{None if mesh is None else mesh.axis_names}); pass "
+                "mesh= to forward/loss_fn or use 'flash'")
         kernel = ring_attention if cfg.attn_impl == "ring" \
             else ulysses_attention
-        from ..parallel.compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(kernel, axis_name="sp", causal=True),
             mesh=mesh, in_specs=(_QKV, _QKV, _QKV), out_specs=_QKV,
             check_vma=False)
         return fn(q, k, v)
     if cfg.attn_impl == "dense":
         return dense_attention(q, k, v, causal=True)
-    return flash_attention(q, k, v, causal=True)
+    flash = partial(flash_attention, causal=True)
+    if mesh is not None and mesh.size > 1:
+        # the Pallas kernel is a custom call XLA cannot partition: left
+        # to GSPMD it would run replicated on gathered operands. Each
+        # device runs it on its own batch rows and heads instead (the
+        # sequence stays whole — that is what ring/ulysses are for).
+        spec = _filter_spec(P(("dp", "fsdp"), "tp", None, None),
+                            mesh.axis_names)
+        flash = jax.shard_map(flash, mesh=mesh,
+                              in_specs=(spec, spec, spec),
+                              out_specs=spec, check_vma=False)
+    return flash(q, k, v)
 
 
 def _layer(cfg: LlamaConfig, mesh, cos, sin, x, lp):

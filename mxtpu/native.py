@@ -2,61 +2,73 @@
 runtime components (RecordIO reader, JPEG decode, threaded decode
 pipeline; the rebuild of the reference's C++ ``src/io`` stack).
 
-The library builds lazily with g++ on first use (no pybind11 in the
-environment — plain C ABI + ctypes per SURVEY.md environment notes);
-everything degrades gracefully to the Python implementations when the
-toolchain or libjpeg is unavailable.
+The library builds with g++ on first use (no pybind11 in the
+environment — plain C ABI + ctypes per SURVEY.md environment notes),
+from the source git holds: its file name carries a hash of
+``libmxtpu.cc``, so a binary built from other source is never loaded.
+A component asked for by name (:class:`NativeRecordReader`,
+:func:`jpeg_decode`, :class:`NativePipeline`) raises when the build
+fails; :func:`available` is for callers with a documented Python
+alternative (``mxtpu.io.ImageRecordIter``) and logs the reason once.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
-from typing import Optional
 
 import numpy as onp
 
 _LIB = None
+_LIB_ERROR = None
 _LOCK = threading.Lock()
 _SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src")
 
 
-def _build() -> Optional[str]:
-    so = os.path.join(_SRC_DIR, "libmxtpu.so")
+def _build() -> str:
+    """Path of the library built from the current ``libmxtpu.cc``,
+    compiling it when no file with that content hash exists yet."""
     src = os.path.join(_SRC_DIR, "libmxtpu.cc")
-    if os.path.exists(so):
-        try:
-            if os.path.getmtime(so) >= os.path.getmtime(src):
-                return so
-        except OSError:
-            return so          # prebuilt .so shipped without source
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-shared",
-             src, "-o", so, "-ljpeg", "-lpthread"],
-            check=True, capture_output=True, timeout=120)
-        return so
-    except Exception:
-        return None
+    with open(src, "rb") as f:
+        tag = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_SRC_DIR, f"libmxtpu-{tag}.so")
+    if not os.path.exists(so):
+        tmp = f"{so}.{os.getpid()}.tmp"     # concurrent builders race
+        try:                                # on the rename, not the file
+            subprocess.run(
+                ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-shared",
+                 src, "-o", tmp, "-ljpeg", "-lpthread"],
+                check=True, capture_output=True, text=True, timeout=120)
+            os.replace(tmp, so)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"libmxtpu build failed:\n{e.stderr[-2000:]}") from e
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
+    return so
 
 
 def get_lib():
-    """Load (building if needed) libmxtpu; None if unavailable."""
-    global _LIB
+    """Load (building if needed) libmxtpu. Raises RuntimeError when it
+    cannot be built or loaded; the failure is remembered, so the
+    compiler runs at most once per process."""
+    global _LIB, _LIB_ERROR
     with _LOCK:
         if _LIB is not None:
-            return _LIB if _LIB is not False else None
-        so = _build()
-        if so is None:
-            _LIB = False
-            return None
+            return _LIB
+        if _LIB_ERROR is not None:
+            raise RuntimeError(_LIB_ERROR)
         try:
-            lib = ctypes.CDLL(so)
-        except OSError:
-            _LIB = False
-            return None
+            lib = ctypes.CDLL(_build())
+        except (OSError, RuntimeError,
+                subprocess.TimeoutExpired) as e:
+            _LIB_ERROR = f"libmxtpu unavailable: {e}"
+            raise RuntimeError(_LIB_ERROR) from e
         lib.mxtpu_rec_open.restype = ctypes.c_void_p
         lib.mxtpu_rec_open.argtypes = [ctypes.c_char_p]
         lib.mxtpu_rec_count.restype = ctypes.c_long
@@ -91,7 +103,17 @@ def get_lib():
 
 
 def available() -> bool:
-    return get_lib() is not None
+    """True when libmxtpu loads. For callers that have a Python path
+    to fall back to; the reason it does not load is logged once."""
+    first = _LIB is None and _LIB_ERROR is None
+    try:
+        get_lib()
+        return True
+    except RuntimeError as e:
+        if first:
+            logging.getLogger(__name__).warning(
+                "%s — using the Python input path", e)
+        return False
 
 
 class NativeRecordReader:
@@ -99,8 +121,6 @@ class NativeRecordReader:
 
     def __init__(self, path: str):
         lib = get_lib()
-        if lib is None:
-            raise RuntimeError("libmxtpu unavailable")
         self._lib = lib
         self._h = lib.mxtpu_rec_open(path.encode())
         if not self._h:
@@ -132,8 +152,6 @@ class NativeRecordReader:
 def jpeg_decode(buf: bytes, channels: int = 3) -> onp.ndarray:
     """Native JPEG decode → HWC uint8."""
     lib = get_lib()
-    if lib is None:
-        raise RuntimeError("libmxtpu unavailable")
     arr = (ctypes.c_ubyte * len(buf)).from_buffer_copy(buf)
     w = ctypes.c_int()
     h = ctypes.c_int()
@@ -159,8 +177,6 @@ class NativePipeline:
                  channels: int = 3, shuffle: bool = False, seed: int = 0,
                  threads: int = 2, out_u8: bool = False):
         lib = get_lib()
-        if lib is None:
-            raise RuntimeError("libmxtpu unavailable")
         self._lib = lib
         self._hwc = (height, width, channels)
         self._u8 = bool(out_u8)

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
-           "ulysses_attention",
+           "flash_path", "ulysses_attention",
            "ring_attention", "slot_decode_attention",
            "paged_decode_attention"]
 
@@ -173,24 +173,37 @@ def _tpu_pallas_flash(q, k, v, causal, scale):
                      block_sizes=bs)
 
 
+def flash_path(q_shape, kv_len: int) -> str:
+    """Which implementation :func:`flash_attention` runs for these
+    shapes on this backend: ``"pallas"`` (the Mosaic kernel — TPU
+    only, and it wants both sequence lengths and the head dim in
+    multiples of 128) or ``"blockwise"`` (the ``lax.scan`` online
+    softmax). Decided from backend and shapes alone, so a caller can
+    ask before timing anything."""
+    if len(q_shape) == 4 and jax.default_backend() == "tpu":
+        sq, d = q_shape[2], q_shape[3]
+        if sq % 128 == 0 and kv_len % 128 == 0 and d % 128 == 0:
+            return "pallas"
+    return "blockwise"
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     kv_block: int = 512):
     """Fused attention: Pallas (Mosaic) kernel on TPU, blockwise scan
-    elsewhere. This is the rebuild's hot-path attention op — the role
-    cuDNN's fused MHA played in the reference."""
+    elsewhere (:func:`flash_path` says which). This is the rebuild's
+    hot-path attention op — the role cuDNN's fused MHA played in the
+    reference. Each path runs under its own named scope, so a lowered
+    program or a trace shows which one it holds; a kernel failure
+    raises."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     kr, vr = _repeat_kv(q, k, v)
-    if q.ndim == 4 and jax.default_backend() == "tpu":
-        # Mosaic wants block-aligned seq lens; fall back otherwise.
-        sq, skv, d = q.shape[2], kr.shape[2], q.shape[3]
-        if sq % 128 == 0 and skv % 128 == 0 and d % 128 == 0:
-            try:
-                return _tpu_pallas_flash(q, kr, vr, causal, scale)
-            except Exception:
-                pass
-    return blockwise_attention(q, kr, vr, causal=causal, scale=scale,
-                               kv_block=kv_block)
+    if flash_path(q.shape, kr.shape[2]) == "pallas":
+        with jax.named_scope("flash_attention_pallas"):
+            return _tpu_pallas_flash(q, kr, vr, causal, scale)
+    with jax.named_scope("flash_attention_blockwise"):
+        return blockwise_attention(q, kr, vr, causal=causal, scale=scale,
+                                   kv_block=kv_block)
 
 
 def slot_decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,

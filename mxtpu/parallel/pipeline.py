@@ -95,8 +95,7 @@ def gpipe(layer_fn: Callable[[Any, Any], Any], stacked_params: Any, x,
         outbuf = jnp.where(stage == S - 1, outbuf, 0)
         return lax.psum(outbuf, axis)
 
-    from .compat import shard_map
-    out = shard_map(pp_fn, mesh=mesh,
-                    in_specs=(param_specs, P()), out_specs=P(),
-                    check_vma=False)(stacked_params, mb)
+    out = jax.shard_map(pp_fn, mesh=mesh,
+                        in_specs=(param_specs, P()), out_specs=P(),
+                        check_vma=False)(stacked_params, mb)
     return out.reshape((B,) + x.shape[1:])

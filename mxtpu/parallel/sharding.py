@@ -159,13 +159,13 @@ def constrain(x, *spec):
         pspec = _filter_spec(spec, mesh.axis_names)
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, pspec))
-    from .compat import abstract_mesh_axes
-    names, auto = abstract_mesh_axes()
-    if not names:                  # no ambient mesh anywhere → no-op
-        return x
+    am = jax.sharding.get_abstract_mesh()
     # inside shard_map, axes are Manual and constraints may only name
     # the remaining Auto axes (e.g. model code running under a gpipe
-    # stage): constrain over those, or no-op when fully manual
+    # stage): constrain over those; no ambient mesh, or a fully manual
+    # one, makes this a no-op
+    auto = tuple(a for a, t in zip(am.axis_names, am.axis_types)
+                 if t == jax.sharding.AxisType.Auto)
     if not auto:
         return x
     return jax.lax.with_sharding_constraint(

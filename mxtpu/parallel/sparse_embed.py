@@ -59,7 +59,6 @@ def sharded_embedding_lookup(table, ids, mesh: Mesh,
         vals = jnp.where(mine[..., None], vals, 0).astype(tbl_shard.dtype)
         return jax.lax.psum(vals, axis)
 
-    from .compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(P(axis, None), P()), out_specs=P(),
         check_vma=False)(table, ids)

@@ -20,7 +20,10 @@ substrate:
   so ``mxtpu_program_peak_hbm_bytes`` is published for AOT-compiled
   programs (:func:`program_costs`) always, and for watched jitted
   programs only under ``MXTPU_TELEMETRY_PERF_MEMORY=1`` (it forces a
-  second full XLA compile per variant).
+  second full XLA compile per variant). libtpu gives an uncompiled
+  program no cost analysis at all, so on the chip that knob is also
+  what fills the catalog; without it a watched program logs once that
+  it stays uncataloged.
 - **live MFU / MBU** — :meth:`PerfScope.on_call` keeps a rolling
   window of inter-dispatch gaps per program. Dispatch itself is async
   (host time is microseconds), but the gap between consecutive
@@ -142,10 +145,10 @@ class DeviceSpec:
         return self.peak_flops / self.peak_bw
 
 
-# matched by substring of jax's device_kind, first hit wins; the CPU
-# row is a nominal desktop-class roofline so CPU CI still classifies
-# deterministically (override with the MXTPU_TELEMETRY_PERF_PEAK_*
-# knobs for honest numbers on other hardware)
+# matched by substring of jax's device_kind, first hit wins. TPU rows
+# are the published per-chip peaks (Google Cloud TPU documentation).
+# The CPU row is nominal — it only lets CPU CI classify programs
+# deterministically; nothing measured on a chip may reach it.
 _SPECS: Tuple[Tuple[Tuple[str, ...], DeviceSpec], ...] = (
     (("v6e", "trillium"), DeviceSpec("v6e", 918e12, 1640e9, 32e9)),
     (("v5p",), DeviceSpec("v5p", 459e12, 2765e9, 95e9)),
@@ -154,17 +157,20 @@ _SPECS: Tuple[Tuple[Tuple[str, ...], DeviceSpec], ...] = (
     (("v4",), DeviceSpec("v4", 275e12, 1228e9, 32e9)),
     (("cpu",), DeviceSpec("cpu", 5e11, 5e10, 16e9)),
 )
-_FALLBACK = DeviceSpec("unknown", 197e12, 819e9, 16e9)   # v5e numbers
 
 
 def spec_for(kind: str) -> DeviceSpec:
     """The roofline spec for a device_kind string (e.g. ``"v5e"`` for
-    bench gates that model v5e serving while running on CPU)."""
+    bench gates that model v5e serving while running on CPU). A kind
+    the table does not hold raises: an MFU against someone else's peak
+    is not a number."""
     k = str(kind).lower()
     for keys, spec in _SPECS:
         if any(key in k for key in keys):
             return spec
-    return _FALLBACK
+    raise KeyError(
+        f"no roofline peaks for device_kind {kind!r}; add a row to "
+        "mxtpu.telemetry.perfscope._SPECS with its source")
 
 
 def _apply_overrides(spec: DeviceSpec) -> DeviceSpec:
@@ -179,12 +185,8 @@ def _apply_overrides(spec: DeviceSpec) -> DeviceSpec:
 def device_spec() -> DeviceSpec:
     """The current process's device spec (first jax device), with the
     MXTPU_TELEMETRY_PERF_PEAK_* env overrides applied."""
-    try:
-        import jax
-        kind = getattr(jax.devices()[0], "device_kind", "cpu")
-    except Exception:
-        kind = "cpu"
-    return _apply_overrides(spec_for(kind))
+    import jax
+    return _apply_overrides(spec_for(jax.devices()[0].device_kind))
 
 
 # -- the shared ratio helpers (bench.py + live gauges) ---------------------
@@ -254,6 +256,12 @@ def _extract_costs(obj) -> Tuple[float, float, float]:
     of ``cost_analysis()``: a Compiled returns a list of per-module
     dicts, a Lowered returns one flat dict."""
     ca = obj.cost_analysis()
+    if ca is None:
+        # libtpu: only a compiled program has costs
+        raise ValueError(
+            "this backend gives no cost analysis before compilation; "
+            "MXTPU_TELEMETRY_PERF_MEMORY=1 catalogs from the compiled "
+            "program (a second compile per variant)")
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}
     return (float(ca.get("flops", 0.0) or 0.0),
@@ -524,12 +532,12 @@ class PerfScope:
             obj = fn_or_compiled
             if not hasattr(obj, "cost_analysis"):
                 obj = obj.lower(*args, **(kwargs or {}))
+            if _MEMORY and hasattr(obj, "compile"):
+                # knob-gated: this is a SECOND full XLA compile
+                obj = obj.compile()
             flops, nbytes, trans = _extract_costs(obj)
             mem = (_extract_memory(obj)
                    if hasattr(obj, "memory_analysis") else {})
-            if not mem and _MEMORY and hasattr(obj, "compile"):
-                # knob-gated: this is a SECOND full XLA compile
-                mem = _extract_memory(obj.compile())
             cost = ProgramCost(
                 name=name, flops=flops, bytes_accessed=nbytes,
                 transcendentals=trans, spec=self.spec(),

@@ -43,19 +43,15 @@ _MAX_KEY_CHARS = 512
 
 def install() -> bool:
     """Register the process-wide compile listener (idempotent).
-    Returns True if the listener is active."""
+    Returns True once the listener is active."""
     global _installed
     with _install_lock:
         if _installed:
             return True
-        try:
-            from jax._src import monitoring as _mon
-        except Exception as e:                  # jax moved the API
-            logging.getLogger(__name__).warning(
-                "telemetry: jax monitoring unavailable (%r); global "
-                "compile counting disabled (per-program watch() still "
-                "works)", e)
-            return False
+        # the public jax.monitoring API; if jax ever drops it this
+        # import raises — a listener that silently counts nothing
+        # would make "zero compiles in the window" trivially true
+        import jax.monitoring as _mon
         from . import _metrics, flight as _fl
         from .registry import SECONDS_BUCKETS as _SECONDS
 

@@ -2,15 +2,18 @@
 regression and PASS an unchanged baseline (ISSUE 3 acceptance; the
 model-level sibling of tests/test_opperf_gate.py).
 
-The fast tests drive the real CLI through ``--replay`` (pure
-measure-file-vs-baseline compare — deterministic, no model runs), so
-the 10%-regression contract is tier-1. The slow test runs the live
-measurement path end to end on the CPU-safe smoke config with an
+The fast tests drive the gate CLI's ``main_gate`` through
+``--replay`` (pure measure-file-vs-baseline compare — deterministic,
+no model runs, in this process: a fresh interpreter per case cost the
+tier-1 budget half a minute), so the 10%-regression contract is
+tier-1. The slow test runs the real CLI and the live measurement path
+end to end on the CPU-safe smoke config with an
 MXTPU_BENCH_INJECT-seeded slowdown."""
 import json
 import os
 import subprocess
 import sys
+import types
 
 import pytest
 
@@ -28,6 +31,22 @@ def _gate(args, inject=""):
         capture_output=True, text=True, timeout=900, env=env, cwd=REPO)
 
 
+def _replay(capsys, run, base):
+    """``bench.py gate --replay run --baseline base`` without the
+    interpreter start-up."""
+    sys.path.insert(0, REPO)
+    import bench
+    capsys.readouterr()
+    try:
+        rc = bench.main_gate(["--replay", run, "--baseline", base])
+    except SystemExit as e:
+        rc = e.code
+    finally:
+        sys.path.remove(REPO)
+    return types.SimpleNamespace(returncode=rc,
+                                 stdout=capsys.readouterr().out)
+
+
 def _write(path, configs, tolerance=1.05):
     with open(path, "w") as f:
         json.dump({"configs": configs, "tolerance": tolerance}, f)
@@ -41,60 +60,62 @@ BASE = {
 }
 
 
-def test_gate_replay_passes_unchanged_baseline(tmp_path):
+def test_gate_replay_passes_unchanged_baseline(tmp_path, capsys):
     base = _write(tmp_path / "base.json", BASE)
     run = _write(tmp_path / "run.json", BASE)
-    out = _gate(["--replay", run, "--baseline", base])
-    assert out.returncode == 0, (out.stdout[-800:], out.stderr[-500:])
+    out = _replay(capsys, run, base)
+    assert out.returncode == 0, out.stdout[-800:]
     assert "bench_gate: OK" in out.stdout
 
 
-def test_gate_replay_flags_10pct_regression(tmp_path):
+def test_gate_replay_flags_10pct_regression(tmp_path, capsys):
     base = _write(tmp_path / "base.json", BASE)
     slowed = {k: dict(v, step_ms=round(v["step_ms"] * 1.10, 2))
               for k, v in BASE.items()}
     run = _write(tmp_path / "run.json", slowed)
-    out = _gate(["--replay", run, "--baseline", base])
+    out = _replay(capsys, run, base)
     assert out.returncode == 1, out.stdout[-800:]
     assert "REGRESSION" in out.stdout
     # one regressed config among healthy ones is still a failure
     one = dict(BASE, resnet50_s2d=dict(BASE["resnet50_s2d"],
                                        step_ms=round(95.0 * 1.10, 2)))
     run = _write(tmp_path / "run.json", one)
-    out = _gate(["--replay", run, "--baseline", base])
+    out = _replay(capsys, run, base)
     assert out.returncode == 1
     assert "REGRESSION resnet50_s2d" in out.stdout
 
 
-def test_gate_replay_missing_config_fails_and_new_config_passes(tmp_path):
+def test_gate_replay_missing_config_fails_and_new_config_passes(
+        tmp_path, capsys):
     base = _write(tmp_path / "base.json", BASE)
     # missing: the baseline is a contract
     run = _write(tmp_path / "run.json",
                  {k: v for k, v in BASE.items() if k != "bert_base"})
-    out = _gate(["--replay", run, "--baseline", base])
+    out = _replay(capsys, run, base)
     assert out.returncode == 1
     assert "MISSING bert_base" in out.stdout
     # extra configs (e.g. a new stem variant awaiting its first chip
     # measurement) are reported but do not gate
     run = _write(tmp_path / "run.json",
                  dict(BASE, llama_509m={"step_ms": 252.5}))
-    out = _gate(["--replay", run, "--baseline", base])
+    out = _replay(capsys, run, base)
     assert out.returncode == 0
     assert "new llama_509m" in out.stdout
 
 
-def test_committed_baseline_is_gateable():
-    """The checked-in baseline must parse and replay-pass against
-    itself — the exact file ci/runtime_functions.sh bench_gate ships
-    to a chip box."""
-    path = os.path.join(REPO, "benchmark", "baseline_models.json")
-    doc = json.load(open(path))
-    assert doc["configs"], "committed baseline has no configs"
-    for name, rec in doc["configs"].items():
-        assert rec["step_ms"] > 0, (name, rec)
-    assert 1.0 < doc.get("tolerance", 1.25) <= 2.0
-    out = _gate(["--replay", path, "--baseline", path])
+def test_committed_baseline_is_gateable(tmp_path, capsys):
+    """A baseline in the shape ``gate --update`` writes on the gated
+    machine (meta and provenance beside the configs, the default band)
+    must parse and replay-pass against itself. None is committed: the
+    machine that measures takes its own."""
+    path = str(tmp_path / "baseline_models.json")
+    with open(path, "w") as f:
+        json.dump({"configs": BASE, "tolerance": 1.25,
+                   "meta": {"device_kind": "TPU v5 lite", "n_devices": 1},
+                   "_provenance": "bench.py gate --update"}, f)
+    out = _replay(capsys, path, path)
     assert out.returncode == 0, out.stdout[-800:]
+    assert "3 configs within 1.25x" in out.stdout
 
 
 @pytest.mark.slow

@@ -1,0 +1,79 @@
+"""``chip_smoke.py`` off the chip: its ``train`` and ``serve`` phase
+functions run at ``tiny`` widths on the CPU backend when called
+directly (so a break in them shows here, not on a paid chip call), and
+``python chip_smoke.py`` itself cannot pass without a TPU. Also the
+compile-cache helper it and ``bench.py`` share."""
+import os
+import subprocess
+import sys
+from dataclasses import replace
+
+import jax
+
+import llama_refs
+from mxtpu import runtime
+from mxtpu.models import llama
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+sys.path.insert(0, REPO)
+import chip_smoke  # noqa: E402
+
+
+def test_train_phase_runs_tiny_on_cpu():
+    cfg = replace(llama.CONFIGS["tiny"], attn_impl="flash", remat=True,
+                  remat_policy="dots_no_batch", max_seq_len=64)
+    info = chip_smoke.phase_train(cfg, 8, 64, 3, expect_attn="blockwise")
+    # off the chip the program holds the scan and the phase says so;
+    # main() asks for "pallas", which only a TPU program can hold
+    assert info["attention"] == "blockwise"
+    assert info["loss"][1] < info["loss"][0]
+
+
+def test_serve_phase_runs_tiny_on_cpu():
+    cfg = llama_refs.serve_config()
+    jobs = chip_smoke.make_jobs(
+        cfg.vocab_size, ((5, 6, 0.0), (12, 8, 0.7), (12, 4, 0.0)),
+        per_shape=2, shared_prefix=9)
+    info, streams = chip_smoke.phase_serve(
+        cfg, jobs, max_slots=2, max_len=32, min_bucket=4, page_size=8,
+        n_pages=25, must_match=True)   # float32, highest (conftest)
+    assert [len(s) for s in streams] == [j["mnew"] for j in jobs]
+    assert info["compiles"] <= info["compile_bound"]
+    assert info["prefix_hits"] >= 1 and info["identical"] == "6/6"
+
+
+def test_main_fails_without_a_chip():
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode != 0
+    assert "no TPU" in out.stderr and "FAIL device" in out.stdout
+    assert '"ok"' not in out.stdout
+
+
+def test_compile_cache_helper(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the helper leaves jax's own
+    setting alone; unset, it names one fixed directory inside the
+    checkout — the same from another process."""
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert runtime.use_compile_cache() == \
+        jax.config.jax_compilation_cache_dir
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    runtime.use_compile_cache()
+    want = os.path.join(REPO, ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", want)]
+
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from mxtpu import runtime; print(runtime.use_compile_cache())"],
+        capture_output=True, text=True, timeout=120, cwd="/",
+        env={**env, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO})
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split()[-1] == want

@@ -12,6 +12,27 @@ pytestmark = pytest.mark.skipif(not native.available(),
                                 reason="libmxtpu build unavailable")
 
 
+def test_library_is_built_from_the_source_git_holds(monkeypatch):
+    """The library's name is a hash of libmxtpu.cc, so a binary built
+    from other source is never loaded; a failed build raises where a
+    native component is asked for by name and answers False where the
+    Python path is the alternative."""
+    import hashlib
+    with open(os.path.join(native._SRC_DIR, "libmxtpu.cc"), "rb") as f:
+        tag = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert os.path.basename(native._build()) == f"libmxtpu-{tag}.so"
+
+    def no_compiler():
+        raise OSError("g++: not found")
+
+    monkeypatch.setattr(native, "_LIB", None)
+    monkeypatch.setattr(native, "_LIB_ERROR", None)
+    monkeypatch.setattr(native, "_build", no_compiler)
+    assert native.available() is False
+    with pytest.raises(RuntimeError, match="g\\+\\+: not found"):
+        native.NativeRecordReader("x.rec")
+
+
 @pytest.fixture(scope="module")
 def rec_file(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("native")

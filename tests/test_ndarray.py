@@ -115,6 +115,19 @@ def test_concat_stack_split():
     assert_almost_equal(parts[0], a.asnumpy())
 
 
+def test_accelerator_context_past_last_chip_raises(monkeypatch):
+    """mx.tpu(i) names chip i or nothing: an id past the last chip
+    must not quietly become chip 0. cpu ids stay nominal."""
+    import jax
+    from mxtpu import context
+    one = jax.devices()[:1]
+    monkeypatch.setattr(context, "_devices_of_type", lambda t: one)
+    assert mx.tpu(0).jax_device() is one[0]
+    with pytest.raises(RuntimeError, match="past the last"):
+        mx.tpu(1).jax_device()
+    assert mx.cpu(3).jax_device() is one[0]
+
+
 def test_astype_context():
     a = mx.nd.ones((2, 2))
     b = a.astype("float16")

@@ -2,7 +2,7 @@
 pass clean (VERDICT r4 #3 'done' criterion). Runs the compare logic on
 the CPU backend against a freshly-made baseline so the test is
 platform-independent; the real CI gate compares the chip sweep against
-the committed ``benchmark/opperf/baseline_tpu.json``."""
+a ``benchmark/opperf/baseline_tpu.json`` taken on the gated machine."""
 import json
 import os
 import subprocess

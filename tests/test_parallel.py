@@ -7,10 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # older jax: experimental location
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from mxtpu import parallel as par
@@ -187,6 +184,23 @@ def test_flash_attention_dispatches(qkv):
     ref = dense_attention(q, k, v, causal=True)
     out = flash_attention(q, k, v, causal=True)
     assert np.allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
+
+
+def test_flash_attention_kernel_failure_raises(qkv, monkeypatch):
+    """Where the shapes pick the Pallas kernel, its failure is the
+    caller's: the blockwise scan must not stand in for it (a cell
+    would time the stand-in as "flash attention")."""
+    from mxtpu.ops import attention
+    q, k, v = qkv
+    assert attention.flash_path(q.shape, k.shape[2]) == "blockwise"
+
+    def boom(*a):
+        raise RuntimeError("mosaic refused the block shapes")
+
+    monkeypatch.setattr(attention, "flash_path", lambda *a: "pallas")
+    monkeypatch.setattr(attention, "_tpu_pallas_flash", boom)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        flash_attention(q, k, v, causal=True)
 
 
 @pytest.mark.parametrize("causal", [False, True])

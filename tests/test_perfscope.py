@@ -62,7 +62,8 @@ def test_spec_for_and_overrides(monkeypatch):
     assert ps.spec_for("TPU v5e").kind == "v5e"
     assert ps.spec_for("TPU v5p something").kind == "v5p"
     assert ps.spec_for("cpu").kind == "cpu"
-    assert ps.spec_for("martian silicon") is ps._FALLBACK
+    with pytest.raises(KeyError, match="no-such-chip"):
+        ps.spec_for("no-such-chip")     # not someone else's peaks
     # the MXTPU_TELEMETRY_PERF_PEAK_FLOPS knob (read at import)
     # overrides the table's peak; everything else stays
     monkeypatch.setattr(ps, "_PEAK_FLOPS", 123e12)

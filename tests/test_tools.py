@@ -48,6 +48,30 @@ def test_launch_local_env_wiring(tmp_path):
         assert content[1] == "127.0.0.1"
 
 
+def test_launch_local_refuses_to_share_chips(monkeypatch):
+    """A chip belongs to one process: N local workers that would all
+    open this host's chips are refused with the reason, workers pinned
+    to the CPU are not, and the launcher asks a child instead of
+    importing jax itself."""
+    import types
+    from tools import launch
+    assert "jax" not in vars(launch)
+    probes = []
+
+    def fake_run(cmd, env=None, **kw):
+        probes.append(cmd)
+        return types.SimpleNamespace(returncode=0, stdout="tpu\n",
+                                     stderr="")
+
+    monkeypatch.setattr(launch.subprocess, "run", fake_run)
+    launch._refuse_shared_chips({"JAX_PLATFORMS": "cpu"}, 4)
+    launch._refuse_shared_chips({}, 1)
+    assert probes == []
+    with pytest.raises(SystemExit, match="one process at a time"):
+        launch._refuse_shared_chips({}, 4)
+    assert "jax.devices()" in probes[0][-1]
+
+
 @pytest.mark.slow
 def test_dist_sync_kvstore_invariants(tmp_path):
     """After a synchronized push from W workers, the pulled value is
@@ -681,7 +705,7 @@ def test_bandwidth_probe_runs_on_virtual_mesh():
     """VERDICT r4 weak #6: the psum-sweep measurement path must
     EXECUTE on the virtual 8-device mesh (harness correctness — the
     GB/s number is meaningless on CPU, but the shard_map/fori_loop/
-    fence machinery must not be dead code until real multi-chip)."""
+    fence machinery is rehearsed here; PR 21 ran it on four chips)."""
     out = subprocess.run(
         [sys.executable,
          os.path.join(REPO, "tools", "bandwidth", "measure.py"),

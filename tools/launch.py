@@ -10,7 +10,11 @@ invocations port verbatim:
     python tools/launch.py -n 4 --launcher local python train.py
 
 Launchers: local (fork N processes on this host) and ssh (one process
-per host from --host-file).
+per host from --host-file). A chip belongs to one process at a time
+and one process drives every chip of its host through the mesh, so
+``local`` with more than one worker is a CPU rehearsal of the
+multi-process protocol: on a host with chips it refuses unless the
+workers are pinned to the CPU (``--env JAX_PLATFORMS=cpu``).
 """
 from __future__ import annotations
 
@@ -30,9 +34,35 @@ def _free_port() -> int:
     return port
 
 
+def _refuse_shared_chips(env, n_workers):
+    """Exit when ``n_workers`` local workers started with ``env`` would
+    all open this host's chips. What jax would run on is asked of a
+    short-lived child — the launcher never imports jax itself, or it
+    would hold the chip its workers need."""
+    if n_workers < 2 or env.get("JAX_PLATFORMS", "").startswith("cpu"):
+        return
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.devices()[0].platform)"],
+        env=env, capture_output=True, text=True, timeout=300)
+    if probe.returncode != 0:
+        raise SystemExit("launch.py: cannot tell which devices the "
+                         f"workers would open:\n{probe.stderr[-2000:]}")
+    platform = probe.stdout.split()[-1]
+    if platform != "cpu":
+        raise SystemExit(
+            f"launch.py: refusing to start {n_workers} local workers: "
+            f"each would open every {platform} chip of this host, and "
+            "a chip belongs to one process at a time (the others fail "
+            "or hang). One process drives all of a host's chips "
+            "through the mesh (mxtpu.parallel.create_mesh); to "
+            "rehearse the multi-process protocol here, pin the workers "
+            "to the CPU with --env JAX_PLATFORMS=cpu.")
+
+
 def launch_local(args, command):
     port = args.port or _free_port()
-    procs = []
+    envs = []
     for rank in range(args.num_workers):
         env = dict(os.environ)
         env.update({
@@ -47,7 +77,9 @@ def launch_local(args, command):
             for kv in args.env:
                 k, _, v = kv.partition("=")
                 env[k] = v
-        procs.append(subprocess.Popen(command, env=env))
+        envs.append(env)
+    _refuse_shared_chips(envs[0], args.num_workers)
+    procs = [subprocess.Popen(command, env=env) for env in envs]
     code = 0
 
     def _kill(*_):
