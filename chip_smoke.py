@@ -208,6 +208,7 @@ def phase_train(cfg, batch, seq, steps, mesh_axes=None,
     import optax
     from mxtpu.models import llama
     from mxtpu.parallel import mesh as pmesh, step as pstep
+    from mxtpu.telemetry import perfscope
 
     t0 = time.perf_counter()
     mesh = pmesh.create_mesh(**(mesh_axes or {"dp": -1}))
@@ -252,11 +253,25 @@ def phase_train(cfg, batch, seq, steps, mesh_axes=None,
     if held == "pallas":
         assert "flash_attention_pallas" in text
 
+    # the watcher cataloged the step when it compiled, from the
+    # executable that call built: cataloging again builds nothing
+    cost = perfscope.catalog().get("train_step")
+    assert cost is not None and cost.flops > 0 and cost.peak_hbm_bytes, \
+        f"perfscope holds no costs for the train step: {cost}"
+    again = perfscope.profile_program(
+        train_step._jitted, "train_step", (state, batch_d, None))
+    assert again is not None and again.flops == cost.flops, again
+    assert _compiles() == c1, "cataloging the step compiled a program"
+
     info = {"setup_s": setup_s, "run_s": run_s,
             "mesh": {a: n for a, n in mesh.shape.items() if n > 1}
             or {"dp": 1},
             "loss": [round(l, 3) for l in (losses[0], losses[-1])],
-            "steps": steps, "attention": held}
+            "steps": steps, "attention": held,
+            "catalog_per_device": {
+                "gflop": round(cost.flops / 1e9, 1),
+                "gb_accessed": round(cost.bytes_accessed / 1e9, 2),
+                "peak_hbm_gb": round(cost.peak_hbm_bytes / 1e9, 2)}}
     if kernels:
         info["mosaic_calls"] = len(kernels)
         info["kernel_operand"] = kernels[0]
@@ -334,7 +349,9 @@ def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
     from mxtpu.serve import ServeEngine
     from mxtpu.serve.engine import bucket_for
     from mxtpu.serve.gateway import Gateway, GatewayClient
+    from mxtpu.telemetry import perfscope
 
+    seen = {p: c.variants for p, c in perfscope.catalog().items()}
     # a cold compile may outlast the supervisor's default stall
     # threshold; a replica restarted mid-compile never finishes one
     gw = Gateway(lambda: ServeEngine(
@@ -371,6 +388,14 @@ def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
         compiled = engine.compile_count
         assert engine.n_buckets == len(buckets), (engine.n_buckets, buckets)
         assert compiled <= bound, (compiled, bound)
+        # each of them cataloged from its executable as it compiled
+        programs = ["serve_decode", "serve_copy_page"] + [
+            f"serve_prefill_b{b}" for b in buckets]
+        now = perfscope.catalog()
+        uncataloged = [p for p in programs if p not in now
+                       or now[p].variants <= seen.get(p, 0)
+                       or not now[p].peak_hbm_bytes]
+        assert not uncataloged, f"perfscope holds no costs for {uncataloged}"
         c_warm = _compiles()
         setup_s = time.perf_counter() - t0
 

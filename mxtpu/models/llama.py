@@ -38,7 +38,7 @@ from ..ops.attention import (flash_attention, dense_attention,
                              ring_attention, ulysses_attention,
                              slot_decode_attention,
                              paged_decode_attention)
-from ..parallel.sharding import ShardingRules, _filter_spec, constrain
+from ..parallel.sharding import ShardingRules, constrain
 from ..parallel.sharding import mcon as _mcon
 
 __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
@@ -257,17 +257,32 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh: Optional[Mesh]):
     if cfg.attn_impl == "dense":
         return dense_attention(q, k, v, causal=True)
     flash = partial(flash_attention, causal=True)
-    if mesh is not None and mesh.size > 1:
-        # the Pallas kernel is a custom call XLA cannot partition: left
-        # to GSPMD it would run replicated on gathered operands. Each
-        # device runs it on its own batch rows and heads instead (the
-        # sequence stays whole — that is what ring/ulysses are for).
-        spec = _filter_spec(P(("dp", "fsdp"), "tp", None, None),
-                            mesh.axis_names)
+    if mesh is not None:
+        # the Pallas kernel is a Mosaic custom call, and XLA refuses to
+        # partition one: on four v5e chips the step does not compile
+        # without this ("Mosaic kernels cannot be automatically
+        # partitioned. Please wrap the call in a shard_map.")
+        spec = _flash_spec(mesh, q.shape[0], k.shape[1])
         flash = jax.shard_map(flash, mesh=mesh,
                               in_specs=(spec, spec, spec),
                               out_specs=spec, check_vma=False)
     return flash(q, k, v)
+
+
+def _flash_spec(mesh: Mesh, batch: int, kv_heads: int) -> P:
+    """How flash attention's (batch, heads, seq, hd) operands are split
+    over ``mesh``: batch rows over dp x fsdp and heads over tp, so each
+    device runs the kernel on its own rows and heads (the sequence
+    stays whole — that is what ring/ulysses are for). An axis whose
+    size does not divide its dimension is left out, and that dimension
+    stays whole on every device; ``kv_heads`` is the smaller head count
+    under GQA, and q's is a multiple of it."""
+    rows = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
+    if batch % math.prod(mesh.shape[a] for a in rows):
+        rows = ()
+    heads = "tp" if "tp" in mesh.axis_names \
+        and kv_heads % mesh.shape["tp"] == 0 else None
+    return P(rows or None, heads, None, None)
 
 
 def _layer(cfg: LlamaConfig, mesh, cos, sin, x, lp):
@@ -517,7 +532,9 @@ def loss_fn(cfg: LlamaConfig, mesh: Optional[Mesh] = None):
     """Causal-LM loss for ``parallel.step.make_train_step``: batch is a
     dict with 'tokens' (b, s) and optional 'mask' (b, s) — predicts
     token t+1 from prefix ≤ t. Large vocabs take the chunked-CE path
-    (see ``chunked_softmax_xent``)."""
+    (see ``chunked_softmax_xent``). Pass the step's ``mesh``: ring and
+    ulysses attention need it, and so does flash attention on more
+    than one TPU chip (its kernel runs per device under shard_map)."""
     def loss(params, batch):
         tokens = batch["tokens"]
         x, moe_aux = forward_hidden(cfg, params, tokens, mesh=mesh,

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
-           "flash_path", "ulysses_attention",
+           "ulysses_attention",
            "ring_attention", "slot_decode_attention",
            "paged_decode_attention"]
 
@@ -173,13 +173,12 @@ def _tpu_pallas_flash(q, k, v, causal, scale):
                      block_sizes=bs)
 
 
-def flash_path(q_shape, kv_len: int) -> str:
+def _flash_path(q_shape, kv_len: int) -> str:
     """Which implementation :func:`flash_attention` runs for these
     shapes on this backend: ``"pallas"`` (the Mosaic kernel — TPU
     only, and it wants both sequence lengths and the head dim in
     multiples of 128) or ``"blockwise"`` (the ``lax.scan`` online
-    softmax). Decided from backend and shapes alone, so a caller can
-    ask before timing anything."""
+    softmax). Decided from backend and shapes alone."""
     if len(q_shape) == 4 and jax.default_backend() == "tpu":
         sq, d = q_shape[2], q_shape[3]
         if sq % 128 == 0 and kv_len % 128 == 0 and d % 128 == 0:
@@ -191,14 +190,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     kv_block: int = 512):
     """Fused attention: Pallas (Mosaic) kernel on TPU, blockwise scan
-    elsewhere (:func:`flash_path` says which). This is the rebuild's
+    elsewhere (:func:`_flash_path` says which). This is the rebuild's
     hot-path attention op — the role cuDNN's fused MHA played in the
     reference. Each path runs under its own named scope, so a lowered
     program or a trace shows which one it holds; a kernel failure
     raises."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     kr, vr = _repeat_kv(q, k, v)
-    if flash_path(q.shape, kr.shape[2]) == "pallas":
+    if _flash_path(q.shape, kr.shape[2]) == "pallas":
         with jax.named_scope("flash_attention_pallas"):
             return _tpu_pallas_flash(q, kr, vr, causal, scale)
     with jax.named_scope("flash_attention_blockwise"):

@@ -7,23 +7,21 @@ waste) were computed inside ``bench.py`` and thrown away. This module
 makes them an always-on runtime layer on the PR 5/PR 8 telemetry
 substrate:
 
-- **program cost catalog** — :func:`profile_program` runs XLA
-  ``cost_analysis()`` once per compiled variant of a watched program
-  (``telemetry.watch`` calls it on every observed compile, so the
-  train step, the fused step, and every serve program get it for
-  free) and publishes ``mxtpu_program_flops``,
-  ``mxtpu_program_bytes_accessed``, arithmetic intensity, and a
-  roofline class (``compute_bound`` vs ``memory_bound`` at the
-  device's FLOP/byte knee). Costs come from the CACHED lowering
-  (``fn.lower`` after a call re-traces from the tracing cache — no
-  second XLA compile); ``memory_analysis()`` needs a compiled object,
-  so ``mxtpu_program_peak_hbm_bytes`` is published for AOT-compiled
-  programs (:func:`program_costs`) always, and for watched jitted
-  programs only under ``MXTPU_TELEMETRY_PERF_MEMORY=1`` (it forces a
-  second full XLA compile per variant). libtpu gives an uncompiled
-  program no cost analysis at all, so on the chip that knob is also
-  what fills the catalog; without it a watched program logs once that
-  it stays uncataloged.
+- **program cost catalog** — :func:`profile_program` reads XLA's
+  ``cost_analysis()`` and ``memory_analysis()`` once per compiled
+  variant of a watched program (``telemetry.watch`` calls it on every
+  observed compile, so the train step, the fused step, and every serve
+  program get it for free) and publishes ``mxtpu_program_flops``,
+  ``mxtpu_program_bytes_accessed``, ``mxtpu_program_peak_hbm_bytes``,
+  arithmetic intensity, and a roofline class (``compute_bound`` vs
+  ``memory_bound`` at the device's FLOP/byte knee). Both analyses come
+  from the EXECUTABLE, on every backend (libtpu has none for an
+  uncompiled program): ``fn.lower`` after a call returns jax's cached
+  lowering, which holds the executable that call just built, so
+  ``.compile()`` on it builds nothing. An executable is one device's
+  partition of the program, so the catalog's numbers are PER DEVICE
+  and the gauges divide by one device's peak. AOT paths pass their
+  ``Compiled`` to :func:`program_costs`.
 - **live MFU / MBU** — :meth:`PerfScope.on_call` keeps a rolling
   window of inter-dispatch gaps per program. Dispatch itself is async
   (host time is microseconds), but the gap between consecutive
@@ -102,12 +100,6 @@ _IDLE_S = env_float(
     "A dispatch gap longer than this is the loop being IDLE (parked "
     "serve engine between requests), not a slow step: the program's "
     "rolling window resets instead of flagging an anomaly.")
-_MEMORY = env_bool(
-    "MXTPU_TELEMETRY_PERF_MEMORY", False,
-    "Also run memory_analysis() (peak HBM) on watched jitted programs "
-    "at compile time. Costs a SECOND full XLA compile per variant — "
-    "AOT paths (bench gates) always get it for free via "
-    "program_costs().")
 _PEAK_FLOPS = env_float(
     "MXTPU_TELEMETRY_PERF_PEAK_FLOPS", 0.0,
     "Override the device's peak FLOP/s for MFU/roofline math "
@@ -228,8 +220,9 @@ def roofline_class(flops: float, bytes_accessed: float,
 # -- program cost catalog --------------------------------------------------
 @dataclass
 class ProgramCost:
-    """One watched program's XLA cost-model summary (latest compiled
-    variant; ``variants`` counts how many signatures were seen)."""
+    """One watched program's XLA cost-model summary, per device (latest
+    compiled variant; ``variants`` counts how many signatures were
+    seen)."""
     name: str
     flops: float
     bytes_accessed: float
@@ -254,14 +247,13 @@ class ProgramCost:
 def _extract_costs(obj) -> Tuple[float, float, float]:
     """flops / bytes accessed / transcendentals from either AOT shape
     of ``cost_analysis()``: a Compiled returns a list of per-module
-    dicts, a Lowered returns one flat dict."""
+    dicts (or one dict), a Lowered returns one flat dict — or, on
+    libtpu, nothing: there only an executable has costs."""
     ca = obj.cost_analysis()
     if ca is None:
-        # libtpu: only a compiled program has costs
         raise ValueError(
             "this backend gives no cost analysis before compilation; "
-            "MXTPU_TELEMETRY_PERF_MEMORY=1 catalogs from the compiled "
-            "program (a second compile per variant)")
+            "pass the compiled program (lowered.compile())")
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}
     return (float(ca.get("flops", 0.0) or 0.0),
@@ -520,24 +512,24 @@ class PerfScope:
     def profile_program(self, fn_or_compiled, name: str,
                         args: tuple = (), kwargs: Optional[dict] = None
                         ) -> Optional[ProgramCost]:
-        """Catalog one program. Accepts an AOT ``Lowered``/``Compiled``
-        (costs read directly) or a jitted callable + the call's args
-        (``fn.lower`` re-traces from the tracing cache — cheap, and
-        safe even when the args were just donated: lowering only
-        reads shape/dtype/sharding metadata, which survives
-        deletion)."""
+        """Catalog one program from its executable. Accepts an AOT
+        ``Compiled``, a ``Lowered`` (compiled here), or a jitted
+        callable + the args of a call it has just served: ``fn.lower``
+        re-traces from the tracing cache and returns the cached
+        lowering, whose ``.compile()`` hands back the executable that
+        call built — no second XLA compile. It is safe even when the
+        args were just donated: lowering only reads shape/dtype/
+        sharding metadata, which survives deletion."""
         if not self._on():
             return None
         try:
             obj = fn_or_compiled
             if not hasattr(obj, "cost_analysis"):
                 obj = obj.lower(*args, **(kwargs or {}))
-            if _MEMORY and hasattr(obj, "compile"):
-                # knob-gated: this is a SECOND full XLA compile
+            if hasattr(obj, "compile"):
                 obj = obj.compile()
             flops, nbytes, trans = _extract_costs(obj)
-            mem = (_extract_memory(obj)
-                   if hasattr(obj, "memory_analysis") else {})
+            mem = _extract_memory(obj)
             cost = ProgramCost(
                 name=name, flops=flops, bytes_accessed=nbytes,
                 transcendentals=trans, spec=self.spec(),
@@ -563,7 +555,8 @@ class PerfScope:
             lbl = {"program": cost.name}
             m.gauge("program_flops",
                     "XLA cost-model FLOPs per execution of the "
-                    "program (whole mesh)", **lbl).set(cost.flops)
+                    "program (one device's partition)",
+                    **lbl).set(cost.flops)
             m.gauge("program_bytes_accessed",
                     "XLA cost-model bytes accessed per execution",
                     **lbl).set(cost.bytes_accessed)
@@ -654,22 +647,20 @@ class PerfScope:
         mean_gap = sum(w.gaps) / len(w.gaps)
         cost = self.catalog.get(name)
         if cost is not None and mean_gap > 0:
-            import jax
             sp = self.spec()
-            # catalog flops are whole-mesh, so the peak is too
-            n_dev = max(1, jax.device_count())
+            # catalog costs are one device's partition, so is the peak
             m.gauge("mfu",
                     "Live model-FLOPs utilization over the rolling "
                     "window (catalog flops / mean dispatch gap / "
                     "device peak)", program=name).set(
                         mfu(cost.flops, mean_gap,
-                            peak_flops=sp.peak_flops * n_dev))
+                            peak_flops=sp.peak_flops))
             m.gauge("hbm_bw_util",
                     "Live HBM-bandwidth utilization over the rolling "
                     "window (catalog bytes / mean dispatch gap / "
                     "device peak bandwidth)", program=name).set(
                         hbm_bw_util(cost.bytes_accessed, mean_gap,
-                                    peak_bw=sp.peak_bw * n_dev))
+                                    peak_bw=sp.peak_bw))
         loop = self._loops.get(name)
         if loop:
             med = _median(list(w.gaps))

@@ -2,12 +2,13 @@
 regression and PASS an unchanged baseline (ISSUE 3 acceptance; the
 model-level sibling of tests/test_opperf_gate.py).
 
-The fast tests drive the gate CLI's ``main_gate`` through
-``--replay`` (pure measure-file-vs-baseline compare — deterministic,
-no model runs, in this process: a fresh interpreter per case cost the
-tier-1 budget half a minute), so the 10%-regression contract is
-tier-1. The slow test runs the real CLI and the live measurement path
-end to end on the CPU-safe smoke config with an
+The fast tests go through ``--replay`` (pure measure-file-vs-baseline
+compare — deterministic, no model runs), so the 10%-regression
+contract is tier-1. One case runs the real CLI, so the ``bench.py
+gate`` dispatch and its exit code stay covered; the rest call
+``main_gate`` in this process (a fresh interpreter per case cost the
+tier-1 budget half a minute). The slow test runs the live measurement
+path end to end on the CPU-safe smoke config with an
 MXTPU_BENCH_INJECT-seeded slowdown."""
 import json
 import os
@@ -19,6 +20,8 @@ import pytest
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 BENCH = os.path.join(REPO, "bench.py")
+sys.path.insert(0, REPO)
+import bench  # noqa: E402
 
 
 def _gate(args, inject=""):
@@ -34,15 +37,11 @@ def _gate(args, inject=""):
 def _replay(capsys, run, base):
     """``bench.py gate --replay run --baseline base`` without the
     interpreter start-up."""
-    sys.path.insert(0, REPO)
-    import bench
     capsys.readouterr()
     try:
         rc = bench.main_gate(["--replay", run, "--baseline", base])
     except SystemExit as e:
         rc = e.code
-    finally:
-        sys.path.remove(REPO)
     return types.SimpleNamespace(returncode=rc,
                                  stdout=capsys.readouterr().out)
 
@@ -73,8 +72,9 @@ def test_gate_replay_flags_10pct_regression(tmp_path, capsys):
     slowed = {k: dict(v, step_ms=round(v["step_ms"] * 1.10, 2))
               for k, v in BASE.items()}
     run = _write(tmp_path / "run.json", slowed)
-    out = _replay(capsys, run, base)
-    assert out.returncode == 1, out.stdout[-800:]
+    # the real CLI: argv dispatch in main(), exit code to the shell
+    out = _gate(["--replay", run, "--baseline", base])
+    assert out.returncode == 1, (out.stdout[-800:], out.stderr[-500:])
     assert "REGRESSION" in out.stdout
     # one regressed config among healthy ones is still a failure
     one = dict(BASE, resnet50_s2d=dict(BASE["resnet50_s2d"],

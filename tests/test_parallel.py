@@ -192,15 +192,39 @@ def test_flash_attention_kernel_failure_raises(qkv, monkeypatch):
     would time the stand-in as "flash attention")."""
     from mxtpu.ops import attention
     q, k, v = qkv
-    assert attention.flash_path(q.shape, k.shape[2]) == "blockwise"
+    assert attention._flash_path(q.shape, k.shape[2]) == "blockwise"
 
     def boom(*a):
         raise RuntimeError("mosaic refused the block shapes")
 
-    monkeypatch.setattr(attention, "flash_path", lambda *a: "pallas")
+    monkeypatch.setattr(attention, "_flash_path", lambda *a: "pallas")
     monkeypatch.setattr(attention, "_tpu_pallas_flash", boom)
     with pytest.raises(RuntimeError, match="mosaic refused"):
         flash_attention(q, k, v, causal=True)
+
+
+@pytest.mark.parametrize("axes,batch", [
+    ({"dp": 2, "tp": 4}, 4),       # tp > n_kv_heads: heads stay whole
+    ({"fsdp": 4, "tp": 2}, 3),     # 4 does not divide 3 rows: they do
+    ({"dp": 2, "fsdp": 2, "tp": 2}, 4)])
+def test_llama_flash_on_a_mesh_matches_no_mesh(axes, batch):
+    """With ``mesh=`` flash attention runs per device under shard_map
+    (the Pallas kernel is a custom call); the layout is cut to what
+    divides, so every mesh the plain program accepts gives its loss."""
+    from dataclasses import replace
+    from mxtpu.models import llama
+    cfg = replace(llama.CONFIGS["tiny"], dtype=jnp.float32,
+                  attn_impl="flash", n_layers=1)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = {"tokens": jnp.asarray(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (batch, 32)), jnp.int32)}
+    want = jax.jit(llama.loss_fn(cfg))(params, tokens)
+    mesh = par.create_mesh(**axes)
+    with par.use_mesh(mesh):
+        got = jax.jit(llama.loss_fn(cfg, mesh))(
+            par.shard_pytree(params, mesh, llama.sharding_rules(cfg)),
+            tokens)
+    assert np.allclose(float(got), float(want), rtol=1e-5), (got, want)
 
 
 @pytest.mark.parametrize("causal", [False, True])

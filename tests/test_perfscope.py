@@ -104,6 +104,28 @@ def test_watched_elementwise_is_memory_bound():
     assert cost.klass == "memory_bound"
 
 
+def test_cataloging_reads_the_executable_the_call_built():
+    """The watcher catalogs from the executable (libtpu has no costs
+    for less), memory analysis included, and that must not be a second
+    compile: the lowering jax cached for the call holds it."""
+    tm.install_compile_listener()
+    f = tm.watch(jax.jit(lambda a, b: jnp.tanh(a @ b)), "ps_executable")
+    x = jnp.ones((64, 64), jnp.float32)
+    jax.block_until_ready(x)
+    before = tm.registry().value("jax_compile_total")
+    f(x, x).block_until_ready()
+    assert tm.registry().value("jax_compile_total") == before + 1
+    cost = ps.catalog()["ps_executable"]
+    assert cost.flops > 0 and cost.peak_hbm_bytes >= 3 * 64 * 64 * 4
+    # a bare Lowered has no costs on libtpu: program_costs says what to
+    # pass instead of cataloging nothing
+    class NoCosts:
+        def cost_analysis(self):
+            return None
+    with pytest.raises(ValueError, match="compiled"):
+        ps.program_costs(NoCosts())
+
+
 def test_program_costs_on_aot_compiled():
     """The bench path: an explicitly lowered+compiled program through
     the SAME helper, memory fields included (AOT has them for free),
@@ -134,9 +156,8 @@ def test_live_mfu_gauge_agrees_with_bench_helper():
         scope.on_call(name, i * 0.010, i * 0.010 + 0.001)
     w = scope._windows[name]
     mean_gap = sum(w.gaps) / len(w.gaps)
-    sp = scope.spec()
-    expect = ps.mfu(1e9, mean_gap,
-                    peak_flops=sp.peak_flops * jax.device_count())
+    # catalog costs are one device's partition, and so is the peak
+    expect = ps.mfu(1e9, mean_gap, peak_flops=scope.spec().peak_flops)
     assert tm.registry().value("mfu", program=name) == \
         pytest.approx(expect)
     assert expect > 0
@@ -292,7 +313,7 @@ def test_train_step_is_cataloged_on_compile():
     jax.block_until_ready(loss)
     cost = ps.catalog().get("train_step")
     assert cost is not None and cost.flops > 0
-    assert cost.bytes_accessed > 0
+    assert cost.bytes_accessed > 0 and cost.peak_hbm_bytes > 0
     # init_state accounted params + optimizer into the ledger
     bd = ps.ledger().breakdown()
     assert bd.get("params", 0) > 0
