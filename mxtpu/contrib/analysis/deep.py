@@ -134,6 +134,8 @@ def _deep_suppressions(source: str) -> Dict[int, Set[str]]:
 _SYNC_CTORS = {"Lock": "lock", "RLock": "rlock",
                "Condition": "condition", "Semaphore": "semaphore",
                "BoundedSemaphore": "semaphore"}
+# calls whose result is a jitted program
+_JIT_MAKERS = ("jit", "watch", "watch_jit", "pjit")
 _MUTATING_METHODS = {"append", "appendleft", "extend", "add", "insert",
                      "remove", "discard", "pop", "popleft", "clear",
                      "update", "setdefault", "reset", "sort",
@@ -536,12 +538,12 @@ def _scan_class(cls: ast.ClassDef, path: str,
                         model.event_attrs.add(attr)
                     elif chain[-1] == "Thread":
                         model.thread_attrs.add(attr)
-                    elif chain[-1] in ("jit", "watch", "pjit"):
+                    elif chain[-1] in _JIT_MAKERS:
                         model.jit_attrs.add(attr)
                 if fn.name == "__init__" and _clock_idiom(node.value):
                     model.clock_attr = attr
         # dict caches of jitted programs:
-        # ``self._prefills[bucket] = telemetry.watch(jax.jit(...))``
+        # ``self._prefills[bucket] = telemetry.watch_jit(...)``
         for node in ast.walk(fn):
             if isinstance(node, ast.Assign):
                 for t in node.targets:
@@ -551,8 +553,7 @@ def _scan_class(cls: ast.ClassDef, path: str,
                                  if isinstance(node.value, ast.Call)
                                  else None)
                         if attr is not None and chain is not None \
-                                and chain[-1] in ("jit", "watch",
-                                                  "pjit"):
+                                and chain[-1] in _JIT_MAKERS:
                             model.jit_attrs.add(attr)
     # pass 2: method scan with the held-lock stack
     for fn in cls.body:

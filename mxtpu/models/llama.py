@@ -37,7 +37,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..ops.attention import (flash_attention, dense_attention,
                              ring_attention, ulysses_attention,
                              slot_decode_attention,
-                             paged_decode_attention)
+                             paged_decode_attention,
+                             ATTENTION_SCOPE, KV_GATHER_SCOPE)
 from ..parallel.sharding import ShardingRules, constrain
 from ..parallel.sharding import mcon as _mcon
 
@@ -215,6 +216,20 @@ _QKV = P(("dp", "fsdp"), "tp", "sp", None)      # (batch, heads, seq, hd)
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+# Every piece of the block runs under a ``jax.named_scope``, spelled
+# once, in the helper the block's five copies (_layer, _layer_cached,
+# _layer_slots, _layer_slots_paged, _layer_slots_spec) share: embed,
+# norm, qkv_proj, rope, kv_write, kv_gather, attention, out_proj, mlp,
+# lm_head, sampler, xent. The compiled program keeps them in each
+# instruction's metadata, and ``telemetry.programs()`` maps a trace's
+# operations back to them, so train and serve report under one set of
+# names. They do not nest (but for the int8 prefill's read of old pages
+# inside kv_write), so an operation's scope is the first of its path.
+KV_WRITE_SCOPE = "kv_write"
+SAMPLER_SCOPE = "sampler"
+
+
+@jax.named_scope("norm")
 def rms_norm(x, weight, eps):
     x32 = x.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -230,6 +245,7 @@ def rope_tables(cfg: LlamaConfig, seq_len: int, offset: int = 0):
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
+@jax.named_scope("rope")
 def apply_rope(x, cos, sin):
     """x: (b, h, s, hd); rotate-half convention."""
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -238,6 +254,53 @@ def apply_rope(x, cos, sin):
     return out.astype(x.dtype)
 
 
+@jax.named_scope("embed")
+def _embed(cfg: LlamaConfig, params, tokens):
+    """Token rows of the embedding table in cfg.dtype. A weight-only
+    int8 table dequantises the GATHERED rows only (its scale is
+    per-dim-channel)."""
+    emb = params["tok_embed"]
+    if isinstance(emb, dict):
+        return emb["q8"][tokens].astype(cfg.dtype) * \
+            emb["s8"][0].astype(cfg.dtype)
+    return emb[tokens].astype(cfg.dtype)
+
+
+def _qkv(cfg: LlamaConfig, lp, h, cos, sin):
+    """The block's q/k/v projections of h (b, s, dim): q (b, n_heads,
+    s, hd), k and v (b, n_kv_heads, s, hd), rope on q and k."""
+    b, s, _ = h.shape
+    hd = cfg.head_dim
+    dt = cfg.dtype
+    with jax.named_scope("qkv_proj"):
+        q = (h @ _wq8(lp["wq"], dt)).reshape(b, s, cfg.n_heads, hd)
+        k = (h @ _wq8(lp["wk"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
+        v = (h @ _wq8(lp["wv"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
+        q = q.transpose(0, 2, 1, 3)          # (b, h, s, hd)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+@jax.named_scope("out_proj")
+def _out_proj(cfg: LlamaConfig, lp, o):
+    """Attention's output o (b, n_heads, s, hd) through wo: (b, s,
+    dim)."""
+    b, _, s, hd = o.shape
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
+    return o @ _wq8(lp["wo"], cfg.dtype)
+
+
+@jax.named_scope("lm_head")
+def _lm_head(cfg: LlamaConfig, params, x):
+    """x (b, s, dim) -> logits (b, s, V) in float32."""
+    hw = (_wq8(params["tok_embed"], cfg.dtype).T if cfg.tie_embeddings
+          else _wq8(params["lm_head"], cfg.dtype))
+    return jnp.einsum("bsd,dv->bsv", x, hw,
+                      preferred_element_type=jnp.float32)
+
+
+@jax.named_scope(ATTENTION_SCOPE)
 def _attention(cfg: LlamaConfig, q, k, v, mesh: Optional[Mesh]):
     sp_ok = mesh is not None and "sp" in mesh.axis_names
     if cfg.attn_impl in ("ring", "ulysses"):
@@ -287,25 +350,13 @@ def _flash_spec(mesh: Mesh, batch: int, kv_heads: int) -> P:
 
 def _layer(cfg: LlamaConfig, mesh, cos, sin, x, lp):
     """One transformer block. x: (b, s, dim) in cfg.dtype."""
-    b, s, _ = x.shape
-    hd = cfg.head_dim
-    dt = cfg.dtype
-
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"].astype(dt)).reshape(b, s, cfg.n_heads, hd)
-    k = (h @ lp["wk"].astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (h @ lp["wv"].astype(dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = q.transpose(0, 2, 1, 3)    # (b, h, s, hd)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _qkv(cfg, lp, h, cos, sin)
     q = constrain(q, *_QKV)
     k = constrain(k, *_QKV)
     v = constrain(v, *_QKV)
     o = _attention(cfg, q, k, v, mesh)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
-    x = x + constrain(o @ lp["wo"].astype(dt), *_ACT)
+    x = x + constrain(_out_proj(cfg, lp, o), *_ACT)
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     delta, aux = _ffn(cfg, lp, h, mesh)
@@ -313,6 +364,7 @@ def _layer(cfg: LlamaConfig, mesh, cos, sin, x, lp):
     return x, aux
 
 
+@jax.named_scope("mlp")
 def _ffn(cfg: LlamaConfig, lp, h, mesh, serving: bool = False):
     """FFN residual delta: dense SwiGLU, or the MoE expert bank when
     ``cfg.moe_experts`` is set (expert parallelism over 'ep';
@@ -350,8 +402,7 @@ def forward_hidden(cfg: LlamaConfig, params, tokens,
     materializing (B, S, V) logits. With ``with_aux`` also returns the
     per-layer-mean MoE load-balancing aux (0 for dense configs)."""
     b, s = tokens.shape
-    x = params["tok_embed"][tokens].astype(cfg.dtype)
-    x = constrain(x, *_ACT)
+    x = constrain(_embed(cfg, params, tokens), *_ACT)
     cos, sin = rope_tables(cfg, s)
 
     layer = partial(_layer, cfg, mesh, cos, sin)
@@ -461,10 +512,7 @@ def forward(cfg: LlamaConfig, params, tokens,
             mesh: Optional[Mesh] = None):
     """tokens: (batch, seq) int32 → logits (batch, seq, vocab) f32."""
     x = forward_hidden(cfg, params, tokens, mesh=mesh)
-    logits = jnp.einsum("bsd,dv->bsv", x,
-                        _head(cfg, params).astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
-    return constrain(logits, ("dp", "fsdp"), "sp", None)
+    return constrain(_lm_head(cfg, params, x), ("dp", "fsdp"), "sp", None)
 
 
 # ---------------------------------------------------------------------------
@@ -544,18 +592,20 @@ def loss_fn(cfg: LlamaConfig, mesh: Optional[Mesh] = None):
         mask = batch.get("mask")
         mask = (jnp.ones_like(targets, jnp.float32) if mask is None
                 else mask[:, 1:].astype(jnp.float32))
-        head = _head(cfg, params).astype(cfg.dtype)
         chunk = _resolve_ce_chunk(cfg)
-        if chunk:
-            nll = chunked_softmax_xent(x, head, targets, chunk)
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", x, head,
-                                preferred_element_type=jnp.float32)
-            logits = constrain(logits, ("dp", "fsdp"), "sp", None)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, targets[..., None],
-                                       axis=-1)[..., 0]
-        ce = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        # the head matmul, the softmax and the NLL, chunked or not
+        with jax.named_scope("xent"):
+            head = _head(cfg, params).astype(cfg.dtype)
+            if chunk:
+                nll = chunked_softmax_xent(x, head, targets, chunk)
+            else:
+                logits = jnp.einsum("bsd,dv->bsv", x, head,
+                                    preferred_element_type=jnp.float32)
+                logits = constrain(logits, ("dp", "fsdp"), "sp", None)
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                nll = -jnp.take_along_axis(logp, targets[..., None],
+                                           axis=-1)[..., 0]
+            ce = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
         if cfg.moe_experts:
             ce = ce + cfg.moe_aux_weight * moe_aux
         return ce
@@ -640,14 +690,7 @@ def _layer_cached(cfg: LlamaConfig, cos, sin, pos, max_len,
     dt = cfg.dtype
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ _wq8(lp["wq"], dt)).reshape(b, s, cfg.n_heads, hd)
-    k = (h @ _wq8(lp["wk"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (h @ _wq8(lp["wv"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = q.transpose(0, 2, 1, 3)          # (b, h, s, hd)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _qkv(cfg, lp, h, cos, sin)
     # pin the batch + head axes — the reshape/transpose chain above can
     # lose the propagated sharding, and a lost head sharding makes the
     # attention materialize the full cache per device. BOTH axes come
@@ -660,10 +703,11 @@ def _layer_cached(cfg: LlamaConfig, cos, sin, pos, max_len,
     q = _mcon(mesh, q, batch_ax, head_ax, None, None)
     k = _mcon(mesh, k, batch_ax, head_ax, None, None)
     v = _mcon(mesh, v, batch_ax, head_ax, None, None)
-    zero = jnp.zeros((), jnp.int32)
-    idx = (zero, zero, pos.astype(jnp.int32), zero)
-    ck = lax.dynamic_update_slice(ck, k.astype(dt), idx)
-    cv = lax.dynamic_update_slice(cv, v.astype(dt), idx)
+    with jax.named_scope(KV_WRITE_SCOPE):
+        zero = jnp.zeros((), jnp.int32)
+        idx = (zero, zero, pos.astype(jnp.int32), zero)
+        ck = lax.dynamic_update_slice(ck, k.astype(dt), idx)
+        cv = lax.dynamic_update_slice(cv, v.astype(dt), idx)
     if mesh is not None:
         from jax.sharding import NamedSharding
         ck = lax.with_sharding_constraint(
@@ -675,20 +719,19 @@ def _layer_cached(cfg: LlamaConfig, cos, sin, pos, max_len,
     # key j visible to query i iff j <= pos + i. GQA-native: group the
     # q heads per kv head instead of materializing repeated KV (the
     # repeat would copy the whole cache every layer, every step)
-    rep = cfg.n_heads // cfg.n_kv_heads
-    qg = q.reshape(b, cfg.n_kv_heads, rep, s, hd)
-    logits = jnp.einsum("bgrsd,bgkd->bgrsk", qg, ck,
-                        preferred_element_type=jnp.float32)
-    logits = logits / math.sqrt(hd)
-    kpos = jnp.arange(max_len)[None, :]             # (1, max_len)
-    qpos = pos + jnp.arange(s)[:, None]             # (s, 1)
-    logits = jnp.where(kpos <= qpos, logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1).astype(dt)
-    o = jnp.einsum("bgrsk,bgkd->bgrsd", p, cv)
-    o = o.reshape(b, cfg.n_heads, s, hd)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
-    x = x + _mcon(mesh, o @ _wq8(lp["wo"], dt),
-                  batch_ax, None, None)
+    with jax.named_scope(ATTENTION_SCOPE):
+        rep = cfg.n_heads // cfg.n_kv_heads
+        qg = q.reshape(b, cfg.n_kv_heads, rep, s, hd)
+        logits = jnp.einsum("bgrsd,bgkd->bgrsk", qg, ck,
+                            preferred_element_type=jnp.float32)
+        logits = logits / math.sqrt(hd)
+        kpos = jnp.arange(max_len)[None, :]             # (1, max_len)
+        qpos = pos + jnp.arange(s)[:, None]             # (s, 1)
+        logits = jnp.where(kpos <= qpos, logits, -jnp.inf)
+        p = jax.nn.softmax(logits, axis=-1).astype(dt)
+        o = jnp.einsum("bgrsk,bgkd->bgrsd", p, cv)
+        o = o.reshape(b, cfg.n_heads, s, hd)
+    x = x + _mcon(mesh, _out_proj(cfg, lp, o), batch_ax, None, None)
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     # serving: exact dropless routing — generation must not depend on
@@ -722,14 +765,7 @@ def _forward_cached(cfg: LlamaConfig, params, tokens, cache,
     if kvspec is not None:               # per-layer view: drop the
         kvspec = P(*kvspec[1:])          # scanned leading L axis
     batch_ax = kvspec[0] if kvspec is not None else ("dp", "fsdp")
-    emb = params["tok_embed"]
-    if isinstance(emb, dict):        # weight-only int8: dequant the
-        # GATHERED rows only (scale is per-dim-channel)
-        x = emb["q8"][tokens].astype(cfg.dtype) * \
-            emb["s8"][0].astype(cfg.dtype)
-    else:
-        x = emb[tokens].astype(cfg.dtype)
-    x = _mcon(mesh, x, batch_ax, None, None)
+    x = _mcon(mesh, _embed(cfg, params, tokens), batch_ax, None, None)
     # rope tables for absolute positions pos..pos+s from one static
     # (max_len, hd/2) table — keeps the program shape-static
     cos_t, sin_t = rope_tables(cfg, max_len)
@@ -757,11 +793,7 @@ def _forward_cached(cfg: LlamaConfig, params, tokens, cache,
         x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
     elif last_only:
         x = x[:, -1:]
-    hw = (_wq8(params["tok_embed"], cfg.dtype).T if cfg.tie_embeddings
-          else _wq8(params["lm_head"], cfg.dtype))
-    logits = jnp.einsum("bsd,dv->bsv", x, hw,
-                        preferred_element_type=jnp.float32)
-    logits = _mcon(mesh, logits, batch_ax, None, None)
+    logits = _mcon(mesh, _lm_head(cfg, params, x), batch_ax, None, None)
     new_cache = {"k": ck, "v": cv, "pos": pos + s}
     return logits, new_cache
 
@@ -837,6 +869,7 @@ def decode_step(cfg: LlamaConfig, params, token, cache,
     return logits[:, 0], cache
 
 
+@jax.named_scope(SAMPLER_SCOPE)
 def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None):
     """THE sampler — one shared helper for :func:`generate` and the
     continuous-batching serving engine (``mxtpu.serve``). lg: (b, V)
@@ -899,6 +932,16 @@ def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None):
     sampled = jax.random.categorical(rng, slg, axis=-1) \
         .astype(jnp.int32)
     return jnp.where(jnp.squeeze(t_col, -1) == 0.0, greedy, sampled)
+
+
+def _sample_slot(key, lg, temperature, top_k, top_p):
+    """One slot's decode-step sample, mirroring generate's step: split
+    the slot's chain, sample on (1, V). Returns (carry key, token)."""
+    with jax.named_scope(SAMPLER_SCOPE):
+        key, sub = jax.random.split(key)
+    tok = sample_logits(sub, lg[None], temperature=temperature,
+                        top_k=top_k, top_p=top_p)[0]
+    return key, tok
 
 
 def _nucleus_mask(lg, top_p):
@@ -1040,19 +1083,10 @@ def _layer_slots(cfg: LlamaConfig, cos, sin, pos, mesh, kvspec,
     slot; ck/cv (S, kvh, max_len, hd). Writes each slot's new K/V at
     its OWN position ``pos[i]`` and attends it against its own prefix
     via the length-masked blockwise kernel."""
-    b, s, _ = x.shape
-    hd = cfg.head_dim
     dt = cfg.dtype
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ _wq8(lp["wq"], dt)).reshape(b, s, cfg.n_heads, hd)
-    k = (h @ _wq8(lp["wk"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (h @ _wq8(lp["wv"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = q.transpose(0, 2, 1, 3)          # (S, h, 1, hd)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, 1, hd)
     head_ax = (kvspec[1] if kvspec is not None and len(kvspec) > 1
                else None)
     q = _mcon(mesh, q, None, head_ax, None, None)
@@ -1064,8 +1098,9 @@ def _layer_slots(cfg: LlamaConfig, cos, sin, pos, mesh, kvspec,
     def write(c, u, p):          # per-slot scatter at its own position
         return lax.dynamic_update_slice(c, u, (zero, p, zero))
 
-    ck = jax.vmap(write)(ck, k.astype(dt), pos)
-    cv = jax.vmap(write)(cv, v.astype(dt), pos)
+    with jax.named_scope(KV_WRITE_SCOPE):
+        ck = jax.vmap(write)(ck, k.astype(dt), pos)
+        cv = jax.vmap(write)(cv, v.astype(dt), pos)
     if mesh is not None:
         from jax.sharding import NamedSharding
         ck = lax.with_sharding_constraint(
@@ -1074,8 +1109,7 @@ def _layer_slots(cfg: LlamaConfig, cos, sin, pos, mesh, kvspec,
             cv, NamedSharding(mesh, kvspec))
 
     o = slot_decode_attention(q, ck, cv, pos + 1)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
-    x = x + _mcon(mesh, o @ _wq8(lp["wo"], dt), None, None, None)
+    x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     delta, _ = _ffn(cfg, lp, h, mesh, serving=True)
@@ -1107,13 +1141,7 @@ def decode_slots(cfg: LlamaConfig, params, kv, sv, active,
     max_len = kv["k"].shape[3]
     lengths = sv["lengths"].astype(jnp.int32)
     pos = jnp.minimum(lengths, max_len - 1)   # per-slot write position
-    tokens = sv["tokens"][:, None]
-    emb = params["tok_embed"]
-    if isinstance(emb, dict):
-        x = emb["q8"][tokens].astype(cfg.dtype) * \
-            emb["s8"][0].astype(cfg.dtype)
-    else:
-        x = emb[tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, sv["tokens"][:, None])
 
     kvspec = None
     if mesh is not None:
@@ -1136,19 +1164,9 @@ def decode_slots(cfg: LlamaConfig, params, kv, sv, active,
         ck = lax.with_sharding_constraint(ck, full)
         cv = lax.with_sharding_constraint(cv, full)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    hw = (_wq8(params["tok_embed"], cfg.dtype).T if cfg.tie_embeddings
-          else _wq8(params["lm_head"], cfg.dtype))
-    logits = jnp.einsum("bsd,dv->bsv", x, hw,
-                        preferred_element_type=jnp.float32)[:, 0]
+    logits = _lm_head(cfg, params, x)[:, 0]
 
-    def one(key, lg, t, kk, pp):
-        # mirror generate's step: split the chain, sample on (1, V)
-        key, sub = jax.random.split(key)
-        tok = sample_logits(sub, lg[None], temperature=t,
-                            top_k=kk, top_p=pp)[0]
-        return key, tok
-
-    new_rngs, sampled = jax.vmap(one)(
+    new_rngs, sampled = jax.vmap(_sample_slot)(
         sv["rngs"], logits, temperature, top_k, top_p)
     new_lengths = lengths + active.astype(jnp.int32)
     if mesh is not None:
@@ -1194,12 +1212,13 @@ def prefill_slot(cfg: LlamaConfig, params, tokens, true_len, slot,
     tok = sample_logits(sub, logits[:, 0], temperature=temperature,
                         top_k=top_k, top_p=top_p)
     z = jnp.zeros((), jnp.int32)
-    new_kv = {
-        "k": lax.dynamic_update_slice(kv["k"], tmp["k"],
-                                      (z, slot, z, z, z)),
-        "v": lax.dynamic_update_slice(kv["v"], tmp["v"],
-                                      (z, slot, z, z, z)),
-    }
+    with jax.named_scope(KV_WRITE_SCOPE):
+        new_kv = {
+            "k": lax.dynamic_update_slice(kv["k"], tmp["k"],
+                                          (z, slot, z, z, z)),
+            "v": lax.dynamic_update_slice(kv["v"], tmp["v"],
+                                          (z, slot, z, z, z)),
+        }
     new_sv = {
         "lengths": lax.dynamic_update_slice(
             sv["lengths"].astype(jnp.int32), true_len[None],
@@ -1430,6 +1449,7 @@ def _q8_token(x):
     return q, s
 
 
+@jax.named_scope(KV_GATHER_SCOPE)
 def _gather_slot_pages(pool, scales, pages_row, dt):
     """One slot's pages → a contiguous (L, kvh, cap, hd) cache view.
     pool: (L, n_pages, kvh, ps, hd); pages_row: (P,) int32."""
@@ -1442,6 +1462,28 @@ def _gather_slot_pages(pool, scales, pages_row, dt):
              .reshape(L, hkv, Pn * ps, hd).astype(dt))
 
 
+@jax.named_scope(KV_WRITE_SCOPE)
+def _write_pages(ck, cv, knew, vnew, phys, off):
+    """The new tokens' K/V into their pool pages: token i at in-page
+    offset ``off[i]`` of page ``phys[i]``."""
+    ck = ck.at[phys, :, off, :].set(knew.astype(ck.dtype))
+    cv = cv.at[phys, :, off, :].set(vnew.astype(cv.dtype))
+    return ck, cv
+
+
+@jax.named_scope(KV_WRITE_SCOPE)
+def _write_pages_q8(ck, cv, cks, cvs, knew, vnew, phys, off):
+    """:func:`_write_pages` for an int8 pool: quantise per token, write
+    the bytes and their scales."""
+    kq, ksc = _q8_token(knew)
+    vq, vsc = _q8_token(vnew)
+    ck = ck.at[phys, :, off, :].set(kq)
+    cv = cv.at[phys, :, off, :].set(vq)
+    cks = cks.at[phys, :, off].set(ksc)
+    cvs = cvs.at[phys, :, off].set(vsc)
+    return ck, cv, cks, cvs
+
+
 def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
                        page_table, mesh, kvspec, x, lp, ck, cv,
                        cks=None, cvs=None):
@@ -1452,19 +1494,10 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
     rows are zeroed, so no live page can alias the write), then the
     slot attends its gathered pages via the length-masked paged
     kernel."""
-    b, s, _ = x.shape
-    hd = cfg.head_dim
     dt = cfg.dtype
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ _wq8(lp["wq"], dt)).reshape(b, s, cfg.n_heads, hd)
-    k = (h @ _wq8(lp["wk"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (h @ _wq8(lp["wv"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = q.transpose(0, 2, 1, 3)          # (S, h, 1, hd)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, 1, hd)
     head_ax = (kvspec[1] if kvspec is not None and len(kvspec) > 1
                else None)
     q = _mcon(mesh, q, None, head_ax, None, None)
@@ -1474,18 +1507,13 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
     knew = k[:, :, 0, :]                 # (S, kvh, hd)
     vnew = v[:, :, 0, :]
     if cks is not None:                  # int8 pool: quantize the write
-        kq, ksc = _q8_token(knew)
-        vq, vsc = _q8_token(vnew)
-        ck = ck.at[phys, :, off, :].set(kq)
-        cv = cv.at[phys, :, off, :].set(vq)
-        cks = cks.at[phys, :, off].set(ksc)
-        cvs = cvs.at[phys, :, off].set(vsc)
+        ck, cv, cks, cvs = _write_pages_q8(ck, cv, cks, cvs, knew,
+                                           vnew, phys, off)
         kf = _gather_slot_pages_batch(ck, cks, page_table, dt)
         vf = _gather_slot_pages_batch(cv, cvs, page_table, dt)
         o = slot_decode_attention(q, kf, vf, pos + 1)
     else:
-        ck = ck.at[phys, :, off, :].set(knew.astype(ck.dtype))
-        cv = cv.at[phys, :, off, :].set(vnew.astype(cv.dtype))
+        ck, cv = _write_pages(ck, cv, knew, vnew, phys, off)
         if mesh is not None:
             from jax.sharding import NamedSharding
             ck = lax.with_sharding_constraint(
@@ -1494,8 +1522,7 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
                 cv, NamedSharding(mesh, kvspec))
         o = paged_decode_attention(q, ck, cv, page_table, pos + 1)
 
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
-    x = x + _mcon(mesh, o @ _wq8(lp["wo"], dt), None, None, None)
+    x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     delta, _ = _ffn(cfg, lp, h, mesh, serving=True)
@@ -1505,6 +1532,7 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
     return x, ck, cv
 
 
+@jax.named_scope(KV_GATHER_SCOPE)
 def _gather_slot_pages_batch(pool, scales, page_table, dt):
     """All slots' pages → (S, kvh, cap, hd) with int8 dequant on the
     gathered bytes (the whole-pool dequant would undo the HBM win)."""
@@ -1542,13 +1570,7 @@ def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
     nslots = page_table.shape[0]
     phys = page_table[jnp.arange(nslots), pos // ps]  # (S,) pool index
     off = pos % ps
-    tokens = sv["tokens"][:, None]
-    emb = params["tok_embed"]
-    if isinstance(emb, dict):
-        x = emb["q8"][tokens].astype(cfg.dtype) * \
-            emb["s8"][0].astype(cfg.dtype)
-    else:
-        x = emb[tokens].astype(cfg.dtype)
+    x = _embed(cfg, params, sv["tokens"][:, None])
 
     kvspec = None
     if mesh is not None:
@@ -1584,18 +1606,9 @@ def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
             cv = lax.with_sharding_constraint(cv, full)
         new_kv = {"k": ck, "v": cv}
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    hw = (_wq8(params["tok_embed"], cfg.dtype).T if cfg.tie_embeddings
-          else _wq8(params["lm_head"], cfg.dtype))
-    logits = jnp.einsum("bsd,dv->bsv", x, hw,
-                        preferred_element_type=jnp.float32)[:, 0]
+    logits = _lm_head(cfg, params, x)[:, 0]
 
-    def one(key, lg, t, kk, pp):
-        key, sub = jax.random.split(key)
-        tok = sample_logits(sub, lg[None], temperature=t,
-                            top_k=kk, top_p=pp)[0]
-        return key, tok
-
-    new_rngs, sampled = jax.vmap(one)(
+    new_rngs, sampled = jax.vmap(_sample_slot)(
         sv["rngs"], logits, temperature, top_k, top_p)
     new_lengths = lengths + active.astype(jnp.int32)
     if mesh is not None:
@@ -1606,6 +1619,7 @@ def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
         {"lengths": new_lengths, "tokens": sampled, "rngs": new_rngs}
 
 
+@jax.named_scope(KV_WRITE_SCOPE)
 def _scatter_slot_pages(kv, pages_row, tmp_k, tmp_v, prefix_len,
                         bucket, int8):
     """Write a slot's contiguous (L, 1, kvh, cap, hd) cache view back
@@ -1654,6 +1668,7 @@ def _scatter_slot_pages(kv, pages_row, tmp_k, tmp_v, prefix_len,
     return out
 
 
+@jax.named_scope(KV_GATHER_SCOPE)
 def _gather_pages_raw(pool, pages_row):
     """(L, n_pages, kvh, ps[, hd]) pool → contiguous (L, kvh, cap[,
     hd]) view of one slot's pages, NO dequant (raw stored bytes)."""
@@ -1837,19 +1852,10 @@ def _layer_slots_spec(cfg: LlamaConfig, cos, sin, qlen, phys, off,
     prefix ``[0, qlen[s, i])`` — the per-query length mask that keeps
     every drafted position's logits exactly what a sequential decode
     at that position would compute."""
-    b, s, _ = x.shape
-    hd = cfg.head_dim
     dt = cfg.dtype
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ _wq8(lp["wq"], dt)).reshape(b, s, cfg.n_heads, hd)
-    k = (h @ _wq8(lp["wk"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (h @ _wq8(lp["wv"], dt)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = q.transpose(0, 2, 1, 3)          # (S, h, W, hd)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, W, hd)
     head_ax = (kvspec[1] if kvspec is not None and len(kvspec) > 1
                else None)
     q = _mcon(mesh, q, None, head_ax, None, None)
@@ -1859,18 +1865,13 @@ def _layer_slots_spec(cfg: LlamaConfig, cos, sin, qlen, phys, off,
     knew = k.transpose(0, 2, 1, 3)       # (S, W, kvh, hd)
     vnew = v.transpose(0, 2, 1, 3)
     if cks is not None:                  # int8 pool: quantize the write
-        kq, ksc = _q8_token(knew)
-        vq, vsc = _q8_token(vnew)
-        ck = ck.at[phys, :, off, :].set(kq)
-        cv = cv.at[phys, :, off, :].set(vq)
-        cks = cks.at[phys, :, off].set(ksc)
-        cvs = cvs.at[phys, :, off].set(vsc)
+        ck, cv, cks, cvs = _write_pages_q8(ck, cv, cks, cvs, knew,
+                                           vnew, phys, off)
         kf = _gather_slot_pages_batch(ck, cks, page_table, dt)
         vf = _gather_slot_pages_batch(cv, cvs, page_table, dt)
         o = slot_decode_attention(q, kf, vf, qlen)
     else:
-        ck = ck.at[phys, :, off, :].set(knew.astype(ck.dtype))
-        cv = cv.at[phys, :, off, :].set(vnew.astype(cv.dtype))
+        ck, cv = _write_pages(ck, cv, knew, vnew, phys, off)
         if mesh is not None:
             from jax.sharding import NamedSharding
             ck = lax.with_sharding_constraint(
@@ -1879,8 +1880,7 @@ def _layer_slots_spec(cfg: LlamaConfig, cos, sin, qlen, phys, off,
                 cv, NamedSharding(mesh, kvspec))
         o = paged_decode_attention(q, ck, cv, page_table, qlen)
 
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * hd)
-    x = x + _mcon(mesh, o @ _wq8(lp["wo"], dt), None, None, None)
+    x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     delta, _ = _ffn(cfg, lp, h, mesh, serving=True)
@@ -1939,12 +1939,7 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
     toks_in = jnp.concatenate(
         [sv["tokens"][:, None], drafts.astype(sv["tokens"].dtype)],
         axis=1)
-    emb = params["tok_embed"]
-    if isinstance(emb, dict):
-        x = emb["q8"][toks_in].astype(cfg.dtype) * \
-            emb["s8"][0].astype(cfg.dtype)
-    else:
-        x = emb[toks_in].astype(cfg.dtype)
+    x = _embed(cfg, params, toks_in)
 
     kvspec = None
     if mesh is not None:
@@ -1980,10 +1975,7 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
             cv = lax.with_sharding_constraint(cv, full)
         new_kv = {"k": ck, "v": cv}
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    hw = (_wq8(params["tok_embed"], cfg.dtype).T if cfg.tie_embeddings
-          else _wq8(params["lm_head"], cfg.dtype))
-    logits = jnp.einsum("bsd,dv->bsv", x, hw,
-                        preferred_element_type=jnp.float32)   # (S, W, V)
+    logits = _lm_head(cfg, params, x)                 # (S, W, V)
 
     # accept oracle: scan the W per-position logits down the slot's rng
     # chain. ok carries "all earlier drafts matched"; the key advances

@@ -29,6 +29,11 @@ __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
 
 _NEG_INF = -1e30  # finite "minus infinity": keeps fully-masked rows NaN-free
 
+# the named scopes this file shares with models/llama.py (its comment
+# above rms_norm has the whole list): a trace reader follows these names
+ATTENTION_SCOPE = "attention"
+KV_GATHER_SCOPE = "kv_gather"
+
 
 def _repeat_kv(q, k, v):
     """Broadcast grouped KV heads up to the query head count (GQA)."""
@@ -205,6 +210,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
                                    kv_block=kv_block)
 
 
+@jax.named_scope(ATTENTION_SCOPE)
 def slot_decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
                           kv_block: int = 512):
     """Length-masked decode attention over a SLOT KV cache — the
@@ -311,6 +317,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     n_pages, hkv, page_size, d = k_pages.shape
     slots, per_slot = page_table.shape
     # gather (S, P, kvh, ps, hd) → contiguous (S, kvh, P*ps, hd)
+    @jax.named_scope(KV_GATHER_SCOPE)
     def flat(pool):
         g = jnp.take(pool, page_table, axis=0)
         return (g.transpose(0, 2, 1, 3, 4)

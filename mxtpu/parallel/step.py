@@ -184,9 +184,13 @@ def make_train_step(loss_fn: Callable[..., Any], tx, mesh: Mesh,
             loss, aux = (val if has_aux else (val, None))
             if has_state:
                 mstate, aux = aux, None
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                              state.params, updates)
+        # named like the model's own pieces (models/llama.py), so a
+        # trace's operations map back to it (telemetry.programs())
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                  state.params, updates)
         # pin updated params to the rule-table layout so the state the
         # next step receives is exactly the init_state placement (no
         # XLA re-layout drift across steps)
@@ -212,20 +216,21 @@ def make_train_step(loss_fn: Callable[..., Any], tx, mesh: Mesh,
             return new, loss, aux
         return new, loss
 
-    jitted = jax.jit(_step, in_shardings=(None, bsharding, None),
-                     donate_argnums=(0,))
-
     from .. import telemetry
     telemetry.install_compile_listener()
     # watched: every compile is cost-cataloged (program_flops/bytes →
     # roofline class) and every dispatch feeds the live MFU/goodput
     # gauges + step-anomaly detector. expected=None — tests legally
     # run one step fn over several shapes; the serve-style recompile
-    # anomaly counter is not this program's contract.
-    watched = telemetry.watch(jitted, "train_step", expected=None,
-                              loop="train")
-    dispatch_span = telemetry.span_factory("train.step_dispatch",
-                                           "train_dispatch")
+    # anomaly counter is not this program's contract. The module is
+    # jit_train_step in a trace.
+    watched = telemetry.watch_jit(
+        _step, "train_step", "train_step", expected=None, loop="train",
+        in_shardings=(None, bsharding, None), donate_argnums=(0,))
+    jitted = watched._fn
+    # every step: kept out of the flight ring
+    dispatch_span = telemetry.span_factory(
+        "train.step_dispatch", "train_dispatch", flight=False)
 
     def step(state: TrainState, batch, rng=None):
         # host DISPATCH time only (the program runs async) — with the

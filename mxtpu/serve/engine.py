@@ -42,7 +42,7 @@ import itertools
 import os
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -135,6 +135,22 @@ def _engine_metrics(eid: str):
             "serve_spec_accepted_total",
             "Drafted tokens accepted (bit-exact match with the "
             "target chain)"),
+        # time to first token inside the engine, split where it is
+        # spent; the three are observed together, so their counts
+        # agree. Distinct names, not one labelled family: a scrape
+        # that sums a name over its labels must keep them apart
+        "ttft_queue": telemetry.histogram(
+            "serve_ttft_queue_ms",
+            "submit -> picked for admission: waiting for a step "
+            "boundary and a slot"),
+        "ttft_admit": telemetry.histogram(
+            "serve_ttft_admit_ms",
+            "picked -> admission program dispatched: page plan, "
+            "copy-page, key, arrays, the dispatch call"),
+        "ttft_first_wait": telemetry.histogram(
+            "serve_ttft_first_wait_ms",
+            "dispatched -> first token on the host: the decode step "
+            "in flight, the prefill, the decode step it rides behind"),
         "spec_len": telemetry.histogram(
             "serve_spec_accepted_len",
             "Tokens emitted per slot per speculative step (1 + "
@@ -369,6 +385,10 @@ class Request:
     deadline_s: Optional[float] = None
     rng: Optional[Any] = None
     ctx: Optional[Any] = None
+    # the engine's TTFT stamps (perf_counter seconds): submit, picked,
+    # admission program dispatched; cleared when the first token is out
+    _stamps: List[float] = field(default_factory=list, init=False,
+                                 repr=False, compare=False)
 
 
 def cancel_counter(reason: str):
@@ -578,11 +598,14 @@ class ServeEngine:
         # watch(): ONE decode program ever — cache growth past 1 is the
         # spurious-recompile anomaly (recompile_total + offending key)
         telemetry.install_compile_listener()
-        self._decode = telemetry.watch(
-            jax.jit(partial(llama.decode_slots_paged if self.paged
-                            else llama.decode_slots, cfg, mesh=mesh),
-                    donate_argnums=(1,)),
-            "serve_decode", expected=1, loop="serve")
+        # watch_jit(): each program is compiled under the name of the
+        # model function it runs, so a trace reads
+        # jit_decode_slots_paged, not jit__unknown
+        decode = llama.decode_slots_paged if self.paged \
+            else llama.decode_slots
+        self._decode = telemetry.watch_jit(
+            partial(decode, cfg, mesh=mesh), "serve_decode",
+            decode.__name__, loop="serve", donate_argnums=(1,))
         self._prefills: Dict[int, Any] = {}
         self._injects: Dict[int, Any] = {}
         self._spec_decode = None
@@ -591,10 +614,10 @@ class ServeEngine:
             # k-verify step) — compile_count's bound grows by exactly
             # this; steps where no slot has a draft still run the
             # plain decode program (mixed stepping, same bank)
-            self._spec_decode = telemetry.watch(
-                jax.jit(partial(llama.decode_slots_spec, cfg,
-                                mesh=mesh), donate_argnums=(1,)),
-                "serve_spec_verify", expected=1, loop="serve")
+            self._spec_decode = telemetry.watch_jit(
+                partial(llama.decode_slots_spec, cfg, mesh=mesh),
+                "serve_spec_verify", "decode_slots_spec", loop="serve",
+                donate_argnums=(1,))
         if self.paged:
             # host page-table (a small int32 operand per step), the
             # refcounted allocator, the prefix cache, and the CoW
@@ -604,14 +627,14 @@ class ServeEngine:
             self._pages = PageAllocator(self.n_pages)
             self._prefix = (PrefixCache(self._pages)
                             if self.prefix_cache_enabled else None)
-            # a per-engine wrapper (NOT bare llama.copy_page): jit
-            # caches key on callable identity, so a shared function
-            # would alias cache sizes across engines and skew both the
-            # recompile watcher and compile_count's churn gate
-            self._copy_fn = telemetry.watch(
-                jax.jit(lambda kv, src, dst: llama.copy_page(
-                    kv, src, dst), donate_argnums=(0,)),
-                "serve_copy_page", expected=1)
+            # a per-engine wrapper (NOT bare llama.copy_page, which
+            # watch_jit's partial is): jit caches key on callable
+            # identity, so a shared function would alias cache sizes
+            # across engines and skew both the recompile watcher and
+            # compile_count's churn gate
+            self._copy_fn = telemetry.watch_jit(
+                llama.copy_page, "serve_copy_page", "copy_page",
+                donate_argnums=(0,))
             # engine-local tallies (the telemetry counters are
             # process-wide totals shared across engines)
             self._prefix_hits = 0
@@ -622,9 +645,20 @@ class ServeEngine:
         self._m = _engine_metrics(eid)
         self._m_cancel: Dict[str, Any] = {}    # per-reason counters
         # span factories pre-bind their registry histograms — the
-        # per-step/per-admission hot paths must not re-intern handles
+        # per-step/per-admission hot paths must not re-intern handles.
+        # The five phase spans cover the engine thread's iteration
+        # (their histograms' sums add to the loop's wall time); they
+        # are entered every step, so they stay out of the flight ring
+        self._span_sweep = telemetry.span_factory(
+            "serve.sweep_pick", flight=False)
+        self._span_admit = telemetry.span_factory(
+            "serve.admit", flight=False)
         self._span_decode = telemetry.span_factory(
-            "serve.decode_step", "serve_decode_dispatch")
+            "serve.decode_step", "serve_decode_dispatch", flight=False)
+        self._span_readback = telemetry.span_factory(
+            "serve.readback", flight=False)
+        self._span_emit = telemetry.span_factory(
+            "serve.emit", flight=False)
         self._span_prefill = telemetry.span_factory(
             "serve.prefill", "serve_prefill")
         # private resettable latency stats (always-on Histogram
@@ -763,6 +797,7 @@ class ServeEngine:
 
     def _enqueue(self, request: Request,
                  handoff: Optional[KVHandoff] = None) -> int:
+        request._stamps = [time.perf_counter()]
         with self._cv:
             rid = self._next_rid
             self._next_rid += 1
@@ -889,6 +924,7 @@ class ServeEngine:
                 if plan is None:
                     break
             heapq.heappop(self._queue)
+            req._stamps.append(time.perf_counter())
             slot = int(free[0])
             self._m["wait"].observe(max(0, self._step_idx - arrival))
             self._seat(slot, rid, req)
@@ -1065,16 +1101,17 @@ class ServeEngine:
                 else:
                     firsts.append(
                         (rid, self._prefill_into(slot, req)))
+            req._stamps.append(time.perf_counter())
 
     def _prefill_into(self, slot: int, req: Request):
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         bucket = bucket_for(prompt.size, self.min_bucket, self.max_len)
         fn = self._prefills.get(bucket)
         if fn is None:
-            fn = telemetry.watch(
-                jax.jit(partial(llama.prefill_slot, self.cfg,
-                                mesh=self.mesh), donate_argnums=(4,)),
-                f"serve_prefill_b{bucket}", expected=1)
+            fn = telemetry.watch_jit(
+                partial(llama.prefill_slot, self.cfg, mesh=self.mesh),
+                f"serve_prefill_b{bucket}", f"prefill_slot_b{bucket}",
+                donate_argnums=(4,))
             self._prefills[bucket] = fn
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :prompt.size] = prompt
@@ -1106,10 +1143,10 @@ class ServeEngine:
         bucket = int(h.k.shape[2])
         fn = self._injects.get(bucket)
         if fn is None:
-            fn = telemetry.watch(
-                jax.jit(partial(llama.inject_slot_kv, self.cfg,
-                                mesh=self.mesh), donate_argnums=(6,)),
-                f"serve_inject_b{bucket}", expected=1)
+            fn = telemetry.watch_jit(
+                partial(llama.inject_slot_kv, self.cfg, mesh=self.mesh),
+                f"serve_inject_b{bucket}", f"inject_slot_kv_b{bucket}",
+                donate_argnums=(6,))
             self._injects[bucket] = fn
         with self._span_prefill(bucket=bucket, inject=True,
                                 role=self.role):
@@ -1125,10 +1162,11 @@ class ServeEngine:
     def _paged_prefill_fn(self, bucket: int):
         fn = self._prefills.get(bucket)
         if fn is None:
-            fn = telemetry.watch(
-                jax.jit(partial(llama.prefill_slot_paged, self.cfg,
-                                mesh=self.mesh), donate_argnums=(6,)),
-                f"serve_prefill_b{bucket}", expected=1)
+            fn = telemetry.watch_jit(
+                partial(llama.prefill_slot_paged, self.cfg,
+                        mesh=self.mesh),
+                f"serve_prefill_b{bucket}",
+                f"prefill_slot_paged_b{bucket}", donate_argnums=(6,))
             self._prefills[bucket] = fn
         return fn
 
@@ -1226,10 +1264,11 @@ class ServeEngine:
             k, v = np.pad(k, pad), np.pad(v, pad)
         fn = self._injects.get(bucket)
         if fn is None:
-            fn = telemetry.watch(
-                jax.jit(partial(llama.inject_paged_kv, self.cfg,
-                                mesh=self.mesh), donate_argnums=(7,)),
-                f"serve_inject_b{bucket}", expected=1)
+            fn = telemetry.watch_jit(
+                partial(llama.inject_paged_kv, self.cfg,
+                        mesh=self.mesh),
+                f"serve_inject_b{bucket}", f"inject_paged_kv_b{bucket}",
+                donate_argnums=(7,))
             self._injects[bucket] = fn
         with self._span_prefill(bucket=bucket, inject=True,
                                 role=self.role):
@@ -1361,20 +1400,37 @@ class ServeEngine:
         if len(self._results[rid]) >= req.max_new_tokens:
             self._finalize(rid, "complete")
 
+    def _observe_ttft(self, rid: int, now: float) -> None:
+        """The request's first token is on the host at ``now`` (lock
+        held): its wait goes into the three TTFT histograms in this
+        one call."""
+        req = self._requests.get(rid)
+        if req is None or len(req._stamps) != 3:
+            return
+        submit, picked, dispatched = req._stamps
+        req._stamps = []
+        self._m["ttft_queue"].observe(1e3 * (picked - submit))
+        self._m["ttft_admit"].observe(1e3 * (dispatched - picked))
+        self._m["ttft_first_wait"].observe(1e3 * (now - dispatched))
+
     def _process(self, disp: _Dispatch) -> None:
         # the device sync happens OUTSIDE the lock — a submitter must
-        # never block behind a device readback
-        sampled = np.asarray(disp.sampled) if disp.slots else None
-        emits = (np.asarray(disp.emits)
-                 if disp.emits is not None and disp.slots else None)
+        # never block behind a device readback. serve.readback is the
+        # time the host waits for the device
+        with self._span_readback():
+            sampled = np.asarray(disp.sampled) if disp.slots else None
+            emits = (np.asarray(disp.emits)
+                     if disp.emits is not None and disp.slots else None)
+            firsts = [(rid, int(np.asarray(dev)[0]))
+                      for rid, dev in disp.firsts]
         now = time.perf_counter()
-        with self._lock:
+        with self._span_emit(), self._lock:
             rid2slot = ({rid: s for s, rid in
                          enumerate(self._slot_rid) if rid is not None}
                         if self.speculate_k else {})
-            for rid, dev in disp.firsts:
+            for rid, tok in firsts:
                 if rid not in self._cancelled:
-                    tok = int(np.asarray(dev)[0])
+                    self._observe_ttft(rid, now)
                     self._emit(rid, tok, now)
                     s = rid2slot.get(rid)
                     if s is not None:
@@ -1449,10 +1505,11 @@ class ServeEngine:
         under this step's device time. Shared by :meth:`run` (batch
         drain) and :meth:`run_forever` (the gateway's replica loop)."""
         firsts: List[Tuple[int, Any]] = []
-        with self._lock:
+        with self._span_sweep(), self._lock:
             self._sweep_cancelled()
             picks = self._pick_admissions()
-        self._run_admissions(picks, firsts)
+        with self._span_admit():
+            self._run_admissions(picks, firsts)
         # any admission leaves its slot active, so firsts are
         # always carried by a dispatch
         out = (self._dispatch(firsts) if self._active.any()

@@ -697,10 +697,11 @@ class PrefillWorker:
     def _fn(self, bucket: int):
         fn = self._fns.get(bucket)
         if fn is None:
-            fn = telemetry.watch(
-                jax.jit(partial(llama.prefill_detached, self.cfg,
-                                mesh=self.mesh)),
-                f"gateway_prefill_b{bucket}", expected=1)
+            fn = telemetry.watch_jit(
+                partial(llama.prefill_detached, self.cfg,
+                        mesh=self.mesh),
+                f"gateway_prefill_b{bucket}",
+                f"prefill_detached_b{bucket}")
             self._fns[bucket] = fn
         return fn
 
@@ -711,11 +712,12 @@ class PrefillWorker:
         cache is donated: chunk c+1 reuses chunk c's buffers."""
         fn = self._cfns.get(bucket)
         if fn is None:
-            fn = telemetry.watch(
-                jax.jit(partial(llama.prefill_detached_chunk,
-                                self.cfg, mesh=self.mesh),
-                        donate_argnums=(2,)),
-                f"gateway_prefill_stream_b{bucket}", expected=1)
+            fn = telemetry.watch_jit(
+                partial(llama.prefill_detached_chunk, self.cfg,
+                        mesh=self.mesh),
+                f"gateway_prefill_stream_b{bucket}",
+                f"prefill_detached_chunk_b{bucket}",
+                donate_argnums=(2,))
             self._cfns[bucket] = fn
         return fn
 

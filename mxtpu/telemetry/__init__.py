@@ -15,6 +15,10 @@ One process-wide, thread-safe layer with four pieces:
   process-wide, and :func:`watch`-wrapped programs attribute each
   compile to its cache key, so an anomalous ``recompile_total`` points
   at the offending signature instead of a bisection session.
+  :func:`watch_jit` also compiles the program under a stable module
+  name, and :func:`programs` maps each compiled program's instructions
+  back to the ``jax.named_scope`` they were traced under
+  (``telemetry/scopes.py``), so a device trace can be read by scope.
 
 Enabled by default; ``MXTPU_TELEMETRY=0`` turns every recording call
 into a no-op (handles created while disabled never record — the knob
@@ -44,7 +48,8 @@ from .tracing import (Span, clear_trace, current_depth, dump_trace,
                       trace_events)
 from . import perfscope
 from .perfscope import goodput_gauge, profile_program
-from .watcher import WatchedFunction, describe_args, watch
+from .scopes import Program, programs
+from .watcher import WatchedFunction, describe_args, watch, watch_jit
 from .watcher import install as install_compile_listener
 
 __all__ = [
@@ -55,6 +60,7 @@ __all__ = [
     "registry", "flight", "enabled", "enable", "reset",
     "prometheus", "summary", "dump_trace", "trace_events",
     "clear_trace", "current_depth", "describe_args", "watch",
+    "watch_jit", "Program", "programs",
     "install_compile_listener", "default_flight_path",
     "process_role", "set_process_role", "escape_label_value",
     "interval_percentile", "federate_text", "parse_prometheus",
@@ -147,23 +153,29 @@ def span(name: str, histogram_name: Optional[str] = None, **args):
     """A traced span. When telemetry is disabled this still returns a
     working ``Span`` timer but records nothing. ``histogram_name``
     additionally feeds the duration (ms) into that registry histogram;
-    every span lands in the flight recorder."""
+    the span lands in the flight recorder and, while a profiler
+    session is open, in its trace."""
     return span_factory(name, histogram_name)(**args)
 
 
-def span_factory(name: str, histogram_name: Optional[str] = None):
+def span_factory(name: str, histogram_name: Optional[str] = None,
+                 flight: bool = True):
     """Pre-bind a span's registry histogram once and return a callable
     producing spans — the hot-path form (per decode step / train step,
     ``span()``'s per-call interning would take the registry lock every
-    iteration)."""
+    iteration). ``flight=False`` keeps the span out of the flight
+    ring: a span entered every step would push the rare records the
+    ring exists for (compiles, recompiles, anomalies) out of it within
+    seconds."""
     if not _enabled:
         return lambda **args: Span(name, record=False, **args)
     h = histogram(f"span_{histogram_name or name}_ms".replace(".", "_"),
                   f"Span durations: {name}") \
         if histogram_name is not False else None
+    ring = _FLIGHT if flight else None
 
     def make(**args):
-        return Span(name, histogram=h, flight=_FLIGHT, **args)
+        return Span(name, histogram=h, flight=ring, **args)
     return make
 
 

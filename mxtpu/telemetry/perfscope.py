@@ -67,6 +67,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..base import env_bool, env_float, env_int
+from . import scopes as _scopes
 
 __all__ = [
     "DeviceSpec", "ProgramCost", "PerfScope", "HBMLedger",
@@ -538,6 +539,8 @@ class PerfScope:
                 temp_bytes=mem.get("temp_bytes"),
                 peak_hbm_bytes=mem.get("peak_hbm_bytes"))
             self.register_cost(cost)
+            # the same executable names its instructions' scopes
+            _scopes.register(name, obj)
             return cost
         except Exception as e:
             w = self._window(name)

@@ -8,6 +8,12 @@ step) that is host dispatch time, which is exactly the quantity the
 overlapped-sync design cares about. Device time stays the XLA trace's
 job; the two are complementary, not redundant.
 
+A recorded span is also a ``jax.profiler.TraceAnnotation`` of its
+name, so while a profiler session is open it lands in the xplane's
+host plane, on the device lines' clock: the ring below stamps
+``perf_counter_ns``, which no trace shares. With no session open the
+annotation costs under a microsecond.
+
 Events accumulate in a bounded in-memory buffer (``trace_events()``,
 dumped by :func:`dump_trace` as a Trace Event Format JSON array) and,
 when ``MXTPU_TELEMETRY_TRACE_PATH`` is set, stream to that file as
@@ -27,6 +33,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..base import env_int, env_str
 from .flight import process_role
@@ -157,17 +165,22 @@ class Span:
         self._histogram = histogram
         self._flight = flight
         self._record_event = record
+        self._annotation = TraceAnnotation(name) if record else None
         self._t0 = 0
 
     def __enter__(self) -> "Span":
         depth = getattr(_tls, "depth", 0)
         _tls.depth = depth + 1
         self.depth = depth
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = _now_us()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         _tls.depth = max(0, getattr(_tls, "depth", 1) - 1)
         self.duration_ms = (t1 - self._t0) / 1000.0
         args = dict(self.args)

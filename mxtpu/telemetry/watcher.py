@@ -26,6 +26,7 @@ true exactly when a retrace bug could hide (same policy as
 """
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -33,7 +34,8 @@ from typing import Any, Callable, List, Optional
 
 from . import perfscope as _perfscope
 
-__all__ = ["install", "watch", "WatchedFunction", "describe_args"]
+__all__ = ["install", "watch", "watch_jit", "WatchedFunction",
+           "describe_args"]
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _install_lock = threading.Lock()
@@ -184,3 +186,22 @@ def watch(fn: Callable, name: str,
     program for perfscope's ``goodput_ratio{loop=...}`` gauge
     (``"train"`` / ``"serve"``)."""
     return WatchedFunction(fn, name, expected=expected, loop=loop)
+
+
+def watch_jit(fn: Callable, name: str, program: str,
+              expected: Optional[int] = 1, loop: Optional[str] = None,
+              **jit_kwargs) -> WatchedFunction:
+    """``watch(jax.jit(fn, **jit_kwargs), name, ...)`` with the compiled
+    module called ``jit_<program>``: a jit of a ``functools.partial`` or
+    a lambda is ``jit__unknown`` / ``jit__lambda`` in every trace and
+    compiler dump, so each watched program is compiled under a stable
+    name taken from the model function it runs, unique per compiled
+    variant (``prefill_slot_paged_b256``). ``name`` stays the watch
+    name operators know (``serve_prefill_b256``);
+    ``telemetry.programs()`` holds both. The fresh partial also gives
+    every caller a jit cache of its own."""
+    import jax
+    named = functools.partial(fn)
+    named.__name__ = named.__qualname__ = program
+    return WatchedFunction(jax.jit(named, **jit_kwargs), name,
+                           expected=expected, loop=loop)
