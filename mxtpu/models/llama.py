@@ -1380,24 +1380,38 @@ def inject_slot_kv(cfg: LlamaConfig, k_block, v_block, true_len, slot,
 # paged serving: fixed-size KV page pool + per-slot page tables
 # (PagedAttention, Kwon et al. SOSP '23). The dense slot bank above
 # reserves max_len KV per slot whether or not a request ever grows
-# there; the paged variant keeps ONE flat pool of (n_pages, kvh,
-# page_size, hd) pages per layer and maps each slot's logical sequence
-# through an int32 page-table row the host owns. Admission is bounded
-# by free PAGES, not slots, and read-only pages can be shared between
-# slots (refcounted copy-on-write prefix sharing — the allocator lives
-# in ``mxtpu.serve.engine``; these are its device halves). Page 0 is
+# there; the paged variant keeps ONE pool of (L, n_pages, page_size,
+# kvh, hd) and maps each slot's logical sequence through an int32
+# page-table row the host owns. Admission is bounded by free PAGES, not
+# slots, and read-only pages can be shared between slots (refcounted
+# copy-on-write prefix sharing — the allocator lives in
+# ``mxtpu.serve.engine``; these are its device halves). Page 0 is
 # scratch: the engine never hands it out, zeroed table rows alias it,
 # and redirected writes land there harmlessly.
+#
+# The pool is stored TOKEN-MAJOR: (layer, page, in-page offset) lead,
+# because those are the dimensions the decode write indexes
+# (``.at[l, phys, off]``). XLA lays a scatter's operand out with its
+# indexed dimensions leading; stored any other way, the donated pool
+# is copied whole into that layout and back every step. The decode
+# programs carry the whole pool through the layer loop and reach it by
+# index, so the write and the page gather are the only operations that
+# touch it. Every page index a program sees lies in [0, n_pages):
+# table rows hold pages the allocator handed out or scratch page 0,
+# which is what lets the gathers say ``promise_in_bounds`` and skip
+# the out-of-bounds fill over every gathered row.
 # ---------------------------------------------------------------------------
 
 def paged_cache_specs(cfg: LlamaConfig, mesh: Mesh):
-    """PartitionSpecs for the paged pool: kv heads over tp (axis 2 of
-    the (L, n_pages, kvh, page_size, hd) pool — same head-axis rule as
-    :func:`slot_cache_specs`), page axis unsharded (the host scatters
-    single pages). Scale pools (int8 mode) follow the same spec."""
+    """PartitionSpecs for the paged pool: kv heads over tp (axis 3 of
+    the token-major (L, n_pages, page_size, kvh, hd) pool — same
+    head-axis rule as :func:`slot_cache_specs`), layer, page and
+    in-page offset unsharded (they are what the decode write indexes,
+    and the host scatters single pages). Scale pools (int8 mode,
+    (L, n_pages, page_size, kvh)) follow the same spec."""
     tp = ("tp" if "tp" in mesh.axis_names
           and cfg.n_kv_heads % mesh.shape["tp"] == 0 else None)
-    kv = P(None, None, tp) if tp is not None else P()
+    kv = P(None, None, None, tp) if tp is not None else P()
     return {"k": kv, "v": kv, "ks": kv, "vs": kv,
             "lengths": P(), "tokens": P(), "rngs": P()}
 
@@ -1405,17 +1419,24 @@ def paged_cache_specs(cfg: LlamaConfig, mesh: Mesh):
 def init_paged_cache(cfg: LlamaConfig, max_slots: int, n_pages: int,
                      page_size: int, mesh: Optional[Mesh] = None,
                      int8: bool = False):
-    """Device state for the PAGED serving engine: per-layer K/V pools
-    of (L, n_pages, n_kv_heads, page_size, hd) plus the same per-slot
+    """Device state for the PAGED serving engine: K/V pools of
+    (L, n_pages, page_size, n_kv_heads, hd) plus the same per-slot
     ``lengths``/``tokens``/``rngs`` vectors as :func:`init_slot_cache`
     (page tables stay HOST-side — a small int32 operand per step, so
     table edits never touch device state). ``int8=True`` stores the
     pools as int8 with per-token-per-head f32 scales ``ks``/``vs`` of
-    (L, n_pages, kvh, page_size) — KV HBM halves again; dequant happens
+    (L, n_pages, page_size, kvh) — KV HBM halves again; dequant happens
     on gather (deterministic, not bit-exact with the f32 pool —
-    docs/serving.md)."""
+    docs/serving.md).
+
+    The layout is token-major so that (layer, page, offset) — the
+    dimensions the decode write ``.at[l, phys, off]`` indexes — lead:
+    the default layout is then the one XLA wants for that scatter, and
+    the donated pool is updated in place instead of being copied into
+    the scatter's layout and back every step (the section comment
+    above has the whole argument)."""
     hd = cfg.head_dim
-    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, hd)
+    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, hd)
 
     def build():
         if int8:
@@ -1449,57 +1470,100 @@ def _q8_token(x):
     return q, s
 
 
+def _pages_to_rows(g):
+    """Gathered token-major pages (A, P, ps, kvh[, hd]) → contiguous
+    head-major rows (A, kvh, P·ps[, hd]), the cache view the attention
+    kernels take."""
+    return jnp.swapaxes(g.reshape(g.shape[0], -1, *g.shape[3:]), 1, 2)
+
+
+def _rows_to_pages(a, ps):
+    """:func:`_pages_to_rows` backwards: (A, kvh, cap[, hd]) →
+    (A, cap/ps, ps, kvh[, hd])."""
+    a = jnp.swapaxes(a, 1, 2)
+    return a.reshape(a.shape[0], -1, ps, *a.shape[2:])
+
+
 @jax.named_scope(KV_GATHER_SCOPE)
 def _gather_slot_pages(pool, scales, pages_row, dt):
     """One slot's pages → a contiguous (L, kvh, cap, hd) cache view.
-    pool: (L, n_pages, kvh, ps, hd); pages_row: (P,) int32."""
-    g = jnp.take(pool, pages_row, axis=1)        # (L, P, kvh, ps, hd)
-    if scales is not None:
-        sc = jnp.take(scales, pages_row, axis=1)  # (L, P, kvh, ps)
+    pool: (L, n_pages, ps, kvh, hd); pages_row: (P,) int32, every
+    entry in [0, n_pages)."""
+    g = pool.at[:, pages_row].get(mode="promise_in_bounds")
+    if scales is not None:               # (L, P, ps, kvh, hd) * (.., kvh)
+        sc = scales.at[:, pages_row].get(mode="promise_in_bounds")
         g = g.astype(jnp.float32) * sc[..., None]
-    L, Pn, hkv, ps, hd = g.shape
-    return (g.transpose(0, 2, 1, 3, 4)
-             .reshape(L, hkv, Pn * ps, hd).astype(dt))
+    return _pages_to_rows(g).astype(dt)
 
 
 @jax.named_scope(KV_WRITE_SCOPE)
-def _write_pages(ck, cv, knew, vnew, phys, off):
-    """The new tokens' K/V into their pool pages: token i at in-page
-    offset ``off[i]`` of page ``phys[i]``."""
-    ck = ck.at[phys, :, off, :].set(knew.astype(ck.dtype))
-    cv = cv.at[phys, :, off, :].set(vnew.astype(cv.dtype))
+def _write_pages(ck, cv, knew, vnew, layer, phys, off):
+    """The new tokens' K/V into the whole (L, n_pages, ps, kvh, hd)
+    pools: token i of layer ``layer`` at in-page offset ``off[i]`` of
+    page ``phys[i]``. One scatter whose indexed dimensions are the
+    pool's leading ones, so the donated (and loop-carried) pool is
+    updated in place in the layout it is stored in."""
+    ck = ck.at[layer, phys, off].set(knew.astype(ck.dtype))
+    cv = cv.at[layer, phys, off].set(vnew.astype(cv.dtype))
     return ck, cv
 
 
 @jax.named_scope(KV_WRITE_SCOPE)
-def _write_pages_q8(ck, cv, cks, cvs, knew, vnew, phys, off):
+def _write_pages_q8(ck, cv, cks, cvs, knew, vnew, layer, phys, off):
     """:func:`_write_pages` for an int8 pool: quantise per token, write
     the bytes and their scales."""
     kq, ksc = _q8_token(knew)
     vq, vsc = _q8_token(vnew)
-    ck = ck.at[phys, :, off, :].set(kq)
-    cv = cv.at[phys, :, off, :].set(vq)
-    cks = cks.at[phys, :, off].set(ksc)
-    cvs = cvs.at[phys, :, off].set(vsc)
+    ck = ck.at[layer, phys, off].set(kq)
+    cv = cv.at[layer, phys, off].set(vq)
+    cks = cks.at[layer, phys, off].set(ksc)
+    cvs = cvs.at[layer, phys, off].set(vsc)
     return ck, cv, cks, cvs
 
 
+def _pool_head_axis(kvspec):
+    """The mesh axis the pool's kv-head dimension (axis 3) is sharded
+    over, if any — q/k/v are pinned to it so the write and the gather
+    stay local."""
+    return kvspec[3] if kvspec is not None and len(kvspec) > 3 else None
+
+
+def _scan_paged_layers(cfg: LlamaConfig, params, kv, x, layer_fn):
+    """Run ``layer_fn(x, lp, layer, *pools) -> (x, *pools)`` over the
+    stack with the WHOLE pools in the loop's carry and the layer index
+    in ``xs``. Scanned as ``xs``/``ys`` instead, each layer's slab is
+    sliced out of the donated pool, rewritten and stacked back: two
+    pool-sized copies a step where the write is one token a slot.
+    Returns (x, new kv pools)."""
+    names = [n for n in ("k", "v", "ks", "vs") if n in kv]
+
+    def body(carry, xs):
+        x, pools = carry
+        lp, layer = xs
+        x, *pools = layer_fn(x, lp, layer, *pools)
+        return (x, tuple(pools)), None
+
+    (x, pools), _ = lax.scan(
+        body, (x, tuple(kv[n] for n in names)),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    return x, dict(zip(names, pools))
+
+
 def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
-                       page_table, mesh, kvspec, x, lp, ck, cv,
+                       page_table, mesh, kvspec, x, lp, layer, ck, cv,
                        cks=None, cvs=None):
     """One block of the PAGED slot decode: x (S, 1, dim); ck/cv are the
-    per-layer page POOLS (n_pages, kvh, ps, hd). Each slot's new K/V
-    scatters into pool page ``phys[i]`` at in-page offset ``off[i]``
-    (the host redirects inactive slots to scratch page 0 — their table
-    rows are zeroed, so no live page can alias the write), then the
-    slot attends its gathered pages via the length-masked paged
-    kernel."""
+    WHOLE page pools (L, n_pages, ps, kvh, hd), reached at ``layer`` by
+    index. Each slot's new K/V scatters into pool page ``phys[i]`` at
+    in-page offset ``off[i]`` (the host redirects inactive slots to
+    scratch page 0 — their table rows are zeroed, so no live page can
+    alias the write), then the slot attends its gathered pages via the
+    length-masked paged kernel."""
     dt = cfg.dtype
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, 1, hd)
-    head_ax = (kvspec[1] if kvspec is not None and len(kvspec) > 1
-               else None)
+    head_ax = _pool_head_axis(kvspec)
     q = _mcon(mesh, q, None, head_ax, None, None)
     k = _mcon(mesh, k, None, head_ax, None, None)
     v = _mcon(mesh, v, None, head_ax, None, None)
@@ -1508,19 +1572,20 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
     vnew = v[:, :, 0, :]
     if cks is not None:                  # int8 pool: quantize the write
         ck, cv, cks, cvs = _write_pages_q8(ck, cv, cks, cvs, knew,
-                                           vnew, phys, off)
-        kf = _gather_slot_pages_batch(ck, cks, page_table, dt)
-        vf = _gather_slot_pages_batch(cv, cvs, page_table, dt)
+                                           vnew, layer, phys, off)
+        kf = _gather_slot_pages_batch(ck, cks, layer, page_table, dt)
+        vf = _gather_slot_pages_batch(cv, cvs, layer, page_table, dt)
         o = slot_decode_attention(q, kf, vf, pos + 1)
     else:
-        ck, cv = _write_pages(ck, cv, knew, vnew, phys, off)
+        ck, cv = _write_pages(ck, cv, knew, vnew, layer, phys, off)
         if mesh is not None:
             from jax.sharding import NamedSharding
             ck = lax.with_sharding_constraint(
                 ck, NamedSharding(mesh, kvspec))
             cv = lax.with_sharding_constraint(
                 cv, NamedSharding(mesh, kvspec))
-        o = paged_decode_attention(q, ck, cv, page_table, pos + 1)
+        o = paged_decode_attention(q, ck, cv, page_table, pos + 1,
+                                   layer=layer)
 
     x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
 
@@ -1533,17 +1598,15 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
 
 
 @jax.named_scope(KV_GATHER_SCOPE)
-def _gather_slot_pages_batch(pool, scales, page_table, dt):
-    """All slots' pages → (S, kvh, cap, hd) with int8 dequant on the
-    gathered bytes (the whole-pool dequant would undo the HBM win)."""
-    # pool here is PER-LAYER: (n_pages, kvh, ps, hd); page_table is
-    # (S, P) so the take yields (S, P, kvh, ps, hd)
-    g = jnp.take(pool, page_table, axis=0)
-    sc = jnp.take(scales, page_table, axis=0)     # (S, P, kvh, ps)
-    g = g.astype(jnp.float32) * sc[..., None]
-    S, Pn, hkv, ps, hd = g.shape
-    return (g.transpose(0, 2, 1, 3, 4)
-             .reshape(S, hkv, Pn * ps, hd).astype(dt))
+def _gather_slot_pages_batch(pool, scales, layer, page_table, dt):
+    """All slots' pages of one layer → (S, kvh, cap, hd) with int8
+    dequant on the gathered bytes (the whole-pool dequant would undo
+    the HBM win). pool is the WHOLE (L, n_pages, ps, kvh, hd) pool;
+    page_table is (S, P), every entry in [0, n_pages)."""
+    g = pool.at[layer, page_table].get(mode="promise_in_bounds")
+    sc = scales.at[layer, page_table].get(mode="promise_in_bounds")
+    g = g.astype(jnp.float32) * sc[..., None]     # (S, P, ps, kvh, hd)
+    return _pages_to_rows(g).astype(dt)
 
 
 def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
@@ -1562,8 +1625,7 @@ def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
     (asserted in tests/test_paged_kv.py). kv: the pool dict from
     :func:`init_paged_cache` minus the per-slot vectors (donatable);
     sv as in :func:`decode_slots`."""
-    int8 = "ks" in kv
-    ps = kv["k"].shape[3]
+    ps = kv["k"].shape[2]
     cap = page_table.shape[1] * ps
     lengths = sv["lengths"].astype(jnp.int32)
     pos = jnp.minimum(lengths, cap - 1)       # per-slot write position
@@ -1574,37 +1636,14 @@ def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
 
     kvspec = None
     if mesh is not None:
-        kvspec = P(*tuple(paged_cache_specs(cfg, mesh)["k"])[1:])
+        kvspec = paged_cache_specs(cfg, mesh)["k"]
     cos_t, sin_t = rope_tables(cfg, cap)
     cos = cos_t[pos][:, None, None, :]        # (S, 1, 1, hd/2)
     sin = sin_t[pos][:, None, None, :]
 
-    if int8:
-        def body(x, xs):
-            lp, ck, cv, cks, cvs = xs
-            x, ck, cv, cks, cvs = _layer_slots_paged(
-                cfg, cos, sin, pos, phys, off, page_table, mesh,
-                kvspec, x, lp, ck, cv, cks, cvs)
-            return x, (ck, cv, cks, cvs)
-        x, (ck, cv, cks, cvs) = lax.scan(
-            body, x, (params["layers"], kv["k"], kv["v"],
-                      kv["ks"], kv["vs"]))
-        new_kv = {"k": ck, "v": cv, "ks": cks, "vs": cvs}
-    else:
-        def body(x, xs):
-            lp, ck, cv = xs
-            x, ck, cv = _layer_slots_paged(
-                cfg, cos, sin, pos, phys, off, page_table, mesh,
-                kvspec, x, lp, ck, cv)
-            return x, (ck, cv)
-        x, (ck, cv) = lax.scan(body, x,
-                               (params["layers"], kv["k"], kv["v"]))
-        if mesh is not None:
-            from jax.sharding import NamedSharding
-            full = NamedSharding(mesh, paged_cache_specs(cfg, mesh)["k"])
-            ck = lax.with_sharding_constraint(ck, full)
-            cv = lax.with_sharding_constraint(cv, full)
-        new_kv = {"k": ck, "v": cv}
+    x, new_kv = _scan_paged_layers(cfg, params, kv, x, partial(
+        _layer_slots_paged, cfg, cos, sin, pos, phys, off, page_table,
+        mesh, kvspec))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _lm_head(cfg, params, x)[:, 0]
 
@@ -1631,14 +1670,8 @@ def _scatter_slot_pages(kv, pages_row, tmp_k, tmp_v, prefix_len,
     [prefix_len, prefix_len+bucket) is re-quantized; untouched
     positions keep their RAW stored bytes — quantize∘dequant is not
     idempotent, so round-tripping shared pages would corrupt them."""
-    L, _, hkv, cap, hd = tmp_k.shape
-    ps = kv["k"].shape[3]
-    Pn = pages_row.shape[0]
-
-    def to_pages(a):                      # (L, kvh, cap, hd) → pages
-        return (a.reshape(L, hkv, Pn, ps, hd)
-                 .transpose(0, 2, 1, 3, 4))
-
+    cap = tmp_k.shape[3]
+    to_pages = partial(_rows_to_pages, ps=kv["k"].shape[2])
     kd, vd = tmp_k[:, 0], tmp_v[:, 0]     # (L, kvh, cap, hd)
     out = dict(kv)
     if int8:
@@ -1656,10 +1689,8 @@ def _scatter_slot_pages(kv, pages_row, tmp_k, tmp_v, prefix_len,
         vsc = jnp.where(written[None, None, :], vsc, old_vs)
         out["k"] = kv["k"].at[:, pages_row].set(to_pages(kq))
         out["v"] = kv["v"].at[:, pages_row].set(to_pages(vq))
-        sc_pages = lambda a: (a.reshape(L, hkv, Pn, ps)
-                               .transpose(0, 2, 1, 3))
-        out["ks"] = kv["ks"].at[:, pages_row].set(sc_pages(ksc))
-        out["vs"] = kv["vs"].at[:, pages_row].set(sc_pages(vsc))
+        out["ks"] = kv["ks"].at[:, pages_row].set(to_pages(ksc))
+        out["vs"] = kv["vs"].at[:, pages_row].set(to_pages(vsc))
     else:
         out["k"] = kv["k"].at[:, pages_row].set(
             to_pages(kd.astype(kv["k"].dtype)))
@@ -1670,14 +1701,10 @@ def _scatter_slot_pages(kv, pages_row, tmp_k, tmp_v, prefix_len,
 
 @jax.named_scope(KV_GATHER_SCOPE)
 def _gather_pages_raw(pool, pages_row):
-    """(L, n_pages, kvh, ps[, hd]) pool → contiguous (L, kvh, cap[,
+    """(L, n_pages, ps, kvh[, hd]) pool → contiguous (L, kvh, cap[,
     hd]) view of one slot's pages, NO dequant (raw stored bytes)."""
-    g = jnp.take(pool, pages_row, axis=1)
-    if g.ndim == 5:
-        L, Pn, hkv, ps, hd = g.shape
-        return g.transpose(0, 2, 1, 3, 4).reshape(L, hkv, Pn * ps, hd)
-    L, Pn, hkv, ps = g.shape
-    return g.transpose(0, 2, 1, 3).reshape(L, hkv, Pn * ps)
+    return _pages_to_rows(
+        pool.at[:, pages_row].get(mode="promise_in_bounds"))
 
 
 def prefill_slot_paged(cfg: LlamaConfig, params, tokens, true_len,
@@ -1759,8 +1786,8 @@ def inject_paged_kv(cfg: LlamaConfig, k_block, v_block, true_len,
     block is quantized per token on the way in. kv donatable. Returns
     (new kv pools, new sv)."""
     int8 = "ks" in kv
-    ps = kv["k"].shape[3]
-    L, hkv, bucket, hd = k_block.shape
+    ps = kv["k"].shape[2]
+    bucket = k_block.shape[2]
     n_blk = -(-bucket // ps)              # pages the block spans
     pad = n_blk * ps - bucket
     if pad:
@@ -1770,10 +1797,7 @@ def inject_paged_kv(cfg: LlamaConfig, k_block, v_block, true_len,
     slot = jnp.asarray(slot, jnp.int32)
     token = jnp.asarray(token, jnp.int32)
     dst = pages_row[:n_blk]
-
-    def to_pages(a):                      # (L, kvh, nP·ps, hd) → pages
-        return (a.reshape(L, hkv, n_blk, ps, hd)
-                 .transpose(0, 2, 1, 3, 4))
+    to_pages = partial(_rows_to_pages, ps=ps)
 
     out = dict(kv)
     if int8:
@@ -1781,10 +1805,8 @@ def inject_paged_kv(cfg: LlamaConfig, k_block, v_block, true_len,
         vq, vsc = _q8_token(v_block)
         out["k"] = kv["k"].at[:, dst].set(to_pages(kq))
         out["v"] = kv["v"].at[:, dst].set(to_pages(vq))
-        sc_pages = lambda a: (a.reshape(L, hkv, n_blk, ps)
-                               .transpose(0, 2, 1, 3))
-        out["ks"] = kv["ks"].at[:, dst].set(sc_pages(ksc))
-        out["vs"] = kv["vs"].at[:, dst].set(sc_pages(vsc))
+        out["ks"] = kv["ks"].at[:, dst].set(to_pages(ksc))
+        out["vs"] = kv["vs"].at[:, dst].set(to_pages(vsc))
     else:
         out["k"] = kv["k"].at[:, dst].set(
             to_pages(k_block.astype(kv["k"].dtype)))
@@ -1842,7 +1864,7 @@ def copy_page(kv, src, dst):
 # ---------------------------------------------------------------------------
 
 def _layer_slots_spec(cfg: LlamaConfig, cos, sin, qlen, phys, off,
-                      page_table, mesh, kvspec, x, lp, ck, cv,
+                      page_table, mesh, kvspec, x, lp, layer, ck, cv,
                       cks=None, cvs=None):
     """One block of the SPECULATIVE paged decode: x (S, W, dim) holds
     each slot's current token plus its drafted run (W = k + 1). Token
@@ -1856,8 +1878,7 @@ def _layer_slots_spec(cfg: LlamaConfig, cos, sin, qlen, phys, off,
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, W, hd)
-    head_ax = (kvspec[1] if kvspec is not None and len(kvspec) > 1
-               else None)
+    head_ax = _pool_head_axis(kvspec)
     q = _mcon(mesh, q, None, head_ax, None, None)
     k = _mcon(mesh, k, None, head_ax, None, None)
     v = _mcon(mesh, v, None, head_ax, None, None)
@@ -1866,19 +1887,20 @@ def _layer_slots_spec(cfg: LlamaConfig, cos, sin, qlen, phys, off,
     vnew = v.transpose(0, 2, 1, 3)
     if cks is not None:                  # int8 pool: quantize the write
         ck, cv, cks, cvs = _write_pages_q8(ck, cv, cks, cvs, knew,
-                                           vnew, phys, off)
-        kf = _gather_slot_pages_batch(ck, cks, page_table, dt)
-        vf = _gather_slot_pages_batch(cv, cvs, page_table, dt)
+                                           vnew, layer, phys, off)
+        kf = _gather_slot_pages_batch(ck, cks, layer, page_table, dt)
+        vf = _gather_slot_pages_batch(cv, cvs, layer, page_table, dt)
         o = slot_decode_attention(q, kf, vf, qlen)
     else:
-        ck, cv = _write_pages(ck, cv, knew, vnew, phys, off)
+        ck, cv = _write_pages(ck, cv, knew, vnew, layer, phys, off)
         if mesh is not None:
             from jax.sharding import NamedSharding
             ck = lax.with_sharding_constraint(
                 ck, NamedSharding(mesh, kvspec))
             cv = lax.with_sharding_constraint(
                 cv, NamedSharding(mesh, kvspec))
-        o = paged_decode_attention(q, ck, cv, page_table, qlen)
+        o = paged_decode_attention(q, ck, cv, page_table, qlen,
+                                   layer=layer)
 
     x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
 
@@ -1921,8 +1943,7 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
     rather than clamped (a clamp would corrupt the slot's last live
     page). Returns (toks (S, W) int32, emits (S, W) bool, new kv,
     new sv): the engine emits ``toks[s, :emits[s].sum()]``."""
-    int8 = "ks" in kv
-    ps = kv["k"].shape[3]
+    ps = kv["k"].shape[2]
     cap = page_table.shape[1] * ps
     S, K = drafts.shape
     W = K + 1
@@ -1943,37 +1964,14 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
 
     kvspec = None
     if mesh is not None:
-        kvspec = P(*tuple(paged_cache_specs(cfg, mesh)["k"])[1:])
+        kvspec = paged_cache_specs(cfg, mesh)["k"]
     cos_t, sin_t = rope_tables(cfg, cap)
     cos = cos_t[cw][:, None]              # (S, 1, W, hd/2)
     sin = sin_t[cw][:, None]
 
-    if int8:
-        def body(x, xs):
-            lp, ck, cv, cks, cvs = xs
-            x, ck, cv, cks, cvs = _layer_slots_spec(
-                cfg, cos, sin, qlen, phys, off, page_table, mesh,
-                kvspec, x, lp, ck, cv, cks, cvs)
-            return x, (ck, cv, cks, cvs)
-        x, (ck, cv, cks, cvs) = lax.scan(
-            body, x, (params["layers"], kv["k"], kv["v"],
-                      kv["ks"], kv["vs"]))
-        new_kv = {"k": ck, "v": cv, "ks": cks, "vs": cvs}
-    else:
-        def body(x, xs):
-            lp, ck, cv = xs
-            x, ck, cv = _layer_slots_spec(
-                cfg, cos, sin, qlen, phys, off, page_table, mesh,
-                kvspec, x, lp, ck, cv)
-            return x, (ck, cv)
-        x, (ck, cv) = lax.scan(body, x,
-                               (params["layers"], kv["k"], kv["v"]))
-        if mesh is not None:
-            from jax.sharding import NamedSharding
-            full = NamedSharding(mesh, paged_cache_specs(cfg, mesh)["k"])
-            ck = lax.with_sharding_constraint(ck, full)
-            cv = lax.with_sharding_constraint(cv, full)
-        new_kv = {"k": ck, "v": cv}
+    x, new_kv = _scan_paged_layers(cfg, params, kv, x, partial(
+        _layer_slots_spec, cfg, cos, sin, qlen, phys, off, page_table,
+        mesh, kvspec))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _lm_head(cfg, params, x)                 # (S, W, V)
 

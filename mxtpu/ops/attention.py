@@ -288,10 +288,11 @@ def slot_decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
+                           layer=None,
                            scale: Optional[float] = None,
                            kv_block: int = 512):
     """Decode attention over a PAGED KV pool (vLLM's PagedAttention,
-    Kwon et al. SOSP '23): the cache is a flat pool of fixed-size pages
+    Kwon et al. SOSP '23): the cache is a pool of fixed-size pages
     and each slot's logical KV sequence is the concatenation of the
     pool pages its row of ``page_table`` names. Gather + the blockwise
     ``slot_decode_attention`` online softmax — bit-exact with the dense
@@ -301,11 +302,20 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     as an exact no-op: m unchanged, corr = exp(0) = 1, p zeroed).
 
     q: (slots, n_heads, s, hd) — s is 1 in decode.
-    k_pages, v_pages: (n_pages, n_kv_heads, page_size, hd) — the shared
-    pool. Page 0 is the engine's scratch page (never attended: every
-    real table entry covering positions < lengths names a live page).
+    k_pages, v_pages: (n_pages, page_size, n_kv_heads, hd) — the shared
+    pool, stored token-major (page, in-page offset lead: the layout the
+    decode write wants, ``llama.init_paged_cache``) — or, with
+    ``layer`` (a traced scalar), the whole (L, n_pages, page_size,
+    n_kv_heads, hd) pool, gathered at (layer, page) in one indexed read
+    so that no layer slab is ever sliced out of it. Page 0 is the
+    engine's scratch page (never attended: every real table entry
+    covering positions < lengths names a live page).
     page_table: (slots, pages_per_slot) int32 — slot i's logical page j
-    lives at pool index ``page_table[i, j]``.
+    lives at pool index ``page_table[i, j]``. Every entry must lie in
+    ``[0, n_pages)``: the gather promises its indices are in bounds
+    (the default out-of-bounds fill is a select over every gathered
+    row), and the allocator hands out nothing else (``serve.engine.
+    PageAllocator``; zeroed entries name scratch page 0).
     lengths: (slots,) int — slot i attends positions ``[0, lengths[i])``
     of its gathered sequence — or (slots, s) for per-query lengths,
     passed straight through to the slot kernel (the speculative
@@ -314,14 +324,15 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     if q.shape[0] != page_table.shape[0]:
         raise ValueError(
             f"page_table rows {page_table.shape[0]} != slots {q.shape[0]}")
-    n_pages, hkv, page_size, d = k_pages.shape
+    page_size, hkv, d = k_pages.shape[-3:]
     slots, per_slot = page_table.shape
-    # gather (S, P, kvh, ps, hd) → contiguous (S, kvh, P*ps, hd)
+    idx = page_table if layer is None else (layer, page_table)
+    # gather (S, P, ps, kvh, hd) → contiguous (S, kvh, P*ps, hd)
     @jax.named_scope(KV_GATHER_SCOPE)
     def flat(pool):
-        g = jnp.take(pool, page_table, axis=0)
-        return (g.transpose(0, 2, 1, 3, 4)
-                 .reshape(slots, hkv, per_slot * page_size, d))
+        g = pool.at[idx].get(mode="promise_in_bounds")
+        return (g.reshape(slots, per_slot * page_size, hkv, d)
+                 .transpose(0, 2, 1, 3))
     return slot_decode_attention(q, flat(k_pages), flat(v_pages), lengths,
                                  scale=scale, kv_block=kv_block)
 

@@ -17,8 +17,15 @@ Contracts:
   backpressure) and still drains every request bit-exactly;
 - a journaled page-table restore (``submit_prefilled`` with a resume
   rng mid-stream) continues the stream exactly where the crashed
-  engine left off.
+  engine left off;
+- the page gathers promise their indices are in bounds: no table row
+  the allocator's grants can build holds an index outside
+  ``[0, n_pages)``, and the programs read the edges of that range
+  (scratch page 0, page ``n_pages - 1``, a page two slots share)
+  exactly as ``generate`` computes.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -99,6 +106,56 @@ def test_page_allocator_guards_scratch_and_dead_pages():
         PageAllocator(1)                    # scratch alone is not a pool
 
 
+@pytest.mark.parametrize("seed", range(4))
+def test_page_allocator_rows_stay_in_bounds(seed):
+    """The invariant the gathers' ``promise_in_bounds`` rests on: walk
+    the allocator through admit (fresh pages, sometimes behind another
+    row's shared prefix), copy-on-write fork, free and preempt in a
+    seeded random order; after every step each table row holds only
+    scratch page 0 or a LIVE page in ``[1, n_pages)``, and a page's
+    refcount is the number of rows that name it."""
+    rng = np.random.default_rng(seed)
+    n_pages, slots, per_slot = 12, 4, 4
+    a = PageAllocator(n_pages)
+    table = np.zeros((slots, per_slot), np.int32)
+
+    def check():
+        assert table.min() >= 0 and table.max() < n_pages
+        held = table[table > 0]
+        assert not set(held.tolist()) & set(a._free)
+        for p in range(1, n_pages):
+            assert a.refcount(p) == int((held == p).sum())
+        assert a.free_pages == n_pages - 1 - len(set(held.tolist()))
+
+    for _ in range(300):
+        slot = int(rng.integers(slots))
+        row = table[slot]
+        live = [int(p) for p in row if p]
+        op = rng.choice(["admit", "fork", "free", "preempt"])
+        if op == "admit" and not live:
+            donor = table[int(rng.integers(slots))]
+            shared = [int(p) for p in donor if p][:int(rng.integers(3))]
+            fresh = a.alloc(int(rng.integers(1, per_slot + 1 - len(shared))))
+            if fresh is None:              # all-or-nothing: row untouched
+                check()
+                continue
+            a.retain(shared)
+            row[:len(shared) + len(fresh)] = shared + fresh
+        elif op == "fork" and live:
+            i = int(rng.integers(len(live)))
+            if a.refcount(live[i]) > 1:    # shared: a private copy
+                got = a.alloc(1)
+                if got is not None:
+                    a.release([live[i]])
+                    row[i] = got[0]
+        elif op in ("free", "preempt") and live:
+            # a finished request and a preempted one both hand back
+            # every hold of the row and leave it on scratch
+            a.release(live)
+            row[:] = 0
+        check()
+
+
 def test_prefix_cache_longest_common_prefix_and_eviction():
     a = PageAllocator(10)
     c = PrefixCache(a, max_entries=2)
@@ -172,12 +229,14 @@ def test_paged_attention_matches_slot_attention(S, hq, hkv):
     n_pages = 1 + S * ppr
     perm = rng.permutation(np.arange(1, n_pages))
     table = np.asarray(perm, np.int32).reshape(S, ppr)
-    kp = np.zeros((n_pages, hkv, ps, hd), np.float32)
-    vp = np.zeros((n_pages, hkv, ps, hd), np.float32)
+    kp = np.zeros((n_pages, ps, hkv, hd), np.float32)   # token-major
+    vp = np.zeros((n_pages, ps, hkv, hd), np.float32)
     for s in range(S):
         for j in range(ppr):
-            kp[table[s, j]] = np.asarray(k[s, :, j * ps:(j + 1) * ps])
-            vp[table[s, j]] = np.asarray(v[s, :, j * ps:(j + 1) * ps])
+            kp[table[s, j]] = np.asarray(
+                k[s, :, j * ps:(j + 1) * ps]).transpose(1, 0, 2)
+            vp[table[s, j]] = np.asarray(
+                v[s, :, j * ps:(j + 1) * ps]).transpose(1, 0, 2)
     ref = slot_decode_attention(q, k, v, lengths, kv_block=16)
     out = paged_decode_attention(q, jnp.asarray(kp), jnp.asarray(vp),
                                  jnp.asarray(table), lengths,
@@ -190,14 +249,77 @@ def test_paged_attention_shared_pages_read_path():
     (CoW sharing before any fork) read identical prefixes."""
     rng = np.random.default_rng(12)
     hkv, hq, hd, ps = 2, 4, 16, 8
-    kp = jnp.asarray(rng.standard_normal((5, hkv, ps, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((5, hkv, ps, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((5, ps, hkv, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((5, ps, hkv, hd)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((2, hq, 1, hd)), jnp.float32)
     table = jnp.asarray([[1, 2], [1, 3]], jnp.int32)   # page 1 shared
     lengths = jnp.asarray([8, 8])                      # prefix only
     out = paged_decode_attention(jnp.repeat(q[:1], 2, 0), kp, vp,
                                  table, lengths)
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out[1]))
+
+
+# ---------------------------------------------------------------------------
+# programs: the edges of the page range the gathers promise to stay in
+# ---------------------------------------------------------------------------
+_EDGE_PAGES = 9                             # scratch + 8
+_EDGE_SHARED = [7, 3, 9, 1, 5, 2, 8, 4]     # one whole page of 8
+# (table row, prompt, prefix_len) per seated slot; unnamed row entries
+# stay 0 and alias the scratch page
+_EDGE_CASES = {
+    # one live slot whose row tail, and the idle slot's whole row, name
+    # page 0; the idle slot's write lands there every step
+    "scratch_page_0": [([2, 5], [21, 22, 23], 0)],
+    "last_page": [([_EDGE_PAGES - 1, 1], [31, 32, 33, 34, 35], 0),
+                  ([4, _EDGE_PAGES - 2], [41, 42], 0)],
+    # page 3 holds the shared first page of both prompts; slot 1 is
+    # admitted warm behind it
+    "shared_page": [([3, 6], _EDGE_SHARED, 0),
+                    ([3, 7], _EDGE_SHARED + [13], 8)],
+}
+
+
+@pytest.fixture(scope="module")
+def paged_programs(cfg):
+    return (jax.jit(partial(llama.prefill_slot_paged, cfg)),
+            jax.jit(partial(llama.decode_slots_paged, cfg)))
+
+
+@pytest.mark.parametrize("case", sorted(_EDGE_CASES))
+def test_paged_programs_at_the_page_range_edges(cfg, params,
+                                                paged_programs, case):
+    """Prefill and decode driven directly, so the table is exactly what
+    the case says: every stream equals greedy ``generate``."""
+    prefill, decode = paged_programs
+    slots, per_slot, ps, mnew = 2, 4, 8, 6
+    state = llama.init_paged_cache(cfg, slots, _EDGE_PAGES, ps)
+    kv = {n: state[n] for n in ("k", "v")}
+    sv = {n: state[n] for n in ("lengths", "tokens", "rngs")}
+    table = np.zeros((slots, per_slot), np.int32)
+    seated = _EDGE_CASES[case]
+    streams = []
+    for slot, (row, prompt, prefix_len) in enumerate(seated):
+        table[slot, :len(row)] = row
+        padded = np.zeros((1, 8), np.int32)
+        suffix = prompt[prefix_len:]
+        padded[0, :len(suffix)] = suffix
+        tok, kv, sv = prefill(
+            params, padded, np.int32(len(prompt)), np.int32(prefix_len),
+            table[slot].copy(), np.int32(slot), kv, sv,
+            jax.random.PRNGKey(0), np.float32(0.0),
+            np.int32(cfg.vocab_size), np.float32(1.0))
+        streams.append([int(np.asarray(tok)[0])])
+    active = np.arange(slots) < len(seated)
+    for _ in range(mnew - 1):
+        sampled, kv, sv = decode(
+            params, kv, sv, active, table,
+            np.zeros(slots, np.float32),
+            np.full(slots, cfg.vocab_size, np.int32),
+            np.ones(slots, np.float32))
+        for slot in range(len(seated)):
+            streams[slot].append(int(np.asarray(sampled)[slot]))
+    for (_, prompt, _), got in zip(seated, streams):
+        assert got == llama_refs.reference(cfg, params, prompt, mnew)
 
 
 # ---------------------------------------------------------------------------
@@ -239,6 +361,33 @@ def test_paged_engine_bit_exact_with_prefix_sharing(cfg, params):
     assert got2 == llama_refs.reference(cfg, params, p2, 5, seed=7,
                                         temperature=1.0)
     assert e.kv_cache_stats()["prefix_hits"] > st["prefix_hits"]
+
+
+def test_paged_sharded_tp2_streams_match_generate(cfg, params):
+    """The paged pool on a tp mesh: kv heads (axis 3 of the token-major
+    pool) sharded, layer, page and offset whole; streams unchanged."""
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 (virtual) devices")
+    from mxtpu.parallel import mesh as pmesh
+    from mxtpu.parallel.sharding import shard_pytree
+
+    mesh = pmesh.create_mesh(tp=2, devices=jax.devices()[:2])
+    e = paged_engine(
+        cfg, shard_pytree(params, mesh, llama.sharding_rules(cfg)),
+        mesh=mesh)
+    k = e._kv["k"]
+    assert k.shape[2:4] == (8, cfg.n_kv_heads), k.shape
+    assert tuple(k.sharding.spec) == (None, None, None, "tp"), k.sharding
+    reqs = [dict(prompt=[7, 3, 9, 1, 5, 2, 8, 4, 6, 11, 12],
+                 max_new_tokens=6, temperature=1.0, seed=0),
+            dict(prompt=[21, 22, 23], max_new_tokens=5, temperature=0.0)]
+    rids = [e.submit(Request(**r)) for r in reqs]
+    out = e.run()
+    for rid, r in zip(rids, reqs):
+        assert [int(t) for t in out[rid]] == llama_refs.reference(
+            cfg, params, r["prompt"], r["max_new_tokens"],
+            seed=r.get("seed", 0), temperature=r["temperature"])
+    assert e.compile_count <= e.n_buckets + 2
 
 
 @pytest.mark.slow   # ~11s; paged_kv_smoke drives pool-bound admission

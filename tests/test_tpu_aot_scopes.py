@@ -1,9 +1,13 @@
-"""The scope map on the TPU compiler's own output, without a chip: the
-decode program compiled for a described v5e keeps every instruction's
-``op_name``, and the operations a trace will show (fusions, copies,
-custom calls) land under the model's scopes. The only test file that
-describes a TPU topology (one process may hold libtpu: the
-on-chip-measurement guide, section 2), and only inside fixtures."""
+"""The TPU compiler's own output, without a chip: the paged serve
+programs compiled for a described v5e. The decode program keeps every
+instruction's ``op_name``, and the operations a trace will show
+(fusions, copies, custom calls) land under the model's scopes; and no
+paged program moves the KV pool — the write updates the donated pool in
+place and the page gather reads it, nothing else touches pool-sized
+bytes. The only test file that describes a TPU topology (one process
+may hold libtpu: the on-chip-measurement guide, section 2), and only
+inside fixtures."""
+import math
 import os
 import re
 import time
@@ -16,6 +20,12 @@ import pytest
 
 from mxtpu.models import llama
 from mxtpu.telemetry import scopes as tscopes
+
+# Mistral's head shapes (32 query / 8 kv heads of 128); depth, FFN and
+# row length cut. 257 pages: a prime, so a shape that holds the pool or
+# one layer's slab of it is known by that factor whatever XLA folds it
+# into, and a slot's gathered rows (8 x 32 pages) never are.
+SLOTS, PAGE, N_PAGES, LAYERS, BUCKET = 8, 16, 257, 4, 128
 
 
 @pytest.fixture(scope="module")
@@ -32,14 +42,14 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
-def decode_text(one_chip):
-    """``decode_slots_paged`` at Mistral's head shapes, two layers, for
-    one v5e chip: the optimised HLO text."""
-    cfg = replace(llama.CONFIGS["tiny"], vocab_size=32768, dim=1024,
-                  n_layers=2, n_heads=8, n_kv_heads=2, hidden_dim=2048,
-                  max_seq_len=512, dtype=jnp.bfloat16,
+def compiled(one_chip):
+    """name -> compiled executable of ``decode_slots_paged``, one
+    ``prefill_slot_paged`` bucket and ``copy_page``, pool donated as
+    the engine donates it, for one v5e chip."""
+    cfg = replace(llama.CONFIGS["tiny"], vocab_size=32768, dim=4096,
+                  n_layers=LAYERS, n_heads=32, n_kv_heads=8,
+                  hidden_dim=2048, max_seq_len=512, dtype=jnp.bfloat16,
                   param_dtype=jnp.bfloat16)
-    slots, page, n_pages = 8, 16, 129
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -50,22 +60,39 @@ def decode_text(one_chip):
     params = on_chip(jax.eval_shape(partial(llama.init_params, cfg),
                                     jax.random.PRNGKey(0)))
     state = jax.eval_shape(
-        lambda: llama.init_paged_cache(cfg, slots, n_pages, page))
+        lambda: llama.init_paged_cache(cfg, SLOTS, N_PAGES, PAGE))
     kv = on_chip({n: state[n] for n in ("k", "v")})
     sv = on_chip({n: state[n] for n in ("lengths", "tokens", "rngs")})
-    fn = partial(llama.decode_slots_paged, cfg)
-    fn.__name__ = "decode_slots_paged"
+    per_slot = cfg.max_seq_len // PAGE
+    scalar = partial(arg, ())
+    decode = partial(llama.decode_slots_paged, cfg)
+    decode.__name__ = "decode_slots_paged"
+    prefill = partial(llama.prefill_slot_paged, cfg)
+    prefill.__name__ = "prefill_slot_paged"
+    lowered = {
+        "decode_slots_paged": jax.jit(decode, donate_argnums=(1,)).lower(
+            params, kv, sv, arg((SLOTS,), jnp.bool_),
+            arg((SLOTS, per_slot), jnp.int32), arg((SLOTS,), jnp.float32),
+            arg((SLOTS,), jnp.int32), arg((SLOTS,), jnp.float32)),
+        "prefill_slot_paged": jax.jit(prefill, donate_argnums=(6,)).lower(
+            params, arg((1, BUCKET), jnp.int32), scalar(jnp.int32),
+            scalar(jnp.int32), arg((per_slot,), jnp.int32),
+            scalar(jnp.int32), kv, sv, arg((2,), jnp.uint32),
+            scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.float32)),
+        "copy_page": jax.jit(llama.copy_page, donate_argnums=(0,)).lower(
+            kv, scalar(jnp.int32), scalar(jnp.int32)),
+    }
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-            params, kv, sv, arg((slots,), jnp.bool_),
-            arg((slots, cfg.max_seq_len // page), jnp.int32),
-            arg((slots,), jnp.float32), arg((slots,), jnp.int32),
-            arg((slots,), jnp.float32)).compile()
+        return {name: low.compile() for name, low in lowered.items()}
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_on)
-    return compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def decode_text(compiled):
+    return compiled["decode_slots_paged"].as_text()
 
 
 def test_tpu_program_keeps_its_name_and_parses_fast(decode_text):
@@ -89,3 +116,94 @@ def test_tpu_fusions_land_under_the_model_scopes(decode_text, scope):
     assert set(shown.values()) <= {
         "", "embed", "norm", "qkv_proj", "rope", "kv_write", "kv_gather",
         "attention", "out_proj", "mlp", "lm_head", "sampler"}
+
+
+# -- no paged program moves the pool ---------------------------------------
+_COMPUTATION = re.compile(r"\s*(ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{$")
+_INSTRUCTION = re.compile(
+    r"\s*(ROOT\s+)?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\((.*)")
+_SLAB = N_PAGES * PAGE * 8 * 128            # one layer of K (or V)
+
+
+def _computations(text):
+    """HLO text -> {computation: [(name, elements, opcode, rest, root)]}
+    for its array-valued instructions (tuples move no bytes)."""
+    out, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = out.setdefault(m.group(2), [])
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and cur is not None:
+            root, name, _, dims, opcode, rest = m.groups()
+            n = math.prod(int(d) for d in dims.split(",")) if dims else 1
+            cur.append((name, n, opcode, rest, bool(root)))
+    return out
+
+
+def _pool_sized(n):
+    return n >= _SLAB and n % N_PAGES == 0
+
+
+def _pool_movers(text):
+    """The executed instructions (fusion bodies are their fusion's)
+    whose result is the pool or a layer's slab of it, other than the
+    in-place writes: a fusion rooted in a ``scatter`` or a
+    ``dynamic-update-slice`` whose first operand is the pool itself and
+    whose every other operand is smaller than a slab."""
+    comps = _computations(text)
+    bodies = {m.group(1) for instrs in comps.values()
+              for _, _, opcode, rest, _ in instrs if opcode == "fusion"
+              for m in [re.search(r"calls=%?([\w.\-]+)", rest)] if m}
+    movers = []
+    for comp, instrs in comps.items():
+        if comp in bodies:
+            continue
+        sizes = {name: n for name, n, _, _, _ in instrs}
+        for name, n, opcode, rest, _ in instrs:
+            if not _pool_sized(n) or opcode in (
+                    "parameter", "get-tuple-element", "bitcast"):
+                continue
+            if opcode == "fusion":
+                body = comps[re.search(r"calls=%?([\w.\-]+)",
+                                       rest).group(1)]
+                root = next(op for _, _, op, _, is_root in body if is_root)
+                operands = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
+                if (root in ("scatter", "dynamic-update-slice")
+                        and sizes.get(operands[0]) == n
+                        and all(sizes.get(o, 0) < _SLAB
+                                for o in operands[1:])):
+                    continue
+            movers.append(f"{comp}: {name} = {opcode}[{n} elements]")
+    return movers
+
+
+@pytest.mark.parametrize("program", ["decode_slots_paged",
+                                     "prefill_slot_paged", "copy_page"])
+def test_tpu_paged_programs_leave_the_pool_in_place(compiled, program):
+    """No copy, slice, update-slice, select or loop fusion produces the
+    pool or a slab of it: the write's scatter (``copy_page``: its
+    one-page update-slice) updates the donated buffer where it lies.
+    Stored head-major, or scanned as ``xs``/``ys``, XLA relays the pool
+    out for the scatter and back, ten pool-sized operations a step."""
+    exe = compiled[program]
+    assert _pool_movers(exe.as_text()) == []
+    # K and V both: the program never holds a second pool
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * LAYERS * _SLAB * 2
+    assert mem.temp_size_in_bytes < LAYERS * _SLAB * 2, mem
+
+
+def test_tpu_page_gather_selects_only_indices(decode_text):
+    """The gather promises its bounds: under ``kv_gather`` nothing
+    selects over gathered rows (``jnp.take``'s default fill was a
+    ``select_n`` over every slot's whole row, 12 ms of an 85 ms step);
+    what is left normalises the page table's own entries."""
+    rows = SLOTS * (512 // PAGE)
+    for line in decode_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(5) == "select" and "/kv_gather/" in line:
+            dims = m.group(4)
+            assert m.group(3) in ("s32", "pred") and math.prod(
+                int(d) for d in dims.split(",")) <= rows, line
