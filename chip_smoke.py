@@ -41,6 +41,9 @@ WIDTHS = dict(vocab_size=32000, dim=2048, n_layers=8, n_heads=16,
 SERVE_SHAPES = ((50, 24, 0.0), (100, 40, 0.7), (200, 16, 0.0),
                 (200, 64, 0.7))
 SERVE_ENGINE = dict(max_slots=8, max_len=768, min_bucket=64)
+# the same for the sambay leg, all greedy: the longer prompt wraps a
+# 512-key window ring three times
+SAMBAY_SHAPES = ((300, 16, 0.0), (1700, 24, 0.0))
 
 
 def _compiles() -> int:
@@ -496,6 +499,80 @@ def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
     return info, streams
 
 
+def phase_serve_sambay(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
+    """The SambaY family (``models/sambay.py``: state-space, window,
+    full, GMU and cross-attention layers) through a paged
+    ``ServeEngine`` behind ``Gateway.start_http``, once in the config's
+    bf16 and once in float32 at ``highest`` precision, against its own
+    ``forward``: ``jobs`` are asked greedily, and each emitted token's
+    logit in one ``forward`` over prompt + stream is held against that
+    position's largest. In float32 the two must agree (gap under
+    ``tol_f32``); in bf16 the worst gap is reported (a near-tie may
+    flip, as it does for llama)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxtpu.models import sambay
+    from mxtpu.serve import ServeEngine
+    from mxtpu.serve.gateway import Gateway, GatewayClient
+
+    t0 = time.perf_counter()
+    info = {"setup_s": 0.0, "run_s": 0.0, "requests": 2 * len(jobs)}
+    for dtype, precision in ((cfg.dtype, None), (jnp.float32, "highest")):
+        c = replace(cfg, dtype=dtype, param_dtype=dtype)
+        name = np.dtype(dtype).name
+        with _matmul_precision(precision):
+            params = jax.jit(lambda k: sambay.init_params(c, k))(
+                jax.random.PRNGKey(0))
+            gw = Gateway(lambda: ServeEngine(c, params, paged=True,
+                                             **engine_kw),
+                         n_replicas=1, queue_max=4 * len(jobs),
+                         supervisor_opts={"stall_s": 900.0,
+                                          "warmup_s": 900.0})
+            results = [None] * len(jobs)
+            try:
+                port = gw.start_http(port=0)
+                engine = gw.backend.replicas()[0].engine
+
+                def ask(i):
+                    results[i] = GatewayClient(
+                        "127.0.0.1", port, timeout=900).generate(
+                            jobs[i]["prompt"], jobs[i]["mnew"],
+                            seed=jobs[i]["seed"], temperature=0.0)
+                ask(0)                   # compiles ahead of the others
+                info["setup_s"] += time.perf_counter() - t0
+                t0 = time.perf_counter()
+                threads = [threading.Thread(target=ask, args=(i,))
+                           for i in range(len(jobs))]
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(900)
+                assert not any(t.is_alive() for t in threads), "a client hung"
+                assert engine.compile_count == 1 + engine.n_buckets
+                kv = engine.kv_cache_stats()
+                assert kv["state_bytes_per_slot"] > 0, kv
+            finally:
+                gw.close()
+            fwd = jax.jit(lambda p, t: sambay.forward(c, p, t))
+            worst = 0.0
+            for job, rec in zip(jobs, results):
+                assert rec is not None and rec["status"] == 200 and \
+                    len(rec["tokens"]) == job["mnew"], rec
+                n0 = len(job["prompt"])
+                seq = job["prompt"] + rec["tokens"]
+                seq = seq + [0] * (-len(seq) % 128)
+                lg = fwd(params, jnp.asarray(seq, jnp.int32)[None])[0]
+                lg = np.asarray(lg[n0 - 1:n0 - 1 + job["mnew"]])
+                took = lg[np.arange(job["mnew"]), rec["tokens"]]
+                worst = max(worst, float((lg.max(-1) - took).max()))
+            info[f"worst_gap_{name}"] = worst
+            info["run_s"] += time.perf_counter() - t0
+            t0 = time.perf_counter()
+    assert info["worst_gap_float32"] <= tol_f32, info
+    return info
+
+
 # -- the run ----------------------------------------------------------------
 def _run(name, fn, *args, **kw):
     """One phase: PASS line with its facts, or FAIL and a non-zero
@@ -542,6 +619,16 @@ def main():
     _run("serve_f32", phase_serve,
          replace(serve_cfg, dtype=jnp.float32), jobs, **SERVE_ENGINE,
          precision="highest", must_match=True)
+
+    # the second serving family, at its published widths and a small
+    # depth (two Mamba+window pairs, layers "4/5", one GMU+cross pair):
+    # prompts longer than three of its 512-token windows
+    from mxtpu.models import sambay
+    sambay_cfg = sambay.SambaYConfig(n_layers=8, max_seq_len=2048)
+    _run("serve_sambay", phase_serve_sambay, sambay_cfg,
+         make_jobs(sambay_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
+                   shared_prefix=0),
+         max_slots=4, max_len=2048, min_bucket=256)
 
     if jax.device_count() >= 4:
         # the same two phases over a mesh with more than one

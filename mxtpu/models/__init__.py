@@ -11,8 +11,20 @@ step, remat, scan-over-layers) — the idiomatic shape for pjit/XLA.
 from . import bert
 from . import llama
 from . import resnet
+from . import sambay
 from .bert import BertConfig
 from .llama import LlamaConfig
 from .resnet import ResNetConfig
+from .sambay import SambaYConfig
 
-__all__ = ["llama", "resnet", "LlamaConfig", "ResNetConfig"]
+__all__ = ["llama", "resnet", "sambay", "LlamaConfig", "ResNetConfig",
+           "SambaYConfig", "SERVING_FAMILIES", "serving_family"]
+
+# the families ``serve.ServeEngine`` can be given, by the ``family`` of
+# their config class; each module has llama.py's serving surface
+SERVING_FAMILIES = {"llama": llama, "sambay": sambay}
+
+
+def serving_family(cfg):
+    """The module whose programs serve ``cfg``."""
+    return SERVING_FAMILIES[cfg.family]

@@ -27,7 +27,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field, replace
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, ClassVar, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +57,7 @@ __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
 
 @dataclass(frozen=True)
 class LlamaConfig:
+    family: ClassVar[str] = "llama"  # models.serving_family's key
     vocab_size: int = 32000
     dim: int = 4096
     n_layers: int = 32

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
-           "ulysses_attention",
+           "ulysses_attention", "window_attention",
            "ring_attention", "slot_decode_attention",
            "paged_decode_attention"]
 
@@ -208,6 +208,58 @@ def flash_attention(q, k, v, *, causal: bool = False,
     with jax.named_scope("flash_attention_blockwise"):
         return blockwise_attention(q, kr, vr, causal=causal, scale=scale,
                                    kv_block=kv_block)
+
+
+def window_attention(q, k, v, *, window: int,
+                     scale: Optional[float] = None, block: int = 512,
+                     k_start=0):
+    """Causal sliding-window attention for a prefill: query ``t`` sees
+    keys ``(t - window, t]``, itself and the ``window - 1`` before it.
+    Blockwise online softmax (:func:`_online_block`) in which a block of
+    queries reads only the key blocks its window can reach — itself and
+    ``ceil((window - 1) / block)`` blocks behind it — so the work is
+    ``seq x window``, not ``seq x seq``; blocks wholly outside the
+    window are never read. q: (b, h, s, d); k: (b, hk, s, d); v:
+    (b, hk, s, dv) (GQA: ``h % hk == 0``). Keys before position
+    ``k_start`` (an int, traced or not) are not seen: a chunk of a longer
+    sequence puts the window's worth of keys before it in front, and
+    at the sequence's start there are none."""
+    k, v = _repeat_kv(q, k, v)
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, h, s, d = q.shape
+    dv = v.shape[-1]
+    block = min(block, s)
+    pad = -s % block
+    back = -(-(window - 1) // block)     # key blocks behind a query block
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    # keys padded in front by ``back`` blocks, so that query block i
+    # reads padded blocks i .. i + back whatever i is; positions < 0
+    # (and the tail's, by causality) are masked
+    k = jnp.pad(k, ((0, 0), (0, 0), (back * block, pad), (0, 0)))
+    v = jnp.pad(v, ((0, 0), (0, 0), (back * block, pad), (0, 0)))
+    at = jnp.arange(block)
+
+    def one(i):
+        qi = lax.dynamic_slice_in_dim(q, i * block, block, axis=2)
+        qpos = i * block + at
+        m = jnp.full((b, h, block), _NEG_INF, jnp.float32)
+        l = jnp.zeros((b, h, block), jnp.float32)
+        o = jnp.zeros((b, h, block, dv), jnp.float32)
+        for j in range(back + 1):
+            start = (i + j) * block
+            kpos = start - back * block + at
+            seen = ((kpos[None, :] <= qpos[:, None])
+                    & (kpos[None, :] > qpos[:, None] - window)
+                    & (kpos[None, :] >= k_start))
+            m, l, o = _online_block(
+                qi, lax.dynamic_slice_in_dim(k, start, block, axis=2),
+                lax.dynamic_slice_in_dim(v, start, block, axis=2),
+                m, l, o, scale, False, 0, 0,
+                extra_mask=seen[None, None])
+        return _finalize(m, l, o, q.dtype)
+
+    out = lax.map(one, jnp.arange((s + pad) // block))
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, h, s + pad, dv)[:, :, :s]
 
 
 @jax.named_scope(ATTENTION_SCOPE)

@@ -39,19 +39,20 @@ from __future__ import annotations
 
 import heapq
 import itertools
+from collections import deque
 import os
 import threading
 import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
 from .. import telemetry
 from ..telemetry import distributed as dtrace
-from ..models import llama
+from ..models import serving_family
 
 __all__ = ["Request", "KVHandoff", "ServeEngine", "bucket_for",
            "resume_key", "PageAllocator", "PrefixCache",
@@ -483,16 +484,37 @@ class _Dispatch:
     proposed: Optional[np.ndarray] = None          # spec: (S,) host
 
 
+@dataclass
+class _PrefillJob:
+    """A prompt on its way through chunked prefill: ``done`` of its
+    tokens are in the stage."""
+    slot: int
+    rid: int
+    req: "Request"
+    prompt: np.ndarray
+    done: int = 0
+
+
 class ServeEngine:
     """Continuous-batching scheduler over one model + one slot bank.
 
-    Args: ``cfg``/``params`` — a llama config and parameter pytree
-    (the weight-only int8 tree from ``quantize_params_int8`` rides the
-    same programs). ``max_slots``/``max_len``/``min_bucket`` default
+    Args: ``cfg``/``params`` — a config and parameter pytree of a
+    family in ``models.SERVING_FAMILIES`` (llama, sambay): the engine
+    takes every program it runs from that family's module, found once
+    here from ``cfg.family``, and refuses at construction the options
+    the family's ``SERVE_UNSUPPORTED`` names, with the mechanism in
+    the way (a llama weight-only int8 tree from ``quantize_params_int8``
+    rides the same programs). ``max_slots``/``max_len``/``min_bucket`` default
     from ``MXTPU_SERVE_MAX_SLOTS`` / the config's ``max_seq_len`` /
     ``MXTPU_SERVE_MIN_BUCKET``. ``mesh`` serves sharded (cache per
     ``llama.slot_cache_specs``, params as placed by the training
-    rules)."""
+    rules). ``prefill_chunk`` (paged, a family with
+    ``prefill_slot_paged_chunk``: sambay) prefills a prompt that many
+    tokens at a time, at most one chunk between two decode steps once
+    the running requests are no fewer than the waiting ones: the gap a
+    running request sees beside an admission is one chunk's time
+    whatever the prompt's length, and the prompt's first token comes
+    that many iterations later."""
 
     def __init__(self, cfg, params, *, max_slots: Optional[int] = None,
                  max_len: Optional[int] = None,
@@ -505,10 +527,14 @@ class ServeEngine:
                  prefix_cache: Optional[bool] = None,
                  int8_pages: Optional[bool] = None,
                  speculate_k: Optional[int] = None,
-                 drafter: Optional[Callable] = None):
+                 drafter: Optional[Callable] = None,
+                 prefill_chunk: Optional[int] = None):
         self.cfg = cfg
         self.params = params
         self.mesh = mesh
+        # the family's module: every program below comes from it
+        fam = self._family = serving_family(cfg)
+        self._unsupported = getattr(fam, "SERVE_UNSUPPORTED", {})
         # deadlines are measured on THIS clock (monotonic seconds);
         # injectable so deadline/autoscale tests are deterministic
         self._clock = clock or time.monotonic
@@ -545,8 +571,8 @@ class ServeEngine:
                 self.max_slots * self._pages_per_slot + 1))
             self.prefix_cache_enabled = (
                 prefix_cache if prefix_cache is not None
-                else os.environ.get("MXTPU_KV_PREFIX_CACHE", "1")
-                != "0")
+                else "prefix_cache" not in self._unsupported
+                and os.environ.get("MXTPU_KV_PREFIX_CACHE", "1") != "0")
             self.int8_pages = (
                 bool(int8_pages) if int8_pages is not None
                 else os.environ.get("MXTPU_KV_INT8_PAGES", "0") == "1")
@@ -572,6 +598,18 @@ class ServeEngine:
                 "speculate_k requires paged=True (the verify program "
                 "runs against the paged KV layout)")
         self._drafter = drafter or ngram_drafter
+        self._refuse({"paged=False": not self.paged,
+                      "prefix_cache": self.prefix_cache_enabled,
+                      "speculate_k": self.speculate_k,
+                      "int8_pages": self.int8_pages,
+                      "mesh": mesh is not None})
+        self.prefill_chunk = int(prefill_chunk or 0)
+        if self.prefill_chunk and not (
+                self.paged and hasattr(fam, "prefill_slot_paged_chunk")):
+            raise ValueError(
+                "prefill_chunk needs paged=True and a family whose "
+                "prefill carries its state from chunk to chunk "
+                f"(sambay); got paged={self.paged}, {cfg.family}")
         if self.speculate_k:
             # the host drafter conditions on every token emitted so
             # far, so the previous step's tokens must be read back
@@ -581,17 +619,17 @@ class ServeEngine:
             self.overlap = False
 
         if self.paged:
-            state = llama.init_paged_cache(
+            state = fam.init_paged_cache(
                 cfg, self.max_slots, self.n_pages, self.page_size,
                 mesh=mesh, int8=self.int8_pages)
-            pool_keys = (("k", "v", "ks", "vs") if self.int8_pages
-                         else ("k", "v"))
-            self._kv = {n: state[n] for n in pool_keys}
         else:
-            state = llama.init_slot_cache(cfg, self.max_slots,
-                                          self.max_len, mesh=mesh)
-            self._kv = {"k": state["k"], "v": state["v"]}
-        self._sv = {n: state[n] for n in ("lengths", "tokens", "rngs")}
+            state = fam.init_slot_cache(cfg, self.max_slots,
+                                        self.max_len, mesh=mesh)
+        # the small per-slot vectors; everything else is the donated
+        # state (llama: the K/V bank or pools; sambay: pools plus the
+        # fixed per-slot rings and recurrent state)
+        self._sv = {n: state.pop(n) for n in ("lengths", "tokens", "rngs")}
+        self._kv = state
         # the kv bank is donated through every program (in-place in
         # HBM); the small vectors are not, so the previous step's
         # sampled tokens stay readable during the overlapped sync.
@@ -601,12 +639,12 @@ class ServeEngine:
         # watch_jit(): each program is compiled under the name of the
         # model function it runs, so a trace reads
         # jit_decode_slots_paged, not jit__unknown
-        decode = llama.decode_slots_paged if self.paged \
-            else llama.decode_slots
+        decode = fam.decode_slots_paged if self.paged \
+            else fam.decode_slots
         self._decode = telemetry.watch_jit(
             partial(decode, cfg, mesh=mesh), "serve_decode",
             decode.__name__, loop="serve", donate_argnums=(1,))
-        self._prefills: Dict[int, Any] = {}
+        self._prefills: Dict[Any, Any] = {}
         self._injects: Dict[int, Any] = {}
         self._spec_decode = None
         if self.speculate_k:
@@ -615,7 +653,7 @@ class ServeEngine:
             # this; steps where no slot has a draft still run the
             # plain decode program (mixed stepping, same bank)
             self._spec_decode = telemetry.watch_jit(
-                partial(llama.decode_slots_spec, cfg, mesh=mesh),
+                partial(fam.decode_slots_spec, cfg, mesh=mesh),
                 "serve_spec_verify", "decode_slots_spec", loop="serve",
                 donate_argnums=(1,))
         if self.paged:
@@ -627,19 +665,30 @@ class ServeEngine:
             self._pages = PageAllocator(self.n_pages)
             self._prefix = (PrefixCache(self._pages)
                             if self.prefix_cache_enabled else None)
-            # a per-engine wrapper (NOT bare llama.copy_page, which
+            # a per-engine wrapper (NOT the bare copy_page, which
             # watch_jit's partial is): jit caches key on callable
             # identity, so a shared function would alias cache sizes
             # across engines and skew both the recompile watcher and
             # compile_count's churn gate
             self._copy_fn = telemetry.watch_jit(
-                llama.copy_page, "serve_copy_page", "copy_page",
+                fam.copy_page, "serve_copy_page", "copy_page",
                 donate_argnums=(0,))
             # engine-local tallies (the telemetry counters are
             # process-wide totals shared across engines)
             self._prefix_hits = 0
             self._prefix_misses = 0
             self._cow_forks = 0
+        # chunked prefill: the prompts being prefilled, in order of
+        # admission (the head's chunks run first), the stage the head's
+        # chunks hand on through (one prompt at a time, outside the
+        # slot bank), and which slots are seated but not yet running
+        self._jobs: Deque[_PrefillJob] = deque()
+        self._prefilling = np.zeros(self.max_slots, bool)
+        self._stage = None
+        if self.prefill_chunk:
+            self._stage = fam.init_prefill_stage(
+                cfg, self._pages_per_slot * self.page_size,
+                self.prefill_chunk)
         eid = str(next(_engine_seq))
         self.engine_id = eid
         self._m = _engine_metrics(eid)
@@ -687,23 +736,34 @@ class ServeEngine:
         # would block the decode loop every token, MXL004). Reserved
         # bytes count the bank's global logical size across the mesh.
         self._slot_len = np.zeros(S, np.int64)
+        # bytes of the donated state by kind: pages (or the dense
+        # bank) grow with the tokens held; a family's other kinds are
+        # fixed blocks per slot
+        grows = "kv_pages" if self.paged else "kv_slots"
+        kinds = getattr(fam, "STATE_KINDS", {})
+        by_kind: Dict[str, int] = {}
+        for n, a in self._kv.items():
+            k = kinds.get(n, grows)
+            by_kind[k] = by_kind.get(k, 0) + int(a.nbytes)
+        for k, nbytes in by_kind.items():
+            telemetry.gauge(
+                "serve_state_bytes", "Bytes of the engine's donated "
+                "device state, by kind: kv_pages (or kv_slots), "
+                "window_ring, ssm", engine=eid, kind=k).set(nbytes)
+        # reserved counts everything donated (in paged mode the scratch
+        # page too — it is real HBM); per-token bytes are the growing
+        # kind's over the tokens it can hold (scale planes included in
+        # int8 mode), per-slot bytes the fixed kinds' over the slots
+        self._kv_reserved = sum(by_kind.values())
+        self._kv_tok_bytes = by_kind[grows] // (
+            self.n_pages * self.page_size if self.paged
+            else self.max_slots * self.max_len)
+        self._slot_state_bytes = ((self._kv_reserved - by_kind[grows])
+                                  // self.max_slots)
         if self.paged:
-            # per-token bytes include the scale planes in int8 mode;
-            # reserved counts the whole pool (scratch page included —
-            # it is real HBM)
-            self._kv_reserved = int(sum(a.nbytes
-                                        for a in self._kv.values()))
-            self._kv_tok_bytes = (self._kv_reserved
-                                  // (self.n_pages * self.page_size))
             self._m["pages_total"].set(self.n_pages - 1)
             self._m["pages_free"].set(self._pages.free_pages)
             self._m["pages_shared"].set(0)
-        else:
-            itemsize = np.dtype(state["k"].dtype).itemsize
-            self._kv_tok_bytes = (2 * cfg.n_layers * cfg.n_kv_heads
-                                  * cfg.head_dim * itemsize)
-            self._kv_reserved = int(state["k"].nbytes
-                                    + state["v"].nbytes)
         self._m["kv_reserved"].set(self._kv_reserved)
         self._m["kv_live"].set(0)
         self._m["kv_occ"].set(0.0)
@@ -760,6 +820,16 @@ class ServeEngine:
                 f"top_p must be in (0, 1], got {request.top_p}")
         return self._enqueue(request)
 
+    def _refuse(self, asked: Dict[str, Any]) -> None:
+        """Raise for the first option in ``asked`` that is set and that
+        the family's ``SERVE_UNSUPPORTED`` names, with its reason."""
+        for option, on in asked.items():
+            if on and option in self._unsupported:
+                raise ValueError(
+                    f"{option} is not supported for the "
+                    f"{self.cfg.family} family: "
+                    f"{self._unsupported[option]}")
+
     def submit_prefilled(self, handoff: KVHandoff,
                          request: Request) -> int:
         """Queue a request whose prompt was already prefilled on a
@@ -767,6 +837,7 @@ class ServeEngine:
         handed-off KV block via ``llama.inject_slot_kv`` instead of
         running a prefill program, and the worker-sampled first token
         is emitted as this request's first token."""
+        self._refuse({"submit_prefilled": True})
         if handoff.true_len < 1:
             raise ValueError("empty handoff")
         if handoff.true_len + request.max_new_tokens > self.max_len:
@@ -1087,6 +1158,17 @@ class ServeEngine:
         """Run the admission programs for already-seated picks (engine
         thread only — slot/cache state is loop-private)."""
         for slot, rid, req, handoff, plan in picks:
+            if self.prefill_chunk:
+                # seated, its pages granted; its chunks run in
+                # _advance_prefills, and until the last one the slot
+                # takes no part in a decode step
+                self._jobs.append(_PrefillJob(
+                    slot, rid, req,
+                    np.asarray(req.prompt, np.int32).reshape(-1)))
+                with self._lock:
+                    self._prefilling[slot] = True
+                    self._slot_len[slot] = 0
+                continue
             with dtrace.use(req.ctx):
                 if self.paged:
                     if handoff is not None:
@@ -1103,33 +1185,94 @@ class ServeEngine:
                         (rid, self._prefill_into(slot, req)))
             req._stamps.append(time.perf_counter())
 
+    def _sampling_of(self, req: Request):
+        """What a prefill program takes to sample ``req``'s first
+        token: (rng key, temperature, top_k, top_p). An explicit resume
+        chain is device-committed: a numpy key is a DIFFERENT jit-cache
+        entry from the PRNGKey device array the normal path passes, so
+        leaving it raw would recompile every prefill bucket once per
+        crash re-dispatch."""
+        key = (jax.random.PRNGKey(req.seed) if req.rng is None  # noqa: MXL301 — chain position 0 is PRNGKey(seed) by definition; the rng branch is a mid-chain resume key
+               else jax.numpy.asarray(np.asarray(req.rng, np.uint32)))
+        return (key, np.float32(req.temperature),
+                np.int32(self.cfg.vocab_size if req.top_k is None
+                         else req.top_k),
+                np.float32(1.0 if req.top_p is None else req.top_p))
+
+    def _chunk_fn(self, last: bool):
+        C = self.prefill_chunk
+        fn = self._prefills.get((C, last))
+        if fn is None:
+            prog = (self._family.prefill_slot_paged_last if last
+                    else self._family.prefill_slot_paged_chunk)
+            fn = telemetry.watch_jit(
+                partial(prog, self.cfg, mesh=self.mesh),
+                f"serve_prefill_{'last' if last else 'chunk'}_b{C}",
+                f"{prog.__name__}_b{C}",
+                donate_argnums=(7,) if last else (3,))
+            self._prefills[(C, last)] = fn
+        return fn
+
+    def _advance_prefills(self, firsts: List[Tuple[int, Any]]) -> None:
+        """Chunked prefill's share of an iteration (engine thread): the
+        head prompt's next chunk, and further chunks only while the
+        prompts waiting outnumber the requests running — a bank that is
+        filling prefills; a bank that is running stalls by one chunk."""
+        C = self.prefill_chunk
+        while self._jobs:
+            job = self._jobs[0]
+            if self._slot_rid[job.slot] != job.rid:
+                # cancelled and finalized while it waited: _process
+                # freed the slot and its pages
+                self._jobs.popleft()
+                again = any(j.slot == job.slot for j in self._jobs)
+                with self._lock:
+                    self._prefilling[job.slot] = again
+                continue
+            req, left = job.req, job.prompt.size - job.done
+            padded = np.zeros((1, C), np.int32)
+            padded[0, :min(left, C)] = job.prompt[job.done:job.done + C]
+            with dtrace.use(req.ctx), self._span_prefill(
+                    bucket=C, role=self.role, prefix_len=job.done):
+                if left > C:
+                    self._stage = self._chunk_fn(False)(
+                        self.params, padded, np.int32(job.done),
+                        self._stage)
+                    job.done += C
+                else:
+                    tok, self._kv, self._sv = self._chunk_fn(True)(
+                        self.params, padded, np.int32(job.done),
+                        np.int32(left), self._stage,
+                        self._pt[job.slot].copy(), np.int32(job.slot),
+                        self._kv, self._sv, *self._sampling_of(req))
+                    self._jobs.popleft()
+                    with self._lock:
+                        self._prefilling[job.slot] = False
+                        self._slot_len[job.slot] = job.prompt.size
+                    firsts.append((job.rid, tok))
+                    req._stamps.append(time.perf_counter())
+            if len(self._jobs) <= int(
+                    (self._active & ~self._prefilling).sum()):
+                break
+
     def _prefill_into(self, slot: int, req: Request):
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         bucket = bucket_for(prompt.size, self.min_bucket, self.max_len)
         fn = self._prefills.get(bucket)
         if fn is None:
             fn = telemetry.watch_jit(
-                partial(llama.prefill_slot, self.cfg, mesh=self.mesh),
+                partial(self._family.prefill_slot, self.cfg,
+                        mesh=self.mesh),
                 f"serve_prefill_b{bucket}", f"prefill_slot_b{bucket}",
                 donate_argnums=(4,))
             self._prefills[bucket] = fn
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :prompt.size] = prompt
-        # device-commit an explicit resume chain: a numpy key is a
-        # DIFFERENT jit-cache entry from the PRNGKey device array the
-        # normal path passes, so leaving it raw would recompile every
-        # prefill bucket once per crash re-dispatch
-        key = (jax.random.PRNGKey(req.seed) if req.rng is None  # noqa: MXL301 — chain position 0 is PRNGKey(seed) by definition; the rng branch is a mid-chain resume key
-               else jax.numpy.asarray(np.asarray(req.rng, np.uint32)))
         with self._span_prefill(bucket=bucket, role=self.role):
             tok, self._kv, self._sv = fn(
                 self.params, padded, np.int32(prompt.size),
                 np.int32(slot), self._kv, self._sv,
-                key,
-                np.float32(req.temperature),
-                np.int32(self.cfg.vocab_size if req.top_k is None
-                         else req.top_k),
-                np.float32(1.0 if req.top_p is None else req.top_p))
+                *self._sampling_of(req))
         with self._lock:      # host mirror of lengths — kv_cache_stats
             self._slot_len[slot] = prompt.size  # sums it under _lock
         return tok
@@ -1144,7 +1287,8 @@ class ServeEngine:
         fn = self._injects.get(bucket)
         if fn is None:
             fn = telemetry.watch_jit(
-                partial(llama.inject_slot_kv, self.cfg, mesh=self.mesh),
+                partial(self._family.inject_slot_kv, self.cfg,
+                        mesh=self.mesh),
                 f"serve_inject_b{bucket}", f"inject_slot_kv_b{bucket}",
                 donate_argnums=(6,))
             self._injects[bucket] = fn
@@ -1163,7 +1307,7 @@ class ServeEngine:
         fn = self._prefills.get(bucket)
         if fn is None:
             fn = telemetry.watch_jit(
-                partial(llama.prefill_slot_paged, self.cfg,
+                partial(self._family.prefill_slot_paged, self.cfg,
                         mesh=self.mesh),
                 f"serve_prefill_b{bucket}",
                 f"prefill_slot_paged_b{bucket}", donate_argnums=(6,))
@@ -1182,18 +1326,13 @@ class ServeEngine:
         fn = self._paged_prefill_fn(bucket)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :suffix.size] = suffix
-        key = (jax.random.PRNGKey(req.seed) if req.rng is None  # noqa: MXL301 — chain position 0 is PRNGKey(seed) by definition; the rng branch is a mid-chain resume key
-               else jax.numpy.asarray(np.asarray(req.rng, np.uint32)))
         with self._span_prefill(bucket=bucket, role=self.role,
                                 prefix_len=prefix_len):
             tok, self._kv, self._sv = fn(
                 self.params, padded, np.int32(total_len),
                 np.int32(prefix_len), self._pt[slot].copy(),
-                np.int32(slot), self._kv, self._sv, key,
-                np.float32(req.temperature),
-                np.int32(self.cfg.vocab_size if req.top_k is None
-                         else req.top_k),
-                np.float32(1.0 if req.top_p is None else req.top_p))
+                np.int32(slot), self._kv, self._sv,
+                *self._sampling_of(req))
         with self._lock:
             self._slot_len[slot] = total_len
         return tok
@@ -1265,7 +1404,7 @@ class ServeEngine:
         fn = self._injects.get(bucket)
         if fn is None:
             fn = telemetry.watch_jit(
-                partial(llama.inject_paged_kv, self.cfg,
+                partial(self._family.inject_paged_kv, self.cfg,
                         mesh=self.mesh),
                 f"serve_inject_b{bucket}", f"inject_paged_kv_b{bucket}",
                 donate_argnums=(7,))
@@ -1359,10 +1498,17 @@ class ServeEngine:
             elif self.paged:
                 # the page table rides as a small int32 operand —
                 # table edits at admission never touch device state
-                # or the jit cache key
+                # or the jit cache key. A slot whose prompt is still
+                # in chunks is seated but does not run: inactive, and
+                # its row the scratch page's, like a free slot's
+                active, pt = self._active, self._pt
+                if self._prefilling.any():
+                    active = active & ~self._prefilling
+                    pt = pt.copy()
+                    pt[self._prefilling] = 0
                 sampled, self._kv, self._sv = self._decode(
-                    self.params, self._kv, self._sv, self._active,
-                    self._pt, self._temps, self._topks, self._topps)
+                    self.params, self._kv, self._sv, active,
+                    pt, self._temps, self._topks, self._topps)
             else:
                 sampled, self._kv, self._sv = self._decode(
                     self.params, self._kv, self._sv, self._active,
@@ -1373,7 +1519,8 @@ class ServeEngine:
             if drafts is not None:
                 self._spec_steps += 1
             slots = [(s, rid) for s, rid in enumerate(self._slot_rid)
-                     if self._active[s] and rid is not None]
+                     if self._active[s] and rid is not None
+                     and not self._prefilling[s]]
             if emits is None:
                 # the decode program appends one cache entry per
                 # active slot; mirror that on the host (no readback —
@@ -1491,8 +1638,7 @@ class ServeEngine:
             if self.paged:
                 self._m["pages_free"].set(self._pages.free_pages)
                 self._m["pages_shared"].set(self._pages.shared_pages)
-            live = (int(self._slot_len[self._active].sum())
-                    * self._kv_tok_bytes)
+            live = self._live_bytes()
             self._m["kv_live"].set(live)
             self._m["kv_occ"].set(live / self._kv_reserved
                                   if self._kv_reserved else 0.0)
@@ -1510,10 +1656,12 @@ class ServeEngine:
             picks = self._pick_admissions()
         with self._span_admit():
             self._run_admissions(picks, firsts)
-        # any admission leaves its slot active, so firsts are
-        # always carried by a dispatch
-        out = (self._dispatch(firsts) if self._active.any()
-               else None)
+            if self._jobs:
+                self._advance_prefills(firsts)
+        # any admission leaves its slot active (a chunked one when its
+        # last chunk ran), so firsts are always carried by a dispatch
+        out = (self._dispatch(firsts)
+               if (self._active & ~self._prefilling).any() else None)
         if not self.overlap and out is not None:
             self._process(out)
             out = None
@@ -1620,6 +1768,13 @@ class ServeEngine:
         compile bound is ``n_buckets + 1`` either way."""
         return len(self._prefills) + len(self._injects)
 
+    def _live_bytes(self) -> int:
+        """Bytes of the state that live requests cover (lock held):
+        their tokens' keys and values and their slots' fixed blocks."""
+        return (int(self._slot_len[self._active].sum())
+                * self._kv_tok_bytes
+                + int(self._active.sum()) * self._slot_state_bytes)
+
     def kv_cache_stats(self) -> Dict[str, Any]:
         """KV slot-bank occupancy: bytes the dense bank RESERVES vs
         bytes live sequence prefixes actually COVER — the exact waste
@@ -1629,9 +1784,10 @@ class ServeEngine:
         would put a sync next to the decode loop — MXL004)."""
         with self._lock:
             active = int(self._active.sum())
-            live_tokens = int(self._slot_len[self._active].sum())
+            live = self._live_bytes()
             out = {"slots": self.max_slots, "active": active,
-                   "reserved_bytes": self._kv_reserved}
+                   "reserved_bytes": self._kv_reserved,
+                   "state_bytes_per_slot": self._slot_state_bytes}
             if self.paged:
                 out.update({
                     "paged": True,
@@ -1661,7 +1817,6 @@ class ServeEngine:
                             else 0.0),
                         "spec_steps": self._spec_steps,
                     })
-        live = live_tokens * self._kv_tok_bytes
         out["live_bytes"] = live
         out["occupancy"] = (live / self._kv_reserved
                             if self._kv_reserved else 0.0)

@@ -42,6 +42,20 @@ def test_serve_phase_runs_tiny_on_cpu():
     assert info["prefix_hits"] >= 1 and info["identical"] == "6/6"
 
 
+def test_serve_sambay_phase_runs_tiny_on_cpu():
+    """The second family's leg: every kind of layer at toy widths,
+    prompts over three windows, both passes float32 here."""
+    from mxtpu.models import sambay
+    cfg = sambay.CONFIGS["tiny"]
+    jobs = chip_smoke.make_jobs(cfg.vocab_size,
+                                ((11, 5, 0.0), (30, 6, 0.0)),
+                                per_shape=2, shared_prefix=0)
+    info = chip_smoke.phase_serve_sambay(
+        cfg, jobs, max_slots=2, max_len=96, min_bucket=16, page_size=8)
+    assert info["requests"] == 8
+    assert info["worst_gap_float32"] <= 1e-3
+
+
 def test_main_fails_without_a_chip():
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
