@@ -895,7 +895,15 @@ def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None):
     Nucleus semantics (both modes): keep the smallest prefix of the
     sorted distribution whose mass reaches p — probabilities computed
     ONCE, and the survivor set applied as a value threshold (the kept
-    minimum) rather than a full-vocab scatter."""
+    minimum) rather than a full-vocab scatter.
+
+    The order comes from a sort of the VALUES (:func:`_sort_descending`),
+    never from a permutation: both thresholds read sorted values only,
+    and an ``argsort`` with a ``take_along_axis`` through it is a
+    vocabulary-sized gather a row, which the TPU carries out one float
+    at a time. The traced mode sorts ONCE: the top-k threshold cuts a
+    suffix of the sorted row, so the same threshold applied to that
+    row is the sorted order the nucleus wants."""
     static = (isinstance(temperature, (int, float))
               and (top_k is None or isinstance(top_k, int))
               and (top_p is None or isinstance(top_p, (int, float))))
@@ -924,12 +932,16 @@ def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None):
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
     slg = lg / jnp.where(t_col == 0.0, 1.0, t_col)
     # top-k as a value threshold: the kth-largest VALUE equals
-    # lax.top_k's kth element, so the mask matches the static path
-    srt = jnp.take_along_axis(slg, jnp.argsort(-slg, axis=-1), axis=-1)
+    # lax.top_k's kth element, so the mask matches the static path.
+    # The threshold cuts a suffix of the sorted row, so the same
+    # ``where`` on it IS the thresholded logits in sorted order: the
+    # nucleus needs no second sort
+    srt = _sort_descending(slg)
     kth = jnp.take_along_axis(srt, jnp.broadcast_to(
         k_col - 1, slg.shape[:-1] + (1,)), axis=-1)
     slg = jnp.where(slg < kth, -jnp.inf, slg)
-    slg = _nucleus_mask(slg, p_col)
+    srt = jnp.where(srt < kth, -jnp.inf, srt)
+    slg = jnp.where(slg >= _nucleus_cutoff(srt, p_col), slg, -jnp.inf)
     sampled = jax.random.categorical(rng, slg, axis=-1) \
         .astype(jnp.int32)
     return jnp.where(jnp.squeeze(t_col, -1) == 0.0, greedy, sampled)
@@ -945,18 +957,28 @@ def _sample_slot(key, lg, temperature, top_k, top_p):
     return key, tok
 
 
-def _nucleus_mask(lg, top_p):
-    """Mask lg to the top-p nucleus: softmax ONCE over the sorted row,
-    keep the smallest prefix reaching p (the top token always
-    survives), and apply the survivor set as a >= threshold on the
-    kept minimum — no full-vocab scatter."""
-    order = jnp.argsort(-lg, axis=-1)
-    sorted_lg = jnp.take_along_axis(lg, order, axis=-1)
+def _sort_descending(lg):
+    """lg's rows, largest first: a sort of the VALUES — one operand, no
+    index beside it, stability not asked for (equal values are
+    interchangeable). Why not ``argsort``: see :func:`sample_logits`."""
+    return -lax.sort(-lg, dimension=-1, is_stable=False)
+
+
+def _nucleus_cutoff(sorted_lg, top_p):
+    """The smallest logit of the top-p nucleus, from a row that is
+    already sorted largest first: softmax ONCE over it, keep the
+    smallest prefix reaching p (the top token always survives)."""
     probs = jax.nn.softmax(sorted_lg, axis=-1)
     csum = jnp.cumsum(probs, axis=-1)
     keep_sorted = (csum - probs) < top_p
-    cutoff = jnp.min(jnp.where(keep_sorted, sorted_lg, jnp.inf),
-                     axis=-1, keepdims=True)
+    return jnp.min(jnp.where(keep_sorted, sorted_lg, jnp.inf),
+                   axis=-1, keepdims=True)
+
+
+def _nucleus_mask(lg, top_p):
+    """Mask lg to the top-p nucleus, the survivor set applied as a >=
+    threshold on the kept minimum — no full-vocab scatter."""
+    cutoff = _nucleus_cutoff(_sort_descending(lg), top_p)
     return jnp.where(lg >= cutoff, lg, -jnp.inf)
 
 

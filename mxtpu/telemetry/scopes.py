@@ -7,7 +7,7 @@ A device trace names an operation by its HLO instruction
 trace carries no framework scope, and fusion numbers change with every
 compile. The optimised HLO text of the executable does carry the
 scope: every instruction's ``metadata={op_name="jit(decode_slots_paged)
-/while/body/sampler/jit(argsort)/sort"}`` is the name stack it was
+/vmap(sampler)/sort"}`` is the name stack it was
 traced under. :func:`scope_map` reads that text once per compiled
 variant (``WatchedFunction._on_compile`` -> ``perfscope.profile_program``
 -> :func:`register`, the executable the cost catalog already holds) and

@@ -74,38 +74,125 @@ def test_slot_attention_rejects_bad_gqa():
 # ---------------------------------------------------------------------------
 # shared sampler: traced per-slot mode == static mode, bit for bit
 # ---------------------------------------------------------------------------
-def test_sample_logits_traced_matches_static():
+def _frozen_sample_logits(rng, lg, temperature, top_k, top_p):
+    """The traced sampler as it stood before it sorted values (PR 27):
+    ``argsort`` and ``take_along_axis``, twice. Kept here only, as the
+    oracle. Returns (tokens, the masked logits ``categorical`` saw)."""
+    def nucleus_mask(lg, top_p):
+        order = jnp.argsort(-lg, axis=-1)
+        sorted_lg = jnp.take_along_axis(lg, order, axis=-1)
+        probs = jax.nn.softmax(sorted_lg, axis=-1)
+        csum = jnp.cumsum(probs, axis=-1)
+        keep_sorted = (csum - probs) < top_p
+        cutoff = jnp.min(jnp.where(keep_sorted, sorted_lg, jnp.inf),
+                         axis=-1, keepdims=True)
+        return jnp.where(lg >= cutoff, lg, -jnp.inf)
+
+    V = lg.shape[-1]
+    t_col = jnp.asarray(temperature, jnp.float32)[:, None]
+    k_col = jnp.clip(jnp.asarray(top_k, jnp.int32)[:, None], 1, V)
+    p_col = jnp.asarray(top_p, jnp.float32)[:, None]
+    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+    slg = lg / jnp.where(t_col == 0.0, 1.0, t_col)
+    srt = jnp.take_along_axis(slg, jnp.argsort(-slg, axis=-1), axis=-1)
+    kth = jnp.take_along_axis(srt, jnp.broadcast_to(
+        k_col - 1, slg.shape[:-1] + (1,)), axis=-1)
+    slg = jnp.where(slg < kth, -jnp.inf, slg)
+    slg = nucleus_mask(slg, p_col)
+    sampled = jax.random.categorical(rng, slg, axis=-1).astype(jnp.int32)
+    return jnp.where(t_col[:, 0] == 0.0, greedy, sampled), slg
+
+
+def _sampler_logits(kind):
+    """(4, V) float32 rows the rewrite could break on."""
+    rng = np.random.default_rng(11)
+    if kind == "f32_v97":
+        return jnp.asarray(rng.standard_normal((4, 97)) * 3, jnp.float32)
+    x = rng.standard_normal((4, 1031)) * 2      # 1031: no multiple of 128
+    if kind == "bf16_v1031":        # hundreds of exact ties a row
+        return jnp.asarray(x, jnp.bfloat16).astype(jnp.float32)
+    if kind == "halves_v1031":      # ~20 distinct values: the kth value
+        # and the nucleus cut-off both fall inside a tie, the maximum too
+        return jnp.asarray(np.round(x * 2) / 2, jnp.float32)
+    assert kind == "inf_dups_v1031"
+    x[:, 515:1030] = x[:, :515]                 # every value twice
+    x[rng.random(x.shape) < 0.3] = -np.inf
+    x[3, 7:] = -np.inf                          # seven candidates left
+    return jnp.asarray(x, jnp.float32)
+
+
+_SAMPLER_KINDS = ["f32_v97", "bf16_v1031", "halves_v1031", "inf_dups_v1031"]
+# (temperature, top_k, top_p); None = off, "V" = the vocabulary's size
+_SAMPLER_CONFIGS = [
+    (0.0, None, None), (0.7, None, None), (1.1, 5, None), (0.9, None, 0.6),
+    (0.8, 12, 0.9), (1.0, 1, None), (1.0, "V", 1.0), (0.6, None, 0.95),
+    (0.7, 40, 0.05), (1.3, 1, 0.05), (0.7, None, 0.05)]
+
+
+def _traced_args(V, row_cfg):
+    """Per-row (temperature, top_k, top_p) as the engine passes them."""
+    t, k, p = zip(*row_cfg)
+    return dict(
+        temperature=jnp.asarray(t, jnp.float32),
+        top_k=jnp.asarray([V if x is None else x for x in k], jnp.int32),
+        top_p=jnp.asarray([1.0 if x is None else x for x in p],
+                          jnp.float32))
+
+
+def _traced_against_oracle(monkeypatch, key, lg, row_cfg):
+    """The traced mode's tokens, after holding them and the masked
+    logits that reached ``categorical`` bit-equal to the frozen
+    formulation's."""
+    seen = []
+    categorical = jax.random.categorical
+    args = _traced_args(lg.shape[-1], row_cfg)
+    with monkeypatch.context() as m:
+        m.setattr(jax.random, "categorical",
+                  lambda rng, logits, **kw: (
+                      seen.append(logits),
+                      categorical(rng, logits, **kw))[1])
+        got = llama.sample_logits(key, lg, **args)
+    want, want_masked = _frozen_sample_logits(key, lg, **args)
+    (masked,) = seen
+    np.testing.assert_array_equal(np.asarray(masked),
+                                  np.asarray(want_masked))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    return got
+
+
+@pytest.mark.parametrize("config", _SAMPLER_CONFIGS, ids=str)
+@pytest.mark.parametrize("kind", _SAMPLER_KINDS)
+def test_sample_logits_traced_matches_static(monkeypatch, kind, config):
     """The serving engine samples through the traced mode (per-slot
     arrays), generate through the static mode — the satellite contract
-    is that equal logits give bit-equal tokens either way."""
-    rng = np.random.default_rng(11)
-    lg = jnp.asarray(rng.standard_normal((4, 97)) * 3, jnp.float32)
+    is that equal logits give bit-equal tokens either way. And the
+    traced mode, which orders values by ONE value sort, hands
+    ``categorical`` the very logits the two-``argsort`` formulation
+    did."""
+    lg = _sampler_logits(kind)
+    V = lg.shape[-1]
+    t, k, p = config
+    k = V if k == "V" else k
     key = jax.random.PRNGKey(5)
-    configs = [(0.0, None, None), (0.7, None, None), (1.1, 5, None),
-               (0.9, None, 0.6), (0.8, 12, 0.9), (1.0, 1, None)]
-    for t, k, p in configs:
-        a = llama.sample_logits(key, lg, temperature=t, top_k=k,
-                                top_p=p)
-        b = llama.sample_logits(
-            key, lg,
-            temperature=jnp.full((4,), t, jnp.float32),
-            top_k=jnp.full((4,), lg.shape[-1] if k is None else k,
-                           jnp.int32),
-            top_p=jnp.full((4,), 1.0 if p is None else p,
-                           jnp.float32))
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=str((t, k, p)))
-    # per-row mixed config == each row's static config
-    mixed = llama.sample_logits(
-        key, lg, temperature=jnp.asarray([0.0, 0.7, 0.9, 0.8]),
-        top_k=jnp.asarray([97, 97, 5, 12]),
-        top_p=jnp.asarray([1.0, 1.0, 1.0, 0.9]))
-    row_cfg = [(0.0, None, None), (0.7, None, None), (0.9, 5, None),
+    a = llama.sample_logits(key, lg, temperature=t, top_k=k, top_p=p)
+    b = _traced_against_oracle(monkeypatch, key, lg, [(t, k, p)] * 4)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kind", _SAMPLER_KINDS)
+def test_sample_logits_mixed_rows_match_each_rows_static(monkeypatch,
+                                                         kind):
+    """Per-row mixed config == each row's static config: a greedy row
+    beside sampled rows, top-k alone, top-p alone, both at once."""
+    lg = _sampler_logits(kind)
+    key = jax.random.PRNGKey(5)
+    row_cfg = [(0.0, None, None), (0.7, None, 0.95), (0.9, 5, None),
                (0.8, 12, 0.9)]
-    full = [llama.sample_logits(key, lg, temperature=t, top_k=k,
-                                top_p=p) for t, k, p in row_cfg]
-    for i in range(4):
-        assert int(mixed[i]) == int(full[i][i]), (i, row_cfg[i])
+    mixed = _traced_against_oracle(monkeypatch, key, lg, row_cfg)
+    for i, (t, k, p) in enumerate(row_cfg):
+        full = llama.sample_logits(key, lg, temperature=t, top_k=k,
+                                   top_p=p)
+        assert int(mixed[i]) == int(full[i]), (i, row_cfg[i])
 
 
 # ---------------------------------------------------------------------------

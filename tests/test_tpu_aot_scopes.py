@@ -4,9 +4,10 @@ instruction's ``op_name``, and the operations a trace will show
 (fusions, copies, custom calls) land under the model's scopes; and no
 paged program moves the KV pool — the write updates the donated pool in
 place and the page gather reads it, nothing else touches pool-sized
-bytes. The only test file that describes a TPU topology (one process
-may hold libtpu: the on-chip-measurement guide, section 2), and only
-inside fixtures."""
+bytes; and the sampler orders a vocabulary by ONE sort of its values,
+with no permutation to gather through. The only test file that
+describes a TPU topology (one process may hold libtpu: the
+on-chip-measurement guide, section 2), and only inside fixtures."""
 import math
 import os
 import re
@@ -18,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxtpu.models import llama
+from mxtpu.models import llama, sambay
 from mxtpu.telemetry import scopes as tscopes
 
 # Mistral's head shapes (32 query / 8 kv heads of 128); depth, FFN and
@@ -41,6 +42,40 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_all(lowered):
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return {name: low.compile() for name, low in lowered.items()}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _lower_decode(family, cfg, one_chip):
+    """``family.decode_slots_paged`` lowered for the chip, its state
+    donated as the engine donates it. Returns (lowered, params, kv, sv)
+    as shapes on the chip."""
+    arg = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(partial(family.init_params, cfg),
+                                    jax.random.PRNGKey(0)))
+    state = jax.eval_shape(
+        lambda: family.init_paged_cache(cfg, SLOTS, N_PAGES, PAGE))
+    per_slot = ("lengths", "tokens", "rngs")
+    kv = on_chip({n: a for n, a in state.items() if n not in per_slot})
+    sv = on_chip({n: state[n] for n in per_slot})
+    decode = partial(family.decode_slots_paged, cfg)
+    decode.__name__ = "decode_slots_paged"
+    lowered = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, kv, sv, arg((SLOTS,), jnp.bool_),
+        arg((SLOTS, cfg.max_seq_len // PAGE), jnp.int32),
+        arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.int32),
+        arg((SLOTS,), jnp.float32))
+    return lowered, params, kv, sv
+
+
 @pytest.fixture(scope="module")
 def compiled(one_chip):
     """name -> compiled executable of ``decode_slots_paged``, one
@@ -51,29 +86,15 @@ def compiled(one_chip):
                   hidden_dim=2048, max_seq_len=512, dtype=jnp.bfloat16,
                   param_dtype=jnp.bfloat16)
 
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    params = on_chip(jax.eval_shape(partial(llama.init_params, cfg),
-                                    jax.random.PRNGKey(0)))
-    state = jax.eval_shape(
-        lambda: llama.init_paged_cache(cfg, SLOTS, N_PAGES, PAGE))
-    kv = on_chip({n: state[n] for n in ("k", "v")})
-    sv = on_chip({n: state[n] for n in ("lengths", "tokens", "rngs")})
+    decode, params, kv, sv = _lower_decode(llama, cfg, one_chip)
     per_slot = cfg.max_seq_len // PAGE
     scalar = partial(arg, ())
-    decode = partial(llama.decode_slots_paged, cfg)
-    decode.__name__ = "decode_slots_paged"
     prefill = partial(llama.prefill_slot_paged, cfg)
     prefill.__name__ = "prefill_slot_paged"
     lowered = {
-        "decode_slots_paged": jax.jit(decode, donate_argnums=(1,)).lower(
-            params, kv, sv, arg((SLOTS,), jnp.bool_),
-            arg((SLOTS, per_slot), jnp.int32), arg((SLOTS,), jnp.float32),
-            arg((SLOTS,), jnp.int32), arg((SLOTS,), jnp.float32)),
+        "decode_slots_paged": decode,
         "prefill_slot_paged": jax.jit(prefill, donate_argnums=(6,)).lower(
             params, arg((1, BUCKET), jnp.int32), scalar(jnp.int32),
             scalar(jnp.int32), arg((per_slot,), jnp.int32),
@@ -82,17 +103,25 @@ def compiled(one_chip):
         "copy_page": jax.jit(llama.copy_page, donate_argnums=(0,)).lower(
             kv, scalar(jnp.int32), scalar(jnp.int32)),
     }
-    cache_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        return {name: low.compile() for name, low in lowered.items()}
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_on)
+    return _compile_all(lowered)
 
 
 @pytest.fixture(scope="module")
 def decode_text(compiled):
     return compiled["decode_slots_paged"].as_text()
+
+
+@pytest.fixture(scope="module")
+def sambay_decode_text(one_chip):
+    """The second family's decode program at toy depth and width over
+    Phi-4-mini-flash's 200064 rows: the sampler is ``llama``'s, at the
+    vocabulary where its gathers cost 130 ms a step."""
+    cfg = replace(sambay.CONFIGS["tiny"], vocab_size=200064, dim=512,
+                  n_layers=8, n_heads=8, n_kv_heads=4, hidden_dim=512,
+                  sliding_window=128, max_seq_len=512, dtype=jnp.bfloat16,
+                  param_dtype=jnp.bfloat16)
+    decode, *_ = _lower_decode(sambay, cfg, one_chip)
+    return _compile_all({"decode": decode})["decode"].as_text()
 
 
 def test_tpu_program_keeps_its_name_and_parses_fast(decode_text):
@@ -207,3 +236,42 @@ def test_tpu_page_gather_selects_only_indices(decode_text):
             dims = m.group(4)
             assert m.group(3) in ("s32", "pred") and math.prod(
                 int(d) for d in dims.split(",")) <= rows, line
+
+
+# -- the sampler sorts values, once, and gathers nothing -------------------
+_IN_SAMPLER = re.compile(r'op_name="[^"]*[/(]sampler[/)]')
+_SORT = re.compile(r" = (\(.*\)|\S+) sort\((.*?)\), dimensions=")
+
+
+@pytest.mark.parametrize("program,rows,vocab", [
+    ("decode_slots_paged", SLOTS, 32768), ("prefill_slot_paged", 1, 32768),
+    ("sambay.decode_slots_paged", SLOTS, 200064)])
+def test_tpu_sampler_sorts_values_once_and_gathers_nothing(
+        request, program, rows, vocab):
+    """Under the scope ``sampler`` the compiled program holds exactly
+    one ``sort``, of one operand (the values; no ``iota`` rides along),
+    and no ``gather`` as large as the logits: the kth value is one
+    element a row. An ``argsort`` with ``take_along_axis`` compiled to
+    two stable two-operand sorts and two ``rows x vocab`` gathers, 153
+    ms of a 194 ms step at 200064 rows (PERF.md, PR 28)."""
+    if program.startswith("sambay."):
+        text = request.getfixturevalue("sambay_decode_text")
+    else:
+        text = request.getfixturevalue("compiled")[program].as_text()
+    sorts, gathers = [], []
+    for line in text.splitlines():
+        if not _IN_SAMPLER.search(line):
+            continue
+        m = _SORT.search(line)
+        if m:
+            sorts.append((m.group(1), m.group(2).count("%")))
+        m = _INSTRUCTION.match(line)
+        if m and m.group(5) == "gather":
+            gathers.append(math.prod(
+                int(d) for d in m.group(4).split(",") if d))
+    assert [n for _, n in sorts] == [1], sorts
+    shape = sorts[0][0]
+    assert shape.startswith("f32[") and not shape.startswith("("), shape
+    assert math.prod(int(d) for d in re.match(
+        r"f32\[([\d,]*)\]", shape).group(1).split(",")) == rows * vocab
+    assert all(n <= rows for n in gathers), gathers
