@@ -275,7 +275,7 @@ class _KVSampler:
             self.peak_occupancy = max(self.peak_occupancy,
                                       kv["occupancy"])
             self.peak_pages_used = max(self.peak_pages_used,
-                                       kv.get("pages_used", 0))
+                                       kv["pages_used"])
 
     def __enter__(self):
         self._thread.start()
@@ -288,19 +288,17 @@ class _KVSampler:
 
 def bench_llama_serve(n_requests=48, max_slots=16, max_len=768,
                       mean_interarrival_steps=4.0, seed=0, int8=False,
-                      cfg=None, paged=False, page_size=None,
-                      n_pages=None, shared_prefix=0):
+                      cfg=None, page_size=None, n_pages=None,
+                      shared_prefix=0):
     """Continuous-batching serving throughput + per-token latency
     (ISSUE 4 tentpole): the same ~500M decode config served through
     ``mxtpu.serve.ServeEngine`` under a SEEDED Poisson arrival stream
     of mixed prompt/output lengths — the regime where whole-batch
     ``generate`` drains to its stragglers and the slot engine keeps
     the decode program at full batch. Reports tok/s over generated
-    tokens plus p50/p99 per-token latency (inter-token gaps) and the
-    KV occupancy the stream actually reached.
-
-    ``paged=True`` serves from the paged KV pool (ISSUE 18) and adds
-    page/prefix-cache stats; ``shared_prefix=N`` prepends one fixed
+    tokens plus p50/p99 per-token latency (inter-token gaps), the KV
+    occupancy the stream actually reached and the page pool's and
+    prefix cache's stats. ``shared_prefix=N`` prepends one fixed
     N-token system prompt to every request — the prefix-sharing
     workload (hits > 0 once the first admission registers it)."""
     from mxtpu.models import llama
@@ -317,8 +315,7 @@ def bench_llama_serve(n_requests=48, max_slots=16, max_len=768,
     engine = ServeEngine(cfg, params, max_slots=max_slots,
                          max_len=max_len,
                          min_bucket=max(4, max_len // 12),
-                         paged=paged, page_size=page_size,
-                         n_pages=n_pages)
+                         page_size=page_size, n_pages=n_pages)
     prefix = (rng.integers(0, cfg.vocab_size, shared_prefix)
               if shared_prefix else None)
 
@@ -357,9 +354,10 @@ def bench_llama_serve(n_requests=48, max_slots=16, max_len=768,
     dt = time.perf_counter() - t0
     lat = engine.latency_stats()
     kv = engine.kv_cache_stats()
+    hits, misses = kv["prefix_hits"], kv["prefix_misses"]
     rec = {"metric": "llama_500m_serve_tokens_per_s"
                      + ("_int8" if int8 else "")
-                     + ("_paged" if paged else ""),
+                     + ("_shared_prefix" if shared_prefix else ""),
            "value": round(total_new / dt, 1), "unit": "tok/s",
            "p50_token_ms": round(lat["p50_token_ms"], 2),
            "p99_token_ms": round(lat["p99_token_ms"], 2),
@@ -369,126 +367,15 @@ def bench_llama_serve(n_requests=48, max_slots=16, max_len=768,
            "buckets": engine.n_buckets,
            "kv_occupancy_ratio": round(sampler.peak_occupancy, 4),
            "peak_active_slots": sampler.peak_active,
-           "total_s": round(dt, 1), "vs_baseline": None}
-    if paged:
-        hits, misses = kv["prefix_hits"], kv["prefix_misses"]
-        rec.update({
-            "pages_total": kv["pages_total"],
-            "peak_pages_used": sampler.peak_pages_used,
-            "pages_shared": kv["pages_shared"],
-            "cow_forks": kv["cow_forks"],
-            "prefix_hits": hits,
-            "prefix_hit_rate": round(
-                hits / (hits + misses), 4) if hits + misses else 0.0})
+           "total_s": round(dt, 1), "vs_baseline": None,
+           "pages_total": kv["pages_total"],
+           "peak_pages_used": sampler.peak_pages_used,
+           "pages_shared": kv["pages_shared"],
+           "cow_forks": kv["cow_forks"],
+           "prefix_hits": hits,
+           "prefix_hit_rate": round(
+               hits / (hits + misses), 4) if hits + misses else 0.0}
     return rec
-
-
-def bench_paged_kv(dense_slots=4, max_len=768, page_size=64,
-                   seed=0, cfg=None):
-    """Paged-vs-dense A/B at IDENTICAL HBM budget (ISSUE 18
-    acceptance): the dense bank reserves ``dense_slots × max_len``
-    tokens of KV; the paged pool gets exactly that many pages' worth
-    (plus the scratch page) under a 4× slot ceiling, so admission is
-    bounded by PAGES. A burst of quarter-footprint requests then
-    measures how many slots each mode actually runs CONCURRENTLY
-    (paged should reach ≥ 3× dense — the users-per-chip lever), that
-    decode tok/s holds, and the warm-vs-cold TTFT win from prefix
-    sharing."""
-    from mxtpu.models import llama
-    from mxtpu.serve import Request, ServeEngine
-
-    cfg = cfg or llama.LlamaConfig(
-        vocab_size=32000, dim=2048, n_layers=8, n_heads=16,
-        n_kv_heads=8, hidden_dim=5632, max_seq_len=max_len,
-        remat=False)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    pages_per_slot = -(-max_len // page_size)
-    n_pages = dense_slots * pages_per_slot + 1     # dense HBM + scratch
-    min_bucket = max(4, max_len // 12)
-    # per-request footprint = max_len/4: a dense slot still reserves
-    # the full max_len for it, a paged slot holds only its pages
-    plen = max(1, max_len // 8)
-    mnew = max(1, max_len // 8)
-    n_requests = dense_slots * 8
-
-    def one_mode(paged):
-        engine = ServeEngine(
-            cfg, params, max_len=max_len, min_bucket=min_bucket,
-            max_slots=dense_slots * 4 if paged else dense_slots,
-            paged=paged, page_size=page_size if paged else None,
-            n_pages=n_pages if paged else None)
-        rng = np.random.default_rng(seed)
-        engine.submit(Request(                       # compile, untimed
-            prompt=rng.integers(0, cfg.vocab_size, plen),
-            max_new_tokens=2))
-        engine.run()
-        engine.reset_stats()
-        total = 0
-        for _ in range(n_requests):
-            engine.submit(Request(
-                prompt=rng.integers(0, cfg.vocab_size, plen),
-                max_new_tokens=mnew))
-            total += mnew
-        t0 = time.perf_counter()
-        with _KVSampler(engine) as sampler:
-            engine.run()
-        dt = time.perf_counter() - t0
-        return {"toks_per_s": round(total / dt, 1),
-                "peak_active_slots": sampler.peak_active,
-                "peak_occupancy": round(sampler.peak_occupancy, 4),
-                "kv_reserved_bytes": engine.kv_cache_stats()[
-                    "reserved_bytes"]}
-
-    dense = one_mode(False)
-    paged = one_mode(True)
-
-    # warm-vs-cold TTFT on a fresh paged engine: one long system
-    # prompt, cold admission registers it, the warm admission prefills
-    # only the suffix bucket (compile cost paid up front on a
-    # THROWAWAY prefix so both timed requests hit compiled programs)
-    engine = ServeEngine(cfg, params, max_len=max_len,
-                         min_bucket=min_bucket, max_slots=4,
-                         paged=True, page_size=page_size)
-    rng = np.random.default_rng(seed + 1)
-    sys_len = max(page_size, max_len // 2)
-
-    # measure TTFT inside the run loop: stamp first-token time
-    def timed_ttft(prompt):
-        stamp = {}
-
-        def on_token(rid, tok):
-            stamp.setdefault("t", time.perf_counter())
-
-        engine.submit(Request(prompt=prompt, max_new_tokens=2,
-                              on_token=on_token))
-        t0 = time.perf_counter()
-        engine.run()
-        return 1e3 * (stamp["t"] - t0)
-
-    warmup_prefix = rng.integers(0, cfg.vocab_size, sys_len)
-    timed_ttft(np.concatenate(                        # compile cold
-        [warmup_prefix, rng.integers(0, cfg.vocab_size, 8)]))
-    timed_ttft(np.concatenate(                        # compile warm
-        [warmup_prefix, rng.integers(0, cfg.vocab_size, 8)]))
-    prefix = rng.integers(0, cfg.vocab_size, sys_len)
-    ttft_cold = timed_ttft(np.concatenate(
-        [prefix, rng.integers(0, cfg.vocab_size, 8)]))
-    ttft_warm = timed_ttft(np.concatenate(
-        [prefix, rng.integers(0, cfg.vocab_size, 8)]))
-    kv = engine.kv_cache_stats()
-    admit_ratio = (paged["peak_active_slots"]
-                   / max(1, dense["peak_active_slots"]))
-    return {"metric": "llama_500m_paged_kv_admit_ratio",
-            "value": round(admit_ratio, 2), "unit": "x",
-            "dense": dense, "paged": paged,
-            "page_size": page_size, "pages_total": n_pages - 1,
-            "tok_s_ratio": round(paged["toks_per_s"]
-                                 / max(1e-9, dense["toks_per_s"]), 3),
-            "ttft_cold_ms": round(ttft_cold, 1),
-            "ttft_warm_ms": round(ttft_warm, 1),
-            "ttft_speedup": round(ttft_cold / max(1e-9, ttft_warm), 2),
-            "prefix_hits": kv["prefix_hits"],
-            "vs_baseline": None}
 
 
 def bench_spec(speculate_k=4, mnew=200, n_requests=6, max_slots=2):
@@ -515,8 +402,8 @@ def bench_spec(speculate_k=4, mnew=200, n_requests=6, max_slots=2):
 
     def one_mode(k):
         engine = ServeEngine(cfg, params, max_len=256, min_bucket=8,
-                             max_slots=max_slots, paged=True,
-                             page_size=16, speculate_k=k)
+                             max_slots=max_slots, page_size=16,
+                             speculate_k=k)
         streams: dict = {}
 
         def cb(i):
@@ -617,7 +504,7 @@ def bench_disagg_stream(wire_mbps=30.0, stream_chunk=64, plen=448,
         tx, rx = KVChannel.pair()
         be = DisaggBackend(cfg, params, n_prefill=1, n_decode=1,
                            max_slots=2, max_len=512, min_bucket=64,
-                           paged=True, page_size=page, stream_chunk=sc,
+                           page_size=page, stream_chunk=sc,
                            channel=(_ThrottledKVTx(tx, wire_mbps), rx))
         gw = Gateway(backend=be, queue_max=16)
         rng = np.random.default_rng(seed)
@@ -1766,23 +1653,20 @@ def main():
     only = sys.argv[1] if len(sys.argv) > 1 else "all"
     if only not in ("all", "resnet", "bert", "llama", "smoke", "aot8b",
                     "aot8b_decode", "aot_moe", "aot8b_int8", "aot8b_32k",
-                    "input", "serve", "serve_paged", "paged_kv",
+                    "input", "serve", "serve_paged",
                     "gateway", "fleet", "spec", "disagg_stream"):
         raise SystemExit(
             "usage: bench.py [all|resnet|bert|llama|smoke|aot8b|"
             "aot8b_decode|aot_moe|aot8b_int8|aot8b_32k|input|serve|"
-            f"serve_paged|paged_kv|gateway|fleet|spec|disagg_stream|"
+            f"serve_paged|gateway|fleet|spec|disagg_stream|"
             f"gate ...] (got {only!r})")
     if only == "serve":
         _emit(bench_llama_serve())
         return
     if only == "serve_paged":
         # the ISSUE 18 sharing workload: every request opens with the
-        # same 128-token system prompt, served from the paged pool
-        _emit(bench_llama_serve(paged=True, shared_prefix=128))
-        return
-    if only == "paged_kv":
-        _emit(bench_paged_kv())
+        # same 128-token system prompt
+        _emit(bench_llama_serve(shared_prefix=128))
         return
     if only == "gateway":
         _emit(bench_gateway())
@@ -1853,7 +1737,6 @@ def main():
                        "value": round(q_s, 1), "unit": "tok/s",
                        "vs_baseline": None})
         extras.append(bench_llama_serve())
-        extras.append(bench_paged_kv())
         extras.append(bench_gateway())
     if only == "all":
         extras.append(bench_input_pipeline())

@@ -358,8 +358,7 @@ def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
     # a cold compile may outlast the supervisor's default stall
     # threshold; a replica restarted mid-compile never finishes one
     gw = Gateway(lambda: ServeEngine(
-        cfg, params, paged=True, prefix_cache=True, mesh=mesh,
-        **engine_kw),
+        cfg, params, prefix_cache=True, mesh=mesh, **engine_kw),
         n_replicas=1, queue_max=4 * len(jobs),
         supervisor_opts={"stall_s": 900.0, "warmup_s": 900.0})
     try:
@@ -524,8 +523,7 @@ def phase_serve_sambay(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
         with _matmul_precision(precision):
             params = jax.jit(lambda k: sambay.init_params(c, k))(
                 jax.random.PRNGKey(0))
-            gw = Gateway(lambda: ServeEngine(c, params, paged=True,
-                                             **engine_kw),
+            gw = Gateway(lambda: ServeEngine(c, params, **engine_kw),
                          n_replicas=1, queue_max=4 * len(jobs),
                          supervisor_opts={"stall_s": 900.0,
                                           "warmup_s": 900.0})

@@ -47,10 +47,8 @@ __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
            "CONFIGS", "init_cache", "cache_specs", "prefill",
            "chunked_prefill", "decode_step", "generate",
            "quantize_params_int8", "int8_sharding_rules",
-           "sample_logits", "init_slot_cache", "slot_cache_specs",
-           "prefill_slot", "decode_slots", "prefill_detached",
-           "prefill_detached_chunk", "inject_slot_kv",
-           "paged_cache_specs", "init_paged_cache",
+           "sample_logits", "prefill_detached",
+           "prefill_detached_chunk", "paged_cache_specs", "init_paged_cache",
            "decode_slots_paged", "prefill_slot_paged",
            "inject_paged_kv", "copy_page", "decode_slots_spec"]
 
@@ -218,8 +216,9 @@ _QKV = P(("dp", "fsdp"), "tp", "sp", None)      # (batch, heads, seq, hd)
 # forward
 # ---------------------------------------------------------------------------
 # Every piece of the block runs under a ``jax.named_scope``, spelled
-# once, in the helper the block's five copies (_layer, _layer_cached,
-# _layer_slots, _layer_slots_paged, _layer_slots_spec) share: embed,
+# once, in the helper the block's three forms (_layer, _layer_cached,
+# _layer_slots_paged: no cache, contiguous rows, the pool by index)
+# share: embed,
 # norm, qkv_proj, rope, kv_write, kv_gather, attention, out_proj, mlp,
 # lm_head, sampler, xent. The compiled program keeps them in each
 # instruction's metadata, and ``telemetry.programs()`` maps a trace's
@@ -1041,239 +1040,30 @@ def generate(cfg: LlamaConfig, params, prompt, max_new_tokens: int,
 
 
 # ---------------------------------------------------------------------------
-# continuous-batching serving: slot KV cache + one-program decode
-# (the model half of ``mxtpu.serve`` — scheduler/queue live there)
+# continuous-batching serving (the model half of ``mxtpu.serve`` — the
+# scheduler, the queue and the page allocator live there)
 # ---------------------------------------------------------------------------
 # ``generate`` above is a WHOLE-BATCH program: every request starts
 # together and holds its cache until the slowest one finishes. The
-# slot path instead serves a fixed bank of ``max_slots`` independent
-# rows: admission overwrites a finished slot in place (Orca-style
-# iteration-level scheduling), per-slot length/position vectors drive
-# ONE compiled decode program for the full bank, and the length-masked
-# ``slot_decode_attention`` kernel confines each slot to its own
-# prefix. Prompts prefill through per-bucket programs (padded to a
-# power of two), so total compilations stay bounded by the bucket
-# count + 1.
-
-def slot_cache_specs(cfg: LlamaConfig, mesh: Mesh):
-    """PartitionSpecs for the serving slot state on ``mesh``: kv heads
-    over tp (dropped when tp doesn't divide them — replication, never
-    an error); the slot axis stays unsharded — admission rewrites one
-    row at a time and must not reshard the bank. Per-slot vectors are
-    replicated."""
-    tp = ("tp" if "tp" in mesh.axis_names
-          and cfg.n_kv_heads % mesh.shape["tp"] == 0 else None)
-    # trailing Nones trimmed: program outputs come back normalized, and
-    # a committed P(..., 'tp', None, None) vs an output P(..., 'tp')
-    # would be unequal jit cache keys — one spurious recompile per
-    # program on the mesh path
-    kv = P(None, None, tp) if tp is not None else P()
-    return {"k": kv, "v": kv, "lengths": P(), "tokens": P(),
-            "rngs": P()}
-
-
-def init_slot_cache(cfg: LlamaConfig, max_slots: int, max_len: int,
-                    mesh: Optional[Mesh] = None):
-    """The serving engine's device state: a fixed slot KV cache
-    ``k``/``v`` of (L, max_slots, n_kv_heads, max_len, hd) in the
-    compute dtype, plus per-slot ``lengths`` (valid cache entries),
-    ``tokens`` (next input token) and ``rngs`` (per-request sampling
-    chains). With ``mesh`` the bank materializes directly sharded per
-    :func:`slot_cache_specs`."""
-    hd = cfg.head_dim
-    shape = (cfg.n_layers, max_slots, cfg.n_kv_heads, max_len, hd)
-
-    def build():
-        return {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype),
-                "lengths": jnp.zeros((max_slots,), jnp.int32),
-                "tokens": jnp.zeros((max_slots,), jnp.int32),
-                "rngs": jnp.zeros((max_slots, 2), jnp.uint32)}
-
-    if mesh is None:
-        return build()
-    from jax.sharding import NamedSharding
-    shardings = jax.tree.map(
-        lambda s: NamedSharding(mesh, s),
-        slot_cache_specs(cfg, mesh),
-        is_leaf=lambda s: isinstance(s, P))
-    return jax.jit(build, out_shardings=shardings)()
-
-
-def _layer_slots(cfg: LlamaConfig, cos, sin, pos, mesh, kvspec,
-                 x, lp, ck, cv):
-    """One block of the slot decode: x (S, 1, dim) — one new token per
-    slot; ck/cv (S, kvh, max_len, hd). Writes each slot's new K/V at
-    its OWN position ``pos[i]`` and attends it against its own prefix
-    via the length-masked blockwise kernel."""
-    dt = cfg.dtype
-
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, 1, hd)
-    head_ax = (kvspec[1] if kvspec is not None and len(kvspec) > 1
-               else None)
-    q = _mcon(mesh, q, None, head_ax, None, None)
-    k = _mcon(mesh, k, None, head_ax, None, None)
-    v = _mcon(mesh, v, None, head_ax, None, None)
-
-    zero = jnp.zeros((), jnp.int32)
-
-    def write(c, u, p):          # per-slot scatter at its own position
-        return lax.dynamic_update_slice(c, u, (zero, p, zero))
-
-    with jax.named_scope(KV_WRITE_SCOPE):
-        ck = jax.vmap(write)(ck, k.astype(dt), pos)
-        cv = jax.vmap(write)(cv, v.astype(dt), pos)
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        ck = lax.with_sharding_constraint(
-            ck, NamedSharding(mesh, kvspec))
-        cv = lax.with_sharding_constraint(
-            cv, NamedSharding(mesh, kvspec))
-
-    o = slot_decode_attention(q, ck, cv, pos + 1)
-    x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
-
-    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-    delta, _ = _ffn(cfg, lp, h, mesh, serving=True)
-    x = x + _mcon(mesh, delta, None, None, None)
-    return x, ck, cv
-
-
-def decode_slots(cfg: LlamaConfig, params, kv, sv, active,
-                 temperature, top_k, top_p,
-                 mesh: Optional[Mesh] = None):
-    """ONE continuous-batching decode step over the whole slot bank —
-    the single compiled program the serving engine keeps hot: per-slot
-    position/length arrays drive the RoPE gather, the cache write and
-    the length-masked attention, so requests entering and leaving the
-    bank never change the program shape (no retraces, ever).
-
-    kv: {"k", "v"} — the big cache bank, safe to DONATE (the engine
-    does). sv: {"lengths", "tokens", "rngs"} — the small per-slot
-    vectors, deliberately NOT donated so the engine can overlap the
-    host read of one step's tokens with the next step's dispatch.
-    active: (S,) bool — inactive slots still flow through (fixed
-    shape) but their lengths do not advance and their samples are
-    discarded by the engine. temperature/top_k/top_p: (S,) per-slot
-    sampling config (traced — a mixed batch shares the program).
-    Sampling advances each slot's own rng chain exactly as a batch-1
-    :func:`generate` would, which is what makes serving output
-    bit-identical to per-request generation. Returns
-    (sampled (S,) int32, new kv, new sv)."""
-    max_len = kv["k"].shape[3]
-    lengths = sv["lengths"].astype(jnp.int32)
-    pos = jnp.minimum(lengths, max_len - 1)   # per-slot write position
-    x = _embed(cfg, params, sv["tokens"][:, None])
-
-    kvspec = None
-    if mesh is not None:
-        kvspec = P(*tuple(slot_cache_specs(cfg, mesh)["k"])[1:])
-    cos_t, sin_t = rope_tables(cfg, max_len)
-    cos = cos_t[pos][:, None, None, :]        # (S, 1, 1, hd/2)
-    sin = sin_t[pos][:, None, None, :]
-
-    def body(x, xs):
-        lp, ck, cv = xs
-        x, ck, cv = _layer_slots(cfg, cos, sin, pos, mesh, kvspec,
-                                 x, lp, ck, cv)
-        return x, (ck, cv)
-
-    x, (ck, cv) = lax.scan(body, x,
-                           (params["layers"], kv["k"], kv["v"]))
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        full = NamedSharding(mesh, slot_cache_specs(cfg, mesh)["k"])
-        ck = lax.with_sharding_constraint(ck, full)
-        cv = lax.with_sharding_constraint(cv, full)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _lm_head(cfg, params, x)[:, 0]
-
-    new_rngs, sampled = jax.vmap(_sample_slot)(
-        sv["rngs"], logits, temperature, top_k, top_p)
-    new_lengths = lengths + active.astype(jnp.int32)
-    if mesh is not None:
-        # pin the small vectors replicated — an unconstrained output
-        # sharding would differ from the bank's committed layout and
-        # force a second decode compilation on the next step
-        sampled = _mcon(mesh, sampled, None)
-        new_lengths = _mcon(mesh, new_lengths, None)
-        new_rngs = _mcon(mesh, new_rngs, None, None)
-    return sampled, {"k": ck, "v": cv}, \
-        {"lengths": new_lengths, "tokens": sampled, "rngs": new_rngs}
-
-
-def prefill_slot(cfg: LlamaConfig, params, tokens, true_len, slot,
-                 kv, sv, rng, temperature, top_k, top_p,
-                 mesh: Optional[Mesh] = None):
-    """Admission: run ONE request's prompt — END-padded to its bucket —
-    through the cached stack, write its K/V into row ``slot`` of the
-    slot bank, seed the slot's rng/next-token, and sample the first
-    generated token. One compiled program per prompt BUCKET (power of
-    two), so compilations are bounded by the bucket count no matter
-    what lengths arrive.
-
-    End padding is exact: causal masking means no real position ever
-    attends a pad (pads sit after the prompt), pad K/V beyond
-    ``true_len`` are excluded by the slot's length mask, and each is
-    overwritten by a real decode write before the length ever reaches
-    it. tokens: (1, bucket); true_len/slot: traced scalars; kv/sv as
-    in :func:`decode_slots` (kv donatable). Returns
-    (first token (1,), new kv, new sv)."""
-    b, bucket = tokens.shape
-    hd = cfg.head_dim
-    tmp = {"k": jnp.zeros((cfg.n_layers, b, cfg.n_kv_heads, bucket,
-                           hd), cfg.dtype),
-           "v": jnp.zeros((cfg.n_layers, b, cfg.n_kv_heads, bucket,
-                           hd), cfg.dtype),
-           "pos": jnp.zeros((), jnp.int32)}
-    true_len = jnp.asarray(true_len, jnp.int32)
-    slot = jnp.asarray(slot, jnp.int32)
-    logits, tmp = _forward_cached(cfg, params, tokens, tmp, mesh=mesh,
-                                  last_index=true_len - 1)
-    rng, sub = jax.random.split(rng)
-    tok = sample_logits(sub, logits[:, 0], temperature=temperature,
-                        top_k=top_k, top_p=top_p)
-    z = jnp.zeros((), jnp.int32)
-    with jax.named_scope(KV_WRITE_SCOPE):
-        new_kv = {
-            "k": lax.dynamic_update_slice(kv["k"], tmp["k"],
-                                          (z, slot, z, z, z)),
-            "v": lax.dynamic_update_slice(kv["v"], tmp["v"],
-                                          (z, slot, z, z, z)),
-        }
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None],
-            (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], tok.astype(sv["tokens"].dtype),
-            (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype),
-            (slot, z)),
-    }
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        specs = slot_cache_specs(cfg, mesh)
-        new_kv = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n]))
-            for n, a in new_kv.items()}
-        new_sv = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n]))
-            for n, a in new_sv.items()}
-        tok = _mcon(mesh, tok, None)
-    return tok, new_kv, new_sv
-
+# serving programs instead run ``max_slots`` independent sequences:
+# admission seats a request in a free slot (Orca-style iteration-level
+# scheduling), per-slot length/position vectors drive ONE compiled
+# decode program over every slot, and the length-masked decode
+# attention confines each slot to its own prefix. Prompts prefill
+# through per-bucket programs (padded to a power of two), so total
+# compilations stay bounded by the bucket count + 2. Two kinds of
+# program follow: the detached prefill a disaggregated prefill worker
+# runs (no serving state at all), and the paged programs the engine
+# runs over its KV page pool.
 
 # ---------------------------------------------------------------------------
 # disaggregated prefill/decode (DistServe, OSDI '24): prefill is
 # compute-bound, decode is memory-bound — the serving gateway runs them
-# on separate worker pools with a KV handoff in between. The two
-# programs below are that handoff's device halves: ``prefill_detached``
-# is ``prefill_slot`` minus the slot bank (it RETURNS the per-request
-# KV block instead of scattering it), and ``inject_slot_kv`` is the
-# scatter alone, run later on the decode worker's bank. Same forward
+# on separate worker pools with a KV handoff in between. Its device
+# halves: ``prefill_detached`` is ``prefill_slot_paged`` minus the
+# pool (it RETURNS the per-request KV block instead of scattering it),
+# and ``inject_paged_kv`` (below, with the paged programs) is the
+# scatter alone, run later on the decode worker's pool. Same forward
 # graph, same sampler, same rng chain — so a prefill→handoff→decode
 # request is bit-identical to the colocated path (tier-1-gated in
 # tests/test_gateway.py).
@@ -1282,12 +1072,13 @@ def prefill_slot(cfg: LlamaConfig, params, tokens, true_len, slot,
 def prefill_detached(cfg: LlamaConfig, params, tokens, true_len, rng,
                      temperature, top_k, top_p,
                      mesh: Optional[Mesh] = None):
-    """Prefill ONE request without a slot bank: run the END-padded
-    prompt (see :func:`prefill_slot` for why end padding is exact)
-    through the cached stack and return the pieces a decode worker
-    needs — ``(first_token (1,), k_block, v_block, new_rng)`` with
+    """Prefill ONE request without any serving state: run the
+    END-padded prompt (see :func:`prefill_slot_paged` for why end
+    padding is exact) through the cached stack and return the pieces a
+    decode worker needs — ``(first_token (1,), k_block, v_block,
+    new_rng)`` with
     k/v blocks shaped (L, n_kv_heads, bucket, hd). One compiled
-    program per prompt bucket, exactly like ``prefill_slot``."""
+    program per prompt bucket, exactly like ``prefill_slot_paged``."""
     b, bucket = tokens.shape
     hd = cfg.head_dim
     tmp = {"k": jnp.zeros((cfg.n_layers, b, cfg.n_kv_heads, bucket,
@@ -1356,56 +1147,13 @@ def prefill_detached_chunk(cfg: LlamaConfig, params, chunk, cache,
     return tok, k_chunk, v_chunk, rng, cache
 
 
-def inject_slot_kv(cfg: LlamaConfig, k_block, v_block, true_len, slot,
-                   token, rng, kv, sv, mesh: Optional[Mesh] = None):
-    """Decode-side admission of a handed-off prefill: write the
-    (L, n_kv_heads, bucket, hd) KV block into row ``slot`` of the slot
-    bank and seed the slot's length/token/rng — the scatter half of
-    :func:`prefill_slot`, with the forward pass already paid on the
-    prefill pool. Pad K/V beyond ``true_len`` are excluded by the
-    slot length mask and overwritten before the length reaches them
-    (same argument as bucketed prefill). One compiled program per
-    block bucket; kv is donatable. Returns (new_kv, new_sv)."""
-    true_len = jnp.asarray(true_len, jnp.int32)
-    slot = jnp.asarray(slot, jnp.int32)
-    token = jnp.asarray(token, jnp.int32)
-    z = jnp.zeros((), jnp.int32)
-    new_kv = {
-        "k": lax.dynamic_update_slice(
-            kv["k"], k_block[:, None].astype(kv["k"].dtype),
-            (z, slot, z, z, z)),
-        "v": lax.dynamic_update_slice(
-            kv["v"], v_block[:, None].astype(kv["v"].dtype),
-            (z, slot, z, z, z)),
-    }
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None], (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], token[None].astype(sv["tokens"].dtype),
-            (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype), (slot, z)),
-    }
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        specs = slot_cache_specs(cfg, mesh)
-        new_kv = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n]))
-            for n, a in new_kv.items()}
-        new_sv = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n]))
-            for n, a in new_sv.items()}
-    return new_kv, new_sv
-
-
 # ---------------------------------------------------------------------------
 # paged serving: fixed-size KV page pool + per-slot page tables
-# (PagedAttention, Kwon et al. SOSP '23). The dense slot bank above
-# reserves max_len KV per slot whether or not a request ever grows
-# there; the paged variant keeps ONE pool of (L, n_pages, page_size,
-# kvh, hd) and maps each slot's logical sequence through an int32
-# page-table row the host owns. Admission is bounded by free PAGES, not
+# (PagedAttention, Kwon et al. SOSP '23). A cache row per slot would
+# reserve max_len KV whether or not a request ever grows there; the
+# engine keeps ONE pool of (L, n_pages, page_size, kvh, hd) and maps
+# each slot's logical sequence through an int32 page-table row the
+# host owns. Admission is bounded by free PAGES, not
 # slots, and read-only pages can be shared between slots (refcounted
 # copy-on-write prefix sharing — the allocator lives in
 # ``mxtpu.serve.engine``; these are its device halves). Page 0 is
@@ -1427,13 +1175,18 @@ def inject_slot_kv(cfg: LlamaConfig, k_block, v_block, true_len, slot,
 
 def paged_cache_specs(cfg: LlamaConfig, mesh: Mesh):
     """PartitionSpecs for the paged pool: kv heads over tp (axis 3 of
-    the token-major (L, n_pages, page_size, kvh, hd) pool — same
-    head-axis rule as :func:`slot_cache_specs`), layer, page and
-    in-page offset unsharded (they are what the decode write indexes,
+    the token-major (L, n_pages, page_size, kvh, hd) pool; dropped
+    when tp doesn't divide them — replication, never an error), layer,
+    page and in-page offset unsharded (they are what the decode write indexes,
     and the host scatters single pages). Scale pools (int8 mode,
-    (L, n_pages, page_size, kvh)) follow the same spec."""
+    (L, n_pages, page_size, kvh)) follow the same spec. Per-slot
+    vectors are replicated."""
     tp = ("tp" if "tp" in mesh.axis_names
           and cfg.n_kv_heads % mesh.shape["tp"] == 0 else None)
+    # trailing Nones trimmed: program outputs come back normalized, and
+    # a committed P(..., 'tp', None) vs an output P(..., 'tp') would be
+    # unequal jit cache keys — one spurious recompile per program on
+    # the mesh path
     kv = P(None, None, None, tp) if tp is not None else P()
     return {"k": kv, "v": kv, "ks": kv, "vs": kv,
             "lengths": P(), "tokens": P(), "rngs": P()}
@@ -1442,9 +1195,11 @@ def paged_cache_specs(cfg: LlamaConfig, mesh: Mesh):
 def init_paged_cache(cfg: LlamaConfig, max_slots: int, n_pages: int,
                      page_size: int, mesh: Optional[Mesh] = None,
                      int8: bool = False):
-    """Device state for the PAGED serving engine: K/V pools of
-    (L, n_pages, page_size, n_kv_heads, hd) plus the same per-slot
-    ``lengths``/``tokens``/``rngs`` vectors as :func:`init_slot_cache`
+    """The serving engine's device state: K/V pools of
+    (L, n_pages, page_size, n_kv_heads, hd) in the compute dtype plus
+    per-slot ``lengths`` (valid cache entries), ``tokens`` (next input
+    token) and ``rngs`` (per-request sampling chains); with ``mesh``
+    it materializes directly sharded per :func:`paged_cache_specs`
     (page tables stay HOST-side — a small int32 operand per step, so
     table edits never touch device state). ``int8=True`` stores the
     pools as int8 with per-token-per-head f32 scales ``ks``/``vs`` of
@@ -1575,24 +1330,39 @@ def _scan_paged_layers(cfg: LlamaConfig, params, kv, x, layer_fn):
 def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
                        page_table, mesh, kvspec, x, lp, layer, ck, cv,
                        cks=None, cvs=None):
-    """One block of the PAGED slot decode: x (S, 1, dim); ck/cv are the
-    WHOLE page pools (L, n_pages, ps, kvh, hd), reached at ``layer`` by
-    index. Each slot's new K/V scatters into pool page ``phys[i]`` at
-    in-page offset ``off[i]`` (the host redirects inactive slots to
-    scratch page 0 — their table rows are zeroed, so no live page can
-    alias the write), then the slot attends its gathered pages via the
+    """One block of the paged slot decode, plain step and speculative
+    verify alike: x (S, W, dim) holds each slot's W new tokens (the
+    plain step: W = 1); ck/cv are the WHOLE page pools
+    (L, n_pages, ps, kvh, hd), reached at ``layer`` by index.
+
+    The index arrays' rank says which step this is, statically. The
+    plain step passes ``pos``/``phys``/``off`` of shape (S,): slot s
+    scatters its one token's K/V, position ``pos[s]`` of its
+    sequence, into pool page ``phys[s]`` at in-page offset ``off[s]``
+    and attends ``[0, pos[s] + 1)``. The verify step passes (S, W):
+    token i of slot s goes to ``phys[s, i]`` at ``off[s, i]`` and
+    attends its OWN causal prefix ``[0, pos[s, i] + 1)`` — the
+    per-query length mask that keeps every drafted position's logits
+    exactly what a sequential decode at that position would compute.
+    The scatter's index shape is the write's own (XLA lays the pool
+    out by it), so the plain step never pays for the verify step's.
+    The host redirects inactive slots (zeroed table rows) and
+    out-of-budget positions to scratch page 0, so no live page can
+    alias a write; then each slot attends its gathered pages via the
     length-masked paged kernel."""
     dt = cfg.dtype
 
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, 1, hd)
+    q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, W, hd)
     head_ax = _pool_head_axis(kvspec)
     q = _mcon(mesh, q, None, head_ax, None, None)
     k = _mcon(mesh, k, None, head_ax, None, None)
     v = _mcon(mesh, v, None, head_ax, None, None)
 
-    knew = k[:, :, 0, :]                 # (S, kvh, hd)
-    vnew = v[:, :, 0, :]
+    if phys.ndim == 1:
+        knew, vnew = k[:, :, 0, :], v[:, :, 0, :]        # (S, kvh, hd)
+    else:                                # (S, W, kvh, hd)
+        knew, vnew = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     if cks is not None:                  # int8 pool: quantize the write
         ck, cv, cks, cvs = _write_pages_q8(ck, cv, cks, cvs, knew,
                                            vnew, layer, phys, off)
@@ -1635,19 +1405,33 @@ def _gather_slot_pages_batch(pool, scales, layer, page_table, dt):
 def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
                        page_table, temperature, top_k, top_p,
                        mesh: Optional[Mesh] = None):
-    """ONE decode step over the PAGED bank — :func:`decode_slots` with
-    the dense (slot, max_len) cache row replaced by a page-table
-    indirection. ``page_table`` (S, pages_per_slot) int32 is a small
+    """ONE continuous-batching decode step over every slot — the
+    single compiled program the serving engine keeps hot: per-slot
+    position/length arrays drive the RoPE gather, the cache write and
+    the length-masked attention, so requests entering and leaving
+    never change the program shape (no retraces, ever).
+
+    kv: the pool dict from :func:`init_paged_cache` minus the per-slot
+    vectors — the big state, safe to DONATE (the engine does). sv:
+    {"lengths", "tokens", "rngs"} — the small per-slot vectors,
+    deliberately NOT donated so the engine can overlap the host read
+    of one step's tokens with the next step's dispatch. active: (S,)
+    bool — inactive slots still flow through (fixed shape) but their
+    lengths do not advance and their samples are discarded by the
+    engine. ``page_table`` (S, pages_per_slot) int32 is a small
     per-step operand (host-owned: admission edits tables without
     touching device state, and the jit cache key never changes).
     Inactive slots carry zeroed table rows, so their cache write lands
     in scratch page 0 and their (discarded) sample reads scratch —
-    active slots' pages are never aliased. Sampling, rng chains, and
-    the length mask are IDENTICAL to the dense path, which is what
-    keeps paged serving bit-identical to per-request ``generate``
-    (asserted in tests/test_paged_kv.py). kv: the pool dict from
-    :func:`init_paged_cache` minus the per-slot vectors (donatable);
-    sv as in :func:`decode_slots`."""
+    active slots' pages are never aliased. temperature/top_k/top_p:
+    (S,) per-slot sampling config (traced — a mixed batch shares the
+    program). Sampling advances each slot's own rng chain exactly as a
+    batch-1 :func:`generate` would (one ``jax.random.split`` per
+    emitted token), and the length mask confines a slot to exactly the
+    keys ``generate`` attends: that is what makes serving output
+    bit-identical to per-request generation (asserted in
+    tests/test_paged_kv.py). Returns (sampled (S,) int32, new kv,
+    new sv)."""
     ps = kv["k"].shape[2]
     cap = page_table.shape[1] * ps
     lengths = sv["lengths"].astype(jnp.int32)
@@ -1734,11 +1518,17 @@ def prefill_slot_paged(cfg: LlamaConfig, params, tokens, true_len,
                        prefix_len, pages_row, slot, kv, sv, rng,
                        temperature, top_k, top_p,
                        mesh: Optional[Mesh] = None):
-    """Paged admission, cold OR warm: gather the slot's pages into a
-    contiguous cache view, run the SUFFIX tokens (END-padded to their
-    bucket) through the cached stack at ``pos=prefix_len``, scatter the
-    pages back, seed the slot vectors, and sample the first generated
-    token.
+    """Admission, cold OR warm: gather the slot's pages into a
+    contiguous cache view, run ONE request's SUFFIX tokens (END-padded
+    to their bucket) through the cached stack at ``pos=prefix_len``,
+    scatter the pages back, seed the slot's length/rng/next-token, and
+    sample the first generated token.
+
+    End padding is exact: causal masking means no real position ever
+    attends a pad (pads sit after the prompt), pad K/V beyond
+    ``true_len`` are excluded by the slot's length mask, and each is
+    overwritten by a real decode write before the length ever reaches
+    it.
 
     Warm admission (``prefix_len > 0``) is what prefix sharing buys:
     the shared pages already hold positions [0, prefix_len), the
@@ -1747,13 +1537,14 @@ def prefill_slot_paged(cfg: LlamaConfig, params, tokens, true_len,
     bit-identity property), and only ``len(prompt) - prefix_len``
     tokens pay forward FLOPs — the TTFT win. Cold admission is the
     same program at ``prefix_len=0``. One compiled program per SUFFIX
-    bucket (the same power-of-two set as dense prefill, so the
-    compile bound is unchanged).
+    bucket (power of two), so compilations are bounded by the bucket
+    count no matter what lengths arrive.
 
     tokens: (1, bucket) suffix; true_len: TOTAL valid length
     (prefix + real suffix); pages_row: (pages_per_slot,) int32 — the
     slot's full table row (scratch-0 tail entries collapse onto the
-    never-attended page 0). The engine guarantees write range
+    never-attended page 0); kv/sv as in :func:`decode_slots_paged`
+    (kv donatable). The engine guarantees write range
     [prefix_len, prefix_len+bucket) stays inside the row's capacity
     and that every page it touches is PRIVATE (CoW forked). Returns
     (first token (1,), new kv pools, new sv)."""
@@ -1800,14 +1591,16 @@ def prefill_slot_paged(cfg: LlamaConfig, params, tokens, true_len,
 def inject_paged_kv(cfg: LlamaConfig, k_block, v_block, true_len,
                     pages_row, slot, token, rng, kv, sv,
                     mesh: Optional[Mesh] = None):
-    """Decode-side admission of a handed-off prefill into the PAGED
-    bank: split the (L, n_kv_heads, bucket, hd) block into page_size
-    chunks and scatter them at the slot's first ceil(bucket/ps) pages —
-    :func:`inject_slot_kv`'s role for the paged layout. Pad K/V beyond
-    ``true_len`` land in pages the slot owns and are excluded by its
-    length mask (same argument as the dense path). In int8 mode the
-    block is quantized per token on the way in. kv donatable. Returns
-    (new kv pools, new sv)."""
+    """Decode-side admission of a handed-off prefill: split the
+    (L, n_kv_heads, bucket, hd) block into page_size chunks, scatter
+    them at the slot's first ceil(bucket/ps) pages and seed the slot's
+    length/token/rng — the scatter half of :func:`prefill_slot_paged`,
+    with the forward pass already paid on the prefill pool. Pad K/V
+    beyond ``true_len`` land in pages the slot owns, are excluded by
+    its length mask and overwritten before the length reaches them
+    (same argument as bucketed prefill). In int8 mode the block is
+    quantized per token on the way in. One compiled program per block
+    bucket; kv donatable. Returns (new kv pools, new sv)."""
     int8 = "ks" in kv
     ps = kv["k"].shape[2]
     bucket = k_block.shape[2]
@@ -1886,55 +1679,6 @@ def copy_page(kv, src, dst):
 # later) — this file only holds the device half.
 # ---------------------------------------------------------------------------
 
-def _layer_slots_spec(cfg: LlamaConfig, cos, sin, qlen, phys, off,
-                      page_table, mesh, kvspec, x, lp, layer, ck, cv,
-                      cks=None, cvs=None):
-    """One block of the SPECULATIVE paged decode: x (S, W, dim) holds
-    each slot's current token plus its drafted run (W = k + 1). Token
-    i of slot s scatters its K/V into pool page ``phys[s, i]`` at
-    offset ``off[s, i]`` (the host redirects out-of-budget positions
-    and inactive slots to scratch page 0), then attends its OWN causal
-    prefix ``[0, qlen[s, i])`` — the per-query length mask that keeps
-    every drafted position's logits exactly what a sequential decode
-    at that position would compute."""
-    dt = cfg.dtype
-
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, lp, h, cos, sin)         # q: (S, h, W, hd)
-    head_ax = _pool_head_axis(kvspec)
-    q = _mcon(mesh, q, None, head_ax, None, None)
-    k = _mcon(mesh, k, None, head_ax, None, None)
-    v = _mcon(mesh, v, None, head_ax, None, None)
-
-    knew = k.transpose(0, 2, 1, 3)       # (S, W, kvh, hd)
-    vnew = v.transpose(0, 2, 1, 3)
-    if cks is not None:                  # int8 pool: quantize the write
-        ck, cv, cks, cvs = _write_pages_q8(ck, cv, cks, cvs, knew,
-                                           vnew, layer, phys, off)
-        kf = _gather_slot_pages_batch(ck, cks, layer, page_table, dt)
-        vf = _gather_slot_pages_batch(cv, cvs, layer, page_table, dt)
-        o = slot_decode_attention(q, kf, vf, qlen)
-    else:
-        ck, cv = _write_pages(ck, cv, knew, vnew, layer, phys, off)
-        if mesh is not None:
-            from jax.sharding import NamedSharding
-            ck = lax.with_sharding_constraint(
-                ck, NamedSharding(mesh, kvspec))
-            cv = lax.with_sharding_constraint(
-                cv, NamedSharding(mesh, kvspec))
-        o = paged_decode_attention(q, ck, cv, page_table, qlen,
-                                   layer=layer)
-
-    x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
-
-    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-    delta, _ = _ffn(cfg, lp, h, mesh, serving=True)
-    x = x + _mcon(mesh, delta, None, None, None)
-    if cks is not None:
-        return x, ck, cv, cks, cvs
-    return x, ck, cv
-
-
 def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
                       page_table, drafts, temperature, top_k, top_p,
                       mesh: Optional[Mesh] = None):
@@ -1978,7 +1722,6 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
     rows = jnp.arange(S)[:, None]
     phys = jnp.where(oob, 0, page_table[rows, cw // ps])
     off = cw % ps
-    qlen = wpos + 1                       # query i attends [0, pos+i+1)
 
     toks_in = jnp.concatenate(
         [sv["tokens"][:, None], drafts.astype(sv["tokens"].dtype)],
@@ -1993,8 +1736,8 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
     sin = sin_t[cw][:, None]
 
     x, new_kv = _scan_paged_layers(cfg, params, kv, x, partial(
-        _layer_slots_spec, cfg, cos, sin, qlen, phys, off, page_table,
-        mesh, kvspec))
+        _layer_slots_paged, cfg, cos, sin, wpos, phys, off,
+        page_table, mesh, kvspec))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _lm_head(cfg, params, x)                 # (S, W, V)
 

@@ -77,8 +77,6 @@ GMU_SCOPE = "gmu"
 # what ``ServeEngine`` cannot do for this family yet, by option, with
 # the mechanism in the way (the engine raises with these words)
 SERVE_UNSUPPORTED = {
-    "paged=False": "the recurrent and window state lives beside a page "
-                   "pool; there is no dense slot bank for this family",
     "prefix_cache": "a prefix hit would need a snapshot of the recurrent "
                     "(SSM and convolution) state and of the window rings "
                     "at the shared boundary, and pages hold keys and "

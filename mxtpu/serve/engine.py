@@ -1,21 +1,26 @@
-"""ServeEngine — continuous batching over the llama slot KV cache.
+"""ServeEngine — continuous batching over a paged KV pool.
 
-Scheduler design (Orca, OSDI '22; slot-structured cache in the spirit
-of vLLM's paged KV, SOSP '23 — one fixed bank, no paging, because XLA
-wants static shapes):
+Scheduler design (Orca, OSDI '22, over PagedAttention's page pool,
+SOSP '23):
 
-- **slot bank**: ``llama.init_slot_cache`` holds ``max_slots``
-  independent cache rows; per-slot ``lengths`` confine attention to
-  each request's own prefix (``slot_decode_attention``).
-- **admission at step boundaries**: a finished slot is overwritten in
-  place by the next queued request via a per-BUCKET prefill program
-  (prompts end-padded to a power of two — exact, see
-  ``llama.prefill_slot``), so prefill compilations are bounded by the
-  bucket count.
-- **one decode program**: every step runs ``llama.decode_slots`` over
-  the full bank; per-slot position/length/rng/sampling vectors make
-  request churn invisible to the compiled shape. The engine asserts
-  this via :attr:`compile_count`.
+- **slots and pages**: the family's ``init_paged_cache`` holds ONE
+  pool of fixed-size KV pages and ``max_slots`` per-slot
+  ``lengths``/``tokens``/``rngs`` vectors. A slot's sequence lives in
+  the pages its host-side page-table row names (:class:`PageAllocator`
+  hands them out, page 0 is scratch), so admission is bounded by free
+  PAGES, and read-only prefix pages are shared between slots
+  (:class:`PrefixCache`, copy-on-write through ``copy_page``).
+  Per-slot ``lengths`` confine attention to each request's own prefix.
+- **admission at step boundaries**: a free slot is seated by the next
+  queued request via a per-BUCKET prefill program (the prompt's
+  unshared suffix end-padded to a power of two — exact, see
+  ``llama.prefill_slot_paged``), so prefill compilations are bounded
+  by the bucket count.
+- **one decode program**: every step runs ``decode_slots_paged`` over
+  every slot; per-slot position/length/rng/sampling vectors and the
+  page table (a small int32 operand) make request churn invisible to
+  the compiled shape. The engine asserts this via
+  :attr:`compile_count`.
 - **overlapped host sync**: the classic serving-latency bug is a host
   readback inside the decode loop blocking the accelerator every token
   (mxlint MXL004 flags the pattern). Here step ``t``'s tokens are read
@@ -24,7 +29,7 @@ wants static shapes):
   the naive synchronous order, e.g. for latency debugging).
 
 Determinism contract: each slot's forward and sampling depend only on
-its own cache row and rng chain, so the engine's output for a request
+its own pages and rng chain, so the engine's output for a request
 never depends on how requests are interleaved, admitted, or delayed
 (tested across slot counts and overlap modes). Against per-request
 ``llama.generate`` the math is identical and the rng chain replays
@@ -92,19 +97,19 @@ def _engine_metrics(eid: str):
         "latency": telemetry.histogram(
             "serve_token_latency_ms",
             "Inter-token gaps per request (host emission clock)"),
-        # KV occupancy: the dense bank's reserved-vs-live waste number
-        # ROADMAP item 1 (paged KV) is gated on (perfscope ledger)
+        # KV occupancy: what the donated state reserves against what
+        # live sequences cover (the perfscope ledger books the former)
         "kv_reserved": telemetry.gauge(
             "serve_kv_reserved_bytes",
-            "Bytes the dense KV slot bank reserves", engine=eid),
+            "Bytes the engine's donated state (page pool and fixed "
+            "per-slot state) reserves", engine=eid),
         "kv_live": telemetry.gauge(
             "serve_kv_live_bytes",
-            "Bytes of the slot bank covered by live sequence "
+            "Bytes of the donated state covered by live sequence "
             "prefixes", engine=eid),
         "kv_occ": telemetry.gauge(
             "serve_kv_occupancy_ratio",
-            "live/reserved fraction of the KV slot bank", engine=eid),
-        # paged mode: the page pool the dense gauges above argue for
+            "live/reserved fraction of the donated state", engine=eid),
         "pages_total": telemetry.gauge(
             "serve_kv_pages_total",
             "Allocatable pages in the paged KV pool (scratch page 0 "
@@ -459,7 +464,7 @@ def ngram_drafter(history: np.ndarray, k: int) -> np.ndarray:
 class KVHandoff:
     """A prefill worker's detached output — everything a decode engine
     needs to seat the request without re-running the prompt
-    (``llama.prefill_detached`` produces it, ``llama.inject_slot_kv``
+    (``llama.prefill_detached`` produces it, ``llama.inject_paged_kv``
     consumes it). ``k``/``v``: (L, n_kv_heads, bucket, hd) host
     arrays; ``rng``: the (2,) uint32 chain state AFTER the first-token
     split, so decode continues the exact chain ``generate`` would."""
@@ -496,7 +501,7 @@ class _PrefillJob:
 
 
 class ServeEngine:
-    """Continuous-batching scheduler over one model + one slot bank.
+    """Continuous-batching scheduler over one model + one KV page pool.
 
     Args: ``cfg``/``params`` — a config and parameter pytree of a
     family in ``models.SERVING_FAMILIES`` (llama, sambay): the engine
@@ -506,11 +511,18 @@ class ServeEngine:
     the way (a llama weight-only int8 tree from ``quantize_params_int8``
     rides the same programs). ``max_slots``/``max_len``/``min_bucket`` default
     from ``MXTPU_SERVE_MAX_SLOTS`` / the config's ``max_seq_len`` /
-    ``MXTPU_SERVE_MIN_BUCKET``. ``mesh`` serves sharded (cache per
-    ``llama.slot_cache_specs``, params as placed by the training
-    rules). ``prefill_chunk`` (paged, a family with
-    ``prefill_slot_paged_chunk``: sambay) prefills a prompt that many
-    tokens at a time, at most one chunk between two decode steps once
+    ``MXTPU_SERVE_MIN_BUCKET``. ``mesh`` serves sharded (pool per
+    ``llama.paged_cache_specs``, params as placed by the training
+    rules). ``page_size`` (``MXTPU_KV_PAGE_SIZE``, 16) and ``n_pages``
+    (``MXTPU_KV_PAGES``; by default every slot's ``max_len`` plus the
+    scratch page) size the pool; ``prefix_cache`` shares prompt
+    prefixes' pages between requests (on unless the family refuses
+    it); ``int8_pages`` stores the pool as int8 with per-token scales.
+    ``paged`` is what is left of a switch between this pool and a dense
+    bank that is gone: ``True`` is its one legal value, kept while the
+    benchmark's drivers pass it (ROADMAP D12). ``prefill_chunk`` (a
+    family with ``prefill_slot_paged_chunk``: sambay) prefills a
+    prompt that many tokens at a time, at most one chunk between two decode steps once
     the running requests are no fewer than the waiting ones: the gap a
     running request sees beside an admission is one chunk's time
     whatever the prompt's length, and the prompt's first token comes
@@ -521,7 +533,7 @@ class ServeEngine:
                  min_bucket: Optional[int] = None,
                  mesh=None, overlap: Optional[bool] = None,
                  clock: Optional[Callable[[], float]] = None,
-                 paged: bool = False,
+                 paged: bool = True,
                  page_size: Optional[int] = None,
                  n_pages: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -555,61 +567,51 @@ class ServeEngine:
         # served each segment, not just which replica
         self.build: Optional[str] = None
 
-        # paged mode (PagedAttention): KV lives in a fixed page pool
-        # with host-owned per-slot page tables; admission is bounded by
+        # KV lives in a fixed page pool (PagedAttention) with
+        # host-owned per-slot page tables; admission is bounded by
         # free PAGES, not slots, and prefix pages are shared CoW
-        self.paged = bool(paged)
-        if self.paged:
-            self.page_size = int(page_size
-                                 or _env_int("MXTPU_KV_PAGE_SIZE", 16))
-            self._pages_per_slot = -(-self.max_len // self.page_size)
-            # default pool = dense-equivalent capacity + scratch: the
-            # A/B bench shrinks it to show paged admits more slots at
-            # the same HBM
-            self.n_pages = int(n_pages or _env_int(
-                "MXTPU_KV_PAGES",
-                self.max_slots * self._pages_per_slot + 1))
-            self.prefix_cache_enabled = (
-                prefix_cache if prefix_cache is not None
-                else "prefix_cache" not in self._unsupported
-                and os.environ.get("MXTPU_KV_PREFIX_CACHE", "1") != "0")
-            self.int8_pages = (
-                bool(int8_pages) if int8_pages is not None
-                else os.environ.get("MXTPU_KV_INT8_PAGES", "0") == "1")
-        else:
-            self.page_size = None
-            self.n_pages = 0
-            self.prefix_cache_enabled = False
-            self.int8_pages = False
+        if paged is not True:
+            raise ValueError(
+                f"paged={paged!r}: the engine has one KV bank, the page "
+                "pool (page_size, n_pages); the dense slot bank went in "
+                "PR 29. Leave the keyword out")
+        self.page_size = int(page_size
+                             or _env_int("MXTPU_KV_PAGE_SIZE", 16))
+        self._pages_per_slot = -(-self.max_len // self.page_size)
+        # default pool: every slot can grow to max_len, plus scratch
+        self.n_pages = int(n_pages or _env_int(
+            "MXTPU_KV_PAGES",
+            self.max_slots * self._pages_per_slot + 1))
+        self.prefix_cache_enabled = (
+            prefix_cache if prefix_cache is not None
+            else "prefix_cache" not in self._unsupported
+            and os.environ.get("MXTPU_KV_PREFIX_CACHE", "1") != "0")
+        self.int8_pages = (
+            bool(int8_pages) if int8_pages is not None
+            else os.environ.get("MXTPU_KV_INT8_PAGES", "0") == "1")
 
         # speculative decoding (ISSUE 19): draft k tokens host-side
-        # per slot per step, verify them in ONE batched forward, and
-        # advance each slot by its accepted run length. Paged-only:
-        # the verify program scatters through the page-table
-        # indirection (decode_slots_spec).
+        # per slot per step, verify them in ONE batched forward
+        # (decode_slots_spec), and advance each slot by its accepted
+        # run length
         self.speculate_k = int(
             speculate_k if speculate_k is not None
             else _env_int("MXTPU_SERVE_SPECULATE_K", 0))
         if self.speculate_k < 0:
             raise ValueError(
                 f"speculate_k must be >= 0, got {self.speculate_k}")
-        if self.speculate_k and not self.paged:
-            raise ValueError(
-                "speculate_k requires paged=True (the verify program "
-                "runs against the paged KV layout)")
         self._drafter = drafter or ngram_drafter
-        self._refuse({"paged=False": not self.paged,
-                      "prefix_cache": self.prefix_cache_enabled,
+        self._refuse({"prefix_cache": self.prefix_cache_enabled,
                       "speculate_k": self.speculate_k,
                       "int8_pages": self.int8_pages,
                       "mesh": mesh is not None})
         self.prefill_chunk = int(prefill_chunk or 0)
-        if self.prefill_chunk and not (
-                self.paged and hasattr(fam, "prefill_slot_paged_chunk")):
+        if self.prefill_chunk and not hasattr(
+                fam, "prefill_slot_paged_chunk"):
             raise ValueError(
-                "prefill_chunk needs paged=True and a family whose "
-                "prefill carries its state from chunk to chunk "
-                f"(sambay); got paged={self.paged}, {cfg.family}")
+                "prefill_chunk needs a family whose prefill carries "
+                "its state from chunk to chunk (sambay); got "
+                f"{cfg.family}")
         if self.speculate_k:
             # the host drafter conditions on every token emitted so
             # far, so the previous step's tokens must be read back
@@ -618,19 +620,15 @@ class ServeEngine:
             # over the whole accepted run rather than one token
             self.overlap = False
 
-        if self.paged:
-            state = fam.init_paged_cache(
-                cfg, self.max_slots, self.n_pages, self.page_size,
-                mesh=mesh, int8=self.int8_pages)
-        else:
-            state = fam.init_slot_cache(cfg, self.max_slots,
-                                        self.max_len, mesh=mesh)
+        state = fam.init_paged_cache(
+            cfg, self.max_slots, self.n_pages, self.page_size,
+            mesh=mesh, int8=self.int8_pages)
         # the small per-slot vectors; everything else is the donated
-        # state (llama: the K/V bank or pools; sambay: pools plus the
-        # fixed per-slot rings and recurrent state)
+        # state (llama: the K/V pools; sambay: a pool plus the fixed
+        # per-slot rings and recurrent state)
         self._sv = {n: state.pop(n) for n in ("lengths", "tokens", "rngs")}
         self._kv = state
-        # the kv bank is donated through every program (in-place in
+        # the kv state is donated through every program (in-place in
         # HBM); the small vectors are not, so the previous step's
         # sampled tokens stay readable during the overlapped sync.
         # watch(): ONE decode program ever — cache growth past 1 is the
@@ -639,11 +637,10 @@ class ServeEngine:
         # watch_jit(): each program is compiled under the name of the
         # model function it runs, so a trace reads
         # jit_decode_slots_paged, not jit__unknown
-        decode = fam.decode_slots_paged if self.paged \
-            else fam.decode_slots
         self._decode = telemetry.watch_jit(
-            partial(decode, cfg, mesh=mesh), "serve_decode",
-            decode.__name__, loop="serve", donate_argnums=(1,))
+            partial(fam.decode_slots_paged, cfg, mesh=mesh),
+            "serve_decode", "decode_slots_paged", loop="serve",
+            donate_argnums=(1,))
         self._prefills: Dict[Any, Any] = {}
         self._injects: Dict[int, Any] = {}
         self._spec_decode = None
@@ -651,37 +648,36 @@ class ServeEngine:
             # the ONE extra watched program speculative mode adds (the
             # k-verify step) — compile_count's bound grows by exactly
             # this; steps where no slot has a draft still run the
-            # plain decode program (mixed stepping, same bank)
+            # plain decode program (mixed stepping, same pool)
             self._spec_decode = telemetry.watch_jit(
                 partial(fam.decode_slots_spec, cfg, mesh=mesh),
                 "serve_spec_verify", "decode_slots_spec", loop="serve",
                 donate_argnums=(1,))
-        if self.paged:
-            # host page-table (a small int32 operand per step), the
-            # refcounted allocator, the prefix cache, and the CoW
-            # fork program (ONE program: src/dst are traced scalars)
-            self._pt = np.zeros(
-                (self.max_slots, self._pages_per_slot), np.int32)
-            self._pages = PageAllocator(self.n_pages)
-            self._prefix = (PrefixCache(self._pages)
-                            if self.prefix_cache_enabled else None)
-            # a per-engine wrapper (NOT the bare copy_page, which
-            # watch_jit's partial is): jit caches key on callable
-            # identity, so a shared function would alias cache sizes
-            # across engines and skew both the recompile watcher and
-            # compile_count's churn gate
-            self._copy_fn = telemetry.watch_jit(
-                fam.copy_page, "serve_copy_page", "copy_page",
-                donate_argnums=(0,))
-            # engine-local tallies (the telemetry counters are
-            # process-wide totals shared across engines)
-            self._prefix_hits = 0
-            self._prefix_misses = 0
-            self._cow_forks = 0
+        # host page-table (a small int32 operand per step), the
+        # refcounted allocator, the prefix cache, and the CoW
+        # fork program (ONE program: src/dst are traced scalars)
+        self._pt = np.zeros(
+            (self.max_slots, self._pages_per_slot), np.int32)
+        self._pages = PageAllocator(self.n_pages)
+        self._prefix = (PrefixCache(self._pages)
+                        if self.prefix_cache_enabled else None)
+        # a per-engine wrapper (NOT the bare copy_page, which
+        # watch_jit's partial is): jit caches key on callable
+        # identity, so a shared function would alias cache sizes
+        # across engines and skew both the recompile watcher and
+        # compile_count's churn gate
+        self._copy_fn = telemetry.watch_jit(
+            fam.copy_page, "serve_copy_page", "copy_page",
+            donate_argnums=(0,))
+        # engine-local tallies (the telemetry counters are
+        # process-wide totals shared across engines)
+        self._prefix_hits = 0
+        self._prefix_misses = 0
+        self._cow_forks = 0
         # chunked prefill: the prompts being prefilled, in order of
         # admission (the head's chunks run first), the stage the head's
         # chunks hand on through (one prompt at a time, outside the
-        # slot bank), and which slots are seated but not yet running
+        # pool), and which slots are seated but not yet running
         self._jobs: Deque[_PrefillJob] = deque()
         self._prefilling = np.zeros(self.max_slots, bool)
         self._stage = None
@@ -734,36 +730,32 @@ class ServeEngine:
         # one entry per active slot — exactly the device's `lengths`
         # vector, tracked WITHOUT reading it back: a device sync here
         # would block the decode loop every token, MXL004). Reserved
-        # bytes count the bank's global logical size across the mesh.
+        # bytes count the pool's global logical size across the mesh.
         self._slot_len = np.zeros(S, np.int64)
-        # bytes of the donated state by kind: pages (or the dense
-        # bank) grow with the tokens held; a family's other kinds are
-        # fixed blocks per slot
-        grows = "kv_pages" if self.paged else "kv_slots"
+        # bytes of the donated state by kind: pages grow with the
+        # tokens held; a family's other kinds are fixed blocks per slot
         kinds = getattr(fam, "STATE_KINDS", {})
         by_kind: Dict[str, int] = {}
         for n, a in self._kv.items():
-            k = kinds.get(n, grows)
+            k = kinds.get(n, "kv_pages")
             by_kind[k] = by_kind.get(k, 0) + int(a.nbytes)
         for k, nbytes in by_kind.items():
             telemetry.gauge(
                 "serve_state_bytes", "Bytes of the engine's donated "
-                "device state, by kind: kv_pages (or kv_slots), "
-                "window_ring, ssm", engine=eid, kind=k).set(nbytes)
-        # reserved counts everything donated (in paged mode the scratch
-        # page too — it is real HBM); per-token bytes are the growing
-        # kind's over the tokens it can hold (scale planes included in
-        # int8 mode), per-slot bytes the fixed kinds' over the slots
+                "device state, by kind: kv_pages, window_ring, ssm",
+                engine=eid, kind=k).set(nbytes)
+        # reserved counts everything donated (the scratch page too — it
+        # is real HBM); per-token bytes are the pages' over the tokens
+        # they can hold (scale planes included in int8 mode), per-slot
+        # bytes the fixed kinds' over the slots
         self._kv_reserved = sum(by_kind.values())
-        self._kv_tok_bytes = by_kind[grows] // (
-            self.n_pages * self.page_size if self.paged
-            else self.max_slots * self.max_len)
-        self._slot_state_bytes = ((self._kv_reserved - by_kind[grows])
+        self._kv_tok_bytes = by_kind["kv_pages"] // (
+            self.n_pages * self.page_size)
+        self._slot_state_bytes = ((self._kv_reserved - by_kind["kv_pages"])
                                   // self.max_slots)
-        if self.paged:
-            self._m["pages_total"].set(self.n_pages - 1)
-            self._m["pages_free"].set(self._pages.free_pages)
-            self._m["pages_shared"].set(0)
+        self._m["pages_total"].set(self.n_pages - 1)
+        self._m["pages_free"].set(self._pages.free_pages)
+        self._m["pages_shared"].set(0)
         self._m["kv_reserved"].set(self._kv_reserved)
         self._m["kv_live"].set(0)
         self._m["kv_occ"].set(0.0)
@@ -771,8 +763,7 @@ class ServeEngine:
         perfscope.ledger().account_tree("params", params,
                                         name=f"engine{eid}")
         perfscope.ledger().account(
-            "kv_page_pool" if self.paged else "kv_slot_bank",
-            self._kv_reserved, name=f"engine{eid}")
+            "kv_page_pool", self._kv_reserved, name=f"engine{eid}")
 
         # batch mode (run()) returns the per-request token lists, so
         # it must retain them; a long-lived gateway replica must NOT —
@@ -834,7 +825,7 @@ class ServeEngine:
                          request: Request) -> int:
         """Queue a request whose prompt was already prefilled on a
         prefill worker (disaggregated mode): admission seats the
-        handed-off KV block via ``llama.inject_slot_kv`` instead of
+        handed-off KV block via ``llama.inject_paged_kv`` instead of
         running a prefill program, and the worker-sampled first token
         is emitted as this request's first token."""
         self._refuse({"submit_prefilled": True})
@@ -851,14 +842,10 @@ class ServeEngine:
                 f"{self.max_len}")
         prompt = np.asarray(request.prompt, np.int32).reshape(-1)
         if prompt.size > handoff.true_len:
-            # journaled-page resume (paged mode): prompt = original +
+            # journaled-page resume: prompt = original +
             # already-emitted tokens; admission injects the journaled
             # pages and warm-prefills ONLY the emitted suffix — no
             # prefill-worker round trip, same rng chain (resume_key)
-            if not self.paged:
-                raise ValueError(
-                    "handoff shorter than prompt: page-journaled "
-                    "resume requires a paged engine")
             if prompt.size + request.max_new_tokens > self.max_len:
                 raise ValueError(
                     f"prompt ({prompt.size}) + max_new_tokens "
@@ -969,9 +956,9 @@ class ServeEngine:
     # routing/scrape paths behind one admission.
     def _pick_admissions(self) -> List[Tuple[int, int, Request,
                                              Optional[KVHandoff],
-                                             Optional[Dict]]]:
+                                             Dict]]:
         picks: List[Tuple[int, int, Request,
-                          Optional[KVHandoff], Optional[Dict]]] = []
+                          Optional[KVHandoff], Dict]] = []
         while self._queue:
             arrival, rid, req = self._queue[0]
             if rid in self._ended:         # cancelled while queued
@@ -984,25 +971,22 @@ class ServeEngine:
             free = np.flatnonzero(~self._active)
             if free.size == 0:
                 break
-            plan = None
-            if self.paged:
-                # paged admission is bounded by free PAGES: plan the
-                # slot's table row (shared prefix + CoW fork + fresh
-                # pages) before committing; a pool too full to seat
-                # the head request leaves it QUEUED (backpressure,
-                # never a crash) — completions free pages and retry
-                plan = self._plan_pages(req, self._handoffs.get(rid))
-                if plan is None:
-                    break
+            # admission is bounded by free PAGES: plan the slot's
+            # table row (shared prefix + CoW fork + fresh pages)
+            # before committing; a pool too full to seat the head
+            # request leaves it QUEUED (backpressure, never a crash)
+            # — completions free pages and retry
+            plan = self._plan_pages(req, self._handoffs.get(rid))
+            if plan is None:
+                break
             heapq.heappop(self._queue)
             req._stamps.append(time.perf_counter())
             slot = int(free[0])
             self._m["wait"].observe(max(0, self._step_idx - arrival))
             self._seat(slot, rid, req)
-            if plan is not None:
-                self._pt[slot, :] = 0
-                row = plan["row"]
-                self._pt[slot, :len(row)] = row
+            self._pt[slot, :] = 0
+            row = plan["row"]
+            self._pt[slot, :len(row)] = row
             if req.ctx is not None:
                 # once per admission, not per token: the timeline's
                 # "which bank, which slot, when" anchor for this hop
@@ -1013,12 +997,11 @@ class ServeEngine:
                           self._handoffs.pop(rid, None), plan))
         self._m["queue"].set(len(self._queue))
         self._m["slots"].set(int(self._active.sum()))
-        if self.paged:
-            self._m["pages_free"].set(self._pages.free_pages)
-            self._m["pages_shared"].set(self._pages.shared_pages)
+        self._m["pages_free"].set(self._pages.free_pages)
+        self._m["pages_shared"].set(self._pages.shared_pages)
         return picks
 
-    # -- paged admission planning (lock held) --------------------------------
+    # -- admission planning (lock held) --------------------------------
     def _alloc_with_evict(self, n: int,
                           keep: Optional[_PrefixEntry] = None
                           ) -> Optional[List[int]]:
@@ -1092,7 +1075,7 @@ class ServeEngine:
         # loop, a re-handed one would alias two logical positions.
         # The holds on the full shared pages transfer to the slot's
         # row; the boundary-page hold pins the CoW fork source until
-        # the copy dispatches (_prefill_into_paged releases it).
+        # the copy dispatches (_prefill_into releases it).
         hold: List[int] = []
         if entry is not None:
             hold = [int(p) for p in entry.pages[:n_shared]]
@@ -1170,19 +1153,12 @@ class ServeEngine:
                     self._slot_len[slot] = 0
                 continue
             with dtrace.use(req.ctx):
-                if self.paged:
-                    if handoff is not None:
-                        firsts.append((rid, self._inject_into_paged(
-                            slot, handoff, req, plan)))
-                    else:
-                        firsts.append((rid, self._prefill_into_paged(
-                            slot, req, plan)))
-                elif handoff is not None:
-                    firsts.append(
-                        (rid, self._inject_into(slot, handoff)))
+                if handoff is not None:
+                    firsts.append((rid, self._inject_into(
+                        slot, handoff, req, plan)))
                 else:
-                    firsts.append(
-                        (rid, self._prefill_into(slot, req)))
+                    firsts.append((rid, self._prefill_into(
+                        slot, req, plan)))
             req._stamps.append(time.perf_counter())
 
     def _sampling_of(self, req: Request):
@@ -1255,55 +1231,8 @@ class ServeEngine:
                     (self._active & ~self._prefilling).sum()):
                 break
 
-    def _prefill_into(self, slot: int, req: Request):
-        prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-        bucket = bucket_for(prompt.size, self.min_bucket, self.max_len)
-        fn = self._prefills.get(bucket)
-        if fn is None:
-            fn = telemetry.watch_jit(
-                partial(self._family.prefill_slot, self.cfg,
-                        mesh=self.mesh),
-                f"serve_prefill_b{bucket}", f"prefill_slot_b{bucket}",
-                donate_argnums=(4,))
-            self._prefills[bucket] = fn
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :prompt.size] = prompt
-        with self._span_prefill(bucket=bucket, role=self.role):
-            tok, self._kv, self._sv = fn(
-                self.params, padded, np.int32(prompt.size),
-                np.int32(slot), self._kv, self._sv,
-                *self._sampling_of(req))
-        with self._lock:      # host mirror of lengths — kv_cache_stats
-            self._slot_len[slot] = prompt.size  # sums it under _lock
-        return tok
-
-    def _inject_into(self, slot: int, h: KVHandoff):
-        """Admission program for a handed-off prefill (disaggregated
-        mode): one compiled inject program per block bucket writes the
-        KV block + per-slot vectors; the first token was already
-        sampled on the prefill worker and is returned as a HOST array
-        (``_process`` reads firsts uniformly)."""
-        bucket = int(h.k.shape[2])
-        fn = self._injects.get(bucket)
-        if fn is None:
-            fn = telemetry.watch_jit(
-                partial(self._family.inject_slot_kv, self.cfg,
-                        mesh=self.mesh),
-                f"serve_inject_b{bucket}", f"inject_slot_kv_b{bucket}",
-                donate_argnums=(6,))
-            self._injects[bucket] = fn
-        with self._span_prefill(bucket=bucket, inject=True,
-                                role=self.role):
-            self._kv, self._sv = fn(
-                h.k, h.v, np.int32(h.true_len), np.int32(slot),
-                np.int32(h.token), np.asarray(h.rng, np.uint32),
-                self._kv, self._sv)
-        with self._lock:      # host mirror of lengths — kv_cache_stats
-            self._slot_len[slot] = h.true_len  # sums it under _lock
-        return np.asarray([h.token], np.int32)
-
-    # -- paged admission programs --------------------------------------------
-    def _paged_prefill_fn(self, bucket: int):
+    # -- admission programs --------------------------------------------------
+    def _prefill_fn(self, bucket: int):
         fn = self._prefills.get(bucket)
         if fn is None:
             fn = telemetry.watch_jit(
@@ -1314,16 +1243,16 @@ class ServeEngine:
             self._prefills[bucket] = fn
         return fn
 
-    def _run_paged_prefill(self, slot: int, req: Request, suffix,
-                           total_len: int, prefix_len: int):
-        """One warm/cold paged prefill: the SUFFIX tokens (end-padded
+    def _run_prefill(self, slot: int, req: Request, suffix,
+                     total_len: int, prefix_len: int):
+        """One warm/cold prefill: the SUFFIX tokens (end-padded
         to their bucket) run at ``pos=prefix_len`` over the slot's
         gathered pages. The suffix bucket is what keys the program, so
         warm admissions hit SMALLER buckets than their full prompt
         would — the prefix-share TTFT win."""
         bucket = bucket_for(int(suffix.size), self.min_bucket,
                             self.max_len)
-        fn = self._paged_prefill_fn(bucket)
+        fn = self._prefill_fn(bucket)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :suffix.size] = suffix
         with self._span_prefill(bucket=bucket, role=self.role,
@@ -1337,7 +1266,7 @@ class ServeEngine:
             self._slot_len[slot] = total_len
         return tok
 
-    def _prefill_into_paged(self, slot: int, req: Request, plan):
+    def _prefill_into(self, slot: int, req: Request, plan):
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         m = plan["prefix_len"]
         if plan["fork"] is not None:
@@ -1353,8 +1282,8 @@ class ServeEngine:
                 # on the pool) — drop the planner's pin on the source
                 self._pages.release([src])
             self._m["cow"].inc()
-        tok = self._run_paged_prefill(slot, req, prompt[m:],
-                                      int(prompt.size), m)
+        tok = self._run_prefill(slot, req, prompt[m:],
+                                int(prompt.size), m)
         reg = plan["register"]
         if reg is not None:
             if reg["copy"] is not None:
@@ -1373,7 +1302,7 @@ class ServeEngine:
         return tok
 
     def _inject_block_len(self, h: KVHandoff) -> int:
-        """The block length the paged inject program runs at. The
+        """The block length the inject program runs at. The
         page-granular wire trims handoff blocks to the page multiple
         covering ``true_len`` — an ARBITRARY multiple per prompt
         length — so injecting at the wire shape would compile up to
@@ -1385,14 +1314,18 @@ class ServeEngine:
         b = -(-b // self.page_size) * self.page_size
         return max(blk, b)
 
-    def _inject_into_paged(self, slot: int, h: KVHandoff,
-                           req: Request, plan):
-        """Paged admission of a handed-off prefill; when the request's
+    def _inject_into(self, slot: int, h: KVHandoff,
+                     req: Request, plan):
+        """Admission of a handed-off prefill (disaggregated mode): one
+        compiled inject program per block bucket writes the KV block's
+        pages + the per-slot vectors; the first token was already
+        sampled on the prefill worker and is returned as a HOST array
+        (``_process`` reads firsts uniformly). When the request's
         prompt is LONGER than the handoff (journaled-page resume after
         a crash), the emitted suffix warm-prefills over the injected
         pages — one admission, no prefill-worker round trip."""
         if plan.get("ignore_handoff"):
-            return self._prefill_into_paged(slot, req, plan)
+            return self._prefill_into(slot, req, plan)
         bucket = self._inject_block_len(h)
         k, v = np.asarray(h.k), np.asarray(h.v)
         if bucket > k.shape[2]:
@@ -1417,7 +1350,7 @@ class ServeEngine:
                 np.asarray(h.rng, np.uint32), self._kv, self._sv)
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         if prompt.size > h.true_len:
-            return self._run_paged_prefill(
+            return self._run_prefill(
                 slot, req, prompt[h.true_len:], int(prompt.size),
                 int(h.true_len))
         with self._lock:
@@ -1495,7 +1428,7 @@ class ServeEngine:
                     self._pt, drafts, self._temps, self._topks,
                     self._topps)
                 proposed = (drafts >= 0).sum(axis=1).astype(np.int64)
-            elif self.paged:
+            else:
                 # the page table rides as a small int32 operand —
                 # table edits at admission never touch device state
                 # or the jit cache key. A slot whose prompt is still
@@ -1509,10 +1442,6 @@ class ServeEngine:
                 sampled, self._kv, self._sv = self._decode(
                     self.params, self._kv, self._sv, active,
                     pt, self._temps, self._topks, self._topps)
-            else:
-                sampled, self._kv, self._sv = self._decode(
-                    self.params, self._kv, self._sv, self._active,
-                    self._temps, self._topks, self._topps)
         self._m["steps"].inc()
         with self._lock:
             self.steps_run += 1
@@ -1625,19 +1554,17 @@ class ServeEngine:
                 if self._done.get(rid, True):
                     self._active[slot] = False   # recycle at the next
                     self._slot_rid[slot] = None  # step boundary
-                    if self.paged:
-                        # release the slot's page hold; prefix-cache
-                        # entries keep their own refs, so shared pages
-                        # survive the request that seeded them
-                        row = self._pt[slot]
-                        held = [int(p) for p in row if p]
-                        if held:
-                            self._pages.release(held)
-                        row[:] = 0
+                    # release the slot's page hold; prefix-cache
+                    # entries keep their own refs, so shared pages
+                    # survive the request that seeded them
+                    row = self._pt[slot]
+                    held = [int(p) for p in row if p]
+                    if held:
+                        self._pages.release(held)
+                    row[:] = 0
             self._m["slots"].set(int(self._active.sum()))
-            if self.paged:
-                self._m["pages_free"].set(self._pages.free_pages)
-                self._m["pages_shared"].set(self._pages.shared_pages)
+            self._m["pages_free"].set(self._pages.free_pages)
+            self._m["pages_shared"].set(self._pages.shared_pages)
             live = self._live_bytes()
             self._m["kv_live"].set(live)
             self._m["kv_occ"].set(live / self._kv_reserved
@@ -1741,20 +1668,19 @@ class ServeEngine:
     @property
     def compile_count(self) -> int:
         """Compiled programs this engine has built: one per admission
-        bucket (prefill or, in disaggregated mode, inject) + the
-        single decode program. The churn test gates this at
-        ``buckets + 1`` — requests entering/leaving must never
+        bucket (prefill or, in disaggregated mode, inject), the
+        single decode program and the page copy. The churn test gates
+        this at ``buckets + 2`` — requests entering/leaving must never
         retrace."""
         # deliberately NO fallback: if jax moves the private
         # _cache_size API this raises loudly — a silent
         # len(fns) stand-in would make the no-retrace gate
         # vacuously true exactly when a retrace bug could hide
-        fns = ([self._decode] + list(self._prefills.values())
+        # the CoW fork/registration copy is ONE program (src/dst are
+        # traced scalars)
+        fns = ([self._decode, self._copy_fn]
+               + list(self._prefills.values())
                + list(self._injects.values()))
-        if self.paged:
-            # the CoW fork/registration copy is ONE program (src/dst
-            # are traced scalars) — the paged bound is buckets + 2
-            fns.append(self._copy_fn)
         if self._spec_decode is not None:
             # speculative mode adds exactly ONE watched program (the
             # k-verify step) — the spec bound is buckets + 3
@@ -1765,7 +1691,7 @@ class ServeEngine:
     def n_buckets(self) -> int:
         """Distinct admission buckets compiled so far — prefill
         programs plus (disaggregated mode) inject programs; the
-        compile bound is ``n_buckets + 1`` either way."""
+        compile bound is ``n_buckets + 2`` either way."""
         return len(self._prefills) + len(self._injects)
 
     def _live_bytes(self) -> int:
@@ -1776,10 +1702,12 @@ class ServeEngine:
                 + int(self._active.sum()) * self._slot_state_bytes)
 
     def kv_cache_stats(self) -> Dict[str, Any]:
-        """KV slot-bank occupancy: bytes the dense bank RESERVES vs
-        bytes live sequence prefixes actually COVER — the exact waste
-        number ROADMAP item 1 (paged KV) is gated on, surfaced in the
-        gateway ``/state`` block. Host arithmetic only (the mirrored
+        """KV occupancy: bytes the donated state (page pool, and a
+        family's fixed per-slot state) RESERVES vs bytes live sequence
+        prefixes actually COVER, with the pool's page counts, prefix
+        cache and speculation tallies — surfaced in the gateway
+        ``/state`` block. ``"paged"`` is always True (readers outside
+        the repo may hold the key). Host arithmetic only (the mirrored
         per-slot lengths; reading the device ``lengths`` vector here
         would put a sync next to the decode loop — MXL004)."""
         with self._lock:
@@ -1787,36 +1715,32 @@ class ServeEngine:
             live = self._live_bytes()
             out = {"slots": self.max_slots, "active": active,
                    "reserved_bytes": self._kv_reserved,
-                   "state_bytes_per_slot": self._slot_state_bytes}
-            if self.paged:
+                   "state_bytes_per_slot": self._slot_state_bytes,
+                   "paged": True,
+                   "page_size": self.page_size,
+                   "pages_total": self.n_pages - 1,
+                   "pages_free": self._pages.free_pages,
+                   "pages_used": self._pages.used_pages,
+                   "pages_shared": self._pages.shared_pages,
+                   "cow_forks": self._cow_forks,
+                   "prefix_hits": self._prefix_hits,
+                   "prefix_misses": self._prefix_misses,
+                   "prefix_entries": (len(self._prefix)
+                                      if self._prefix is not None
+                                      else 0),
+                   "top_prefixes": (self._prefix.top()
+                                    if self._prefix is not None
+                                    else [])}
+            if self.speculate_k:
+                prop = self._spec_proposed
                 out.update({
-                    "paged": True,
-                    "page_size": self.page_size,
-                    "pages_total": self.n_pages - 1,
-                    "pages_free": self._pages.free_pages,
-                    "pages_used": self._pages.used_pages,
-                    "pages_shared": self._pages.shared_pages,
-                    "cow_forks": self._cow_forks,
-                    "prefix_hits": self._prefix_hits,
-                    "prefix_misses": self._prefix_misses,
-                    "prefix_entries": (len(self._prefix)
-                                       if self._prefix is not None
-                                       else 0),
-                    "top_prefixes": (self._prefix.top()
-                                     if self._prefix is not None
-                                     else []),
+                    "speculate_k": self.speculate_k,
+                    "spec_proposed": prop,
+                    "spec_accepted": self._spec_accepted,
+                    "spec_accept_rate": (
+                        self._spec_accepted / prop if prop else 0.0),
+                    "spec_steps": self._spec_steps,
                 })
-                if self.speculate_k:
-                    prop = self._spec_proposed
-                    out.update({
-                        "speculate_k": self.speculate_k,
-                        "spec_proposed": prop,
-                        "spec_accepted": self._spec_accepted,
-                        "spec_accept_rate": (
-                            self._spec_accepted / prop if prop
-                            else 0.0),
-                        "spec_steps": self._spec_steps,
-                    })
         out["live_bytes"] = live
         out["occupancy"] = (live / self._kv_reserved
                             if self._kv_reserved else 0.0)

@@ -6,7 +6,8 @@ independent pools joined by a KV handoff:
 
 - :class:`PrefillWorker` — runs ``llama.prefill_detached`` (one
   compiled program per prompt bucket), reads the per-request KV block
-  back to host, and ships it over the channel.
+  back to host, and ships it over the channel, one acked frame per
+  page of the decode pool (``handoff_to_page_frames``).
 - :class:`KVChannel` — the handoff wire: ``mxtpu.rpc`` framed
   messages (same codec + HMAC + frame-size ceiling as the kvstore)
   over a socketpair (same host) or TCP (``listen``/``connect`` — the
@@ -15,7 +16,9 @@ independent pools joined by a KV handoff:
 - :class:`DisaggBackend` — the Gateway-facing composition: routes
   prompts to the least-queued prefill worker, a feeder thread receives
   handoffs and seats them in the least-loaded decode replica via
-  ``ServeEngine.submit_prefilled`` (→ ``llama.inject_slot_kv``).
+  ``ServeEngine.submit_prefilled`` (→ ``llama.inject_paged_kv``), and
+  a bounded journal of seated handoffs lets a crash re-dispatch
+  re-seat the pages without a prefill round trip.
 
 Self-healing (PR 7): TCP channels carry an HMAC hello handshake on
 every (re)connect and an ACK per handoff frame. A severed connection
@@ -28,16 +31,17 @@ already-authenticated peer poisons only that connection (drop +
 re-accept + resend). A prefill worker that dies is respawned and its
 in-flight job resubmitted ONCE (the DataLoader dead-worker pattern);
 sustained prefill-path failure trips a circuit breaker that falls
-back to COLOCATED prefill on the decode replicas — ``prefill_slot``
-is the same graph/sampler/rng chain as detached+inject, so the
-fallback stays bit-identical while ``/healthz`` reports ``degraded``.
+back to COLOCATED prefill on the decode replicas —
+``prefill_slot_paged`` is the same graph/sampler/rng chain as
+detached+inject, so the fallback stays bit-identical while
+``/healthz`` reports ``degraded``.
 
 Bit-identity: ``prefill_detached`` is the same forward graph, sampler
-and rng chain as ``prefill_slot``; the block crosses the wire as raw
-bytes; ``inject_slot_kv`` is the scatter ``prefill_slot`` would have
-done. So a disaggregated request's tokens are bit-identical to the
-colocated engine AND to per-request ``generate`` — with or without
-injected faults (tier-1-gated in tests/test_serve_chaos.py).
+and rng chain as ``prefill_slot_paged``; the block crosses the wire
+as raw bytes; ``inject_paged_kv`` is the scatter
+``prefill_slot_paged`` would have done. So a disaggregated request's
+tokens are bit-identical to the colocated engine AND to per-request
+``generate`` — with or without injected faults (tier-1-gated in tests/test_serve_chaos.py).
 """
 from __future__ import annotations
 
@@ -318,8 +322,8 @@ class KVChannel:
                 # trace-context header acks exactly like a bare one
                 inner, _ctx = rpc.split_context(msg)
                 if (isinstance(inner, tuple) and len(inner) >= 2
-                        and inner[0] in ("kv", "kverr",
-                                         "kvpage", "kvdone")):
+                        and inner[0] in ("kverr", "kvpage",
+                                         "kvdone")):
                     with self._send_lock:
                         rpc.send_msg(self._sock, ("kvack", inner[1]),
                                      self._secret)
@@ -368,25 +372,9 @@ class KVChannel:
             self._sock.close()
 
 
-def handoff_to_wire(rid: int, h: KVHandoff) -> tuple:
-    return ("kv", int(rid), int(h.true_len), int(h.token),
-            np.asarray(h.k), np.asarray(h.v),
-            np.asarray(h.rng, np.uint32))
-
-
-def wire_to_handoff(msg: tuple) -> Tuple[int, KVHandoff]:
-    if not (isinstance(msg, tuple) and len(msg) == 7
-            and msg[0] == "kv"):
-        raise rpc.RPCProtocolError(
-            f"not a KV-handoff frame: {str(msg)[:80]}")
-    _, rid, true_len, token, k, v, rng = msg
-    return int(rid), KVHandoff(k=k, v=v, true_len=int(true_len),
-                               token=int(token), rng=rng)
-
-
 def handoff_to_page_frames(rid: int, h: KVHandoff,
                            page_size: int) -> List[tuple]:
-    """Page-granular wire encoding (the paged-KV handoff): the block
+    """The handoff's wire encoding, page-granular: the block
     is TRIMMED to the page multiple covering ``true_len`` — prompt-
     bucket padding never crosses the wire — and split into one
     ``kvpage`` frame per page, closed by a ``kvdone`` frame carrying
@@ -604,17 +592,17 @@ class PrefillWorker:
                  name: str = "p0",
                  on_fail: Optional[Callable[[int, str],
                                             None]] = None,
-                 wire_page_size: Optional[int] = None,
+                 wire_page_size: int,
                  stream_chunk: Optional[int] = None):
         self.cfg = cfg
         self.params = params
         self.channel = channel
         self.min_bucket = min_bucket
         self.max_len = max_len
-        # page-granular handoff (paged decode pool): ship the block as
-        # one acked frame per KV page, trimmed to the pages true_len
-        # covers — bucket padding never crosses the wire
-        self.wire_page_size = wire_page_size
+        # page-granular handoff: ship the block as one acked frame
+        # per KV page of the decode pool, trimmed to the pages
+        # true_len covers — bucket padding never crosses the wire
+        self.wire_page_size = int(wire_page_size)
         # streamed prefill pages: compute the prompt in fixed-width
         # chunks and ship each page's frame AS IT FILLS, so wire
         # transfer + feeder staging overlap prefill compute instead of
@@ -633,10 +621,10 @@ class PrefillWorker:
             "0 keeps the one-shot prefill, where every page ships at "
             "completion."))
         self.stream_chunk = 0
-        if sc and wire_page_size and not (int(wire_page_size)
-                                          & (int(wire_page_size) - 1)):
+        if sc and not (self.wire_page_size
+                       & (self.wire_page_size - 1)):
             cw = 1
-            while cw < max(int(sc), int(wire_page_size)):
+            while cw < max(int(sc), self.wire_page_size):
                 cw *= 2
             self.stream_chunk = cw
         self.mesh = mesh
@@ -766,7 +754,7 @@ class PrefillWorker:
             key = (jax.random.PRNGKey(req.seed) if req.rng is None  # noqa: MXL301 — chain position 0 is PRNGKey(seed); the rng branch is a mid-chain resume key
                    else jax.numpy.asarray(np.asarray(req.rng,
                                                      np.uint32)))
-            if self.stream_chunk and self.wire_page_size:
+            if self.stream_chunk:
                 with dtrace.use(ctx), self._span(bucket=bucket,
                                                  worker=self.name):
                     self._one_streamed(rid, req, padded,
@@ -787,18 +775,11 @@ class PrefillWorker:
                           true_len=int(prompt.size),
                           token=int(np.asarray(tok)[0]),
                           rng=np.asarray(rng, np.uint32))
-            if self.wire_page_size:
-                # the trace context rides the CLOSING frame — that is
-                # the one the feeder seats from
-                for frame in handoff_to_page_frames(
-                        rid, h, int(self.wire_page_size)):
-                    if ctx is not None and frame[0] == "kvdone":
-                        frame = rpc.attach_context(frame,
-                                                   ctx.to_wire())
-                    self.channel.send_handoff(frame)
-            else:
-                frame = handoff_to_wire(rid, h)
-                if ctx is not None:
+            # the trace context rides the CLOSING frame — that is
+            # the one the feeder seats from
+            for frame in handoff_to_page_frames(
+                    rid, h, self.wire_page_size):
+                if ctx is not None and frame[0] == "kvdone":
                     frame = rpc.attach_context(frame, ctx.to_wire())
                 self.channel.send_handoff(frame)
         except rpc.RPCAuthError:
@@ -855,7 +836,7 @@ class PrefillWorker:
         only their timing changes. The closing kvdone is sent after
         the shipper drains, and carries the final chunk's token/rng
         and the trace context, exactly like the one-shot sender."""
-        ps = int(self.wire_page_size)
+        ps = self.wire_page_size
         cw = min(self.stream_chunk, bucket)
         # every page that carries prompt tokens, capped at the bucket
         # (same trim rule as handoff_to_page_frames)
@@ -925,7 +906,7 @@ class PrefillWorker:
         than a page, same as the one-shot encoder). Returns the frame
         count."""
         k, v = np.asarray(kc), np.asarray(vc)
-        ps = int(self.wire_page_size)
+        ps = self.wire_page_size
         sent = 0
         for off in range(0, k.shape[2], ps):
             if pos + off >= n_send:
@@ -953,7 +934,6 @@ class DisaggBackend:
                  channel: Optional[Tuple[KVChannel, KVChannel]] = None,
                  breaker: Optional[CircuitBreaker] = None,
                  clock=None, started: bool = True,
-                 paged: bool = False,
                  page_size: Optional[int] = None,
                  n_pages: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -967,19 +947,16 @@ class DisaggBackend:
         self._mesh = mesh
         self._min_bucket = min_bucket
         self._mlen = max_len
-        # paged decode pool: page-granular wire + journaled handoffs
-        self.paged = bool(paged)
-        self._wire_ps = (int(page_size
-                             or _env_int("MXTPU_KV_PAGE_SIZE", 16))
-                         if self.paged else None)
+        # the wire's page is the decode pool's
+        self._wire_ps = int(page_size
+                            or _env_int("MXTPU_KV_PAGE_SIZE", 16))
         tx, rx = channel if channel is not None else KVChannel.pair()
         self._tx, self._rx = tx, rx
         self.decode = ReplicaSet(
             lambda: ServeEngine(cfg, params, max_slots=max_slots,
                                 max_len=max_len, min_bucket=min_bucket,
                                 mesh=mesh, clock=clock,
-                                paged=paged, page_size=page_size,
-                                n_pages=n_pages,
+                                page_size=page_size, n_pages=n_pages,
                                 prefix_cache=prefix_cache,
                                 int8_pages=int8_pages),
             n_decode, started=started)
@@ -988,7 +965,7 @@ class DisaggBackend:
         # seating at kvdone does no assembly work)
         self._parts: Dict[int, _PageBuffer] = {}
         self._stream_chunk = stream_chunk
-        # KV journal (paged re-dispatch seam): the last N seated
+        # KV journal (re-dispatch seam): the last N seated
         # handoffs, keyed by their prompt tokens — a crash re-dispatch
         # whose prompt EXTENDS a journaled one re-seats the pages and
         # warm-prefills only the emitted suffix, instead of burning a
@@ -999,9 +976,8 @@ class DisaggBackend:
         # entries: oldest entries fall off once the total crosses
         # MXTPU_GATEWAY_KV_JOURNAL_MB (kv_journal still caps the
         # entry count; 0 for either disables the journal).
-        cap = (kv_journal if kv_journal is not None
-               else (32 if self.paged else 0))
-        self._journal_cap = max(0, int(cap))
+        self._journal_cap = max(
+            0, int(32 if kv_journal is None else kv_journal))
         self._journal_max_bytes = max(0, env_int(
             "MXTPU_GATEWAY_KV_JOURNAL_MB", 256,
             "Total host-RAM byte budget (in MB) for the gateway's "
@@ -1011,7 +987,7 @@ class DisaggBackend:
         self._journal: "Dict[Tuple[int, ...], KVHandoff]" = {}
         self._m_journal_hits = telemetry.counter(
             "gateway_kv_journal_hits_total",
-            "Crash re-dispatches seated from the KV journal (paged "
+            "Crash re-dispatches seated from the KV journal (page "
             "inject + suffix warm prefill, no full re-prefill)")
         self._m_page_frames = telemetry.counter(
             "gateway_kv_page_frames_total",
@@ -1064,7 +1040,7 @@ class DisaggBackend:
             if entry[0].on_done is not None:
                 entry[0].on_done(rid, reason)
 
-    # -- KV journal (paged re-dispatch) --------------------------------------
+    # -- KV journal (re-dispatch) --------------------------------------------
     @staticmethod
     def _handoff_nbytes(h: KVHandoff) -> int:
         return int(np.asarray(h.k).nbytes) + int(np.asarray(h.v).nbytes)
@@ -1108,8 +1084,7 @@ class DisaggBackend:
     def route(self, req: Request, handoff=None) -> "Ticket":
         if handoff is not None:
             return self.decode.route(req, handoff=handoff)
-        if self.paged and req.rng is not None \
-                and self._journal_cap > 0:
+        if req.rng is not None and self._journal_cap > 0:
             # a resume chain (crash re-dispatch): if the journal holds
             # the original prompt's pages, seat them directly — the
             # engine injects the pages and warm-prefills only the
@@ -1144,9 +1119,9 @@ class DisaggBackend:
                              f"{req.top_p}")
         if not self.breaker.allow():
             # OPEN breaker: colocated fallback — the decode engine
-            # runs prefill_slot itself (same graph/sampler/rng chain,
-            # so tokens stay bit-identical); latency degrades, the
-            # request does not
+            # runs prefill_slot_paged itself (same graph/sampler/rng
+            # chain, so tokens stay bit-identical); latency degrades,
+            # the request does not
             self._m_fallback.inc()
             return self.decode.route(req)
         ticket = _DisaggTicket(self)
@@ -1189,7 +1164,7 @@ class DisaggBackend:
                    for r in self.decode.state()]
                 + [dict(name="handoff", role="channel", alive=True,
                         queued=n_pending, active=0, slots=0,
-                        paged=self.paged,
+                        paged=True,
                         kv_journal=len(self._journal),
                         kv_journal_bytes=int(self._journal_bytes),
                         breaker=self.breaker.describe())])
@@ -1313,13 +1288,13 @@ class DisaggBackend:
                 self._m_page_frames.inc()
                 continue
             try:
-                if (isinstance(msg, tuple) and msg
+                if not (isinstance(msg, tuple) and len(msg) > 1
                         and msg[0] == "kvdone"):
-                    buf = self._parts.pop(int(msg[1]), None)
-                    rid, handoff = (buf if buf is not None
-                                    else _PageBuffer()).finish(msg)
-                else:
-                    rid, handoff = wire_to_handoff(msg)
+                    raise rpc.RPCProtocolError(
+                        f"not a KV-handoff frame: {str(msg)[:80]}")
+                buf = self._parts.pop(int(msg[1]), None)
+                rid, handoff = (buf if buf is not None
+                                else _PageBuffer()).finish(msg)
             except rpc.RPCProtocolError as e:
                 # a foreign frame means the stream is desynced — stop
                 # feeding loudly rather than seat corrupt state

@@ -1079,8 +1079,8 @@ class Gateway:
         sup = (self.supervisor.describe()
                if self.supervisor else None)
         replicas = self.backend.state()
-        # fleet KV occupancy: the dense-bank waste number, summed over
-        # every decode replica that reports one (perfscope's ledger
+        # fleet KV occupancy: reserved against live bytes, summed over
+        # every decode replica that reports them (perfscope's ledger
         # carries the same bytes as gauges; this is the /state view)
         kv_rows = [r["kv_cache"] for r in replicas
                    if isinstance(r, dict) and r.get("kv_cache")]
@@ -1090,36 +1090,26 @@ class Gateway:
                     "active": sum(r["active"] for r in kv_rows),
                     "reserved_bytes": reserved, "live_bytes": live,
                     "occupancy": (live / reserved) if reserved else 0.0}
-        paged_rows = [r for r in kv_rows if r.get("paged")]
-        if paged_rows:
-            # paged-pool fleet view (.get() guards: a mixed fleet may
-            # carry dense replicas whose rows lack these fields)
-            hits = sum(r.get("prefix_hits", 0) for r in paged_rows)
-            misses = sum(r.get("prefix_misses", 0)
-                         for r in paged_rows)
-            tops = [p for r in paged_rows
-                    for p in r.get("top_prefixes", [])]
+        if kv_rows:
+            # the page pools' fleet view
+            kv_cache.update({k: sum(r[k] for r in kv_rows) for k in (
+                "pages_total", "pages_free", "pages_used",
+                "pages_shared", "cow_forks", "prefix_hits",
+                "prefix_misses")})
+            hits, misses = kv_cache["prefix_hits"], \
+                kv_cache["prefix_misses"]
+            tops = [p for r in kv_rows for p in r["top_prefixes"]]
             tops.sort(key=lambda p: -p.get("hits", 0))
-            # speculative-decode acceptance, fleet-wide (per-replica
-            # rates stay in each replica row's kv_cache — diagnose kv
-            # renders both from this one scrape)
-            prop = sum(r.get("spec_proposed", 0) for r in paged_rows)
-            acc = sum(r.get("spec_accepted", 0) for r in paged_rows)
+            # speculative-decode acceptance, fleet-wide, over the
+            # replicas that speculate (per-replica rates stay in each
+            # replica row's kv_cache — diagnose kv renders both from
+            # this one scrape)
+            prop = sum(r.get("spec_proposed", 0) for r in kv_rows)
+            acc = sum(r.get("spec_accepted", 0) for r in kv_rows)
             kv_cache.update({
                 "spec_proposed": prop, "spec_accepted": acc,
                 "spec_accept_rate": (acc / prop) if prop else 0.0,
                 "paged": True,
-                "pages_total": sum(r.get("pages_total", 0)
-                                   for r in paged_rows),
-                "pages_free": sum(r.get("pages_free", 0)
-                                  for r in paged_rows),
-                "pages_used": sum(r.get("pages_used", 0)
-                                  for r in paged_rows),
-                "pages_shared": sum(r.get("pages_shared", 0)
-                                    for r in paged_rows),
-                "cow_forks": sum(r.get("cow_forks", 0)
-                                 for r in paged_rows),
-                "prefix_hits": hits, "prefix_misses": misses,
                 "prefix_hit_rate": (hits / (hits + misses)
                                     if hits + misses else 0.0),
                 "top_prefixes": tops[:5]})

@@ -32,13 +32,11 @@ substrate:
   pair (:func:`mfu` / :func:`hbm_bw_util`) that ``bench.py`` also
   calls, so offline and live MFU cannot disagree by construction.
 - **HBM ledger** — :class:`HBMLedger` accounts device-resident bytes
-  by category (params / optimizer / kv_slot_bank / workspace),
+  by category (params / optimizer / kv_page_pool / workspace),
   publishes ``mxtpu_hbm_ledger_bytes{category}`` +
   ``mxtpu_hbm_headroom_bytes``, and leaves an OOM-adjacent flight
   record when headroom first dips below
-  ``MXTPU_TELEMETRY_PERF_HEADROOM_BYTES``. The KV byte helpers here
-  (:func:`kv_slot_bank_bytes` / :func:`kv_live_bytes`) are the exact
-  waste arithmetic ROADMAP item 1 (paged KV) is gated on.
+  ``MXTPU_TELEMETRY_PERF_HEADROOM_BYTES``.
 - **step-anomaly detector** — per-program rolling median/MAD over the
   same gaps; a gap beyond ``median + k*MAD`` emits a ``perf.anomaly``
   instant, a flight record naming the program, and increments
@@ -73,8 +71,7 @@ __all__ = [
     "DeviceSpec", "ProgramCost", "PerfScope", "HBMLedger",
     "device_spec", "spec_for", "mfu", "hbm_bw_util", "roofline_class",
     "profile_program", "program_costs", "on_call", "scope", "catalog",
-    "ledger", "goodput_gauge", "tree_bytes", "kv_slot_bank_bytes",
-    "kv_live_bytes", "reset",
+    "ledger", "goodput_gauge", "tree_bytes", "reset",
 ]
 
 _log = logging.getLogger(__name__)
@@ -319,25 +316,6 @@ def tree_bytes(tree: Any) -> int:
                    for l in jax.tree_util.tree_leaves(tree)))
 
 
-def kv_slot_bank_bytes(n_layers: int, n_kv_heads: int, head_dim: int,
-                       max_slots: int, max_len: int,
-                       itemsize: int) -> int:
-    """Bytes the dense serve slot bank RESERVES: k and v of
-    (L, max_slots, n_kv_heads, max_len, head_dim) each."""
-    return 2 * n_layers * max_slots * n_kv_heads * max_len \
-        * head_dim * itemsize
-
-
-def kv_live_bytes(n_layers: int, n_kv_heads: int, head_dim: int,
-                  lengths, itemsize: int) -> int:
-    """Bytes live sequence prefixes actually COVER: the per-token KV
-    row (k+v across layers/heads) times the summed live lengths. The
-    reserved-minus-live gap is the dense bank's waste — the number
-    ROADMAP item 1 (paged KV) is gated on."""
-    per_token = 2 * n_layers * n_kv_heads * head_dim * itemsize
-    return int(per_token * int(sum(int(x) for x in lengths)))
-
-
 # -- HBM ledger ------------------------------------------------------------
 class HBMLedger:
     """Per-process device-memory accounting. Entries are keyed
@@ -409,7 +387,7 @@ class HBMLedger:
             for cat, n in per_cat.items():
                 m.gauge("hbm_ledger_bytes",
                         "Accounted device-resident bytes by category "
-                        "(params/optimizer/kv_slot_bank/workspace)",
+                        "(params/optimizer/kv_page_pool/workspace)",
                         category=cat).set(n)
             head = self.headroom()
             m.gauge("hbm_headroom_bytes",

@@ -267,7 +267,7 @@ def test_gateway_two_replicas_poisson_bit_identical(cfg, params):
         st = gw.state()
         assert st["n_replicas"] == 2 and len(st["replicas"]) == 2
         # ISSUE 13: per-replica + aggregate KV-cache occupancy ride
-        # /state (reserved is the static slot bank; the engines are
+        # /state (reserved is the static page pool; the engines are
         # drained here so live is back to 0)
         kv = st["kv_cache"]
         assert kv["reserved_bytes"] == sum(
@@ -322,6 +322,28 @@ def test_gateway_backpressure_429(cfg, params):
             cfg, params, np.arange(4) % cfg.vocab_size, 2, seed=9)
     finally:
         gw.close()
+
+
+def test_state_and_diagnose_kv_show_the_page_pool(cfg, params, capsys):
+    """An engine built with nothing said about its bank reports its
+    page pool through ``/state``, and ``tools/diagnose.py kv`` renders
+    it, fleet line and replica row, from that one scrape."""
+    from tools.diagnose import kv_state
+    gw = Gateway(llama_refs.engine_factory(cfg, params), n_replicas=1,
+                 queue_max=8)
+    try:
+        port = gw.start_http(port=0)
+        assert gw.submit(np.arange(1, 6), 3).result(120) is not None
+        kv = gw.state()["kv_cache"]
+        assert kv["paged"] and kv["pages_total"] == 2 * (32 // 16)
+        assert kv["pages_used"] == 0 and kv["prefix_misses"] == 1
+        assert kv_state(f"127.0.0.1:{port}")
+    finally:
+        gw.close()
+    out = capsys.readouterr().out
+    assert "pages: 0/4 used (4 free, 0 shared)" in out, out
+    assert "prefix cache: hits=0 misses=1" in out, out
+    assert "pages=0/4" in out and "paged: off" not in out, out
 
 
 def test_gateway_deadline_reclaims_slot_end_to_end(cfg, params):

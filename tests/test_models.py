@@ -501,14 +501,22 @@ def test_resnet_s2d_stem_train_trajectory_matches_std():
     """Because the kernel transform is linear and its zero taps are
     structural (re-created from zeros every step), gradients flow back
     to the shared 7×7 parameter unchanged: a jitted train trajectory
-    from identical init must track the standard stem step for step."""
-    cfg = replace(resnet.CONFIGS["tiny"], dtype=jnp.float32)
+    from identical init must track the standard stem step for step.
+
+    Compared in float64. The two stems sum the same products in
+    different orders, and this trajectory (momentum 0.9, lr 0.1, eight
+    images, batch norm) grows a difference about a hundredfold a step:
+    float32's 4e-6 in the first loss is 0.28 in the fourth, which says
+    nothing about the stem. In float64 the gap stays at rounding size,
+    and a wrong gradient would show in the second loss."""
+    cfg = replace(resnet.CONFIGS["tiny"], dtype=jnp.float64,
+                  param_dtype=jnp.float64)
     cfg_s2d = replace(cfg, stem="s2d")
     params = resnet.init_params(cfg, jax.random.PRNGKey(0))
     mesh = pmesh.create_mesh(dp=-1)
     rules = ShardingRules([(r".*", P())])
     batch = {"image": jax.random.normal(jax.random.PRNGKey(1),
-                                        (8, 32, 32, 3), jnp.float32),
+                                        (8, 32, 32, 3), jnp.float64),
              "label": jnp.arange(8, dtype=jnp.int32)}
 
     losses = {}
@@ -525,15 +533,14 @@ def test_resnet_s2d_stem_train_trajectory_matches_std():
             ls.append(float(loss))
         losses[key] = ls
         final[key] = tstate.params
+    assert final["s2d"]["stem_conv"].dtype == jnp.float64
+    assert losses["std"][0] > losses["std"][-1]      # it trains
     np.testing.assert_allclose(losses["s2d"], losses["std"],
-                               rtol=1e-4, atol=1e-5)
+                               rtol=1e-9, atol=1e-10)
     # the stem parameter itself (same tree both sides) stays aligned
-    # (atol covers conv-reduction reassociation noise amplified by
-    # 4 momentum-SGD steps at lr 0.1; exactness is impossible in f32)
     np.testing.assert_allclose(
-        np.asarray(final["s2d"]["stem_conv"], np.float32),
-        np.asarray(final["std"]["stem_conv"], np.float32),
-        rtol=1e-3, atol=2e-4)
+        np.asarray(final["s2d"]["stem_conv"]),
+        np.asarray(final["std"]["stem_conv"]), rtol=1e-8, atol=1e-9)
 
 
 def test_resnet_s2d_stem_rejects_odd_input():

@@ -52,7 +52,6 @@ def params(serve_params):
 
 
 def paged_engine(cfg, params, **kw):
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return llama_refs.engine_factory(cfg, params, **kw)()
 
@@ -322,6 +321,125 @@ def test_paged_programs_at_the_page_range_edges(cfg, params,
         assert got == llama_refs.reference(cfg, params, prompt, mnew)
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_verify_step_without_drafts_is_the_plain_step(cfg, params,
+                                                      paged_programs,
+                                                      int8):
+    """One block serves both decode programs: ``decode_slots_spec``
+    with no drafts (every entry < 0) emits one token a slot, the one
+    ``decode_slots_paged`` emits, and leaves lengths, tokens, rng
+    chains and every live cache entry bit-for-bit where it leaves them
+    (past a slot's length the verify step has written its undrafted
+    positions: masked, and overwritten before the length gets there) —
+    a sampled and a greedy slot, step after step on its own state, the
+    pool in float32 and in int8 with its scales."""
+    prefill, decode = paged_programs
+    spec = jax.jit(partial(llama.decode_slots_spec, cfg))
+    slots, ps = 2, 8
+    state = llama.init_paged_cache(cfg, slots, _EDGE_PAGES, ps,
+                                   int8=int8)
+    sv = {n: state.pop(n) for n in ("lengths", "tokens", "rngs")}
+    kv = state
+    table = np.asarray([[2, 5, 0, 0], [7, 1, 0, 0]], np.int32)
+    temps = np.asarray([1.0, 0.0], np.float32)
+    for slot, prompt in enumerate([[21, 22, 23], _EDGE_SHARED + [13]]):
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, :len(prompt)] = prompt
+        _, kv, sv = prefill(
+            params, padded, np.int32(len(prompt)), np.int32(0),
+            table[slot].copy(), np.int32(slot), kv, sv,
+            jax.random.PRNGKey(slot), temps[slot],
+            np.int32(cfg.vocab_size), np.float32(1.0))
+    sampling = (temps, np.full(slots, cfg.vocab_size, np.int32),
+                np.ones(slots, np.float32))
+    active = np.ones(slots, bool)
+    kv_s, sv_s = kv, sv
+    for _ in range(4):
+        toks, emits, kv_s, sv_s = spec(
+            params, kv_s, sv_s, active, table,
+            np.full((slots, 2), -1, np.int32), *sampling)
+        sampled, kv, sv = decode(params, kv, sv, active, table,
+                                 *sampling)
+        assert np.asarray(emits).tolist() == [[True, False, False]] * 2
+        np.testing.assert_array_equal(np.asarray(toks)[:, 0],
+                                      np.asarray(sampled))
+        assert sorted(sv_s) == sorted(sv)
+        for n in sv:
+            np.testing.assert_array_equal(np.asarray(sv_s[n]),
+                                          np.asarray(sv[n]), n)
+    assert sorted(kv_s) == sorted(kv) == (
+        ["k", "ks", "v", "vs"] if int8 else ["k", "v"])
+    for slot, length in enumerate(np.asarray(sv["lengths"])):
+        live = np.arange(length)
+        page, off = table[slot, live // ps], live % ps
+        for n in kv:
+            np.testing.assert_array_equal(
+                np.asarray(kv_s[n])[:, page, off],
+                np.asarray(kv[n])[:, page, off], n)
+
+
+# ---------------------------------------------------------------------------
+# the engine has one bank
+# ---------------------------------------------------------------------------
+def test_engine_has_no_dense_bank_to_switch_to(cfg, params):
+    with pytest.raises(ValueError, match="page pool"):
+        llama_refs.engine_factory(cfg, params, paged=False)()
+
+
+def test_engine_built_with_no_bank_argument_serves_from_pages(cfg,
+                                                              params):
+    """``ServeEngine(cfg, params)`` IS the page pool (page_size 16,
+    every slot's max_len plus the scratch page): it reports pages, and
+    its float32 streams equal ``generate`` for a sampled and a greedy
+    request."""
+    e = llama_refs.engine_factory(cfg, params)()
+    st = e.kv_cache_stats()
+    assert st["paged"] and st["page_size"] == 16
+    assert st["pages_total"] == 2 * (32 // 16)
+    assert st["pages_free"] == st["pages_total"]
+    reqs = [dict(prompt=[7, 3, 9, 1, 5], max_new_tokens=6,
+                 temperature=1.0, seed=3),
+            dict(prompt=[21, 22, 23], max_new_tokens=5,
+                 temperature=0.0)]
+    rids = [e.submit(Request(**r)) for r in reqs]
+    out = e.run()
+    for rid, r in zip(rids, reqs):
+        assert [int(t) for t in out[rid]] == llama_refs.reference(
+            cfg, params, r["prompt"], r["max_new_tokens"],
+            seed=r.get("seed", 0), temperature=r["temperature"])
+    assert e.kv_cache_stats()["pages_used"] == 0
+
+
+def test_disagg_built_with_no_bank_argument_pages_and_journals(cfg,
+                                                               params):
+    """``DisaggBackend`` with nothing said about the bank ships the
+    handoff as page frames, seats it in the decode pool and journals
+    it (32 entries by default)."""
+    import threading
+    from mxtpu.serve.gateway.disagg import DisaggBackend
+    be = DisaggBackend(cfg, params, n_prefill=1, n_decode=1,
+                       max_slots=2, max_len=32, min_bucket=4)
+    try:
+        assert be._journal_cap == 32 and not hasattr(be, "paged")
+        frames = int(be._m_page_frames.value)
+        prompt = [7, 3, 9, 1, 5, 2, 8, 4, 6, 11, 12, 13, 14, 15, 16,
+                  17, 18]                   # 17 tokens: two pages of 16
+        toks, done = [], threading.Event()
+        be.route(Request(prompt=prompt, max_new_tokens=4,
+                         temperature=1.0, seed=2,
+                         on_token=lambda rid, t: toks.append(int(t)),
+                         on_done=lambda rid, r: done.set()))
+        assert done.wait(120)
+        assert toks == llama_refs.reference(cfg, params, prompt, 4,
+                                            seed=2, temperature=1.0)
+        assert int(be._m_page_frames.value) - frames == 2
+        assert len(be._journal) == 1
+        engine = be.decode.replicas()[0].engine
+        assert engine.kv_cache_stats()["page_size"] == 16
+    finally:
+        be.close()
+
+
 # ---------------------------------------------------------------------------
 # engine: paged streams == generate oracle; sharing changes no tokens
 # ---------------------------------------------------------------------------
@@ -589,7 +707,7 @@ def test_disagg_paged_wire_and_journal(cfg, params):
 
     be = DisaggBackend(cfg, params, n_prefill=1, n_decode=1,
                        max_slots=2, max_len=32, min_bucket=4,
-                       paged=True, page_size=8)
+                       page_size=8)
     try:
         p1 = [7, 3, 9, 1, 5, 2, 8, 4, 6, 11, 12]
         full = llama_refs.reference(cfg, params, p1, 6, seed=0,

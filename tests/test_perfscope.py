@@ -166,14 +166,6 @@ def test_live_mfu_gauge_agrees_with_bench_helper():
 # ---------------------------------------------------------------------------
 # KV-cache occupancy
 # ---------------------------------------------------------------------------
-def test_kv_byte_helpers_exact():
-    # L=4, kvh=2, hd=8, 16 slots x 32 max_len, bf16
-    reserved = ps.kv_slot_bank_bytes(4, 2, 8, 16, 32, 2)
-    assert reserved == 2 * 4 * 16 * 2 * 32 * 8 * 2
-    live = ps.kv_live_bytes(4, 2, 8, [5, 0, 7], 2)
-    assert live == 2 * 4 * 2 * 8 * 2 * 12
-
-
 def test_serve_engine_kv_occupancy_accounting():
     from mxtpu.models import llama
     from mxtpu.serve import ServeEngine, Request
@@ -184,15 +176,19 @@ def test_serve_engine_kv_occupancy_accounting():
     eng = ServeEngine(cfg, params, max_slots=2, max_len=32,
                       min_bucket=4)
     stats = eng.kv_cache_stats()
-    itemsize = np.dtype(jnp.bfloat16).itemsize
-    expect_reserved = ps.kv_slot_bank_bytes(
-        cfg.n_layers, cfg.n_kv_heads, cfg.head_dim, 2, 32, itemsize)
+    # the pool the engine builds by default: 2 slots x 2 pages of 16
+    # and the scratch page, K and V, in the config's bf16
+    pool = llama.init_paged_cache(cfg, 2, 5, 16)
+    expect_reserved = ps.tree_bytes([pool["k"], pool["v"]])
+    assert expect_reserved == (2 * cfg.n_layers * 5 * 16 * cfg.n_kv_heads
+                               * cfg.head_dim
+                               * np.dtype(jnp.bfloat16).itemsize)
     assert stats["reserved_bytes"] == expect_reserved
     assert stats["live_bytes"] == 0 and stats["occupancy"] == 0.0
     eng.submit(Request(prompt=[1, 2, 3], max_new_tokens=4))
     eng.run()
     # drained engine: slots released, occupancy back to 0; the
-    # reserved bank is a static allocation and never changes
+    # reserved pool is a static allocation and never changes
     stats = eng.kv_cache_stats()
     assert stats["reserved_bytes"] == expect_reserved
     assert stats["active"] == 0
@@ -204,8 +200,8 @@ def test_serve_engine_kv_occupancy_accounting():
     # while the request was live, occupancy rose above 0 then fell;
     # at drain the live gauge is back to 0
     assert reg.value("serve_kv_live_bytes", engine=eid) == 0
-    # the ledger recorded the bank under kv_slot_bank
-    assert ps.ledger().breakdown().get("kv_slot_bank", 0) >= \
+    # the ledger recorded the pool under kv_page_pool
+    assert ps.ledger().breakdown().get("kv_page_pool", 0) >= \
         expect_reserved
 
 

@@ -45,8 +45,7 @@ LOGIT_TOL = 2e-4
 # an emitted token's reference logit under the reference's maximum: 0
 # unless two logits lie within LOGIT_TOL of each other
 GAP_TOL = 2 * LOGIT_TOL
-ENGINE = dict(paged=True, max_slots=3, max_len=96, min_bucket=16,
-              page_size=8)
+ENGINE = dict(max_slots=3, max_len=96, min_bucket=16, page_size=8)
 
 
 def _load_reference():
@@ -429,8 +428,7 @@ def test_slots_of_mixed_ages_in_one_step(params):
 @pytest.mark.parametrize("option,word", [
     ({"prefix_cache": True}, "snapshot"),
     ({"speculate_k": 2}, "rolled back"),
-    ({"int8_pages": True}, "quantised"),
-    ({"paged": False}, "dense slot bank")])
+    ({"int8_pages": True}, "quantised")])
 def test_engine_refuses_what_it_cannot_do(params, option, word):
     with pytest.raises(ValueError, match="sambay family.*" + word):
         ServeEngine(CFG, params, **{**ENGINE, **option})
@@ -575,5 +573,5 @@ def test_engine_refuses_a_chunk_that_does_not_fit(params, kw, word):
 def test_engine_refuses_chunked_prefill_for_llama(serve_cfg):
     from mxtpu.models import llama
     with pytest.raises(ValueError, match="prefill_chunk needs.*sambay"):
-        ServeEngine(serve_cfg, llama.init_params(serve_cfg), paged=True,
+        ServeEngine(serve_cfg, llama.init_params(serve_cfg),
                     max_slots=2, max_len=64, prefill_chunk=16)

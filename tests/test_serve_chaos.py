@@ -218,7 +218,7 @@ def test_replica_kill_mid_speculative_run_bit_identical(cfg, params):
     the kill); the sampled request observes every split position."""
     reg = telemetry.registry()
     rd0 = reg.value("gateway_redispatch_total")
-    gw = Gateway(lambda: _engine(cfg, params, paged=True, page_size=8,
+    gw = Gateway(lambda: _engine(cfg, params, page_size=8,
                                  speculate_k=3),
                  n_replicas=1, queue_max=16, supervisor_opts=SUP)
     plan = attach_serve(gw, ServeChaosPlan(
@@ -379,20 +379,18 @@ def test_kv_channel_sever_reconnect_reauth_bit_identical():
     t = threading.Thread(target=feeder, daemon=True)
     t.start()
     block = np.arange(48, dtype=np.float32).reshape(2, 2, 6, 2)
-    frame = ("kv", 11, 5, 42, block, block * 2,
-             np.asarray([3, 4], np.uint32))
+    frame = ("kvpage", 11, 0, block, block * 2)
     tx.send_handoff(frame)
     # sever mid-stream: the next handoff must ride a fresh,
     # re-authenticated connection
     tx._sock.close()
-    frame2 = ("kv", 12, 5, 43, block + 1, block * 3,
-              np.asarray([5, 6], np.uint32))
+    frame2 = ("kvpage", 12, 0, block + 1, block * 3)
     tx.send_handoff(frame2)
     assert done.wait(60)
     assert [m[1] for m in got] == [11, 12]
-    np.testing.assert_array_equal(got[1][4], block + 1)   # bit-exact
-    np.testing.assert_array_equal(got[1][5], block * 3)
-    assert got[1][4].dtype == np.float32
+    np.testing.assert_array_equal(got[1][3], block + 1)   # bit-exact
+    np.testing.assert_array_equal(got[1][4], block * 3)
+    assert got[1][3].dtype == np.float32
     assert reg.value("gateway_kv_reconnects_total") - rc0 >= 1
     assert reg.value("gateway_kv_resends_total") - rs0 >= 1
     tx.close()
@@ -722,18 +720,22 @@ def test_kv_frame_context_header_is_versioned():
     """The wire-compat satellite: a pre-ISSUE-8 frame (no header)
     splits to itself and still decodes as a handoff; a wrapped frame
     round-trips its context through the rpc codec; an UNKNOWN header
-    version keeps the payload usable and only drops the context."""
-    from mxtpu.serve.gateway.disagg import (handoff_to_wire,
-                                            wire_to_handoff)
+    version keeps the payload usable and only drops the context. The
+    frame is the handoff's closing ``kvdone``, the one the context
+    rides."""
+    from mxtpu.serve.gateway.disagg import (handoff_to_page_frames,
+                                            pages_to_handoff)
     from mxtpu.serve.engine import KVHandoff
     block = np.arange(24, dtype=np.float32).reshape(1, 2, 6, 2)
     h = KVHandoff(k=block, v=block * 2, true_len=5, token=42,
                   rng=np.asarray([1, 2], np.uint32))
-    old_frame = handoff_to_wire(3, h)
+    *pages, old_frame = handoff_to_page_frames(3, h, 4)
+    parts = {f[2]: (f[3], f[4]) for f in pages}
+    assert old_frame[0] == "kvdone" and sorted(parts) == [0, 1]
     # old frame: pass-through, no context
     payload, ctx = rpc.split_context(old_frame)
     assert payload is old_frame and ctx is None
-    rid, h2 = wire_to_handoff(payload)
+    rid, h2 = pages_to_handoff(payload, parts)
     assert rid == 3 and h2.token == 42
     # new frame: context survives the full encode/decode round trip
     tctx = telemetry.distributed.mint(rid=3, seed=7,
@@ -744,7 +746,7 @@ def test_kv_frame_context_header_is_versioned():
     got = telemetry.TraceContext.from_wire(ctx)
     assert got.trace_id == tctx.trace_id and got.rid == 3
     assert got.seed == 7 and got.deadline_abs == 12.5
-    rid, h3 = wire_to_handoff(payload)
+    rid, h3 = pages_to_handoff(payload, parts)
     assert rid == 3
     np.testing.assert_array_equal(h3.k, block)
     # future version: payload usable, context dropped — never an error
@@ -753,7 +755,7 @@ def test_kv_frame_context_header_is_versioned():
     payload, ctx = rpc.split_context(
         rpc.decode(bytes(rpc.encode(future))))
     assert ctx is None
-    assert wire_to_handoff(payload)[0] == 3
+    assert pages_to_handoff(payload, parts)[0] == 3
 
 
 def test_slo_burn_rate_degrades_healthz(cfg, params, monkeypatch):

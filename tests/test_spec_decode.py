@@ -45,7 +45,6 @@ def params(serve_params):
 
 
 def spec_engine(cfg, params, **kw):
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     kw.setdefault("speculate_k", 3)
     return llama_refs.engine_factory(cfg, params, **kw)()
@@ -94,10 +93,12 @@ def test_ngram_drafter_degenerate_inputs_draft_nothing():
 
 def test_speculate_k_constructor_validation(cfg, params):
     with pytest.raises(ValueError):
-        llama_refs.engine_factory(cfg, params, paged=True, page_size=8,
+        llama_refs.engine_factory(cfg, params, page_size=8,
                                   speculate_k=-1)()
-    with pytest.raises(ValueError):        # verify needs the page table
-        llama_refs.engine_factory(cfg, params, speculate_k=2)()
+    # nothing but the count: the verify program runs over the one bank
+    e = llama_refs.engine_factory(cfg, params, speculate_k=2)()
+    assert e.speculate_k == 2 and e.overlap is False
+    assert e.kv_cache_stats()["speculate_k"] == 2
 
 
 # ---------------------------------------------------------------------------

@@ -149,10 +149,6 @@ def kv_state(addr: str = ""):
           f"live={kv.get('live_bytes', 0):,}B "
           f"occupancy={occ:.3f} "
           f"active={kv.get('active', 0)}/{kv.get('slots', 0)} slots")
-    if not kv.get("paged"):
-        print("paged: off (dense slot banks; see docs/serving.md "
-              "'Paged KV cache' to enable)")
-        return True
     total = kv.get("pages_total", 0)
     used = kv.get("pages_used", 0)
     hits = kv.get("prefix_hits", 0)
@@ -181,7 +177,7 @@ def kv_state(addr: str = ""):
               f"head={p.get('head')}")
     for r in state.get("replicas", []):
         rkv = r.get("kv_cache") if isinstance(r, dict) else None
-        if not rkv or not rkv.get("paged"):
+        if not rkv:
             continue
         spec = (f"accept={rkv.get('spec_accept_rate', 0.0):.2f} "
                 if rkv.get("speculate_k") else "")
