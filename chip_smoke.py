@@ -438,6 +438,7 @@ def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
         assert status == 200 and "serve_" in metrics, metrics[:200]
 
         info = {"setup_s": setup_s, "run_s": run_s,
+                "decode_attention": kv["decode_attention"],
                 "requests": len(jobs),
                 "tokens": sum(j["mnew"] for j in jobs),
                 "buckets": buckets, "warmup_s": warm_s,
@@ -457,7 +458,7 @@ def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
 
 
 def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
-                must_match=False, **engine_kw):
+                must_match=False, expect_attention=None, **engine_kw):
     """A paged ``ServeEngine`` (prefix cache on) behind
     ``Gateway.start_http``, asked ``jobs`` by ``GatewayClient`` threads
     of this process. Every request must come back 200 and whole, the
@@ -468,7 +469,10 @@ def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
     agree is returned, and asserted only under ``must_match`` (what
     holds on the chip: float32 at highest precision; bf16 streams part
     from ``generate`` at near-ties). ``precision`` is jax's default
-    matmul precision for the phase; ``engine_kw`` shapes the engine."""
+    matmul precision for the phase; ``engine_kw`` shapes the engine.
+    ``expect_attention`` is what the engine must say its decode program's
+    attention was built on (``"pages"``: the Pallas kernel over live
+    pages, a bf16 pool on one chip; ``"gathered"``: everything else)."""
     import jax
     import numpy as np
     from mxtpu.models import llama
@@ -495,7 +499,58 @@ def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
                 first_difference_at=parted)
     assert not (must_match and parted), \
         f"streams parted from {against} at {parted}"
+    assert expect_attention in (None, info["decode_attention"]), info
     return info, streams
+
+
+def phase_pages_kernel(*, slots=32, n_heads=32, n_kv_heads=8, head_dim=128,
+                       page_size=16, capacity=2048, layers=2,
+                       interpret=False):
+    """The pages kernel (``ops/paged_attention.py``) against the
+    gathered path on ONE random bf16 pool at Mistral-7B's head shapes:
+    ``slots`` slots of ragged lengths 1..``capacity`` (1, a page's edge
+    and ``capacity`` among them) over shuffled pages, the last layer of
+    ``layers`` by a traced index. Prints the largest difference; it may
+    be a few bf16 roundings of the largest output (the two sum in another
+    order). ``interpret`` runs the kernel interpreted (the CPU test)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxtpu.ops.attention import gathered_decode_attention
+    from mxtpu.ops.paged_attention import paged_attention_pages
+
+    t0 = time.perf_counter()
+    per_slot = capacity // page_size
+    n_pages = 1 + slots * per_slot
+    rng = np.random.default_rng(0)
+    lengths = rng.integers(1, capacity + 1, slots).astype(np.int32)
+    lengths[:4] = 1, capacity, page_size, page_size + 1
+    table = (1 + rng.permutation(n_pages - 1)).astype(np.int32).reshape(
+        slots, per_slot)
+    shape = (layers, n_pages, page_size, n_kv_heads, head_dim)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    pool = jax.jit(lambda k: jax.random.normal(k, shape, jnp.bfloat16))
+    kp, vp = pool(keys[0]), pool(keys[1])
+    q = jax.random.normal(keys[2], (slots, n_heads, 1, head_dim),
+                          jnp.bfloat16)
+    layer = jnp.int32(layers - 1)
+    kernel = jax.jit(lambda *a: paged_attention_pages(
+        *a, layer=layer, interpret=interpret))
+    gathered = jax.jit(lambda *a: gathered_decode_attention(*a, layer=layer))
+    args = (q, kp, vp, jnp.asarray(table), jnp.asarray(lengths))
+    got = np.asarray(kernel(*args), np.float32)
+    setup_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    want = np.asarray(gathered(*args), np.float32)
+    worst, largest = float(np.abs(got - want).max()), float(
+        np.abs(want).max())
+    assert np.isfinite(got).all()
+    assert worst <= 4 * 2.0 ** -8 * max(1.0, largest), (worst, largest)
+    return {"setup_s": setup_s, "run_s": time.perf_counter() - t0,
+            "slots": slots, "lengths": [int(lengths.min()),
+                                        int(lengths.max())],
+            "pool_bytes": 2 * int(np.prod(shape)) * 2,
+            "largest_difference": worst, "largest_output": largest}
 
 
 def phase_serve_sambay(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
@@ -613,10 +668,13 @@ def main():
     serve_cfg = llama.LlamaConfig(
         **WIDTHS, max_seq_len=SERVE_ENGINE["max_len"], remat=False)
     jobs = make_jobs(serve_cfg.vocab_size, SERVE_SHAPES)
-    streams = _run("serve", phase_serve, serve_cfg, jobs, **SERVE_ENGINE)
+    _run("pages_kernel", phase_pages_kernel)
+    streams = _run("serve", phase_serve, serve_cfg, jobs, **SERVE_ENGINE,
+                   expect_attention="pages")
     _run("serve_f32", phase_serve,
          replace(serve_cfg, dtype=jnp.float32), jobs, **SERVE_ENGINE,
-         precision="highest", must_match=True)
+         precision="highest", must_match=True,
+         expect_attention="gathered")
 
     # the second serving family, at its published widths and a small
     # depth (two Mamba+window pairs, layers "4/5", one GMU+cross pair):
@@ -638,7 +696,8 @@ def main():
              mesh_axes={"dp": -1, "fsdp": 2, "sp": 2},
              expect_attn="ring")
         _run("serve_tp4", phase_serve, serve_cfg, jobs, **SERVE_ENGINE,
-             mesh=pmesh.create_mesh(dp=-1, tp=4), compare_to=streams)
+             mesh=pmesh.create_mesh(dp=-1, tp=4), compare_to=streams,
+             expect_attention="gathered")
 
     faulthandler.cancel_dump_traceback_later()
     d0 = jax.devices()[0]

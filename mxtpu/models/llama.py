@@ -37,7 +37,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..ops.attention import (flash_attention, dense_attention,
                              ring_attention, ulysses_attention,
                              slot_decode_attention,
-                             paged_decode_attention,
+                             paged_decode_attention, paged_decode_path,
                              ATTENTION_SCOPE, KV_GATHER_SCOPE)
 from ..parallel.sharding import ShardingRules, constrain
 from ..parallel.sharding import mcon as _mcon
@@ -50,7 +50,8 @@ __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
            "sample_logits", "prefill_detached",
            "prefill_detached_chunk", "paged_cache_specs", "init_paged_cache",
            "decode_slots_paged", "prefill_slot_paged",
-           "inject_paged_kv", "copy_page", "decode_slots_spec"]
+           "inject_paged_kv", "copy_page", "decode_slots_spec",
+           "decode_attention_path"]
 
 
 @dataclass(frozen=True)
@@ -1378,7 +1379,7 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
             cv = lax.with_sharding_constraint(
                 cv, NamedSharding(mesh, kvspec))
         o = paged_decode_attention(q, ck, cv, page_table, pos + 1,
-                                   layer=layer)
+                                   layer=layer, mesh=mesh)
 
     x = x + _mcon(mesh, _out_proj(cfg, lp, o), None, None, None)
 
@@ -1388,6 +1389,22 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
     if cks is not None:
         return x, ck, cv, cks, cvs
     return x, ck, cv
+
+
+def decode_attention_path(cfg: LlamaConfig, kv, mesh: Optional[Mesh] = None,
+                          *, verify: bool = False) -> str:
+    """Which attention :func:`decode_slots_paged` (``verify``:
+    :func:`decode_slots_spec`) builds its program on over the pools
+    ``kv`` (arrays or shapes): ``"pages"``, the Pallas kernel that
+    reads live pages out of the pool, or ``"gathered"`` —
+    ``ops.attention.paged_decode_path``'s answer for what
+    :func:`_layer_slots_paged` hands it. Static per compiled program;
+    the engine exports it (``serve_decode_steps_total{attention}``,
+    ``kv_cache_stats()``)."""
+    return paged_decode_path(
+        (1, cfg.n_heads, 2 if verify else 1, cfg.head_dim),
+        kv["k"].shape, kv["k"].dtype, 2 if verify else 1,
+        scales="ks" in kv, mesh=mesh)
 
 
 @jax.named_scope(KV_GATHER_SCOPE)

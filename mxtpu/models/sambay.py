@@ -61,7 +61,8 @@ from .llama import KV_WRITE_SCOPE
 __all__ = ["SambaYConfig", "CONFIGS", "init_params", "forward",
            "init_paged_cache", "prefill_slot_paged",
            "init_prefill_stage", "prefill_slot_paged_chunk",
-           "prefill_slot_paged_last", "decode_slots_paged", "copy_page"]
+           "prefill_slot_paged_last", "decode_slots_paged", "copy_page",
+           "decode_attention_path"]
 
 # the named scopes of this family's programs, besides the ones it
 # shares with llama.py (embed and lm_head are llama.py's own functions;
@@ -571,6 +572,14 @@ def prefill_logits(cfg: SambaYConfig, params, tokens, true_len,
 # ---------------------------------------------------------------------------
 # serving state and programs
 # ---------------------------------------------------------------------------
+def decode_attention_path(cfg, kv, mesh=None, *, verify: bool = False) -> str:
+    """Which attention the decode program is built on (``llama.
+    decode_attention_path``): this family gathers its one shared pool
+    once a step (:func:`_gather_rows`) and reads the rows eight times;
+    the pages kernel is llama's so far."""
+    return "gathered"
+
+
 def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
                      page_size: int, mesh=None, int8: bool = False):
     """Device state for the paged serving engine, three kinds side by

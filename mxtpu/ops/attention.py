@@ -25,7 +25,8 @@ from jax import lax
 __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
            "ulysses_attention", "window_attention",
            "ring_attention", "slot_decode_attention",
-           "paged_decode_attention"]
+           "paged_decode_attention", "paged_decode_path",
+           "gathered_decode_attention"]
 
 _NEG_INF = -1e30  # finite "minus infinity": keeps fully-masked rows NaN-free
 
@@ -339,27 +340,70 @@ def slot_decode_attention(q, k, v, lengths, *, scale: Optional[float] = None,
     return out.reshape(b, hq, sq, d)
 
 
+def paged_decode_path(q_shape, pool_shape, pool_dtype, lengths_ndim: int,
+                      *, scales: bool = False, mesh=None) -> str:
+    """Which implementation :func:`paged_decode_attention` runs for
+    these inputs on this backend: ``"pages"`` (the Pallas kernel of
+    ``ops.paged_attention``: it reads the live pages out of the pool
+    and nothing else) or ``"gathered"`` (every slot's whole row of
+    pages copied out, then :func:`slot_decode_attention`). Decided from
+    the backend, shapes and dtypes alone, as :func:`_flash_path` is;
+    nobody sets it.
+
+    The kernel runs on a TPU, for the plain decode step (one query a
+    slot, (slots,) lengths), over a pool it takes as it is stored
+    (``ops.paged_attention.takes``: bfloat16, no scale pools, heads of a
+    multiple of 128 lanes in multiples of 8, so that its view of the
+    pool is a bitcast), and no mesh. Everything else is gathered: the
+    speculative verify step ((slots, W) lengths), int8 pools
+    (dequantised on the gathered bytes), a ``tp`` mesh (a kernel under
+    ``shard_map`` is a later step), float32 pools (the tests and
+    ``chip_smoke.py``'s float32 leg compare tokens with ``generate``
+    bit for bit, which another block size would break), and any other
+    backend."""
+    from .paged_attention import takes
+    if (jax.default_backend() == "tpu" and mesh is None and not scales
+            and lengths_ndim == 1
+            and takes(q_shape, pool_shape, pool_dtype)):
+        return "pages"
+    return "gathered"
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            layer=None,
                            scale: Optional[float] = None,
-                           kv_block: int = 512):
+                           kv_block: int = 512, mesh=None):
     """Decode attention over a PAGED KV pool (vLLM's PagedAttention,
     Kwon et al. SOSP '23): the cache is a pool of fixed-size pages
     and each slot's logical KV sequence is the concatenation of the
-    pool pages its row of ``page_table`` names. Gather + the blockwise
-    ``slot_decode_attention`` online softmax — bit-exact with the dense
-    slot kernel on the same logical KV (the gather materializes the
-    identical (slots, kvh, capacity, hd) operand; trailing pages past
-    ``lengths`` are fully masked, which the online-softmax scan treats
-    as an exact no-op: m unchanged, corr = exp(0) = 1, p zeroed).
+    pool pages its row of ``page_table`` names.
+
+    Two carriers of one algorithm, the float32 online softmax over a
+    slot's keys ``[0, lengths[s])``; :func:`paged_decode_path` says
+    which runs, from the inputs. **On a TPU, the plain decode step over
+    a bfloat16 pool** runs the Pallas kernel ``ops.paged_attention.
+    paged_attention_pages`` (``paged_decode_attention_pages`` in a
+    trace, under the scope ``attention``): the whole pool stays in HBM
+    where it lies, and the kernel DMAs pages ``page_table[s, 0 :
+    ceil(lengths[s] / page_size)]`` of ``pool[layer]`` into VMEM and
+    stops there; no gathered copy exists and nothing runs under
+    ``kv_gather``. **Everything else** (the verify step's (slots, s)
+    lengths, float32 and int8 pools, a mesh, a CPU or GPU) gathers +
+    runs the blockwise ``slot_decode_attention`` online softmax — the
+    tests' reference, bit-exact with the dense slot kernel on the same
+    logical KV (the gather materializes the identical (slots, kvh,
+    capacity, hd) operand; trailing pages past ``lengths`` are fully
+    masked, which the online-softmax scan treats as an exact no-op: m
+    unchanged, corr = exp(0) = 1, p zeroed). The two agree up to the
+    order of summation.
 
     q: (slots, n_heads, s, hd) — s is 1 in decode.
     k_pages, v_pages: (n_pages, page_size, n_kv_heads, hd) — the shared
     pool, stored token-major (page, in-page offset lead: the layout the
     decode write wants, ``llama.init_paged_cache``) — or, with
     ``layer`` (a traced scalar), the whole (L, n_pages, page_size,
-    n_kv_heads, hd) pool, gathered at (layer, page) in one indexed read
-    so that no layer slab is ever sliced out of it. Page 0 is the
+    n_kv_heads, hd) pool, read at (layer, page) by index so that no
+    layer slab is ever sliced out of it. Page 0 is the
     engine's scratch page (never attended: every real table entry
     covering positions < lengths names a live page).
     page_table: (slots, pages_per_slot) int32 — slot i's logical page j
@@ -369,13 +413,33 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     row), and the allocator hands out nothing else (``serve.engine.
     PageAllocator``; zeroed entries name scratch page 0).
     lengths: (slots,) int — slot i attends positions ``[0, lengths[i])``
-    of its gathered sequence — or (slots, s) for per-query lengths,
+    of its pages — or (slots, s) for per-query lengths,
     passed straight through to the slot kernel (the speculative
     verify step's mask).
+    mesh: the mesh the caller's program is partitioned over, if any
+    (the kernel is not partitioned yet).
     """
     if q.shape[0] != page_table.shape[0]:
         raise ValueError(
             f"page_table rows {page_table.shape[0]} != slots {q.shape[0]}")
+    if paged_decode_path(q.shape, k_pages.shape, k_pages.dtype,
+                         lengths.ndim, mesh=mesh) == "pages":
+        from .paged_attention import paged_attention_pages
+        with jax.named_scope(ATTENTION_SCOPE):
+            return paged_attention_pages(q, k_pages, v_pages, page_table,
+                                         lengths, layer=layer, scale=scale)
+    return gathered_decode_attention(q, k_pages, v_pages, page_table,
+                                     lengths, layer=layer, scale=scale,
+                                     kv_block=kv_block)
+
+
+def gathered_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
+                              layer=None, scale: Optional[float] = None,
+                              kv_block: int = 512):
+    """:func:`paged_decode_attention`'s gathered arm, whatever the
+    inputs: every slot's whole row of pages copied out of the pool
+    (under ``kv_gather``), then :func:`slot_decode_attention`. What the
+    kernel is held against, in the tests and on the chip."""
     page_size, hkv, d = k_pages.shape[-3:]
     slots, per_slot = page_table.shape
     idx = page_table if layer is None else (layer, page_table)

@@ -70,7 +70,7 @@ _WAIT_STEP_BUCKETS = (0.0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 1024)
 _engine_seq = itertools.count(1)     # atomic: engines build on threads
 
 
-def _engine_metrics(eid: str):
+def _engine_metrics(eid: str, attention: Dict[str, str]):
     """Process-wide serve metrics (one handle set per engine; the
     registry interns children, so every engine shares the TOTALS).
     Point-in-time gauges are labelled per engine instead — two live
@@ -84,6 +84,15 @@ def _engine_metrics(eid: str):
             "serve_tokens_total", "Tokens emitted by ServeEngine"),
         "steps": telemetry.counter(
             "serve_steps_total", "Decode steps dispatched"),
+        # the same steps by what their program's attention was built on
+        # (static per compiled program; ``attention`` maps "plain" and
+        # "verify" to the family's word for each)
+        **{"steps_" + step: telemetry.counter(
+            "serve_decode_steps_total",
+            "Decode steps dispatched, by the attention their program "
+            "was built with: pages (the kernel reads live pages out of "
+            "the pool) or gathered (every slot's whole row copied out)",
+            attention=path) for step, path in attention.items()},
         "queue": telemetry.gauge(
             "serve_queue_depth", "Requests queued, not yet admitted",
             engine=eid),
@@ -628,6 +637,13 @@ class ServeEngine:
         # per-slot rings and recurrent state)
         self._sv = {n: state.pop(n) for n in ("lengths", "tokens", "rngs")}
         self._kv = state
+        # which attention the family builds the decode programs on over
+        # this state ("pages" | "gathered"): its choice, exported as it
+        # is (serve_decode_steps_total{attention}, kv_cache_stats())
+        self._attention = {
+            "plain": fam.decode_attention_path(cfg, state, mesh),
+            "verify": fam.decode_attention_path(cfg, state, mesh,
+                                                verify=True)}
         # the kv state is donated through every program (in-place in
         # HBM); the small vectors are not, so the previous step's
         # sampled tokens stay readable during the overlapped sync.
@@ -687,7 +703,7 @@ class ServeEngine:
                 self.prefill_chunk)
         eid = str(next(_engine_seq))
         self.engine_id = eid
-        self._m = _engine_metrics(eid)
+        self._m = _engine_metrics(eid, self._attention)
         self._m_cancel: Dict[str, Any] = {}    # per-reason counters
         # span factories pre-bind their registry histograms — the
         # per-step/per-admission hot paths must not re-intern handles.
@@ -1443,6 +1459,7 @@ class ServeEngine:
                     self.params, self._kv, self._sv, active,
                     pt, self._temps, self._topks, self._topps)
         self._m["steps"].inc()
+        self._m["steps_plain" if drafts is None else "steps_verify"].inc()
         with self._lock:
             self.steps_run += 1
             if drafts is not None:
@@ -1707,7 +1724,10 @@ class ServeEngine:
         prefixes actually COVER, with the pool's page counts, prefix
         cache and speculation tallies — surfaced in the gateway
         ``/state`` block. ``"paged"`` is always True (readers outside
-        the repo may hold the key). Host arithmetic only (the mirrored
+        the repo may hold the key); ``"decode_attention"`` is what the
+        decode program's attention was built on, ``"pages"`` (the
+        kernel over live pages) or ``"gathered"``, as the family's
+        ``decode_attention_path`` gave it. Host arithmetic only (the mirrored
         per-slot lengths; reading the device ``lengths`` vector here
         would put a sync next to the decode loop — MXL004)."""
         with self._lock:
@@ -1717,6 +1737,7 @@ class ServeEngine:
                    "reserved_bytes": self._kv_reserved,
                    "state_bytes_per_slot": self._slot_state_bytes,
                    "paged": True,
+                   "decode_attention": self._attention["plain"],
                    "page_size": self.page_size,
                    "pages_total": self.n_pages - 1,
                    "pages_free": self._pages.free_pages,

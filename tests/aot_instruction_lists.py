@@ -6,8 +6,9 @@ lists, for comparing two trees::
 ``<tree>`` is the checkout whose ``mxtpu`` is compiled (this one, or a
 ``git archive`` of another commit); the shapes are
 ``tests/test_tpu_aot_scopes.py``'s. One file a program in ``<out>``:
-``decode_slots_paged``, ``prefill_slot_paged``, ``copy_page`` and
-``sambay.decode_slots_paged``, a line an instruction of the optimised
+``decode_slots_paged``, ``decode_slots_spec``, ``prefill_slot_paged``,
+``copy_page`` and ``sambay.decode_slots_paged``, a line an instruction
+of the optimised
 module — computation, opcode, result type with its layout, ``op_name``
 — with XLA's instruction numbering taken out. Two trees that give
 ``diff -r`` nothing hand the chip the same programs. Not a test: one

@@ -36,10 +36,22 @@ def test_serve_phase_runs_tiny_on_cpu():
         per_shape=2, shared_prefix=9)
     info, streams = chip_smoke.phase_serve(
         cfg, jobs, max_slots=2, max_len=32, min_bucket=4, page_size=8,
-        n_pages=25, must_match=True)   # float32, highest (conftest)
+        n_pages=25, must_match=True,   # float32, highest (conftest)
+        expect_attention="gathered")
     assert [len(s) for s in streams] == [j["mnew"] for j in jobs]
     assert info["compiles"] <= info["compile_bound"]
     assert info["prefix_hits"] >= 1 and info["identical"] == "6/6"
+
+
+def test_pages_kernel_phase_runs_tiny_on_cpu():
+    """The kernel-against-gathered leg, interpreted, at toy widths: a
+    length of 1, a full row and both sides of a page's edge among its
+    ragged slots."""
+    info = chip_smoke.phase_pages_kernel(
+        slots=6, n_heads=4, n_kv_heads=2, head_dim=16, page_size=4,
+        capacity=32, layers=2, interpret=True)
+    assert info["lengths"] == [1, 32]      # the phase holds the numbers
+    assert info["largest_output"] > 0.0
 
 
 def test_serve_sambay_phase_runs_tiny_on_cpu():

@@ -389,8 +389,10 @@ def test_dataloader_dead_worker_reports_exit_codes():
     breadcrumb the bare TimeoutError lacked)."""
     from mxtpu.gluon.data import DataLoader
     ds = _CrashingDataset(8, crash_idx=1, marker=None)   # always dies
+    # the timeout has to outlast a worker's start (it imports mxtpu:
+    # 3.3-4.0 s here), or both rounds end before any worker has died
     loader = DataLoader(ds, batch_size=4, num_workers=2,
-                        thread_pool=False, timeout=4)
+                        thread_pool=False, timeout=8)
     with pytest.raises(RuntimeError, match=r"exit code"):
         list(loader)
 

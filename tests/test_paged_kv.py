@@ -259,6 +259,207 @@ def test_paged_attention_shared_pages_read_path():
 
 
 # ---------------------------------------------------------------------------
+# the pages kernel (ops/paged_attention.py), interpreted on the CPU at
+# toy widths, against the gathered path on the same pool and table
+# ---------------------------------------------------------------------------
+_PK = dict(S=4, per_slot=4, ps=4, hkv=2, hd=16, layers=2)
+_PK_CAP = _PK["per_slot"] * _PK["ps"]
+_PK_NAN = 1 + _PK["S"] * _PK["per_slot"]    # the last page: all NaN
+# case -> the four slots' lengths (every case but the last two walks
+# its own shuffled pages of layer 1)
+_PK_LENGTHS = {
+    "length_0": [0, 9, 0, 3],
+    "length_1": [1, 1, 14, 1],
+    "page_less_1": [3, 7, 15, 11],
+    "page": [4, 8, 4, 12],
+    "page_plus_1": [5, 9, 13, 5],
+    "ragged": [2, 16, 7, 10],
+    "capacity": [16, 16, 16, 16],
+    # slots 0 and 2 name the same physical pages (a shared prefix)
+    "shared_page": [8, 5, 6, 11],
+    # idle slots as the engine leaves them: zeroed rows, one key of
+    # scratch page 0
+    "scratch_rows": [1, 13, 1, 6],
+    # every table entry past a slot's length names the NaN page
+    "nan_past_length": [0, 5, 8, 15],
+    # layer 0 of the two, traced: the other cases read layer 1
+    "traced_layer_0": [6, 12, 1, 16],
+}
+
+
+@pytest.fixture(scope="module")
+def pages_kernel():
+    """(kernel, gathered), each jitted once a shape: two pages to a
+    block and one to a chunk, so a slot takes one block, two, or a
+    block half read, and a block one chunk or two."""
+    from mxtpu.ops.attention import gathered_decode_attention
+    from mxtpu.ops.paged_attention import paged_attention_pages
+    return (jax.jit(partial(paged_attention_pages, block_pages=2,
+                            chunk_pages=1, interpret=True)),
+            jax.jit(partial(gathered_decode_attention, kv_block=8)))
+
+
+@pytest.fixture(scope="module")
+def pages_kernel_pool():
+    """dtype -> (k pool, v pool) of two layers, random but for scratch
+    page 0 (zeros) and the last page (NaN)."""
+    rng = np.random.default_rng(30)
+    shape = (_PK["layers"], _PK_NAN + 1, _PK["ps"], _PK["hkv"], _PK["hd"])
+
+    def pool():
+        a = rng.standard_normal(shape).astype(np.float32)
+        a[:, 0], a[:, _PK_NAN] = 0.0, np.nan
+        return a
+    k, v = pool(), pool()
+    return {dt: (jnp.asarray(k, dt), jnp.asarray(v, dt))
+            for dt in (jnp.float32, jnp.bfloat16)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("case", list(_PK_LENGTHS))
+def test_pages_kernel_matches_the_gathered_path(pages_kernel,
+                                                pages_kernel_pool, case,
+                                                rep, dtype):
+    """The Pallas kernel walks each slot's pages out of the whole pool
+    and stops at the slot's length: the same numbers as gather-then-
+    ``slot_decode_attention`` up to the order of summation, zeros for a
+    slot of length 0, and nothing of a page past the length (the
+    interpreter's buffers start as NaN, so would an unread page that
+    leaked)."""
+    kernel, gathered = pages_kernel
+    S, per_slot, ps, hkv, hd = (_PK[n] for n in
+                                ("S", "per_slot", "ps", "hkv", "hd"))
+    kp, vp = pages_kernel_pool[dtype]
+    rng = np.random.default_rng(sorted(_PK_LENGTHS).index(case))
+    q = jnp.asarray(rng.standard_normal((S, hkv * rep, 1, hd)), dtype)
+    lengths = np.asarray(_PK_LENGTHS[case], np.int32)
+    table = (1 + rng.permutation(S * per_slot)).astype(
+        np.int32).reshape(S, per_slot)
+    if case == "shared_page":
+        table[2, :2] = table[0, :2]
+    if case == "scratch_rows":
+        table[[0, 2]] = 0
+    clean = table.copy()
+    if case == "nan_past_length":
+        live = np.arange(per_slot)[None] * ps < lengths[:, None]
+        table, clean = (np.where(live, table, fill).astype(np.int32)
+                        for fill in (_PK_NAN, 0))
+    layer = 0 if case == "traced_layer_0" else 1
+    out = kernel(q, kp, vp, table, lengths, layer=jnp.int32(layer))
+    ref = gathered(q, kp, vp, clean, lengths, layer=jnp.int32(layer))
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    out, ref = (np.asarray(a, np.float32) for a in (out, ref))
+    assert np.isfinite(out).all()
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    np.testing.assert_array_equal(out[lengths == 0], 0.0)
+    if case == "shared_page":       # the same keys under the same query
+        again = kernel(q.at[2].set(q[0]), kp, vp, table,
+                       jnp.asarray(lengths).at[2].set(lengths[0]),
+                       layer=jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(again[0], np.float32),
+                                      np.asarray(again[2], np.float32))
+
+
+# ---------------------------------------------------------------------------
+# which carrier runs is read off the inputs, and the engine says which
+# ---------------------------------------------------------------------------
+def _chat_shapes(dtype=jnp.bfloat16, int8=False, n_kv_heads=8):
+    """(cfg, kv pools as shapes): the chat cell's widths (32 query / 8
+    KV heads of 128, pages of 16 tokens) at two layers."""
+    from dataclasses import replace
+    cfg = replace(llama.CONFIGS["tiny"], dim=4096, n_heads=32,
+                  n_kv_heads=n_kv_heads, n_layers=2, max_seq_len=2048,
+                  dtype=dtype, param_dtype=dtype)
+    state = jax.eval_shape(lambda: llama.init_paged_cache(
+        cfg, 32, 2049, 16, int8=int8))
+    return cfg, {n: a for n, a in state.items()
+                 if n not in ("lengths", "tokens", "rngs")}
+
+
+@pytest.mark.parametrize("case,path", [
+    ("chat_cell_on_a_tpu", "pages"), ("float32_pool", "gathered"),
+    ("int8_pool", "gathered"), ("verify_step", "gathered"),
+    ("mesh", "gathered"), ("cpu_backend", "gathered"),
+    ("four_kv_heads", "gathered")])
+def test_decode_attention_path_is_read_off_the_inputs(monkeypatch, case,
+                                                      path):
+    """Backend, shapes and dtypes decide, statically: the kernel for
+    the chat cell's plain step on a TPU, the gathered path for
+    everything the kernel does not do yet (four KV heads are half an
+    (8, 128) tile: the kernel's view of such a pool is no bitcast, and
+    XLA would copy the pool for it); and the traced program holds the
+    kernel exactly when the family says so."""
+    if case != "cpu_backend":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, kv = _chat_shapes(
+        jnp.float32 if case == "float32_pool" else jnp.bfloat16,
+        int8=case == "int8_pool",
+        n_kv_heads=4 if case == "four_kv_heads" else 8)
+    mesh = None
+    if case == "mesh":
+        from mxtpu.parallel import create_mesh
+        mesh = create_mesh(tp=2, devices=jax.devices()[:2])
+    verify = case == "verify_step"
+    assert llama.decode_attention_path(cfg, kv, mesh, verify=verify) == path
+    if mesh is not None:
+        return          # the traced text below needs no second copy
+    params = jax.eval_shape(partial(llama.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    S = 32
+    sv = {"lengths": jax.ShapeDtypeStruct((S,), jnp.int32),
+          "tokens": jax.ShapeDtypeStruct((S,), jnp.int32),
+          "rngs": jax.ShapeDtypeStruct((S, 2), jnp.uint32)}
+    args = [params, kv, sv, jax.ShapeDtypeStruct((S,), jnp.bool_),
+            jax.ShapeDtypeStruct((S, 128), jnp.int32)]
+    if verify:
+        args.append(jax.ShapeDtypeStruct((S, 3), jnp.int32))
+    args += [jax.ShapeDtypeStruct((S,), jnp.float32),
+             jax.ShapeDtypeStruct((S,), jnp.int32),
+             jax.ShapeDtypeStruct((S,), jnp.float32)]
+    program = llama.decode_slots_spec if verify else llama.decode_slots_paged
+    text = str(jax.make_jaxpr(partial(program, cfg))(*args))
+    from mxtpu.ops.paged_attention import KERNEL_NAME
+    assert (KERNEL_NAME in text) == (path == "pages")
+
+
+@pytest.mark.parametrize("backend,path", [("cpu", "gathered"),
+                                          ("tpu", "pages")])
+def test_engine_names_the_attention_its_program_was_built_with(
+        monkeypatch, cfg, params, backend, path):
+    """``kv_cache_stats()`` and ``serve_decode_steps_total{attention}``
+    carry the family's word; the counter steps where
+    ``serve_steps_total`` does."""
+    from mxtpu import telemetry
+    if backend == "tpu":
+        # construction compiles nothing; a bfloat16 pool of eight
+        # 128-lane heads is what the rule wants to see
+        from dataclasses import replace
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = replace(cfg, dim=1024, n_heads=8, n_kv_heads=8,
+                      dtype=jnp.bfloat16)
+    eng = paged_engine(cfg, params)
+    assert eng.kv_cache_stats()["decode_attention"] == path
+    assert path == llama.decode_attention_path(cfg, eng._kv, None)
+    if backend == "tpu":
+        return
+    reg = telemetry.registry()
+    before = {a: reg.value("serve_decode_steps_total", attention=a)
+              for a in ("pages", "gathered")}
+    steps = reg.value("serve_steps_total")
+    eng.submit(Request(prompt=[5, 6, 7], max_new_tokens=4))
+    eng.run()
+    ran = reg.value("serve_steps_total") - steps
+    assert ran >= 3
+    assert reg.value("serve_decode_steps_total",
+                     attention="gathered") - before["gathered"] == ran
+    assert reg.value("serve_decode_steps_total",
+                     attention="pages") == before["pages"]
+
+
+# ---------------------------------------------------------------------------
 # programs: the edges of the page range the gathers promise to stay in
 # ---------------------------------------------------------------------------
 _EDGE_PAGES = 9                             # scratch + 8

@@ -3,11 +3,18 @@ programs compiled for a described v5e. The decode program keeps every
 instruction's ``op_name``, and the operations a trace will show
 (fusions, copies, custom calls) land under the model's scopes; and no
 paged program moves the KV pool — the write updates the donated pool in
-place and the page gather reads it, nothing else touches pool-sized
-bytes; and the sampler orders a vocabulary by ONE sort of its values,
-with no permutation to gather through. The only test file that
+place and the attention reads it (the plain step through ONE Pallas
+kernel call a layer that walks live pages, so that no gathered row
+exists; the verify step through the page gather), nothing else touches
+pool-sized bytes; and the sampler orders a vocabulary by ONE sort of its
+values, with no permutation to gather through. Code that asks
+``jax.default_backend()`` here still sees the CPU (section 2 of the
+guide), and the rule that picks the kernel asks, so the fixtures that
+lower llama's programs answer ``"tpu"`` for it while they trace, as the
+chip these compile for will. The only test file that
 describes a TPU topology (one process may hold libtpu: the
 on-chip-measurement guide, section 2), and only inside fixtures."""
+import contextlib
 import math
 import os
 import re
@@ -23,10 +30,13 @@ from mxtpu.models import llama, sambay
 from mxtpu.telemetry import scopes as tscopes
 
 # Mistral's head shapes (32 query / 8 kv heads of 128); depth, FFN and
-# row length cut. 257 pages: a prime, so a shape that holds the pool or
+# row length cut. 1031 pages: a prime, so a shape that holds the pool or
 # one layer's slab of it is known by that factor whatever XLA folds it
-# into, and a slot's gathered rows (8 x 32 pages) never are.
-SLOTS, PAGE, N_PAGES, LAYERS, BUCKET = 8, 16, 257, 4, 128
+# into, and a slot's gathered rows (8 x 32 pages) never are; and K's
+# pool (135 MB) is more than the chip's 128 MiB of VMEM, as every
+# deployment's is (at 257 pages XLA staged the 34 MB pool in VMEM for
+# the write and copied it back out for the kernel, which reads HBM).
+SLOTS, PAGE, N_PAGES, LAYERS, BUCKET = 8, 16, 1031, 4, 128
 
 
 @pytest.fixture(scope="module")
@@ -51,10 +61,21 @@ def _compile_all(lowered):
         jax.config.update("jax_enable_compilation_cache", cache_on)
 
 
-def _lower_decode(family, cfg, one_chip):
-    """``family.decode_slots_paged`` lowered for the chip, its state
-    donated as the engine donates it. Returns (lowered, params, kv, sv)
-    as shapes on the chip."""
+@contextlib.contextmanager
+def _as_on_a_tpu():
+    """While it is entered, ``jax.default_backend()`` answers ``"tpu"``
+    to the program's own code, as it will on the chip this compiles
+    for."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        yield
+
+
+def _lower_decode(family, cfg, one_chip, drafts=None):
+    """``family.decode_slots_paged`` (with ``drafts`` a slot:
+    ``decode_slots_spec``, the verify step) lowered for the chip, its
+    state donated as the engine donates it. Returns (lowered, params,
+    kv, sv) as shapes on the chip."""
     arg = partial(jax.ShapeDtypeStruct, sharding=one_chip)
 
     def on_chip(tree):
@@ -66,19 +87,23 @@ def _lower_decode(family, cfg, one_chip):
     per_slot = ("lengths", "tokens", "rngs")
     kv = on_chip({n: a for n, a in state.items() if n not in per_slot})
     sv = on_chip({n: state[n] for n in per_slot})
-    decode = partial(family.decode_slots_paged, cfg)
-    decode.__name__ = "decode_slots_paged"
-    lowered = jax.jit(decode, donate_argnums=(1,)).lower(
-        params, kv, sv, arg((SLOTS,), jnp.bool_),
-        arg((SLOTS, cfg.max_seq_len // PAGE), jnp.int32),
-        arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.int32),
-        arg((SLOTS,), jnp.float32))
+    name = "decode_slots_spec" if drafts else "decode_slots_paged"
+    decode = partial(getattr(family, name), cfg)
+    decode.__name__ = name
+    with _as_on_a_tpu():
+        lowered = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, kv, sv, arg((SLOTS,), jnp.bool_),
+            arg((SLOTS, cfg.max_seq_len // PAGE), jnp.int32),
+            *([arg((SLOTS, drafts), jnp.int32)] if drafts else []),
+            arg((SLOTS,), jnp.float32), arg((SLOTS,), jnp.int32),
+            arg((SLOTS,), jnp.float32))
     return lowered, params, kv, sv
 
 
 @pytest.fixture(scope="module")
 def compiled(one_chip):
-    """name -> compiled executable of ``decode_slots_paged``, one
+    """name -> compiled executable of ``decode_slots_paged``,
+    ``decode_slots_spec`` (the verify step, three drafts a slot), one
     ``prefill_slot_paged`` bucket and ``copy_page``, pool donated as
     the engine donates it, for one v5e chip."""
     cfg = replace(llama.CONFIGS["tiny"], vocab_size=32768, dim=4096,
@@ -95,6 +120,8 @@ def compiled(one_chip):
     prefill.__name__ = "prefill_slot_paged"
     lowered = {
         "decode_slots_paged": decode,
+        "decode_slots_spec": _lower_decode(llama, cfg, one_chip,
+                                           drafts=3)[0],
         "prefill_slot_paged": jax.jit(prefill, donate_argnums=(6,)).lower(
             params, arg((1, BUCKET), jnp.int32), scalar(jnp.int32),
             scalar(jnp.int32), arg((per_slot,), jnp.int32),
@@ -132,14 +159,16 @@ def test_tpu_program_keeps_its_name_and_parses_fast(decode_text):
     assert len(scopes) > 500
 
 
-@pytest.mark.parametrize("scope", ["sampler", "kv_gather", "attention",
+@pytest.mark.parametrize("scope", ["sampler", "attention",
                                    "kv_write", "mlp", "norm", ""])
 def test_tpu_fusions_land_under_the_model_scopes(decode_text, scope):
     """What the trace's "XLA Ops" line shows are fusions, copies and
     custom calls; each scope a reader follows must hold some."""
+    from mxtpu.ops.paged_attention import KERNEL_NAME
     _, scopes = tscopes.scope_map(decode_text)
     shown = {name: path.split("/")[0] for name, (path, _) in
-             scopes.items() if re.search(r"fusion|^copy|custom-call", name)}
+             scopes.items() if re.search(
+                 rf"fusion|^copy|custom-call|^{KERNEL_NAME}", name)}
     assert scope in set(shown.values()), sorted(set(shown.values()))
     # the model's scopes and nothing else: no function's name leaks in
     assert set(shown.values()) <= {
@@ -155,8 +184,9 @@ _SLAB = N_PAGES * PAGE * 8 * 128            # one layer of K (or V)
 
 
 def _computations(text):
-    """HLO text -> {computation: [(name, elements, opcode, rest, root)]}
-    for its array-valued instructions (tuples move no bytes)."""
+    """HLO text -> {computation: [(name, elements, opcode, rest, root,
+    last dimension)]} for its array-valued instructions (tuples move no
+    bytes)."""
     out, cur = {}, None
     for line in text.splitlines():
         m = _COMPUTATION.match(line)
@@ -166,9 +196,23 @@ def _computations(text):
         m = _INSTRUCTION.match(line)
         if m and cur is not None:
             root, name, _, dims, opcode, rest = m.groups()
-            n = math.prod(int(d) for d in dims.split(",")) if dims else 1
-            cur.append((name, n, opcode, rest, bool(root)))
+            dims = [int(d) for d in dims.split(",") if d]
+            cur.append((name, math.prod(dims), opcode, rest, bool(root),
+                        dims[-1] if dims else 1))
     return out
+
+
+_NOT_RUN = ("parameter", "get-tuple-element", "bitcast")
+
+
+def _executed(comps):
+    """{computation: instructions} without the fusions' bodies (they
+    are their fusion's) and the computations nothing names."""
+    bodies = {m.group(1) for instrs in comps.values()
+              for _, _, opcode, rest, *_ in instrs if opcode == "fusion"
+              for m in [re.search(r"calls=%?([\w.\-]+)", rest)] if m}
+    return {comp: instrs for comp, instrs in comps.items()
+            if comp not in bodies}
 
 
 def _pool_sized(n):
@@ -182,22 +226,17 @@ def _pool_movers(text):
     ``dynamic-update-slice`` whose first operand is the pool itself and
     whose every other operand is smaller than a slab."""
     comps = _computations(text)
-    bodies = {m.group(1) for instrs in comps.values()
-              for _, _, opcode, rest, _ in instrs if opcode == "fusion"
-              for m in [re.search(r"calls=%?([\w.\-]+)", rest)] if m}
     movers = []
-    for comp, instrs in comps.items():
-        if comp in bodies:
-            continue
-        sizes = {name: n for name, n, _, _, _ in instrs}
-        for name, n, opcode, rest, _ in instrs:
-            if not _pool_sized(n) or opcode in (
-                    "parameter", "get-tuple-element", "bitcast"):
+    for comp, instrs in _executed(comps).items():
+        sizes = {name: n for name, n, *_ in instrs}
+        for name, n, opcode, rest, *_ in instrs:
+            if not _pool_sized(n) or opcode in _NOT_RUN:
                 continue
             if opcode == "fusion":
                 body = comps[re.search(r"calls=%?([\w.\-]+)",
                                        rest).group(1)]
-                root = next(op for _, _, op, _, is_root in body if is_root)
+                root = next(op for _, _, op, _, is_root, _ in body
+                            if is_root)
                 operands = re.findall(r"%([\w.\-]+)", rest.split(")")[0])
                 if (root in ("scatter", "dynamic-update-slice")
                         and sizes.get(operands[0]) == n
@@ -209,7 +248,8 @@ def _pool_movers(text):
 
 
 @pytest.mark.parametrize("program", ["decode_slots_paged",
-                                     "prefill_slot_paged", "copy_page"])
+                                     "prefill_slot_paged", "copy_page",
+                                     "decode_slots_spec"])
 def test_tpu_paged_programs_leave_the_pool_in_place(compiled, program):
     """No copy, slice, update-slice, select or loop fusion produces the
     pool or a slab of it: the write's scatter (``copy_page``: its
@@ -224,13 +264,53 @@ def test_tpu_paged_programs_leave_the_pool_in_place(compiled, program):
     assert mem.temp_size_in_bytes < LAYERS * _SLAB * 2, mem
 
 
-def test_tpu_page_gather_selects_only_indices(decode_text):
+_ROW = SLOTS * 512 * 8 * 128        # every slot's whole row of K (or V)
+
+
+def _rows(text):
+    """The executed instructions whose result is as large as all the
+    slots' capacity-long rows of one layer's K and ends in a head's 128
+    lanes: the gathered copy ``[8, 8, 512, 128]``, its token-major twin
+    ``[256, 16, 8, 128]``, or either cut into key blocks. (A layer's
+    ``wk`` is as large, and ends in 1024 or 4096.)"""
+    return [f"{comp}: {name} = {opcode}"
+            for comp, instrs in _executed(_computations(text)).items()
+            for name, n, opcode, _, _, last in instrs
+            if n == _ROW and last == 128 and opcode not in _NOT_RUN]
+
+
+def test_tpu_decode_attention_is_one_kernel_call_over_live_pages(
+        compiled):
+    """The plain step's attention is ONE Pallas call in the layer
+    loop's body, under ``attention``, fed by the pool itself; nothing
+    under ``kv_gather`` is left, and no operation of the program
+    produces a capacity-long row of every slot, in either layout (the
+    gather wrote one for K and one for V, 268 MB each at the chat
+    cell's shapes, and the key-block reads read them back)."""
+    from mxtpu.ops.paged_attention import KERNEL_NAME
+    text = compiled["decode_slots_paged"].as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert re.search(rf"%{KERNEL_NAME}[.\d]* = ", calls[0]), calls[0]
+    assert re.search(r'op_name="[^"]*/while/body/[^"]*/attention/'
+                     + KERNEL_NAME, calls[0]), calls[0]
+    assert "/kv_gather/" not in text
+    assert _rows(text) == []
+    # the same reading finds the verify step's rows, which still gather
+    assert _rows(compiled["decode_slots_spec"].as_text())
+
+
+def test_tpu_page_gather_selects_only_indices(compiled):
     """The gather promises its bounds: under ``kv_gather`` nothing
     selects over gathered rows (``jnp.take``'s default fill was a
     ``select_n`` over every slot's whole row, 12 ms of an 85 ms step);
-    what is left normalises the page table's own entries."""
+    what is left normalises the page table's own entries. Read off the
+    verify step, the llama program that still gathers on a TPU."""
+    text = compiled["decode_slots_spec"].as_text()
+    assert "/kv_gather/" in text
     rows = SLOTS * (512 // PAGE)
-    for line in decode_text.splitlines():
+    for line in text.splitlines():
         m = _INSTRUCTION.match(line)
         if m and m.group(5) == "select" and "/kv_gather/" in line:
             dims = m.group(4)
