@@ -41,8 +41,8 @@ WIDTHS = dict(vocab_size=32000, dim=2048, n_layers=8, n_heads=16,
 SERVE_SHAPES = ((50, 24, 0.0), (100, 40, 0.7), (200, 16, 0.0),
                 (200, 64, 0.7))
 SERVE_ENGINE = dict(max_slots=8, max_len=768, min_bucket=64)
-# the same for the sambay leg, all greedy: the longer prompt wraps a
-# 512-key window ring three times
+# the same for the sambay and latent_moe legs, all greedy: the longer
+# prompt wraps a 512-key window ring three times, and is two chunks
 SAMBAY_SHAPES = ((300, 16, 0.0), (1700, 24, 0.0))
 
 
@@ -553,21 +553,70 @@ def phase_pages_kernel(*, slots=32, n_heads=32, n_kv_heads=8, head_dim=128,
             "largest_difference": worst, "largest_output": largest}
 
 
-def phase_serve_sambay(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
-    """The SambaY family (``models/sambay.py``: state-space, window,
-    full, GMU and cross-attention layers) through a paged
-    ``ServeEngine`` behind ``Gateway.start_http``, once in the config's
-    bf16 and once in float32 at ``highest`` precision, against its own
-    ``forward``: ``jobs`` are asked greedily, and each emitted token's
-    logit in one ``forward`` over prompt + stream is held against that
-    position's largest. In float32 the two must agree (gap under
-    ``tol_f32``); in bf16 the worst gap is reported (a near-tie may
-    flip, as it does for llama)."""
+def phase_latent_kernel(*, slots=32, n_heads=32, row=640, value_dim=512,
+                        page_size=16, capacity=4096, layers=2,
+                        interpret=False):
+    """:func:`phase_pages_kernel` for a latent pool
+    (``ops.paged_attention.paged_latent_pages`` against
+    ``ops.attention.gathered_latent_decode_attention``) at
+    ``models/latent_moe.py``'s published shapes: 32 heads over ONE row
+    of 640 a token, values its first 512."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from mxtpu.models import sambay
+    from mxtpu.ops.attention import gathered_latent_decode_attention
+    from mxtpu.ops.paged_attention import paged_latent_pages
+
+    t0 = time.perf_counter()
+    per_slot = capacity // page_size
+    n_pages = 1 + slots * per_slot
+    rng = np.random.default_rng(0)
+    lengths = rng.integers(1, capacity + 1, slots).astype(np.int32)
+    lengths[:4] = 1, capacity, page_size, page_size + 1
+    table = (1 + rng.permutation(n_pages - 1)).astype(np.int32).reshape(
+        slots, per_slot)
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    pool = jax.jit(lambda k: jax.random.normal(
+        k, (layers, n_pages, page_size, row), jnp.bfloat16))(keys[0])
+    q = jax.random.normal(keys[1], (slots, n_heads, 1, row), jnp.bfloat16)
+    layer, scale = jnp.int32(layers - 1), 1.0 / 24
+    kernel = jax.jit(lambda *a: paged_latent_pages(
+        *a, layer=layer, scale=scale, interpret=interpret))
+    gathered = jax.jit(lambda *a: gathered_latent_decode_attention(
+        *a, layer=layer, value_dim=value_dim, scale=scale))
+    args = (q, pool, jnp.asarray(table), jnp.asarray(lengths))
+    want = np.asarray(gathered(*args), np.float32)
+    setup_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    got = np.asarray(kernel(*args), np.float32)[..., :value_dim]
+    worst, largest = float(np.abs(got - want).max()), float(
+        np.abs(want).max())
+    assert np.isfinite(got).all()
+    assert worst <= 4 * 2.0 ** -8 * max(1.0, largest), (worst, largest)
+    return {"setup_s": setup_s, "run_s": time.perf_counter() - t0,
+            "slots": slots, "lengths": [int(lengths.min()),
+                                        int(lengths.max())],
+            "pool_bytes": int(np.prod(pool.shape)) * 2,
+            "largest_difference": worst, "largest_output": largest}
+
+
+def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
+    """A serving family other than llama (``models.serving_family(cfg)``:
+    ``sambay.py``'s state-space, window, full, GMU and cross-attention
+    layers; ``latent_moe.py``'s latent attention and routed experts)
+    through a paged ``ServeEngine`` behind ``Gateway.start_http``, once
+    in the config's bf16 and once in float32 at ``highest`` precision,
+    against its own ``forward``: ``jobs`` are asked greedily, and each
+    emitted token's logit in one ``forward`` over prompt + stream is
+    held against that position's largest. In float32 the two must agree
+    (gap under ``tol_f32``); in bf16 the worst gap is reported (a
+    near-tie may flip, as it does for llama)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxtpu.models import serving_family
     from mxtpu.serve import ServeEngine
+    family = serving_family(cfg)
     from mxtpu.serve.gateway import Gateway, GatewayClient
 
     t0 = time.perf_counter()
@@ -576,7 +625,7 @@ def phase_serve_sambay(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
         c = replace(cfg, dtype=dtype, param_dtype=dtype)
         name = np.dtype(dtype).name
         with _matmul_precision(precision):
-            params = jax.jit(lambda k: sambay.init_params(c, k))(
+            params = jax.jit(lambda k: family.init_params(c, k))(
                 jax.random.PRNGKey(0))
             gw = Gateway(lambda: ServeEngine(c, params, **engine_kw),
                          n_replicas=1, queue_max=4 * len(jobs),
@@ -604,10 +653,10 @@ def phase_serve_sambay(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
                 assert not any(t.is_alive() for t in threads), "a client hung"
                 assert engine.compile_count == 1 + engine.n_buckets
                 kv = engine.kv_cache_stats()
-                assert kv["state_bytes_per_slot"] > 0, kv
+                assert kv["reserved_bytes"] > 0, kv
             finally:
                 gw.close()
-            fwd = jax.jit(lambda p, t: sambay.forward(c, p, t))
+            fwd = jax.jit(lambda p, t: family.forward(c, p, t))
             worst = 0.0
             for job, rec in zip(jobs, results):
                 assert rec is not None and rec["status"] == 200 and \
@@ -681,10 +730,21 @@ def main():
     # prompts longer than three of its 512-token windows
     from mxtpu.models import sambay
     sambay_cfg = sambay.SambaYConfig(n_layers=8, max_seq_len=2048)
-    _run("serve_sambay", phase_serve_sambay, sambay_cfg,
+    _run("serve_sambay", phase_serve_family, sambay_cfg,
          make_jobs(sambay_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
                    shared_prefix=0),
          max_slots=4, max_len=2048, min_bucket=256)
+
+    # the third, at its published widths and a small depth (one dense
+    # and two expert layers, all 128 experts): the longer prompt is
+    # prefilled in two chunks, then absorbed decode over latent pages
+    from mxtpu.models import latent_moe
+    _run("latent_kernel", phase_latent_kernel)
+    moe_cfg = latent_moe.LatentMoEConfig(n_layers=3, max_seq_len=2048)
+    _run("serve_latent_moe", phase_serve_family, moe_cfg,
+         make_jobs(moe_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
+                   shared_prefix=0),
+         max_slots=4, max_len=2048, min_bucket=256, prefill_chunk=1024)
 
     if jax.device_count() >= 4:
         # the same two phases over a mesh with more than one

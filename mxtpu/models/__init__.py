@@ -9,20 +9,24 @@ compose directly with ``mxtpu.parallel`` (sharding rules, jitted train
 step, remat, scan-over-layers) — the idiomatic shape for pjit/XLA.
 """
 from . import bert
+from . import latent_moe
 from . import llama
 from . import resnet
 from . import sambay
 from .bert import BertConfig
+from .latent_moe import LatentMoEConfig
 from .llama import LlamaConfig
 from .resnet import ResNetConfig
 from .sambay import SambaYConfig
 
-__all__ = ["llama", "resnet", "sambay", "LlamaConfig", "ResNetConfig",
-           "SambaYConfig", "SERVING_FAMILIES", "serving_family"]
+__all__ = ["llama", "resnet", "sambay", "latent_moe", "LlamaConfig",
+           "ResNetConfig", "SambaYConfig", "LatentMoEConfig",
+           "SERVING_FAMILIES", "serving_family"]
 
 # the families ``serve.ServeEngine`` can be given, by the ``family`` of
 # their config class; each module has llama.py's serving surface
-SERVING_FAMILIES = {"llama": llama, "sambay": sambay}
+SERVING_FAMILIES = {"llama": llama, "sambay": sambay,
+                    "latent_moe": latent_moe}
 
 
 def serving_family(cfg):

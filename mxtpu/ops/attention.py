@@ -26,7 +26,9 @@ __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
            "ulysses_attention", "window_attention",
            "ring_attention", "slot_decode_attention",
            "paged_decode_attention", "paged_decode_path",
-           "gathered_decode_attention"]
+           "gathered_decode_attention", "latent_decode_path",
+           "paged_latent_decode_attention",
+           "gathered_latent_decode_attention", "latent_prefill_attention"]
 
 _NEG_INF = -1e30  # finite "minus infinity": keeps fully-masked rows NaN-free
 
@@ -34,6 +36,9 @@ _NEG_INF = -1e30  # finite "minus infinity": keeps fully-masked rows NaN-free
 # above rms_norm has the whole list): a trace reader follows these names
 ATTENTION_SCOPE = "attention"
 KV_GATHER_SCOPE = "kv_gather"
+# latent (MLA) attention, models/latent_moe.py: its own name, so that a
+# trace tells a latent layer's time from a per-head layer's
+MLA_SCOPE = "mla_attention"
 
 
 def _repeat_kv(q, k, v):
@@ -451,6 +456,148 @@ def gathered_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                  .transpose(0, 2, 1, 3))
     return slot_decode_attention(q, flat(k_pages), flat(v_pages), lengths,
                                  scale=scale, kv_block=kv_block)
+
+
+def latent_decode_path(q_shape, pool_shape, pool_dtype, *, mesh=None) -> str:
+    """Which implementation :func:`paged_latent_decode_attention` runs
+    over a latent pool (L, n_pages, page_size, row), decided like
+    :func:`paged_decode_path` from backend, shapes and dtypes:
+    ``"pages"`` (``ops.paged_attention.paged_latent_pages``: the live
+    pages read out of the pool, once, as keys and values) on a TPU over
+    a bfloat16 pool it takes as stored (``takes_latent``: rows of whole
+    lane tiles) and no mesh; ``"gathered"`` everywhere else. ``q_shape``
+    is the query as the pool's rows are wide."""
+    from .paged_attention import takes_latent
+    if (jax.default_backend() == "tpu" and mesh is None
+            and takes_latent(q_shape, pool_shape, pool_dtype)):
+        return "pages"
+    return "gathered"
+
+
+def paged_latent_decode_attention(q, pool, page_table, lengths, *, layer,
+                                  value_dim: int,
+                                  scale: Optional[float] = None):
+    """Decode attention in the absorbed form over a PAGED pool of
+    latent rows: every head reads the same row a token, which is its
+    key and, in its first ``value_dim`` values, its value.
+
+    q: (slots, n_heads, 1, row): a head's query carried into the
+    latent space, its rope part behind it. pool: the whole (L, n_pages,
+    page_size, >= row) pool, read at (``layer``, page) by index; a
+    stored row may end in zeros (padding to whole lane tiles), which
+    the query is padded to meet. page_table: (slots, pages_per_slot)
+    int32, every entry in [0, n_pages). lengths: (slots,), slot i
+    attends ``[0, lengths[i])``. Returns (slots, n_heads, 1, value_dim)
+    float32, the softmax-weighted sum of the rows' value parts (a slot
+    of length 0: zeros).
+
+    Two carriers (:func:`latent_decode_path`), both under
+    ``mla_attention``. **pages**: the Pallas kernel walks each slot's
+    live pages (``paged_latent_attention_pages`` in a trace); nothing
+    is gathered. **gathered**: :func:`gathered_latent_decode_attention`."""
+    row = pool.shape[-1]
+    scale = float(scale if scale is not None
+                  else 1.0 / math.sqrt(q.shape[-1]))
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, row - q.shape[-1])))
+    if latent_decode_path(q.shape, pool.shape, pool.dtype) == "pages":
+        from .paged_attention import paged_latent_pages
+        with jax.named_scope(MLA_SCOPE):
+            return paged_latent_pages(
+                q, pool, page_table, lengths, layer=layer, scale=scale
+            )[..., :value_dim].astype(jnp.float32)
+    return gathered_latent_decode_attention(
+        q, pool, page_table, lengths, layer=layer, value_dim=value_dim,
+        scale=scale)
+
+
+def gathered_latent_decode_attention(q, pool, page_table, lengths, *, layer,
+                                     value_dim: int, scale: float):
+    """:func:`paged_latent_decode_attention`'s gathered arm, whatever
+    the inputs (q as wide as the pool's rows): every slot's whole row of
+    pages copied out under ``kv_gather``; scores over the whole capacity
+    (slots x heads x capacity floats: the row is shared, so there is no
+    per-head copy to block over), softmax in float32, the weights
+    rounded to the rows' type for the value product. What the kernel is
+    held against, in the tests and on the chip."""
+    slots, per_slot = page_table.shape
+    page_size, row = pool.shape[-2:]
+    with jax.named_scope(KV_GATHER_SCOPE):
+        rows = pool.at[layer, page_table].get(mode="promise_in_bounds")
+        rows = rows.reshape(slots, per_slot * page_size, row)
+    with jax.named_scope(MLA_SCOPE):
+        s = jnp.einsum("bhsd,bkd->bhsk", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        allowed = (jnp.arange(rows.shape[1])[None, :]
+                   < lengths.astype(jnp.int32)[:, None])[:, None, None, :]
+        s = jnp.where(allowed, s, _NEG_INF)
+        e = jnp.where(allowed, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+        denom = e.sum(-1, keepdims=True)
+        p = e / jnp.where(denom == 0.0, 1.0, denom)
+        # over the whole row, the small result cut to its value part:
+        # a slice of the gathered rows would be a copy of them
+        return jnp.einsum("bhsk,bkv->bhsv", p.astype(rows.dtype), rows,
+                          preferred_element_type=jnp.float32
+                          )[..., :value_dim]
+
+
+def latent_prefill_attention(q_nope, q_rope, rows, wkvb, *, layer,
+                             q_offset, scale: float, kv_block: int = 512):
+    """Causal attention of a run of queries over latent rows in the
+    DECOMPRESSED form: a block of keys at a time, per-head keys and
+    values are rebuilt from the rows (``[k_nope, v] = c W_kvb``), ``score
+    = q_nope . k_nope + q_rope . k_rope`` with the row's rope part
+    shared by every head, online softmax in float32
+    (:func:`_online_block`'s arithmetic).
+
+    q_nope: (b, H, s, nope), q_rope: (b, H, s, rope), the queries at
+    positions ``q_offset .. q_offset + s`` (``q_offset`` a traced
+    scalar). rows: the whole (L, b, capacity, >= rank + rope) row
+    store, read at ``layer``; position p's row at index p (zeros may
+    follow its rope part); ``capacity`` a multiple of ``kv_block``. wkvb: (rank, H, nope + v). Only the key
+    blocks below ``q_offset + s`` are read, so a chunk early in a
+    prompt costs less than one late in it. Returns (b, H, s, v)
+    float32."""
+    b, H, s, nope = q_nope.shape
+    scale = float(scale)
+    rank = wkvb.shape[0]
+    dv = wkvb.shape[-1] - nope
+    q_offset = jnp.asarray(q_offset, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    qpos = q_offset + jnp.arange(s, dtype=jnp.int32)
+    at = jnp.arange(kv_block, dtype=jnp.int32)
+    z = jnp.zeros((), jnp.int32)
+
+    def body(i, carry):
+        m, l, o = carry
+        i = i.astype(jnp.int32)       # under x64 the loop counts in 64
+        blk = lax.dynamic_slice(
+            rows, (layer, z, i * kv_block, z),
+            (1, b, kv_block, rows.shape[-1]))[0]
+        c = blk[..., :rank]
+        k_rope = blk[..., rank:rank + q_rope.shape[-1]]
+        kv = jnp.einsum("bkr,rhn->bhkn", c, wkvb)
+        sc = (jnp.einsum("bhqd,bhkd->bhqk", q_nope, kv[..., :nope],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhqd,bkd->bhqk", q_rope, k_rope,
+                           preferred_element_type=jnp.float32)) * scale
+        allowed = ((i * kv_block + at)[None, :]
+                   <= qpos[:, None])[None, None]
+        sc = jnp.where(allowed, sc, _NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        corr = jnp.exp(m - m_new)
+        p = jnp.where(allowed, jnp.exp(sc - m_new[..., None]), 0.0)
+        l_new = l * corr + p.sum(axis=-1)
+        o_new = o * corr[..., None] + jnp.einsum(
+            "bhqk,bhkv->bhqv", p.astype(kv.dtype), kv[..., nope:],
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, o_new
+
+    init = (jnp.full((b, H, s), _NEG_INF, jnp.float32),
+            jnp.zeros((b, H, s), jnp.float32),
+            jnp.zeros((b, H, s, dv), jnp.float32))
+    n_blocks = (q_offset + s + kv_block - 1) // kv_block
+    m, l, o = lax.fori_loop(z, n_blocks, body, init)
+    return _finalize(m, l, o, jnp.float32)
 
 
 def ring_attention(q, k, v, *, axis_name: str = "sp",

@@ -35,6 +35,13 @@ latching the keys, not streaming a handful of query rows past them.
 Written from ``jax.experimental.pallas.ops.tpu.ragged_paged_attention``
 (jax 0.9.0), which wants K and V interleaved on the head axis of ONE
 pool; this pool keeps K and V apart, so each is walked by its own DMAs.
+
+A LATENT pool (``models/latent_moe.py``: (L, n_pages, page_size, row),
+one row a token and layer, read by every head as its key and, in its
+first part, as its value) is the same walk with one pool in both
+roles (:func:`paged_latent_pages`): a page is DMAed once, every query
+head is of the one "KV head", and the output is the softmax-weighted
+sum of whole rows, which the caller cuts to the value part.
 """
 from __future__ import annotations
 
@@ -48,10 +55,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_attention_pages", "takes", "KERNEL_NAME"]
+__all__ = ["paged_attention_pages", "takes", "KERNEL_NAME",
+           "paged_latent_pages", "takes_latent", "LATENT_KERNEL_NAME"]
 
-# the kernel's name as a device trace prints it
+# the kernels' names as a device trace prints them
 KERNEL_NAME = "paged_decode_attention_pages"
+LATENT_KERNEL_NAME = "paged_latent_attention_pages"
 
 _NEG_INF = -1e30    # ops.attention's finite "minus infinity"
 # bytes of K (and of V) to a DMA block, two of each in VMEM; half a block
@@ -101,7 +110,30 @@ def _kernel(layer_ref, table_ref, lengths_ref,      # scalar prefetch
             o_ref,                                  # (slots, hq, hd)
             kbuf, vbuf,                 # (2, ppb, ps * kvh, hd), VMEM
             ksem, vsem,                 # DMA semaphores, one a buffer
-            *, scale: float, page_size: int, kvh: int, cpages: int):
+            **static):
+    _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref,
+          (k_hbm, kbuf, ksem), (v_hbm, vbuf, vsem), **static)
+
+
+def _latent_kernel(layer_ref, table_ref, lengths_ref,
+                   q_ref,                           # (slots, hq, row)
+                   pool_hbm,                # (L, n_pages, ps, row), HBM
+                   o_ref,                           # (slots, hq, row)
+                   buf, sem, **static):
+    """The walk over ONE pool whose rows are key and value at once."""
+    rows = (pool_hbm, buf, sem)
+    _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, rows, rows,
+          kvh=1, **static)
+
+
+def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
+          *, scale: float, page_size: int, kvh: int, cpages: int):
+    """The kernels' body. ``keys`` and ``values`` are (pool in HBM,
+    (2, ppb, rows a page, hd) VMEM buffer, DMA semaphores): two pools,
+    or one pool twice, whose pages are then copied once."""
+    k_hbm, kbuf, ksem = keys
+    v_hbm, vbuf, vsem = values
+    pools = (keys,) if values is keys else (keys, values)
     n_slots, hq, hd = q_ref.shape
     rep = hq // kvh
     per_slot = table_ref.shape[1]
@@ -127,7 +159,7 @@ def _kernel(layer_ref, table_ref, lengths_ref,      # scalar prefetch
     def start(slot, b, buf):
         def page(i, _):
             phys = table_ref[slot, b * _i32(ppb) + i]
-            for hbm, vm, sem in ((k_hbm, kbuf, ksem), (v_hbm, vbuf, vsem)):
+            for hbm, vm, sem in pools:
                 pltpu.make_async_copy(hbm.at[layer, phys], vm.at[buf, i],
                                       sem.at[buf]).start()
         lax.fori_loop(_i32(0), pages_in(slot, b), page, None)
@@ -140,8 +172,7 @@ def _kernel(layer_ref, table_ref, lengths_ref,      # scalar prefetch
         while size:
             @pl.when((n & _i32(size)) != _i32(0))
             def _():
-                for hbm, vm, sem in ((k_hbm, kbuf, ksem),
-                                     (v_hbm, vbuf, vsem)):
+                for hbm, vm, sem in pools:
                     pltpu.make_async_copy(
                         hbm.at[layer, pl.ds(0, size)],
                         vm.at[buf, pl.ds(0, size)], sem.at[buf]).wait()
@@ -280,3 +311,62 @@ def paged_attention_pages(q, k_pages, v_pages, page_table, lengths, *,
     )(_i32(layer).reshape(1), _i32(page_table), _i32(lengths),
       q.reshape(slots, hq, hd), view(k_pages), view(v_pages))
     return out.reshape(slots, hq, 1, hd)
+
+
+def takes_latent(q_shape, pool_shape, pool_dtype) -> bool:
+    """Whether :func:`paged_latent_pages` takes a latent pool (L,
+    n_pages, page_size, row) as it is stored: one query a slot, as wide
+    as the row; a bfloat16 pool whose rows are whole lane tiles and
+    whose pages are whole (16, 128) tiles for the DMAs; queries that fit
+    VMEM beside the page buffers."""
+    page_size, row = pool_shape[-2:]
+    return (len(q_shape) == 4 and q_shape[2] == 1 and q_shape[3] == row
+            and jnp.dtype(pool_dtype) == jnp.bfloat16
+            and row % 128 == 0 and page_size % 16 == 0
+            and page_size * row * 2 <= _BLOCK_BYTES
+            and 2 * math.prod(q_shape) * 2 <= _QUERY_BYTES)
+
+
+def paged_latent_pages(q, pool, page_table, lengths, *, layer,
+                       scale: Optional[float] = None,
+                       block_pages: Optional[int] = None,
+                       chunk_pages: Optional[int] = None,
+                       interpret: bool = False):
+    """Decode attention over the live pages of a latent pool. q:
+    (slots, n_heads, 1, row); pool: the whole (L, n_pages, page_size,
+    row) pool, read at ``layer`` (a traced scalar); page_table: (slots,
+    pages_per_slot) int32, every entry in ``[0, n_pages)``; lengths:
+    (slots,) int. Returns (slots, n_heads, 1, row) in q's dtype: for
+    each head the softmax over ``q . row`` of the slot's rows ``[0,
+    lengths[s])`` times the rows themselves; a slot of length 0 gives
+    zeros, and no page past ``ceil(lengths[s] / page_size)`` is read.
+    :func:`paged_attention_pages`'s walk with one pool as keys and
+    values; ``block_pages``, ``chunk_pages`` and ``interpret`` as
+    there."""
+    n_layers, n_pages, page_size, row = pool.shape
+    slots, hq, sq, _ = q.shape
+    if sq != 1 or lengths.ndim != 1:
+        raise ValueError("one query a slot")
+    per_slot = page_table.shape[1]
+    page_bytes = page_size * row * pool.dtype.itemsize
+    ppb = block_pages or max(1, _BLOCK_BYTES // page_bytes)
+    ppb = 1 << (min(ppb, per_slot).bit_length() - 1)    # a power of two
+    cpages = min(chunk_pages or max(1, ppb // 2), ppb)
+    scale = scale if scale is not None else 1.0 / math.sqrt(row)
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, scale=float(scale),
+                          page_size=page_size, cpages=cpages),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, page_size, row), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((slots, hq, row), q.dtype),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name=LATENT_KERNEL_NAME,
+    )(_i32(layer).reshape(1), _i32(page_table), _i32(lengths),
+      q.reshape(slots, hq, row), pool)
+    return out.reshape(slots, hq, 1, row)

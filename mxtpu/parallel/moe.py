@@ -24,6 +24,7 @@ parallelism as the one NEW-era strategy the reference lacked).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Optional
 
@@ -33,7 +34,15 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["init_moe_params", "moe_ffn", "moe_ffn_dense",
-           "load_balance_loss"]
+           "load_balance_loss", "route_sigmoid", "moe_ffn_dropless"]
+
+# the named scopes of a routed expert layer (models/latent_moe.py opens
+# each at the top level of its layer): the router; the dispatch (sort,
+# permute, unpermute, combine); the grouped products; the shared expert
+ROUTER_SCOPE = "moe_router"
+DISPATCH_SCOPE = "moe_dispatch"
+EXPERTS_SCOPE = "moe_experts"
+SHARED_SCOPE = "moe_shared"
 
 
 def init_moe_params(key, dim: int, hidden: int, n_experts: int,
@@ -218,3 +227,133 @@ def load_balance_loss(probs, first_choice):
                  axis=0)
     pbar = jnp.mean(probs, axis=0)
     return E * jnp.sum(f * pbar)
+
+
+# ---------------------------------------------------------------------------
+# A dropless routed layer for serving: every token reaches the experts
+# it chose, whatever the others chose, and an expert nobody chose is
+# not read. One code path for a 32-token decode step and a 1024-token
+# prefill chunk. ``moe_ffn_dense`` above (every token through every
+# expert) is what its tests hold it against.
+# ---------------------------------------------------------------------------
+@jax.named_scope(ROUTER_SCOPE)
+def route_sigmoid(x, w_router, bias, *, top_k: int, renorm: bool = True,
+                  scale: float = 1.0):
+    """The bias-corrected sigmoid router (DeepSeek-V3's ``noaux_tc``
+    with one group): ``s = sigmoid(float32(x) W_r)``; the ``top_k``
+    experts by ``s + bias``; their weights ``s`` itself (the bias
+    changes who is chosen, not how much they count), divided by their
+    sum (+ 1e-20) if ``renorm``, times ``scale``. The product is in
+    float32 at ``highest`` precision whatever x's type: a near-tie
+    decided in bfloat16 is another choice. x: (T, d); w_router: (d, E);
+    bias: (E,). Returns (idx (T, top_k) int32, weights (T, top_k)
+    float32)."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renorm:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+# rows of a tile of the grouped product (``megablox.gmm``), and the most
+# of the contraction and of the output columns it takes at once: at this
+# family's widths a group's WHOLE matrix (2048 x 768, 768 x 2048: 3.1
+# MB) is one tile. Timed on a v5e at 192 and 6,144 rows over 128 groups
+# against ``lax.ragged_dot`` and narrower tiles (256 output columns: the
+# down projection at 0.67 ms for 0.46; PERF.md section 6, PR 31)
+_GMM_TILE = (192, 2048, 2048)
+
+
+def grouped_matmul_kernel(lhs, rhs, group_sizes, tm: int,
+                          interpret: bool = False):
+    """``megablox.gmm``, the Pallas grouped matmul that ships with jax:
+    it visits the (row tile, group) pairs that hold rows and reads no
+    other group's matrix. lhs: (m, k), m a multiple of ``tm``, rows
+    sorted by group; rhs: (G, k, n); group_sizes: (G,) int32. Rows past
+    the groups' total come out undefined. ``interpret`` runs it in
+    Pallas' interpret mode (the tests, on a CPU)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    # a bfloat16 product names its precision: Mosaic refuses one at
+    # float32 contract precision, which a process-wide
+    # ``jax_default_matmul_precision=highest`` would ask of it
+    ambient = (jax.default_matmul_precision("default")
+               if lhs.dtype == jnp.bfloat16 else contextlib.nullcontext())
+    # and its tile counts are 32-bit: traced under x64 (the tests) the
+    # library's own arithmetic hands the kernel a 64-bit scalar, which
+    # the TPU compiler does not take
+    with ambient, jax.enable_x64(False):
+        return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                   tiling=(tm, min(_GMM_TILE[1], rhs.shape[1]),
+                           min(_GMM_TILE[2], rhs.shape[2])),
+                   interpret=interpret)
+
+
+def _grouped_dot(lhs, rhs, group_sizes, tm: int):
+    """``lhs[rows of group g] @ rhs[g]`` for every group with rows. On a
+    TPU :func:`grouped_matmul_kernel`; elsewhere ``lax.ragged_dot``,
+    XLA's plain lowering of the same product, which the kernel is
+    tested against (decided from the backend alone, as
+    ``ops.attention._flash_path`` is)."""
+    if jax.default_backend() == "tpu":
+        return grouped_matmul_kernel(lhs, rhs, group_sizes, tm)
+    return lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def moe_ffn_dropless(bank, x, idx, weights, *, layer=None, valid=None):
+    """``out[t] = sum_k weights[t, k] * E_idx[t, k](x[t])`` over SwiGLU
+    experts, dropless: the (token, choice) assignments are sorted by
+    expert, each expert runs on its run of rows in ONE grouped product
+    a projection (:func:`_grouped_dot`: an expert with no row is not
+    read), and the rows go back to their tokens, weighted, and are
+    summed.
+
+    bank: ``w_gate``/``w_up`` (E, d, f) and ``w_down`` (E, f, d), or the
+    whole stack's (L, E, ..) with ``layer`` (a traced scalar) saying
+    which layer's experts to use: the stack is then handed to the
+    product as it is stored, as L x E groups of which this layer's have
+    rows (a layer's slab cut out of it would be a copy of it). x:
+    (T, d); idx, weights: (T, K) from a router. valid: (T,) bool, the
+    tokens that are real; the others are assigned nowhere and come out
+    zero. Returns (out (T, d) in x's type, the experts' loads (E,)
+    int32)."""
+    T, d = x.shape
+    K = idx.shape[1]
+    wg, wu, wd = bank["w_gate"], bank["w_up"], bank["w_down"]
+    E = wg.shape[-3]
+    # the sorted rows, padded to whole tiles of the grouped product
+    tm = min(_GMM_TILE[0], -(-T * K // 8) * 8)
+    rows = -(-T * K // tm) * tm
+    with jax.named_scope(DISPATCH_SCOPE):
+        flat = idx.reshape(T * K)
+        if valid is not None:      # past every expert: sorted to the end
+            flat = jnp.where(jnp.repeat(valid, K), flat, E)
+        flat = jnp.pad(flat, (0, rows - T * K), constant_values=E)
+        sorted_e, order = lax.sort_key_val(
+            flat, jnp.arange(rows, dtype=jnp.int32))
+        sizes = (sorted_e[:, None] == jnp.arange(E, dtype=jnp.int32)
+                 ).sum(0, dtype=jnp.int32)
+        groups = sizes
+        if layer is not None:
+            L = wg.shape[0]
+            wg, wu, wd = (a.reshape((L * E,) + a.shape[2:])
+                          for a in (wg, wu, wd))
+            groups = lax.dynamic_update_slice(
+                jnp.zeros((L * E,), jnp.int32), sizes,
+                (jnp.asarray(layer, jnp.int32) * E,))
+        # a padding row reads the last token: it belongs to no group
+        xs = x[jnp.minimum(order // K, T - 1)]
+    with jax.named_scope(EXPERTS_SCOPE):
+        h = jax.nn.silu(_grouped_dot(xs, wg, groups, tm)) \
+            * _grouped_dot(xs, wu, groups, tm)
+        ys = _grouped_dot(h, wd, groups, tm)
+    with jax.named_scope(DISPATCH_SCOPE):
+        # rows past the last group hold no expert's output
+        ys = jnp.where((sorted_e < E)[:, None], ys, 0)
+        back = jnp.zeros((rows,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32))
+        y = ys[back[:T * K]].reshape(T, K, d).astype(jnp.float32)
+        out = (y * weights[..., None]).sum(1).astype(x.dtype)
+    return out, sizes

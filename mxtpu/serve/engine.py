@@ -657,6 +657,15 @@ class ServeEngine:
             partial(fam.decode_slots_paged, cfg, mesh=mesh),
             "serve_decode", "decode_slots_paged", loop="serve",
             donate_argnums=(1,))
+        # what the family's decode program counts on the device: the
+        # values ride behind the sampled tokens in the array _process
+        # reads back anyway (the family's STEP_COUNTS names the series;
+        # a histogram's value is the int over its ``per``)
+        self._step_counts = [
+            (telemetry.histogram(c["name"], c["help"], buckets=c["buckets"]),
+             c["per"]) if "buckets" in c
+            else (telemetry.counter(c["name"], c["help"]), 0)
+            for c in getattr(fam, "STEP_COUNTS", ())]
         self._prefills: Dict[Any, Any] = {}
         self._injects: Dict[int, Any] = {}
         self._spec_decode = None
@@ -758,16 +767,18 @@ class ServeEngine:
         for k, nbytes in by_kind.items():
             telemetry.gauge(
                 "serve_state_bytes", "Bytes of the engine's donated "
-                "device state, by kind: kv_pages, window_ring, ssm",
+                "device state, by kind: kv_pages, latent_pages, "
+                "window_ring, ssm",
                 engine=eid, kind=k).set(nbytes)
         # reserved counts everything donated (the scratch page too — it
-        # is real HBM); per-token bytes are the pages' over the tokens
+        # is real HBM); per-token bytes are the pages' (the kinds named
+        # ``*_pages``: keys and values, or latent rows) over the tokens
         # they can hold (scale planes included in int8 mode), per-slot
         # bytes the fixed kinds' over the slots
         self._kv_reserved = sum(by_kind.values())
-        self._kv_tok_bytes = by_kind["kv_pages"] // (
-            self.n_pages * self.page_size)
-        self._slot_state_bytes = ((self._kv_reserved - by_kind["kv_pages"])
+        paged = sum(n for k, n in by_kind.items() if k.endswith("_pages"))
+        self._kv_tok_bytes = paged // (self.n_pages * self.page_size)
+        self._slot_state_bytes = ((self._kv_reserved - paged)
                                   // self.max_slots)
         self._m["pages_total"].set(self.n_pages - 1)
         self._m["pages_free"].set(self._pages.free_pages)
@@ -1450,7 +1461,11 @@ class ServeEngine:
                 # or the jit cache key. A slot whose prompt is still
                 # in chunks is seated but does not run: inactive, and
                 # its row the scratch page's, like a free slot's
-                active, pt = self._active, self._pt
+                # (a copy of the mask: the CPU backend reads a numpy
+                # operand where it lies, while the program runs, and
+                # _process edits _active under the next step; a program
+                # that reads it in every layer would see it change)
+                active, pt = self._active.copy(), self._pt
                 if self._prefilling.any():
                     active = active & ~self._prefilling
                     pt = pt.copy()
@@ -1517,6 +1532,13 @@ class ServeEngine:
             firsts = [(rid, int(np.asarray(dev)[0]))
                       for rid, dev in disp.firsts]
         now = time.perf_counter()
+        if sampled is not None and self._step_counts:
+            sampled, counts = np.split(sampled, [self.max_slots])
+            for (series, per), value in zip(self._step_counts, counts):
+                if per:
+                    series.observe(float(value) / per)
+                else:
+                    series.inc(int(value))
         with self._span_emit(), self._lock:
             rid2slot = ({rid: s for s, rid in
                          enumerate(self._slot_rid) if rid is not None}

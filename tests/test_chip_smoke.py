@@ -54,6 +54,14 @@ def test_pages_kernel_phase_runs_tiny_on_cpu():
     assert info["largest_output"] > 0.0
 
 
+def test_latent_kernel_phase_runs_tiny_on_cpu():
+    info = chip_smoke.phase_latent_kernel(
+        slots=6, n_heads=4, row=128, value_dim=96, page_size=16,
+        capacity=64, layers=2, interpret=True)
+    assert info["lengths"] == [1, 64]
+    assert info["largest_output"] > 0.0
+
+
 def test_serve_sambay_phase_runs_tiny_on_cpu():
     """The second family's leg: every kind of layer at toy widths,
     prompts over three windows, both passes float32 here."""
@@ -62,9 +70,25 @@ def test_serve_sambay_phase_runs_tiny_on_cpu():
     jobs = chip_smoke.make_jobs(cfg.vocab_size,
                                 ((11, 5, 0.0), (30, 6, 0.0)),
                                 per_shape=2, shared_prefix=0)
-    info = chip_smoke.phase_serve_sambay(
+    info = chip_smoke.phase_serve_family(
         cfg, jobs, max_slots=2, max_len=96, min_bucket=16, page_size=8)
     assert info["requests"] == 8
+    assert info["worst_gap_float32"] <= 1e-3
+
+
+def test_serve_latent_moe_phase_runs_tiny_on_cpu():
+    """The third family's leg: latent attention and routed experts at
+    toy widths, the longer prompt in two chunks, both passes float32
+    here."""
+    from mxtpu.models import latent_moe
+    cfg = latent_moe.CONFIGS["tiny"]
+    jobs = chip_smoke.make_jobs(cfg.vocab_size,
+                                ((11, 5, 0.0), (30, 6, 0.0)),
+                                per_shape=1, shared_prefix=0)
+    info = chip_smoke.phase_serve_family(
+        cfg, jobs, max_slots=2, max_len=96, min_bucket=16, page_size=8,
+        prefill_chunk=16)
+    assert info["requests"] == 4
     assert info["worst_gap_float32"] <= 1e-3
 
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxtpu.models import llama, sambay
+from mxtpu.models import latent_moe, llama, sambay
 from mxtpu.telemetry import scopes as tscopes
 
 # Mistral's head shapes (32 query / 8 kv heads of 128); depth, FFN and
@@ -355,3 +355,70 @@ def test_tpu_sampler_sorts_values_once_and_gathers_nothing(
     assert math.prod(int(d) for d in re.match(
         r"f32\[([\d,]*)\]", shape).group(1).split(",")) == rows * vocab
     assert all(n <= rows for n in gathers), gathers
+
+
+# -- the latent-attention, routed-expert family -------------------------------
+@pytest.fixture(scope="module")
+def latent_moe_decode(one_chip):
+    """``latent_moe.decode_slots_paged`` at the published attention and
+    expert widths (32 heads of 128 + 64, a 512 + 64 row stored 640 wide,
+    128 experts of 2048 x 768 top-6), one dense and seven expert
+    layers, a 32768-row vocabulary: a 169 MB pool (more than the chip's
+    VMEM) and a 4.2 GB expert bank, as shapes."""
+    cfg = latent_moe.LatentMoEConfig(n_layers=8, vocab_size=32768,
+                                     max_seq_len=512)
+    decode, _, kv, _ = _lower_decode(latent_moe, cfg, one_chip)
+    return cfg, kv, _compile_all({"decode": decode})["decode"]
+
+
+def test_tpu_latent_decode_leaves_the_pool_and_the_bank_in_place(
+        latent_moe_decode):
+    """The one-token write updates the donated pool where it lies, and
+    the expert bank reaches the grouped products as it is stored: the
+    program's temporaries are the step's activations (no gathered
+    row exists: the attention kernel reads the pool where it lies). A 576-wide row is laid out page-minor by the v5e and
+    copied whole around the write (1.68 GB at the benchmark's size); a
+    layer's slab of the bank cut out by the layer scan is 600 MB."""
+    cfg, kv, exe = latent_moe_decode
+    pool = math.prod(kv["latent"].shape) * 2
+    assert kv["latent"].shape == (8, N_PAGES, PAGE, 640)
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool
+    slab = cfg.n_routed_experts * cfg.dim * cfg.moe_hidden_dim * 2
+    assert mem.temp_size_in_bytes < min(pool, slab) / 2, mem
+    # three grouped products (the Pallas kernel ``gmm``), each over the
+    # whole stack's 7 x 128 groups
+    text = exe.as_text()
+    calls = [ln for ln in text.splitlines()
+             if re.match(r"\s*%?gmm[.\d]* = ", ln)]
+    assert len(calls) == 3, len(calls)
+    assert all("tpu_custom_call" in ln for ln in calls)
+    assert sum("bf16[896,2048,768]" in ln for ln in calls) == 2
+    assert sum("bf16[896,768,2048]" in ln for ln in calls) == 1
+
+
+@pytest.mark.parametrize("scope", ["moe_router", "moe_dispatch",
+                                   "moe_experts", "moe_shared",
+                                   "mla_attention", "kv_write", "sampler"])
+def test_tpu_latent_decode_operations_land_under_its_scopes(
+        latent_moe_decode, scope):
+    """As for llama's program; the grouped products are the kernel
+    ``gmm`` under ``moe_experts``, the attention ONE call a layer of the
+    kernel that walks the latent pool's live pages, under
+    ``mla_attention``, and nothing is gathered."""
+    from mxtpu.ops.paged_attention import LATENT_KERNEL_NAME
+    _, scopes = tscopes.scope_map(latent_moe_decode[2].as_text())
+    shown = {name: path.split("/")[0] for name, (path, _) in
+             scopes.items() if re.search(
+                 rf"fusion|^copy|custom-call|^gmm|^{LATENT_KERNEL_NAME}",
+                 name)}
+    walks = [n for n in shown if n.startswith(LATENT_KERNEL_NAME)]
+    assert [shown[n] for n in walks] == 2 * ["mla_attention"]
+    assert "kv_gather" not in set(shown.values())
+    assert scope in set(shown.values()), sorted(set(shown.values()))
+    assert set(shown.values()) <= {
+        "", "embed", "norm", "qkv_proj", "rope", "kv_write", "kv_gather",
+        "mla_attention", "out_proj", "mlp", "lm_head", "sampler",
+        "moe_router", "moe_dispatch", "moe_experts", "moe_shared"}
+    assert {shown[n] for n in shown if n.startswith("gmm")} == {
+        "moe_experts"}
