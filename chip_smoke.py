@@ -600,7 +600,77 @@ def phase_latent_kernel(*, slots=32, n_heads=32, row=640, value_dim=512,
             "largest_difference": worst, "largest_output": largest}
 
 
-def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
+def phase_sambay_kernel(*, slots=32, n_heads=40, kv_heads=10, head_dim=128,
+                        page_size=16, capacity=6144, lengths=(1024, 4400),
+                        reads=8, interpret=False):
+    """:func:`phase_pages_kernel` for two pools of token rows
+    (``ops.paged_attention.paged_attention_rows`` against
+    ``ops.attention.gathered_rows_decode_attention``) at the agent
+    cell's shapes: ``models/sambay.py``'s one-layer pool, a token's 10
+    paired KV heads of 128 end to end, 40 query heads, ``slots`` slots
+    whose lengths are drawn from ``lengths`` (both ends among them).
+    Also times the kernel alone: ``kernel_ms`` is one of the decode
+    step's ``reads`` walks, ``memory_speed_share`` the live K and V
+    bytes over that time against the chip's 819 GB/s."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxtpu.ops.attention import gathered_rows_decode_attention
+    from mxtpu.ops.paged_attention import paged_attention_rows
+
+    t0 = time.perf_counter()
+    per_slot = capacity // page_size
+    n_pages = 1 + slots * per_slot
+    rng = np.random.default_rng(0)
+    lens = rng.integers(lengths[0], lengths[1] + 1, slots).astype(np.int32)
+    lens[:3] = lengths[0], lengths[0] + page_size + 1, lengths[1]
+    table = (1 + rng.permutation(n_pages - 1)).astype(np.int32).reshape(
+        slots, per_slot)
+    shape = (1, n_pages, page_size, kv_heads * head_dim)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    pool = jax.jit(lambda k: jax.random.normal(k, shape, jnp.bfloat16))
+    kp, vp = pool(keys[0]), pool(keys[1])
+    q = jax.random.normal(keys[2], (slots, n_heads, 1, head_dim),
+                          jnp.bfloat16)
+    scale = (head_dim // 2) ** -0.5             # a paired head is two
+    kernel = jax.jit(lambda *a: paged_attention_rows(
+        *a, layer=0, scale=scale, interpret=interpret))
+    gathered = jax.jit(lambda *a: gathered_rows_decode_attention(
+        *a, layer=0, scale=scale))
+    args = (q, kp, vp, jnp.asarray(table), jnp.asarray(lens))
+    got = np.asarray(kernel(*args), np.float32)
+    setup_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    want = np.asarray(gathered(*args), np.float32)
+    worst, largest = float(np.abs(got - want).max()), float(
+        np.abs(want).max())
+    assert np.isfinite(got).all()
+    assert worst <= 4 * 2.0 ** -8 * max(1.0, largest), (worst, largest)
+
+    @jax.jit
+    def step(q, *rest):         # the decode step's chain of reads
+        for _ in range(reads):
+            q = q + paged_attention_rows(q, *rest, layer=0, scale=scale,
+                                         interpret=interpret)
+        return q
+    step(*args).block_until_ready()
+    t1 = time.perf_counter()
+    rounds = 1 if interpret else 20
+    for _ in range(rounds):
+        out = step(*args)
+    out.block_until_ready()
+    kernel_ms = (time.perf_counter() - t1) * 1e3 / rounds / reads
+    live_bytes = 2 * int(lens.sum()) * kv_heads * head_dim * 2
+    return {"setup_s": setup_s, "run_s": time.perf_counter() - t0,
+            "slots": slots, "lengths": [int(lens.min()), int(lens.max())],
+            "pool_bytes": 2 * int(np.prod(shape)) * 2,
+            "largest_difference": worst, "largest_output": largest,
+            "kernel_ms": kernel_ms, "live_bytes": live_bytes,
+            "memory_speed_share": live_bytes / (kernel_ms * 1e-3) / 819e9}
+
+
+def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
+                       **engine_kw):
     """A serving family other than llama (``models.serving_family(cfg)``:
     ``sambay.py``'s state-space, window, full, GMU and cross-attention
     layers; ``latent_moe.py``'s latent attention and routed experts)
@@ -610,7 +680,10 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
     emitted token's logit in one ``forward`` over prompt + stream is
     held against that position's largest. In float32 the two must agree
     (gap under ``tol_f32``); in bf16 the worst gap is reported (a
-    near-tie may flip, as it does for llama)."""
+    near-tie may flip, as it does for llama). ``expect_attention`` is
+    what the engine must say the first pass's decode program reads its
+    pool through (``"pages"`` on the chip: a bf16 pool the family's
+    kernel takes as stored); the float32 pass gathers everywhere."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -654,6 +727,7 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
                 assert engine.compile_count == 1 + engine.n_buckets
                 kv = engine.kv_cache_stats()
                 assert kv["reserved_bytes"] > 0, kv
+                info[f"decode_attention_{name}"] = kv["decode_attention"]
             finally:
                 gw.close()
             fwd = jax.jit(lambda p, t: family.forward(c, p, t))
@@ -672,6 +746,9 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, **engine_kw):
             info["run_s"] += time.perf_counter() - t0
             t0 = time.perf_counter()
     assert info["worst_gap_float32"] <= tol_f32, info
+    assert info["decode_attention_float32"] == "gathered", info
+    assert expect_attention in (
+        None, info[f"decode_attention_{np.dtype(cfg.dtype).name}"]), info
     return info
 
 
@@ -729,11 +806,13 @@ def main():
     # depth (two Mamba+window pairs, layers "4/5", one GMU+cross pair):
     # prompts longer than three of its 512-token windows
     from mxtpu.models import sambay
+    _run("sambay_kernel", phase_sambay_kernel)
     sambay_cfg = sambay.SambaYConfig(n_layers=8, max_seq_len=2048)
     _run("serve_sambay", phase_serve_family, sambay_cfg,
          make_jobs(sambay_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
                    shared_prefix=0),
-         max_slots=4, max_len=2048, min_bucket=256)
+         max_slots=4, max_len=2048, min_bucket=256,
+         expect_attention="pages")
 
     # the third, at its published widths and a small depth (one dense
     # and two expert layers, all 128 experts): the longer prompt is
@@ -744,7 +823,8 @@ def main():
     _run("serve_latent_moe", phase_serve_family, moe_cfg,
          make_jobs(moe_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
                    shared_prefix=0),
-         max_slots=4, max_len=2048, min_bucket=256, prefill_chunk=1024)
+         max_slots=4, max_len=2048, min_bucket=256, prefill_chunk=1024,
+         expect_attention="pages")
 
     if jax.device_count() >= 4:
         # the same two phases over a mesh with more than one

@@ -27,7 +27,10 @@ with keys and values read once.
 
 Serving keeps three kinds of state (``init_paged_cache``): the full
 layer's keys and values in a one-layer page pool (the only state that
-grows with the sequence; the cross layers read it too), a ring of the
+grows with the sequence; the cross layers read it too: on a TPU every
+read walks the slot's live pages where they lie, ``ops.paged_attention.
+paged_attention_rows``; elsewhere the rows are gathered once a step), a
+ring of the
 last ``sliding_window`` keys and values per window layer and slot, and
 the convolution tail and float32 scan state per Mamba layer and slot.
 A prompt needs the self-decoder only, so the prefill program runs the
@@ -53,7 +56,8 @@ from jax import lax
 
 from ..ops.attention import (ATTENTION_SCOPE, KV_GATHER_SCOPE,
                              blockwise_attention, flash_attention,
-                             slot_decode_attention, window_attention)
+                             rows_decode_path, slot_decode_attention,
+                             window_attention)
 from ..ops.ssm import causal_conv1d, selective_scan, selective_scan_step
 from . import llama
 from .llama import KV_WRITE_SCOPE
@@ -573,11 +577,20 @@ def prefill_logits(cfg: SambaYConfig, params, tokens, true_len,
 # serving state and programs
 # ---------------------------------------------------------------------------
 def decode_attention_path(cfg, kv, mesh=None, *, verify: bool = False) -> str:
-    """Which attention the decode program is built on (``llama.
-    decode_attention_path``): this family gathers its one shared pool
-    once a step (:func:`_gather_rows`) and reads the rows eight times;
-    the pages kernel is llama's so far."""
-    return "gathered"
+    """Which attention :func:`decode_slots_paged` builds its eight reads
+    of the one shared pool on, over the state ``kv`` (arrays or shapes):
+    ``ops.attention.rows_decode_path``'s answer for what the program
+    hands it. ``"pages"``: the full layer and each cross layer walk the
+    live pages where they lie (``ops.paged_attention.
+    paged_attention_rows``), and nothing is gathered; ``"gathered"``:
+    the pool's rows are copied out once a step (:func:`_gather_rows`)
+    and read eight times. Static per compiled program; the engine
+    exports it (``serve_decode_steps_total{attention}``,
+    ``kv_cache_stats()``)."""
+    del verify
+    return rows_decode_path(
+        (kv["wk"].shape[1], cfg.n_heads, 1, 2 * cfg.head_dim),
+        kv["k"].shape, kv["k"].dtype, mesh=mesh)
 
 
 def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
@@ -638,8 +651,9 @@ def _mamba_step(cfg: SambaYConfig, lp, x, conv, ssm, layer):
 @jax.named_scope(KV_GATHER_SCOPE)
 def _gather_rows(cfg, pool, page_table):
     """Every slot's pages of the one-layer pool -> (S, kv_pairs, cap,
-    2 hd) rows. Once a step: the full layer and every cross layer read
-    this one copy."""
+    2 hd) rows: the gathered path's copy of capacity (a CPU, float32
+    pools), made once a step and read by the full layer and every cross
+    layer. The pages path never traces it."""
     g = pool.at[0, page_table].get(mode="promise_in_bounds")
     return _kv_heads(cfg, g.reshape(g.shape[0], -1, g.shape[-1]))
 
@@ -700,10 +714,18 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
     phys = page_table[at, pos // ps]
     ck, cv = llama._write_pages(kv["k"], kv["v"], k[:, 0], v[:, 0], 0,
                                 phys, pos % ps)
-    kf = _gather_rows(cfg, ck, page_table)
-    vf = _gather_rows(cfg, cv, page_table)
-    attend = partial(slot_decode_attention, k=kf, v=vf, lengths=pos + 1,
-                     scale=_scale(cfg))
+    if decode_attention_path(cfg, kv, mesh) == "pages":
+        # eight walks of the live pages: each cross layer's query hangs
+        # on the layer before it, so they cannot share one
+        from ..ops.paged_attention import paged_attention_rows
+        attend = partial(paged_attention_rows, k_pages=ck, v_pages=cv,
+                         page_table=page_table, lengths=pos + 1, layer=0,
+                         scale=_scale(cfg))
+    else:
+        attend = partial(slot_decode_attention,
+                         k=_gather_rows(cfg, ck, page_table),
+                         v=_gather_rows(cfg, cv, page_table),
+                         lengths=pos + 1, scale=_scale(cfg))
     with jax.named_scope(ATTENTION_SCOPE):
         out = _diff_out(cfg, ap, attend(q), lam_full)
     x = _residual_mlp(cfg, ap, x + out)

@@ -27,6 +27,7 @@ __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
            "ring_attention", "slot_decode_attention",
            "paged_decode_attention", "paged_decode_path",
            "gathered_decode_attention", "latent_decode_path",
+           "rows_decode_path", "gathered_rows_decode_attention",
            "paged_latent_decode_attention",
            "gathered_latent_decode_attention", "latent_prefill_attention"]
 
@@ -538,6 +539,48 @@ def gathered_latent_decode_attention(q, pool, page_table, lengths, *, layer,
         return jnp.einsum("bhsk,bkv->bhsv", p.astype(rows.dtype), rows,
                           preferred_element_type=jnp.float32
                           )[..., :value_dim]
+
+
+def rows_decode_path(q_shape, pool_shape, pool_dtype, *, mesh=None) -> str:
+    """Which attention a decode program reads pools of TOKEN rows (L,
+    n_pages, page_size, kvh * hd) through (``models/sambay.py``'s one
+    shared cache: K and V apart, a token's heads end to end), decided
+    like :func:`paged_decode_path` from backend, shapes and dtypes:
+    ``"pages"`` (``ops.paged_attention.paged_attention_rows``: each
+    read walks the slot's live pages where they lie) on a TPU over
+    bfloat16 pools it takes as stored (``takes_rows``: heads of whole
+    lane tiles, pages of whole (16, 128) tiles) and no mesh;
+    ``"gathered"`` everywhere else (every slot's whole row of pages
+    copied out and turned head-major, then
+    :func:`slot_decode_attention`). ``q_shape`` is (slots, n_heads, 1,
+    hd)."""
+    from .paged_attention import takes_rows
+    if (jax.default_backend() == "tpu" and mesh is None
+            and takes_rows(q_shape, pool_shape, pool_dtype)):
+        return "pages"
+    return "gathered"
+
+
+def gathered_rows_decode_attention(q, k_pages, v_pages, page_table, lengths,
+                                   *, layer, scale: Optional[float] = None,
+                                   kv_block: int = 512):
+    """Decode attention over pools of token rows (L, n_pages, page_size,
+    kvh * hd) by the gathered arm, whatever the inputs: every slot's
+    whole row of pages copied out of the pool and turned head-major
+    (under ``kv_gather``), then :func:`slot_decode_attention`. What
+    ``paged_attention_rows`` is held against, in the tests and on the
+    chip."""
+    slots, per_slot = page_table.shape
+    page_size, width = k_pages.shape[-2:]
+    kvh = width // q.shape[-1]
+
+    @jax.named_scope(KV_GATHER_SCOPE)
+    def flat(pool):
+        g = pool.at[layer, page_table].get(mode="promise_in_bounds")
+        return (g.reshape(slots, per_slot * page_size, kvh, width // kvh)
+                 .transpose(0, 2, 1, 3))
+    return slot_decode_attention(q, flat(k_pages), flat(v_pages), lengths,
+                                 scale=scale, kv_block=kv_block)
 
 
 def latent_prefill_attention(q_nope, q_rope, rows, wkvb, *, layer,

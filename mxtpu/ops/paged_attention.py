@@ -42,6 +42,16 @@ first part, as its value) is the same walk with one pool in both
 roles (:func:`paged_latent_pages`): a page is DMAed once, every query
 head is of the one "KV head", and the output is the softmax-weighted
 sum of whole rows, which the caller cuts to the value part.
+
+Two pools of TOKEN rows (``models/sambay.py``: K and V each (L, n_pages,
+page_size, kvh * hd), a token's heads end to end in the lanes) are the
+same walk again (:func:`paged_attention_rows`): a page is DMAed as it is
+stored, KV head ``g`` is the lane slice ``[g * hd, (g + 1) * hd)`` of a
+row, and a query head is placed in its KV head's lanes of an otherwise
+zero row-wide query, so that ONE product of a chunk's rows with all the
+query heads scores every head against its own keys (the zeros cost
+nothing: the MXU latches the same key tiles either way); the value
+product is as wide as a row, and each head keeps its own lanes of it.
 """
 from __future__ import annotations
 
@@ -56,11 +66,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention_pages", "takes", "KERNEL_NAME",
-           "paged_latent_pages", "takes_latent", "LATENT_KERNEL_NAME"]
+           "paged_latent_pages", "takes_latent", "LATENT_KERNEL_NAME",
+           "paged_attention_rows", "takes_rows", "ROWS_KERNEL_NAME"]
 
 # the kernels' names as a device trace prints them
 KERNEL_NAME = "paged_decode_attention_pages"
 LATENT_KERNEL_NAME = "paged_latent_attention_pages"
+ROWS_KERNEL_NAME = "paged_rows_attention_pages"
 
 _NEG_INF = -1e30    # ops.attention's finite "minus infinity"
 # bytes of K (and of V) to a DMA block, two of each in VMEM; half a block
@@ -106,9 +118,10 @@ def takes(q_shape, pool_shape, pool_dtype) -> bool:
 
 def _kernel(layer_ref, table_ref, lengths_ref,      # scalar prefetch
             q_ref,                                  # (slots, hq, hd)
-            k_hbm, v_hbm,                # (L, n_pages, ps * kvh, hd), HBM
+            k_hbm, v_hbm,       # (L, n_pages, ps * kvh, hd), HBM; token
+                                # rows: (L, n_pages, ps, kvh * hd)
             o_ref,                                  # (slots, hq, hd)
-            kbuf, vbuf,                 # (2, ppb, ps * kvh, hd), VMEM
+            kbuf, vbuf,                 # (2, ppb, a page as stored), VMEM
             ksem, vsem,                 # DMA semaphores, one a buffer
             **static):
     _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref,
@@ -127,17 +140,20 @@ def _latent_kernel(layer_ref, table_ref, lengths_ref,
 
 
 def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
-          *, scale: float, page_size: int, kvh: int, cpages: int):
+          *, scale: float, page_size: int, kvh: int, cpages: int,
+          lane_heads: int = 1):
     """The kernels' body. ``keys`` and ``values`` are (pool in HBM,
-    (2, ppb, rows a page, hd) VMEM buffer, DMA semaphores): two pools,
-    or one pool twice, whose pages are then copied once."""
+    (2, ppb, rows a page, lanes a row) VMEM buffer, DMA semaphores): two
+    pools, or one pool twice, whose pages are then copied once. ``kvh``
+    KV heads take turns in a page's rows; ``lane_heads`` lie end to end
+    in a row's lanes (one of the two is 1)."""
     k_hbm, kbuf, ksem = keys
     v_hbm, vbuf, vsem = values
     pools = (keys,) if values is keys else (keys, values)
     n_slots, hq, hd = q_ref.shape
     rep = hq // kvh
     per_slot = table_ref.shape[1]
-    ppb, page_rows = kbuf.shape[1], kbuf.shape[2]
+    ppb, page_rows, width = kbuf.shape[1:]
     crow = cpages * page_rows               # rows of a compute chunk
     layer = layer_ref[0]
     # said here, not left to jax_default_matmul_precision: products of
@@ -184,7 +200,13 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
                        _i32(rep))
     col = lax.broadcasted_iota(jnp.int32, (hq, crow), 1)
     own_head = lax.rem(col, _i32(kvh)) == row_head
-    vrow = lax.broadcasted_iota(jnp.int32, (crow, hd), 0)
+    vrow = lax.broadcasted_iota(jnp.int32, (crow, width), 0)
+    if lane_heads > 1:
+        # query head h reads lanes [h // (hq / lane_heads) * hd, + hd)
+        own_lanes = lax.div(
+            lax.broadcasted_iota(jnp.int32, (hq, width), 1), _i32(hd)
+        ) == lax.div(lax.broadcasted_iota(jnp.int32, (hq, width), 0),
+                     _i32(hq // lane_heads))
 
     def slot(s, buf):
         length = lengths_ref[s]
@@ -192,6 +214,10 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
         # read, nothing computed, and the buffers alternate all the same
         n_blocks = jnp.maximum(_cdiv(live_pages(s), ppb), _i32(1))
         q = q_ref[s]                                        # (hq, hd)
+        if lane_heads > 1:      # each head in its own lanes of a row
+            q = jnp.where(own_lanes,
+                          jnp.concatenate([q] * lane_heads, axis=1),
+                          jnp.zeros((), q.dtype))
 
         def block(b, carry):
             m, l, acc, buf = carry
@@ -214,8 +240,8 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
             def chunk(c, carry):
                 m, l, acc = carry
                 at = pl.ds(pl.multiple_of(c * _i32(cpages), cpages), cpages)
-                k = kbuf[buf, at].reshape(crow, hd)
-                v = vbuf[buf, at].reshape(crow, hd)
+                k = kbuf[buf, at].reshape(crow, width)
+                v = vbuf[buf, at].reshape(crow, width)
                 left = live_rows - c * _i32(crow)
                 scores = lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())), precision=precision,
@@ -245,12 +271,56 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
             _i32(0), n_blocks, block,
             (jnp.full((hq, 1), _NEG_INF, jnp.float32),
              jnp.zeros((hq, 1), jnp.float32),
-             jnp.zeros((hq, hd), jnp.float32), buf))
+             jnp.zeros((hq, width), jnp.float32), buf))
         l = jnp.where(l == _f32(0), _f32(1), l)   # length 0: zeros
-        o_ref[s] = (acc / l).astype(o_ref.dtype)
+        out = acc / l
+        if lane_heads > 1:      # each head's own lanes of the row-wide sum
+            out = jnp.where(own_lanes, out, _f32(0))
+            out = functools.reduce(jnp.add, (
+                out[:, g * hd:(g + 1) * hd] for g in range(lane_heads)))
+        o_ref[s] = out.astype(o_ref.dtype)
         return buf
 
     lax.fori_loop(_i32(0), _i32(n_slots), slot, _i32(0))
+
+
+def _blocks(page_bytes: int, per_slot: int, block_pages, chunk_pages):
+    """(pages a DMA block, pages a compute chunk): ``_BLOCK_BYTES`` of
+    pages, a power of two within a slot's row, and half of it, unless
+    the caller says."""
+    ppb = block_pages or max(1, _BLOCK_BYTES // page_bytes)
+    ppb = 1 << (min(ppb, per_slot).bit_length() - 1)
+    return ppb, min(chunk_pages or max(1, ppb // 2), ppb)
+
+
+def _call(kernel, name, q, pools, page, ppb, layer, page_table, lengths,
+          interpret):
+    """One invocation of a walk. q: (slots, hq, 1, hd), it and the
+    output in VMEM; each of ``pools`` (L, n_pages, ...) whole in HBM,
+    seen as pages of shape ``page`` (llama's (page_size * kvh, hd) is a
+    bitcast of the stored layout; the others' is the stored one), with a
+    (2, ppb) + ``page`` buffer and two DMA semaphores of its own;
+    ``layer``, the page table and the lengths scalar-prefetched."""
+    slots, hq, _, hd = q.shape
+    scalars = (_i32(layer).reshape(1), _i32(page_table), _i32(lengths))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(),        # the slots are looped inside: each hands its
+                            # successor a buffer in flight
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((2, ppb) + page, pool.dtype)
+                            for pool in pools]
+            + [pltpu.SemaphoreType.DMA((2,))] * len(pools)),
+        out_shape=jax.ShapeDtypeStruct((slots, hq, hd), q.dtype),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name=name,
+    )(*scalars, q.reshape(slots, hq, hd),
+      *(pool.reshape(pool.shape[:2] + page) for pool in pools))
+    return out.reshape(q.shape)
 
 
 def paged_attention_pages(q, k_pages, v_pages, page_table, lengths, *,
@@ -279,38 +349,14 @@ def paged_attention_pages(q, k_pages, v_pages, page_table, lengths, *,
                          "lengths take the gathered path")
     if hq % kvh:
         raise ValueError(f"{hq} q heads not divisible by {kvh} kv heads")
-    per_slot = page_table.shape[1]
     page_rows = page_size * kvh
-    page_bytes = page_rows * hd * k_pages.dtype.itemsize
-    ppb = block_pages or max(1, _BLOCK_BYTES // page_bytes)
-    ppb = 1 << (min(ppb, per_slot).bit_length() - 1)    # a power of two
-    cpages = min(chunk_pages or max(1, ppb // 2), ppb)
+    ppb, cpages = _blocks(page_rows * hd * k_pages.dtype.itemsize,
+                          page_table.shape[1], block_pages, chunk_pages)
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
-
-    def view(pool):             # a bitcast of the stored layout
-        return pool.reshape(n_layers, n_pages, page_rows, hd)
-    in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, page_size=page_size,
-                          kvh=kvh, cpages=cpages),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(),        # the slots are looped inside: each hands its
-                            # successor a buffer in flight
-            in_specs=[in_vmem, in_hbm, in_hbm],
-            out_specs=in_vmem,
-            scratch_shapes=[
-                pltpu.VMEM((2, ppb, page_rows, hd), k_pages.dtype),
-                pltpu.VMEM((2, ppb, page_rows, hd), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((2,))]),
-        out_shape=jax.ShapeDtypeStruct((slots, hq, hd), q.dtype),
-        interpret=pltpu.InterpretParams() if interpret else False,
-        name=KERNEL_NAME,
-    )(_i32(layer).reshape(1), _i32(page_table), _i32(lengths),
-      q.reshape(slots, hq, hd), view(k_pages), view(v_pages))
-    return out.reshape(slots, hq, 1, hd)
+    return _call(functools.partial(_kernel, scale=scale, page_size=page_size,
+                                   kvh=kvh, cpages=cpages),
+                 KERNEL_NAME, q, (k_pages, v_pages), (page_rows, hd), ppb,
+                 layer, page_table, lengths, interpret)
 
 
 def takes_latent(q_shape, pool_shape, pool_dtype) -> bool:
@@ -347,26 +393,60 @@ def paged_latent_pages(q, pool, page_table, lengths, *, layer,
     slots, hq, sq, _ = q.shape
     if sq != 1 or lengths.ndim != 1:
         raise ValueError("one query a slot")
-    per_slot = page_table.shape[1]
-    page_bytes = page_size * row * pool.dtype.itemsize
-    ppb = block_pages or max(1, _BLOCK_BYTES // page_bytes)
-    ppb = 1 << (min(ppb, per_slot).bit_length() - 1)    # a power of two
-    cpages = min(chunk_pages or max(1, ppb // 2), ppb)
+    ppb, cpages = _blocks(page_size * row * pool.dtype.itemsize,
+                          page_table.shape[1], block_pages, chunk_pages)
     scale = scale if scale is not None else 1.0 / math.sqrt(row)
-    out = pl.pallas_call(
-        functools.partial(_latent_kernel, scale=float(scale),
-                          page_size=page_size, cpages=cpages),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            scratch_shapes=[
-                pltpu.VMEM((2, ppb, page_size, row), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,))]),
-        out_shape=jax.ShapeDtypeStruct((slots, hq, row), q.dtype),
-        interpret=pltpu.InterpretParams() if interpret else False,
-        name=LATENT_KERNEL_NAME,
-    )(_i32(layer).reshape(1), _i32(page_table), _i32(lengths),
-      q.reshape(slots, hq, row), pool)
-    return out.reshape(slots, hq, 1, row)
+    return _call(functools.partial(_latent_kernel, scale=float(scale),
+                                   page_size=page_size, cpages=cpages),
+                 LATENT_KERNEL_NAME, q, (pool,), (page_size, row), ppb,
+                 layer, page_table, lengths, interpret)
+
+
+def takes_rows(q_shape, pool_shape, pool_dtype) -> bool:
+    """Whether :func:`paged_attention_rows` takes pools of token rows
+    (L, n_pages, page_size, kvh * hd) as they are stored: one query a
+    slot, of a head's ``hd`` lanes, the query heads a multiple of the
+    row's ``kvh``; bfloat16 pools whose heads are whole lane tiles (a KV
+    head is then a lane slice of the page as it lies) and whose pages
+    are whole (16, 128) tiles for the DMAs; queries that fit VMEM beside
+    the page buffers."""
+    page_size, width = pool_shape[-2:]
+    hd = q_shape[-1]
+    return (len(q_shape) == 4 and q_shape[2] == 1
+            and width % hd == 0 and q_shape[1] % (width // hd) == 0
+            and jnp.dtype(pool_dtype) == jnp.bfloat16
+            and hd % 128 == 0 and page_size % 16 == 0
+            and page_size * width * 2 <= _BLOCK_BYTES
+            and 2 * math.prod(q_shape) * 2 <= _QUERY_BYTES)
+
+
+def paged_attention_rows(q, k_pages, v_pages, page_table, lengths, *, layer,
+                         scale: Optional[float] = None,
+                         block_pages: Optional[int] = None,
+                         chunk_pages: Optional[int] = None,
+                         interpret: bool = False):
+    """Decode attention over the live pages of two pools of token rows.
+    q: (slots, n_heads, 1, hd); k_pages, v_pages: the whole (L, n_pages,
+    page_size, kvh * hd) pools, a token's ``kvh`` heads end to end, read
+    at ``layer`` (a traced scalar); page_table: (slots, pages_per_slot)
+    int32, every entry in ``[0, n_pages)``; lengths: (slots,) int.
+    Returns (slots, n_heads, 1, hd) in q's dtype, as
+    :func:`paged_attention_pages` does: query head h attends KV head
+    ``h // (n_heads / kvh)``'s keys ``[0, lengths[s])``, a slot of
+    length 0 gives zeros, and no page past ``ceil(lengths[s] /
+    page_size)`` is read. The same walk; ``block_pages``,
+    ``chunk_pages`` and ``interpret`` as there."""
+    n_layers, n_pages, page_size, width = k_pages.shape
+    slots, hq, sq, hd = q.shape
+    if sq != 1 or lengths.ndim != 1:
+        raise ValueError("one query a slot")
+    if width % hd or hq % (width // hd):
+        raise ValueError(f"{hq} q heads of {hd} over rows of {width}")
+    ppb, cpages = _blocks(page_size * width * k_pages.dtype.itemsize,
+                          page_table.shape[1], block_pages, chunk_pages)
+    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
+    return _call(functools.partial(_kernel, scale=float(scale),
+                                   page_size=page_size, kvh=1, cpages=cpages,
+                                   lane_heads=width // hd),
+                 ROWS_KERNEL_NAME, q, (k_pages, v_pages), (page_size, width),
+                 ppb, layer, page_table, lengths, interpret)

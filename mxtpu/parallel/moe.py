@@ -263,7 +263,10 @@ def route_sigmoid(x, w_router, bias, *, top_k: int, renorm: bool = True,
 # family's widths a group's WHOLE matrix (2048 x 768, 768 x 2048: 3.1
 # MB) is one tile. Timed on a v5e at 192 and 6,144 rows over 128 groups
 # against ``lax.ragged_dot`` and narrower tiles (256 output columns: the
-# down projection at 0.67 ms for 0.46; PERF.md section 6, PR 31)
+# down projection at 0.67 ms for 0.46; PERF.md section 6, PR 31). The
+# two widths are a bfloat16 tile's: a wider type takes as many BYTES (a
+# float32 group's whole matrix, twice double-buffered, is past the
+# chip's VMEM: ``chip_smoke.py``'s float32 pass died of it)
 _GMM_TILE = (192, 2048, 2048)
 
 
@@ -284,10 +287,10 @@ def grouped_matmul_kernel(lhs, rhs, group_sizes, tm: int,
     # and its tile counts are 32-bit: traced under x64 (the tests) the
     # library's own arithmetic hands the kernel a 64-bit scalar, which
     # the TPU compiler does not take
+    tk, tn = (2 * t // rhs.dtype.itemsize for t in _GMM_TILE[1:])
     with ambient, jax.enable_x64(False):
         return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-                   tiling=(tm, min(_GMM_TILE[1], rhs.shape[1]),
-                           min(_GMM_TILE[2], rhs.shape[2])),
+                   tiling=(tm, min(tk, rhs.shape[1]), min(tn, rhs.shape[2])),
                    interpret=interpret)
 
 

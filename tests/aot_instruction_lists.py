@@ -7,9 +7,9 @@ lists, for comparing two trees::
 ``git archive`` of another commit); the shapes are
 ``tests/test_tpu_aot_scopes.py``'s. One file a program in ``<out>``:
 ``decode_slots_paged``, ``decode_slots_spec``, ``prefill_slot_paged``,
-``copy_page`` and ``sambay.decode_slots_paged``, a line an instruction
-of the optimised
-module — computation, opcode, result type with its layout, ``op_name``
+``copy_page``, ``sambay.decode_slots_paged`` and
+``latent_moe.decode_slots_paged``, a line an instruction of the
+optimised module — computation, opcode, result type with its layout, ``op_name``
 — with XLA's instruction numbering taken out. Two trees that give
 ``diff -r`` nothing hand the chip the same programs. Not a test: one
 process may hold libtpu, so run it on its own, once a tree."""
@@ -51,7 +51,9 @@ def main(tree, out_dir):
     texts = {name: exe.as_text() for name, exe
              in aot.compiled.__wrapped__(one_chip).items()}
     texts["sambay.decode_slots_paged"] = \
-        aot.sambay_decode_text.__wrapped__(one_chip)
+        aot.sambay_decode.__wrapped__(one_chip).as_text()
+    texts["latent_moe.decode_slots_paged"] = \
+        aot.latent_moe_decode.__wrapped__(one_chip)[2].as_text()
     os.makedirs(out_dir, exist_ok=True)
     for name, text in texts.items():
         lines = _normal(text)

@@ -62,6 +62,16 @@ def test_latent_kernel_phase_runs_tiny_on_cpu():
     assert info["largest_output"] > 0.0
 
 
+def test_sambay_kernel_phase_runs_tiny_on_cpu():
+    """Two pools of token rows, three heads end to end, a page's edge
+    (16) and the full row among the lengths; one timed read."""
+    info = chip_smoke.phase_sambay_kernel(
+        slots=5, n_heads=6, kv_heads=3, head_dim=128, page_size=4,
+        capacity=32, lengths=(16, 32), reads=1, interpret=True)
+    assert info["lengths"] == [16, 32]
+    assert info["largest_output"] > 0.0 and info["kernel_ms"] > 0.0
+
+
 def test_serve_sambay_phase_runs_tiny_on_cpu():
     """The second family's leg: every kind of layer at toy widths,
     prompts over three windows, both passes float32 here."""

@@ -287,51 +287,71 @@ _PK_LENGTHS = {
 }
 
 
+# form -> (KV heads, lanes a head): llama's pool keeps a page's heads in
+# rows of ``hd`` lanes; sambay's keeps a token's ten paired heads of 128
+# end to end in one row, and the kernel cuts them out as lane slices
+_PK_FORMS = {"head_rows": (_PK["hkv"], _PK["hd"]), "token_rows": (10, 128)}
+
+
 @pytest.fixture(scope="module")
 def pages_kernel():
-    """(kernel, gathered), each jitted once a shape: two pages to a
-    block and one to a chunk, so a slot takes one block, two, or a
+    """form -> (kernel, gathered), each jitted once a shape: two pages
+    to a block and one to a chunk, so a slot takes one block, two, or a
     block half read, and a block one chunk or two."""
-    from mxtpu.ops.attention import gathered_decode_attention
-    from mxtpu.ops.paged_attention import paged_attention_pages
-    return (jax.jit(partial(paged_attention_pages, block_pages=2,
-                            chunk_pages=1, interpret=True)),
-            jax.jit(partial(gathered_decode_attention, kv_block=8)))
+    from mxtpu.ops.attention import gathered_decode_attention, \
+        gathered_rows_decode_attention
+    from mxtpu.ops.paged_attention import paged_attention_pages, \
+        paged_attention_rows
+    sizes = dict(block_pages=2, chunk_pages=1, interpret=True)
+    return {"head_rows": (jax.jit(partial(paged_attention_pages, **sizes)),
+                          jax.jit(partial(gathered_decode_attention,
+                                          kv_block=8))),
+            "token_rows": (jax.jit(partial(paged_attention_rows, **sizes)),
+                           jax.jit(partial(gathered_rows_decode_attention,
+                                           kv_block=8)))}
 
 
 @pytest.fixture(scope="module")
 def pages_kernel_pool():
-    """dtype -> (k pool, v pool) of two layers, random but for scratch
-    page 0 (zeros) and the last page (NaN)."""
+    """(form, dtype) -> (k pool, v pool) of two layers, random but for
+    scratch page 0 (zeros) and the last page (NaN)."""
     rng = np.random.default_rng(30)
-    shape = (_PK["layers"], _PK_NAN + 1, _PK["ps"], _PK["hkv"], _PK["hd"])
+    out = {}
+    for form, (hkv, hd) in _PK_FORMS.items():
+        tail = (hkv, hd) if form == "head_rows" else (hkv * hd,)
+        shape = (_PK["layers"], _PK_NAN + 1, _PK["ps"]) + tail
 
-    def pool():
-        a = rng.standard_normal(shape).astype(np.float32)
-        a[:, 0], a[:, _PK_NAN] = 0.0, np.nan
-        return a
-    k, v = pool(), pool()
-    return {dt: (jnp.asarray(k, dt), jnp.asarray(v, dt))
-            for dt in (jnp.float32, jnp.bfloat16)}
+        def pool():
+            a = rng.standard_normal(shape).astype(np.float32)
+            a[:, 0], a[:, _PK_NAN] = 0.0, np.nan
+            return a
+        k, v = pool(), pool()
+        for dt in (jnp.float32, jnp.bfloat16):
+            out[form, dt] = jnp.asarray(k, dt), jnp.asarray(v, dt)
+    return out
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("rep", [1, 4])
 @pytest.mark.parametrize("case", list(_PK_LENGTHS))
+@pytest.mark.parametrize("form", list(_PK_FORMS))
 def test_pages_kernel_matches_the_gathered_path(pages_kernel,
-                                                pages_kernel_pool, case,
-                                                rep, dtype):
+                                                pages_kernel_pool, form,
+                                                case, rep, dtype):
     """The Pallas kernel walks each slot's pages out of the whole pool
     and stops at the slot's length: the same numbers as gather-then-
     ``slot_decode_attention`` up to the order of summation, zeros for a
     slot of length 0, and nothing of a page past the length (the
     interpreter's buffers start as NaN, so would an unread page that
-    leaked)."""
-    kernel, gathered = pages_kernel
-    S, per_slot, ps, hkv, hd = (_PK[n] for n in
-                                ("S", "per_slot", "ps", "hkv", "hd"))
-    kp, vp = pages_kernel_pool[dtype]
+    leaked). Both layouts of two pools: heads in a page's rows
+    (``paged_attention_pages``), and a token's heads end to end in one
+    row (``paged_attention_rows``: sambay's ten heads of 128 lanes under
+    40 or 10 query heads)."""
+    kernel, gathered = pages_kernel[form]
+    S, per_slot, ps = (_PK[n] for n in ("S", "per_slot", "ps"))
+    hkv, hd = _PK_FORMS[form]
+    kp, vp = pages_kernel_pool[form, dtype]
     rng = np.random.default_rng(sorted(_PK_LENGTHS).index(case))
     q = jnp.asarray(rng.standard_normal((S, hkv * rep, 1, hd)), dtype)
     lengths = np.asarray(_PK_LENGTHS[case], np.int32)

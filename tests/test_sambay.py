@@ -320,6 +320,80 @@ def test_programs_gather_once_and_cross_decode_one_position(params):
     assert ((1, 16, CFG.dim),) in carries and ((1, 1, CFG.dim),) in carries
 
 
+def _agent_shapes(dtype=jnp.bfloat16, **widths):
+    """(cfg, kv state as shapes): Phi-4-mini-flash's attention widths
+    (40 query / 20 KV heads of 64, read as 40 over 10 paired heads of
+    128) at eight layers, a pool of 16-token pages under 4 slots."""
+    from dataclasses import replace
+    cfg = replace(sambay.SambaYConfig(n_layers=8, max_seq_len=256,
+                                      vocab_size=512, hidden_dim=256),
+                  dtype=dtype, param_dtype=dtype, **widths)
+    state = jax.eval_shape(lambda: sambay.init_paged_cache(cfg, 4, 65, 16))
+    return cfg, {n: a for n, a in state.items()
+                 if n not in ("lengths", "tokens", "rngs")}
+
+
+@pytest.mark.parametrize("case,path", [
+    ("agent_cell_on_a_tpu", "pages"), ("float32_pool", "gathered"),
+    ("mesh", "gathered"), ("cpu_backend", "gathered"),
+    ("paired_heads_of_64_lanes", "gathered")])
+def test_decode_attention_path_is_read_off_the_inputs(monkeypatch, case,
+                                                      path):
+    """Backend, shapes and dtypes decide, statically, as for llama: on a
+    TPU over bfloat16 pools whose paired heads are whole lane tiles the
+    full layer and the cross layers' scan each hold ONE call of the rows
+    kernel and nothing gathers the pool; everywhere else the pool is
+    gathered once for K and once for V and no kernel is traced."""
+    from mxtpu.ops.paged_attention import ROWS_KERNEL_NAME
+    if case != "cpu_backend":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, kv = _agent_shapes(
+        jnp.float32 if case == "float32_pool" else jnp.bfloat16,
+        **(dict(dim=1280) if case == "paired_heads_of_64_lanes" else {}))
+    mesh = None
+    if case == "mesh":
+        from mxtpu.parallel import create_mesh
+        mesh = create_mesh(tp=2, devices=jax.devices()[:2])
+    assert sambay.decode_attention_path(cfg, kv, mesh) == path
+    assert sambay.decode_attention_path(cfg, kv, mesh, verify=True) == path
+    S = kv["wk"].shape[1]
+    abstract = jax.ShapeDtypeStruct
+    sv = {"lengths": abstract((S,), jnp.int32),
+          "tokens": abstract((S,), jnp.int32),
+          "rngs": abstract((S, 2), jnp.uint32)}
+    jaxpr = jax.make_jaxpr(partial(sambay.decode_slots_paged, cfg,
+                                   mesh=mesh))(
+        jax.eval_shape(partial(sambay.init_params, cfg),
+                       jax.random.PRNGKey(0)),
+        kv, sv, abstract((S,), jnp.bool_), abstract((S, 16), jnp.int32),
+        abstract((S,), jnp.float32), abstract((S,), jnp.int32),
+        abstract((S,), jnp.float32))
+    eqns = list(_eqns(jaxpr.jaxpr))
+    walks = [e for e in eqns if e.primitive.name == "pallas_call"]
+    gathers = [e for e in eqns if e.primitive.name == "gather"
+               and e.invars[0].aval.shape == kv["k"].shape]
+    if path == "pages":
+        assert len(walks) == 2 and not gathers
+        assert all(e.params["name"] == ROWS_KERNEL_NAME
+                   for e in walks)
+        # the pools as they are stored are the kernel's own operands
+        assert all([v.aval.shape for v in e.invars[-2:]]
+                   == 2 * [kv["k"].shape] for e in walks)
+    else:
+        assert not walks and len(gathers) == 2
+
+
+def test_engine_names_the_rows_kernel_on_a_tpu(monkeypatch):
+    """``kv_cache_stats()`` carries the family's word for this family
+    too (construction compiles nothing)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, _ = _agent_shapes()
+    p = jax.jit(partial(sambay.init_params, cfg))(jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, p, max_slots=2, max_len=256, min_bucket=128,
+                      page_size=16)
+    assert eng.kv_cache_stats()["decode_attention"] == "pages"
+
+
 # -- through the engine ------------------------------------------------------
 def test_engine_picks_the_family_from_the_config(params, serve_cfg):
     from mxtpu.models import llama

@@ -139,8 +139,10 @@ def decode_text(compiled):
 
 
 @pytest.fixture(scope="module")
-def sambay_decode_text(one_chip):
-    """The second family's decode program at toy depth and width over
+def sambay_decode(one_chip):
+    """The second family's decode program at toy depth and width (two
+    Mamba+window pairs, layers "4/5", ONE GMU+cross pair; 8 query heads
+    over 2 paired KV heads of 128 lanes, a 256-wide row) over
     Phi-4-mini-flash's 200064 rows: the sampler is ``llama``'s, at the
     vocabulary where its gathers cost 130 ms a step."""
     cfg = replace(sambay.CONFIGS["tiny"], vocab_size=200064, dim=512,
@@ -148,7 +150,12 @@ def sambay_decode_text(one_chip):
                   sliding_window=128, max_seq_len=512, dtype=jnp.bfloat16,
                   param_dtype=jnp.bfloat16)
     decode, *_ = _lower_decode(sambay, cfg, one_chip)
-    return _compile_all({"decode": decode})["decode"].as_text()
+    return _compile_all({"decode": decode})["decode"]
+
+
+@pytest.fixture(scope="module")
+def sambay_decode_text(sambay_decode):
+    return sambay_decode.as_text()
 
 
 def test_tpu_program_keeps_its_name_and_parses_fast(decode_text):
@@ -267,7 +274,7 @@ def test_tpu_paged_programs_leave_the_pool_in_place(compiled, program):
 _ROW = SLOTS * 512 * 8 * 128        # every slot's whole row of K (or V)
 
 
-def _rows(text):
+def _rows(text, elements=_ROW, lanes=(128,)):
     """The executed instructions whose result is as large as all the
     slots' capacity-long rows of one layer's K and ends in a head's 128
     lanes: the gathered copy ``[8, 8, 512, 128]``, its token-major twin
@@ -276,7 +283,7 @@ def _rows(text):
     return [f"{comp}: {name} = {opcode}"
             for comp, instrs in _executed(_computations(text)).items()
             for name, n, opcode, _, _, last in instrs
-            if n == _ROW and last == 128 and opcode not in _NOT_RUN]
+            if n == elements and last in lanes and opcode not in _NOT_RUN]
 
 
 def test_tpu_decode_attention_is_one_kernel_call_over_live_pages(
@@ -299,6 +306,41 @@ def test_tpu_decode_attention_is_one_kernel_call_over_live_pages(
     assert _rows(text) == []
     # the same reading finds the verify step's rows, which still gather
     assert _rows(compiled["decode_slots_spec"].as_text())
+
+
+def test_tpu_sambay_reads_its_shared_pool_through_the_rows_kernel(
+        sambay_decode):
+    """The second family's eight reads of its one shared pool: the full
+    layer's is ONE call of the rows kernel under ``attention``, the
+    cross layers' ONE call in their scan's body under
+    ``cross_attention`` (seven trips at Phi-4-mini-flash's depth, one
+    here), each fed by the pools as they are stored. Nothing under
+    ``kv_gather`` is left, no operation produces every slot's
+    capacity-long rows, token-major ``[8, 512, 256]`` or head-major
+    ``[8, 2, 512, 128]`` (the gather wrote both, and the reads relaid
+    them out four times more: 2.62 GB of temporaries at the agent
+    cell's shapes, 0.09 GB now), and the donated pools stay in place."""
+    from mxtpu.ops.paged_attention import ROWS_KERNEL_NAME
+    text = sambay_decode.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2, calls
+    assert all(re.search(rf"%{ROWS_KERNEL_NAME}[.\d]* = ", c)
+               for c in calls), calls
+    scopes = sorted(re.search(r'op_name="([^"]*)"', c).group(1)
+                    for c in calls)
+    assert scopes[0].startswith("jit(decode_slots_paged)/attention/"
+                                + ROWS_KERNEL_NAME), scopes
+    assert re.search(rf"/while/body/([^/]*/)?cross_attention/"
+                     rf"{ROWS_KERNEL_NAME}", scopes[1]), scopes
+    pool = f"bf16[1,{N_PAGES},{PAGE},256]"
+    assert all(c.split("operand_layout_constraints")[1].count(pool) == 2
+               for c in calls), calls
+    assert "/kv_gather/" not in text
+    row = SLOTS * 512 * 256
+    assert _rows(text, row, (128, 256)) == []
+    mem = sambay_decode.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * N_PAGES * PAGE * 256 * 2
 
 
 def test_tpu_page_gather_selects_only_indices(compiled):
@@ -422,3 +464,24 @@ def test_tpu_latent_decode_operations_land_under_its_scopes(
         "moe_router", "moe_dispatch", "moe_experts", "moe_shared"}
     assert {shown[n] for n in shown if n.startswith("gmm")} == {
         "moe_experts"}
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)],
+                         ids=["gate_up", "down"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_tpu_grouped_product_fits_vmem_in_both_types(one_chip, dtype, k, n):
+    """``megablox.gmm`` at the routed experts' published widths, a
+    decode step's 192 rows over the stack's 7 x 128 groups, compiles
+    for a v5e in bfloat16 (the rag cell) and in float32
+    (``chip_smoke.py``'s second pass): a float32 group's whole matrix as
+    one tile is 6.3 MB, and double-buffered beside its rows and its
+    accumulator it ran out of VMEM (every replica of that pass died in
+    its first decode step), so a tile is sized in bytes."""
+    from mxtpu.parallel import moe
+    arg = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    exe = _compile_all({"gmm": jax.jit(partial(
+        moe.grouped_matmul_kernel, tm=192)).lower(
+            arg((192, k), dtype), arg((896, k, n), dtype),
+            arg((896,), jnp.int32))})["gmm"]
+    assert "tpu_custom_call" in exe.as_text()
