@@ -600,6 +600,71 @@ def phase_latent_kernel(*, slots=32, n_heads=32, row=640, value_dim=512,
             "largest_difference": worst, "largest_output": largest}
 
 
+def phase_retention_kernel(*, slots=16, n_heads=40, kv_heads=8, head_dim=128,
+                           layers=2, steps=5, interpret=False):
+    """The Pallas decode step of a power-retention layer
+    (``ops.retention.retention_step_bank``: a layer's state read once
+    and written once, in place, the read-out accumulated tile by tile)
+    against the ``jnp`` form (``retention_step`` on the layer's slice) on
+    one random float32 bank at ``models/retention.py``'s published
+    shapes: the state written equal to float32's rounding, the outputs
+    to bf16's (the kernel's read-out multiplies bf16 operands). Then
+    ``steps`` timed steps of the kernel, a layer each: ``kernel_ms`` and
+    the share of the memory's speed (2 x a layer's state a step)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxtpu.ops import retention as ops
+
+    t0 = time.perf_counter()
+    F = ops.sympow2_rows(head_dim)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    S = jax.jit(lambda k: jax.random.normal(
+        k, (layers, slots, kv_heads, head_dim, F), jnp.float32))(ks[0])
+    z = 30.0 + jax.random.normal(ks[1], (layers, slots, kv_heads, F))
+    q = jax.random.normal(ks[2], (slots, n_heads, head_dim), jnp.bfloat16)
+    k = jax.random.normal(ks[3], (slots, kv_heads, head_dim), jnp.bfloat16)
+    v = jax.random.normal(ks[4], (slots, kv_heads, head_dim), jnp.bfloat16)
+    log_g = jax.nn.log_sigmoid(4.0 + jax.random.normal(
+        ks[5], (slots, kv_heads)))
+    layer, kw = jnp.int32(layers - 1), dict(scale=head_dim ** -0.5)
+    want_y, want_S, want_z = jax.jit(lambda q, k, v, g, S, z: (
+        ops.retention_step(q, k, v, g, S[layers - 1], z[layers - 1], **kw)
+    ))(q, k, v, log_g, S, z)
+    want_y, want_S, want_z = (np.asarray(a) for a in
+                              (want_y, want_S, want_z))
+    untouched = np.asarray(S[0, 0, 0])
+    kernel = jax.jit(lambda q, k, v, g, S, z: ops.retention_step_bank(
+        q, k, v, g, S, z, layer, interpret=interpret, **kw),
+        donate_argnums=(4, 5))
+    if not interpret:
+        assert ops.retention_step_path(S.shape, S.dtype) == "kernel"
+    setup_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    y, S, z = kernel(q, k, v, log_g, S, z)
+    y = np.asarray(y)
+    assert np.isfinite(y).all()
+    worst_S = float(np.abs(np.asarray(S[layers - 1]) - want_S).max())
+    worst_y, largest = float(np.abs(y - want_y).max()), float(
+        np.abs(want_y).max())
+    assert worst_S <= 1e-5 * float(np.abs(want_S).max()), worst_S
+    np.testing.assert_allclose(np.asarray(z[layers - 1]), want_z, rtol=1e-6)
+    assert worst_y <= 8 * 2.0 ** -8 * max(1.0, largest), (worst_y, largest)
+    np.testing.assert_array_equal(np.asarray(S[0, 0, 0]), untouched)
+    jax.block_until_ready(S)
+    t1 = time.perf_counter()
+    for _ in range(steps):
+        y, S, z = kernel(q, k, v, log_g, S, z)
+    jax.block_until_ready((y, S))
+    kernel_ms = 1e3 * (time.perf_counter() - t1) / steps
+    moved = 2 * slots * kv_heads * head_dim * F * 4
+    return {"setup_s": setup_s, "run_s": time.perf_counter() - t0,
+            "slots": slots, "state_bytes_a_layer": moved // 2,
+            "largest_difference": worst_y, "largest_output": largest,
+            "largest_state_difference": worst_S, "kernel_ms": kernel_ms,
+            "hbm_share": moved / (kernel_ms * 1e-3) / 819e9}
+
+
 def phase_sambay_kernel(*, slots=32, n_heads=40, kv_heads=10, head_dim=128,
                         page_size=16, capacity=6144, lengths=(1024, 4400),
                         reads=8, interpret=False):
@@ -670,10 +735,11 @@ def phase_sambay_kernel(*, slots=32, n_heads=40, kv_heads=10, head_dim=128,
 
 
 def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
-                       **engine_kw):
+                       expect_attention_f32="gathered", **engine_kw):
     """A serving family other than llama (``models.serving_family(cfg)``:
     ``sambay.py``'s state-space, window, full, GMU and cross-attention
-    layers; ``latent_moe.py``'s latent attention and routed experts)
+    layers; ``latent_moe.py``'s latent attention and routed experts;
+    ``retention.py``'s power-retention layers, whose pool has no pages)
     through a paged ``ServeEngine`` behind ``Gateway.start_http``, once
     in the config's bf16 and once in float32 at ``highest`` precision,
     against its own ``forward``: ``jobs`` are asked greedily, and each
@@ -683,7 +749,9 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
     near-tie may flip, as it does for llama). ``expect_attention`` is
     what the engine must say the first pass's decode program reads its
     pool through (``"pages"`` on the chip: a bf16 pool the family's
-    kernel takes as stored); the float32 pass gathers everywhere."""
+    kernel takes as stored); the float32 pass gathers everywhere
+    (``expect_attention_f32``; a retention step reads no pool and says
+    ``"state"`` in both)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -746,7 +814,7 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
             info["run_s"] += time.perf_counter() - t0
             t0 = time.perf_counter()
     assert info["worst_gap_float32"] <= tol_f32, info
-    assert info["decode_attention_float32"] == "gathered", info
+    assert info["decode_attention_float32"] == expect_attention_f32, info
     assert expect_attention in (
         None, info[f"decode_attention_{np.dtype(cfg.dtype).name}"]), info
     return info
@@ -825,6 +893,21 @@ def main():
                    shared_prefix=0),
          max_slots=4, max_len=2048, min_bucket=256, prefill_chunk=1024,
          expect_attention="pages")
+
+    # the fourth, at its published widths and a small depth (two
+    # power-retention layers): the longer prompt is prefilled in two
+    # chunks (the state handed on through the stage), then decode steps
+    # over a fixed float32 state a slot, through the kernel in both
+    # passes; the engine's pool has no pages
+    from mxtpu.models import retention
+    _run("retention_kernel", phase_retention_kernel)
+    ret_cfg = retention.RetentionConfig(n_layers=2, max_seq_len=2048)
+    _run("serve_retention", phase_serve_family, ret_cfg,
+         make_jobs(ret_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
+                   shared_prefix=0),
+         max_slots=4, max_len=2048, min_bucket=256, prefill_chunk=1024,
+         expect_attention="state_kernel",
+         expect_attention_f32="state_kernel")
 
     if jax.device_count() >= 4:
         # the same two phases over a mesh with more than one

@@ -12,21 +12,24 @@ from . import bert
 from . import latent_moe
 from . import llama
 from . import resnet
+from . import retention
 from . import sambay
 from .bert import BertConfig
 from .latent_moe import LatentMoEConfig
 from .llama import LlamaConfig
 from .resnet import ResNetConfig
+from .retention import RetentionConfig
 from .sambay import SambaYConfig
 
-__all__ = ["llama", "resnet", "sambay", "latent_moe", "LlamaConfig",
-           "ResNetConfig", "SambaYConfig", "LatentMoEConfig",
+__all__ = ["llama", "resnet", "sambay", "latent_moe", "retention",
+           "LlamaConfig", "ResNetConfig", "SambaYConfig", "LatentMoEConfig",
+           "RetentionConfig",
            "SERVING_FAMILIES", "serving_family"]
 
 # the families ``serve.ServeEngine`` can be given, by the ``family`` of
 # their config class; each module has llama.py's serving surface
 SERVING_FAMILIES = {"llama": llama, "sambay": sambay,
-                    "latent_moe": latent_moe}
+                    "latent_moe": latent_moe, "retention": retention}
 
 
 def serving_family(cfg):

@@ -11,6 +11,11 @@ SOSP '23):
   PAGES, and read-only prefix pages are shared between slots
   (:class:`PrefixCache`, copy-on-write through ``copy_page``).
   Per-slot ``lengths`` confine attention to each request's own prefix.
+  A family none of whose state grows with tokens (retention: a fixed
+  block a slot) gets the same bank with a pool of no pages: admission
+  is bounded by free SLOTS, page-table rows are empty, and
+  ``kv_cache_stats()`` reports ``pages_total`` 0 and the block as
+  ``state_bytes_per_slot``.
 - **admission at step boundaries**: a free slot is seated by the next
   queued request via a per-BUCKET prefill program (the prompt's
   unshared suffix end-padded to a power of two — exact, see
@@ -91,7 +96,9 @@ def _engine_metrics(eid: str, attention: Dict[str, str]):
             "serve_decode_steps_total",
             "Decode steps dispatched, by the attention their program "
             "was built with: pages (the kernel reads live pages out of "
-            "the pool) or gathered (every slot's whole row copied out)",
+            "the pool), gathered (every slot's whole row copied out), "
+            "state_kernel or state (a retention step over a fixed state "
+            "a slot: one Pallas kernel a layer, or the jnp form)",
             attention=path) for step, path in attention.items()},
         "queue": telemetry.gauge(
             "serve_queue_depth", "Requests queued, not yet admitted",
@@ -200,13 +207,16 @@ class PageAllocator:
     stack, shared read-only via :meth:`retain` (prefix sharing), and
     returned to the stack only when their last owner releases them.
     Page 0 is the SCRATCH page — never allocated, zeroed page-table
-    rows alias it, redirected writes land there. Pure host state; the
-    caller (ServeEngine) serializes access under its own lock."""
+    rows alias it, redirected writes land there. ``n_pages`` 1 is the
+    pool of no pages (the scratch page alone): what the engine keeps
+    for a family whose state does not grow with tokens; it grants
+    ``alloc(0)`` and nothing else. Pure host state; the caller
+    (ServeEngine) serializes access under its own lock."""
 
     def __init__(self, n_pages: int):
-        if n_pages < 2:
+        if n_pages < 1:
             raise ValueError(
-                f"need >= 2 pages (scratch + 1), got {n_pages}")
+                f"need >= 1 page (the scratch page), got {n_pages}")
         self.n_pages = int(n_pages)
         self._ref = np.zeros(self.n_pages, np.int32)
         # LIFO free stack: recently-freed pages are re-handed first
@@ -513,7 +523,8 @@ class ServeEngine:
     """Continuous-batching scheduler over one model + one KV page pool.
 
     Args: ``cfg``/``params`` — a config and parameter pytree of a
-    family in ``models.SERVING_FAMILIES`` (llama, sambay): the engine
+    family in ``models.SERVING_FAMILIES`` (llama, sambay, latent_moe,
+    retention): the engine
     takes every program it runs from that family's module, found once
     here from ``cfg.family``, and refuses at construction the options
     the family's ``SERVE_UNSUPPORTED`` names, with the mechanism in
@@ -530,7 +541,8 @@ class ServeEngine:
     ``paged`` is what is left of a switch between this pool and a dense
     bank that is gone: ``True`` is its one legal value, kept while the
     benchmark's drivers pass it (ROADMAP D12). ``prefill_chunk`` (a
-    family with ``prefill_slot_paged_chunk``: sambay) prefills a
+    family with ``prefill_slot_paged_chunk``: sambay, latent_moe,
+    retention) prefills a
     prompt that many tokens at a time, at most one chunk between two decode steps once
     the running requests are no fewer than the waiting ones: the gap a
     running request sees beside an admission is one chunk's time
@@ -637,9 +649,25 @@ class ServeEngine:
         # per-slot rings and recurrent state)
         self._sv = {n: state.pop(n) for n in ("lengths", "tokens", "rngs")}
         self._kv = state
+        # bytes of the donated state by kind: pages (the kinds named
+        # ``*_pages``: keys and values, or latent rows) grow with the
+        # tokens held; a family's other kinds are fixed blocks per slot
+        kinds = getattr(fam, "STATE_KINDS", {})
+        by_kind: Dict[str, int] = {}
+        for n, a in state.items():
+            k = kinds.get(n, "kv_pages")
+            by_kind[k] = by_kind.get(k, 0) + int(a.nbytes)
+        paged = sum(n for k, n in by_kind.items() if k.endswith("_pages"))
+        if not paged:
+            # no kind of this family's state grows with tokens
+            # (retention): the pool is the scratch page alone, a free
+            # slot is all an admission needs, and max_len bounds
+            # positions only
+            self.n_pages = 1
         # which attention the family builds the decode programs on over
-        # this state ("pages" | "gathered"): its choice, exported as it
-        # is (serve_decode_steps_total{attention}, kv_cache_stats())
+        # this state ("pages" | "gathered" | "state_kernel" | "state"):
+        # its choice, exported as it is
+        # (serve_decode_steps_total{attention}, kv_cache_stats())
         self._attention = {
             "plain": fam.decode_attention_path(cfg, state, mesh),
             "verify": fam.decode_attention_path(cfg, state, mesh,
@@ -682,7 +710,8 @@ class ServeEngine:
         # refcounted allocator, the prefix cache, and the CoW
         # fork program (ONE program: src/dst are traced scalars)
         self._pt = np.zeros(
-            (self.max_slots, self._pages_per_slot), np.int32)
+            (self.max_slots, self._pages_per_slot if paged else 0),
+            np.int32)
         self._pages = PageAllocator(self.n_pages)
         self._prefix = (PrefixCache(self._pages)
                         if self.prefix_cache_enabled else None)
@@ -757,26 +786,18 @@ class ServeEngine:
         # would block the decode loop every token, MXL004). Reserved
         # bytes count the pool's global logical size across the mesh.
         self._slot_len = np.zeros(S, np.int64)
-        # bytes of the donated state by kind: pages grow with the
-        # tokens held; a family's other kinds are fixed blocks per slot
-        kinds = getattr(fam, "STATE_KINDS", {})
-        by_kind: Dict[str, int] = {}
-        for n, a in self._kv.items():
-            k = kinds.get(n, "kv_pages")
-            by_kind[k] = by_kind.get(k, 0) + int(a.nbytes)
         for k, nbytes in by_kind.items():
             telemetry.gauge(
                 "serve_state_bytes", "Bytes of the engine's donated "
                 "device state, by kind: kv_pages, latent_pages, "
-                "window_ring, ssm",
+                "window_ring, ssm, retention_state",
                 engine=eid, kind=k).set(nbytes)
         # reserved counts everything donated (the scratch page too — it
-        # is real HBM); per-token bytes are the pages' (the kinds named
-        # ``*_pages``: keys and values, or latent rows) over the tokens
-        # they can hold (scale planes included in int8 mode), per-slot
-        # bytes the fixed kinds' over the slots
+        # is real HBM); per-token bytes are the pages' over the tokens
+        # they can hold (scale planes included in int8 mode; 0 where
+        # the state has no pages), per-slot bytes the fixed kinds' over
+        # the slots
         self._kv_reserved = sum(by_kind.values())
-        paged = sum(n for k, n in by_kind.items() if k.endswith("_pages"))
         self._kv_tok_bytes = paged // (self.n_pages * self.page_size)
         self._slot_state_bytes = ((self._kv_reserved - paged)
                                   // self.max_slots)
@@ -1053,6 +1074,13 @@ class ServeEngine:
         (request stays queued). Mutates ONLY the allocator/prefix
         cache (under the engine lock); the device work happens later
         in ``_run_admissions``."""
+        if not self._kv_tok_bytes:
+            # a token holds no bytes of this family's state: the free
+            # slot the caller found is the whole grant (the family
+            # refuses prefix cache and hand-off, which plan pages)
+            return {"row": np.zeros(0, np.int32), "prefix_len": 0,
+                    "fork": None, "register": None,
+                    "ignore_handoff": False}
         ps = self.page_size
         cap = self._pages_per_slot * ps
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
@@ -1748,7 +1776,9 @@ class ServeEngine:
         ``/state`` block. ``"paged"`` is always True (readers outside
         the repo may hold the key); ``"decode_attention"`` is what the
         decode program's attention was built on, ``"pages"`` (the
-        kernel over live pages) or ``"gathered"``, as the family's
+        kernel over live pages), ``"gathered"``, or a retention step's
+        ``"state_kernel"`` / ``"state"`` (no keys or values: the Pallas
+        kernel over the bank, or the ``jnp`` form), as the family's
         ``decode_attention_path`` gave it. Host arithmetic only (the mirrored
         per-slot lengths; reading the device ``lengths`` vector here
         would put a sync next to the decode loop — MXL004)."""

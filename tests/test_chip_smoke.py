@@ -102,6 +102,33 @@ def test_serve_latent_moe_phase_runs_tiny_on_cpu():
     assert info["worst_gap_float32"] <= 1e-3
 
 
+def test_retention_kernel_phase_runs_tiny_on_cpu():
+    """Three slots, ten query heads over two KV heads of 128 (five
+    tiles of the state a head), the kernel in interpret mode."""
+    info = chip_smoke.phase_retention_kernel(
+        slots=3, n_heads=10, kv_heads=2, head_dim=128, steps=1,
+        interpret=True)
+    assert info["state_bytes_a_layer"] == 3 * 2 * 128 * 8320 * 4
+    assert info["largest_output"] > 0.0 and info["kernel_ms"] > 0.0
+
+
+def test_serve_retention_phase_runs_tiny_on_cpu():
+    """The fourth family's leg: power-retention layers at toy widths,
+    the longer prompt in two chunks, a pool of no pages, both passes
+    float32 here."""
+    from mxtpu.models import retention
+    cfg = retention.CONFIGS["tiny"]
+    jobs = chip_smoke.make_jobs(cfg.vocab_size,
+                                ((11, 5, 0.0), (30, 6, 0.0)),
+                                per_shape=1, shared_prefix=0)
+    info = chip_smoke.phase_serve_family(
+        cfg, jobs, max_slots=2, max_len=96, min_bucket=16, page_size=8,
+        prefill_chunk=16, expect_attention="state",
+        expect_attention_f32="state")
+    assert info["requests"] == 4
+    assert info["worst_gap_float32"] <= 1e-3
+
+
 def test_main_fails_without_a_chip():
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],

@@ -102,7 +102,10 @@ def test_page_allocator_guards_scratch_and_dead_pages():
     with pytest.raises(ValueError):
         a.alloc(-1)
     with pytest.raises(ValueError):
-        PageAllocator(1)                    # scratch alone is not a pool
+        PageAllocator(0)                    # no pool without its scratch page
+    empty = PageAllocator(1)                # scratch alone: a pool of no
+    assert empty.free_pages == 0            # pages (tests/test_retention.py)
+    assert empty.alloc(1) is None
 
 
 @pytest.mark.parametrize("seed", range(4))

@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxtpu.models import latent_moe, llama, sambay
+from mxtpu.models import latent_moe, llama, retention, sambay
+from mxtpu.ops.retention import STEP_KERNEL_NAME
 from mxtpu.telemetry import scopes as tscopes
 
 # Mistral's head shapes (32 query / 8 kv heads of 128); depth, FFN and
@@ -464,6 +465,69 @@ def test_tpu_latent_decode_operations_land_under_its_scopes(
         "moe_router", "moe_dispatch", "moe_experts", "moe_shared"}
     assert {shown[n] for n in shown if n.startswith("gmm")} == {
         "moe_experts"}
+
+
+# -- the power-retention family ---------------------------------------------------
+@pytest.fixture(scope="module")
+def retention_decode(one_chip):
+    """``retention.decode_slots_paged`` at the published head shapes (40
+    query heads over 8 KV heads of 128: 8320 stored rows a head), four
+    layers, the SwiGLU and the vocabulary cut: 8 slots' state is 1.1 GB
+    (34.35 MB a slot and layer), as shapes."""
+    cfg = retention.RetentionConfig(n_layers=4, vocab_size=32768,
+                                    hidden_dim=2048, max_seq_len=512)
+    decode, _, kv, _ = _lower_decode(retention, cfg, one_chip)
+    return cfg, kv, _compile_all({"decode": decode})["decode"]
+
+
+def test_tpu_retention_decode_updates_the_state_where_it_lies(
+        retention_decode):
+    """The decayed, updated state is written into the donated bank in
+    place by the step's Pallas kernel (``ops.retention``: one read and
+    one write of a layer's state): the program's temporaries are the
+    step's activations, far under one layer's slice of the state (275
+    MB here)."""
+    cfg, kv, exe = retention_decode
+    assert kv["S"].shape == (4, SLOTS, 8, 128, 8320)
+    assert kv["z"].shape == (4, SLOTS, 8, 8320)
+    assert {a.dtype for a in kv.values()} == {jnp.dtype(jnp.float32)}
+    state = sum(math.prod(a.shape) * 4 for a in kv.values())
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state / cfg.n_layers / 4, mem
+    # no copy of a layer's slice, or of the bank, is executed
+    # ONE call of the step's kernel (the layer loop's), which takes the
+    # loop-carried bank itself and hands it back aliased
+    text = exe.as_text()
+    calls = [ln for ln in text.splitlines() if re.match(
+        rf"\s*%?{STEP_KERNEL_NAME}[.\d]* = ", ln)]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert "f32[4,8,8,128,8320]" in calls[0].split("custom-call(")[0]
+    slice_ = SLOTS * 8 * 8320 * 128
+    copies = [name for instrs in _executed(
+        _computations(exe.as_text())).values()
+        for name, n, opcode, *_ in instrs
+        if opcode == "copy" and n >= slice_]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("scope", ["retention_state", "retention_gate",
+                                   "qkv_proj", "out_proj", "mlp", "sampler"])
+def test_tpu_retention_decode_operations_land_under_its_scopes(
+        retention_decode, scope):
+    """As for llama's program: what a trace will show of the step lands
+    under the family's scopes; nothing is written, gathered or attended
+    as keys and values."""
+    _, scopes = tscopes.scope_map(retention_decode[2].as_text())
+    shown = {name: path.split("/")[0] for name, (path, _) in
+             scopes.items() if re.search(
+                 rf"fusion|^copy|custom-call|^{STEP_KERNEL_NAME}", name)}
+    assert {shown[n] for n in shown if n.startswith(STEP_KERNEL_NAME)} == {
+        "retention_state"}
+    assert scope in set(shown.values()), sorted(set(shown.values()))
+    assert set(shown.values()) <= {
+        "", "embed", "norm", "qkv_proj", "rope", "retention_gate",
+        "retention_state", "out_proj", "mlp", "lm_head", "sampler"}
 
 
 @pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)],
