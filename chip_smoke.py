@@ -22,6 +22,7 @@ from __future__ import annotations
 import contextlib
 import faulthandler
 import json
+import logging
 import os
 import re
 import sys
@@ -439,6 +440,7 @@ def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
 
         info = {"setup_s": setup_s, "run_s": run_s,
                 "decode_attention": kv["decode_attention"],
+                "sampler": kv["sampler"],
                 "requests": len(jobs),
                 "tokens": sum(j["mnew"] for j in jobs),
                 "buckets": buckets, "warmup_s": warm_s,
@@ -458,7 +460,8 @@ def _serve_jobs(cfg, params, jobs, mesh, engine_kw, t0):
 
 
 def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
-                must_match=False, expect_attention=None, **engine_kw):
+                must_match=False, expect_attention=None,
+                expect_sampler=None, **engine_kw):
     """A paged ``ServeEngine`` (prefix cache on) behind
     ``Gateway.start_http``, asked ``jobs`` by ``GatewayClient`` threads
     of this process. Every request must come back 200 and whole, the
@@ -472,7 +475,9 @@ def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
     matmul precision for the phase; ``engine_kw`` shapes the engine.
     ``expect_attention`` is what the engine must say its decode program's
     attention was built on (``"pages"``: the Pallas kernel over live
-    pages, a bf16 pool on one chip; ``"gathered"``: everything else)."""
+    pages, a bf16 pool on one chip; ``"gathered"``: everything else),
+    ``expect_sampler`` the same for its sampler's threshold search
+    (``"search_kernel"``: a bank of eight slots or more on one chip)."""
     import jax
     import numpy as np
     from mxtpu.models import llama
@@ -500,6 +505,7 @@ def phase_serve(cfg, jobs, *, mesh=None, precision=None, compare_to=None,
     assert not (must_match and parted), \
         f"streams parted from {against} at {parted}"
     assert expect_attention in (None, info["decode_attention"]), info
+    assert expect_sampler in (None, info["sampler"]), info
     return info, streams
 
 
@@ -665,6 +671,250 @@ def phase_retention_kernel(*, slots=16, n_heads=40, kv_heads=8, head_dim=128,
             "hbm_share": moved / (kernel_ms * 1e-3) / 819e9}
 
 
+# (rows, vocabulary, temperature): the four serve cells' decode samples,
+# and the one row a last prefill chunk samples in the agent cell (fewer
+# than eight rows: the ``jnp`` search ships there, not the kernel)
+SAMPLER_SHAPES = ((32, 200064, 0.6), (32, 128256, 0.7), (16, 151936, 0.7),
+                  (32, 32768, 0.7), (1, 200064, 0.6))
+
+
+def _sorted_thresholds(lg, k, p):
+    """The sampler's two thresholds as it found them from PR 28 to PR 33,
+    frozen here as the comparison: ONE sort of the values, ``kth`` from
+    the sorted row, the nucleus by ``cumsum`` over the softmax of the
+    thresholded sorted row."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    srt = -lax.sort(-lg, dimension=-1, is_stable=False)
+    kth = jnp.take_along_axis(srt, k - 1, axis=-1)
+    srt = jnp.where(srt < kth, -jnp.inf, srt)
+    probs = jax.nn.softmax(srt, axis=-1)
+    keep = (jnp.cumsum(probs, axis=-1) - probs) < p
+    return kth, jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1,
+                        keepdims=True)
+
+
+def _nucleus_slack(row, kth, cut, p):
+    """How far a row's cut-off lies from the nucleus's boundary, as
+    float64 ``numpy`` reckons the mass: 0.0 where ``cut`` is the
+    smallest value with less than ``p`` of the survivors' mass above it,
+    else the mass by which it is too high or too low."""
+    import numpy as np
+    x = np.sort(row[row >= kth].astype(np.float64))[::-1]
+    mass = np.exp(x - x[0])
+    csum = np.cumsum(mass / mass.sum())
+    above = csum[np.searchsorted(-x, -float(cut), side="left") - 1] \
+        if cut < x[0] else 0.0
+    through = csum[np.searchsorted(-x, -float(cut), side="right") - 1]
+    return max(above - p, p - through, 0.0)
+
+
+def phase_sampler_search(*, shapes=SAMPLER_SHAPES, top_p=0.95, top_k=40,
+                         calls=20, interpret=False):
+    """The sampler's threshold search (``ops.threshold.thresholds``: on
+    a TPU the Pallas kernel, eight rows resident in VMEM, for a block
+    of eight rows or more) against the frozen sort form on random rows
+    at the four serve cells' rows x vocabulary and one row of the
+    largest, twice a shape: ``top_p`` alone (the cells' requests),
+    then with ``top_k`` on every other row. ``kth`` must equal the
+    sort's bit for bit; the cut-off must BE the nucleus's boundary to
+    within 2e-6 of mass as float64 reckons it (the float32 sums are
+    taken in another order than ``cumsum``'s; how many rows equal the
+    sort's cut-off bit for bit is printed, and the sort's own slack).
+    Then ms a call of the sort, the ``jnp`` search and the search that
+    ships, for ``top_p`` alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxtpu.ops import threshold
+
+    t0 = time.perf_counter()
+    run = jax.jit(lambda lg, k, p: threshold.thresholds(
+        lg, k, p, interpret=interpret))
+    forms = {"sort": jax.jit(_sorted_thresholds),
+             "search_jnp": jax.jit(threshold._thresholds_jnp),
+             "ships": run}
+    setup_s, t0 = time.perf_counter() - t0, time.perf_counter()
+    facts = {"path": "interpreted" if interpret else {
+        f"{rows}x{V}": threshold.thresholds_path((rows, V), jnp.float32)
+        for rows, V, _ in shapes}}
+    equal = rows_seen = 0
+    worst = worst_sort = 0.0
+    for rows, V, temperature in shapes:
+        lg = jax.random.normal(jax.random.PRNGKey(V), (rows, V),
+                               jnp.float32) / temperature
+        p = jnp.full((rows, 1), top_p, jnp.float32)
+        off = jnp.full((rows, 1), V, jnp.int32)
+        some = jnp.where(jnp.arange(rows)[:, None] % 2 == 0, top_k, off)
+        host = np.asarray(lg)
+        for k in (off, some):
+            (kth, cut), (want_kth, want_cut) = (
+                [np.asarray(a)[:, 0] for a in f(lg, k, p)]
+                for f in (run, forms["sort"]))
+            asked = np.asarray(k)[:, 0] < V
+            np.testing.assert_array_equal(kth[asked], want_kth[asked])
+            assert (kth[~asked] == -np.inf).all()
+            for i in range(rows):
+                worst = max(worst, _nucleus_slack(
+                    host[i], kth[i], cut[i], top_p))
+                worst_sort = max(worst_sort, _nucleus_slack(
+                    host[i], kth[i], want_cut[i], top_p))
+            equal += int((cut == want_cut).sum())
+            rows_seen += rows
+        ms = {}
+        for name, f in forms.items():
+            jax.block_until_ready(f(lg, off, p))
+            t1 = time.perf_counter()
+            for _ in range(calls):
+                out = f(lg, off, p)
+            jax.block_until_ready(out)
+            ms[name] = round(1e3 * (time.perf_counter() - t1) / calls, 4)
+        facts[f"ms_{rows}x{V}"] = ms
+    assert worst <= 2e-6, worst
+    facts.update(cutoffs_equal_to_sort=f"{equal}/{rows_seen}",
+                 worst_slack=worst, worst_slack_of_sort=worst_sort)
+    return {"setup_s": setup_s, "run_s": time.perf_counter() - t0, **facts}
+
+
+# the chat cell's engine (``benchmark/grid/configs``' Mistral serve file)
+# at two layers: the layers are a loop, so depth changes no program
+WARM_SETUP_WIDTHS = dict(vocab_size=32768, dim=4096, n_layers=2, n_heads=32,
+                         n_kv_heads=8, hidden_dim=14336)
+WARM_SETUP_ENGINE = dict(max_slots=32, max_len=2048, min_bucket=128,
+                         page_size=16, n_pages=2049, prefix_cache=True)
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s"}
+_ENGINE_PROGRAMS = ("jit(decode_slots", "jit(prefill_slot", "jit(copy_page")
+
+
+class _NotWritten(logging.Handler):
+    """The programs jax says it did not write to its persistent cache
+    (they compiled in under its threshold), as ``jit(<name>)``."""
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.programs = set()
+
+    def emit(self, record):
+        m = re.match(r"Not writing persistent cache entry for 'jit_(\w+)'",
+                     record.getMessage())
+        if m:
+            self.programs.add(f"jit({m.group(1)})")
+
+
+def phase_serve_warm_setup(cfg, *, buckets=(128, 256, 512, 1024),
+                           **engine_kw):
+    """Where a serve cell's set-up seconds go, program by program,
+    without the benchmark: the chat-shaped engine built TWICE in this
+    process against the one compile cache directory, jax's in-memory
+    caches dropped in between, so that the second pass is what a warm
+    run pays (trace, lower, fetch). A pass asks one request a prefill
+    bucket, two tokens each (the first also runs the decode program and
+    ``copy_page``), and clocks each request; beside it each of the
+    engine's programs the backend was asked for, by jax's own name for
+    it: the seconds of its trace, of its lowering and in the backend
+    (compile or fetch), and whether the persistent cache held it. What
+    the first pass compiled and wrote, the second must find: a program
+    that misses there is compiled in every run of every cell that holds
+    it (PERF.md, PR 34-35). jax writes no executable that compiled in
+    under ``jax_persistent_cache_min_compile_time_secs`` and says so in
+    its debug log, which the phase reads: those programs are named
+    (``unwritten``: every run compiles them again), not failed."""
+    import jax
+    import jax.monitoring
+    import numpy as np
+    from mxtpu.models import llama
+    from mxtpu.serve import Request, ServeEngine
+
+    t0 = time.perf_counter()
+    seen = {"on": True, "cache": "-", "programs": {}}
+
+    def on_event(event, **kw):
+        if seen["on"] and event in _CACHE_EVENTS:
+            seen["cache"] = _CACHE_EVENTS[event]
+
+    def on_duration(event, secs, fun_name="?", **kw):
+        # tracing names a program ``f``, lowering and the backend
+        # ``jit(f)``; the small programs around the engine's (casts,
+        # seeds) share names, so they are one row. The hit or miss is
+        # counted inside the span the backend's event closes
+        if not (seen["on"] and event in _PHASE_EVENTS):
+            return
+        name = fun_name if fun_name.startswith("jit(") \
+            else f"jit({fun_name})"
+        if not name.startswith(_ENGINE_PROGRAMS):
+            name = "others"
+        row = seen["programs"].setdefault(
+            name, {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                   "hit": 0, "miss": 0})
+        phase = _PHASE_EVENTS[event]
+        row[phase] = round(row[phase] + secs, 2)
+        if phase == "backend_s":
+            if seen["cache"] != "-":     # "miss": compiled AND written
+                row[seen["cache"]] += 1
+            seen["cache"] = "-"
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    params = jax.jit(lambda k: llama.init_params(cfg, k))(
+        jax.random.PRNGKey(0))
+    jax.block_until_ready(params)
+    setup_s, t0 = time.perf_counter() - t0, time.perf_counter()
+    rng = np.random.default_rng(2)
+    skipped = _NotWritten()
+    # jax's debug lines go to the phase alone, not on to stderr
+    compiler_log = logging.getLogger("jax._src.compiler")
+    level, onward = compiler_log.level, compiler_log.propagate
+    compiler_log.addHandler(skipped)
+    compiler_log.setLevel(logging.DEBUG)
+    compiler_log.propagate = False
+
+    def one_pass():
+        seen["programs"] = {}
+        engine = ServeEngine(cfg, params, paged=True, **engine_kw)
+        calls = {}
+        for b in buckets:
+            t1 = time.perf_counter()
+            engine.submit(Request(
+                prompt=rng.integers(0, cfg.vocab_size, b - 1).tolist(),
+                max_new_tokens=2, temperature=0.7, top_p=0.95, seed=7))
+            engine.run()
+            calls[f"b{b}"] = round(time.perf_counter() - t1, 2)
+        assert engine.n_buckets == len(buckets), engine.n_buckets
+        return calls, seen["programs"], engine.kv_cache_stats()
+
+    try:
+        # both builds from ONE line: a kernel's payload holds its call
+        # sites, and another line here would be another cache key
+        passes = []
+        for _ in range(2):
+            jax.clear_caches()
+            passes.append(one_pass())
+        (first_calls, first, kv), (second_calls, second, _) = passes
+    finally:
+        seen["on"] = False
+        compiler_log.removeHandler(skipped)
+        compiler_log.setLevel(level)
+        compiler_log.propagate = onward
+    mine = [n for n in first if n != "others"]
+    unwritten = sorted(set(mine) & skipped.programs)
+    cold = sorted(n for n in mine if n not in skipped.programs
+                  and not second.get(n, {}).get("hit"))
+    assert not cold, (
+        f"compiled again on a second build against the same cache: {cold}",
+        first, second)
+    return {"setup_s": setup_s, "run_s": time.perf_counter() - t0,
+            "decode_attention": kv["decode_attention"],
+            "sampler": kv["sampler"],
+            "first_calls_s": first_calls, "second_calls_s": second_calls,
+            "unwritten": unwritten, "first": first, "second": second}
+
+
 def phase_sambay_kernel(*, slots=32, n_heads=40, kv_heads=10, head_dim=128,
                         page_size=16, capacity=6144, lengths=(1024, 4400),
                         reads=8, interpret=False):
@@ -735,7 +985,8 @@ def phase_sambay_kernel(*, slots=32, n_heads=40, kv_heads=10, head_dim=128,
 
 
 def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
-                       expect_attention_f32="gathered", **engine_kw):
+                       expect_attention_f32="gathered", expect_sampler=None,
+                       **engine_kw):
     """A serving family other than llama (``models.serving_family(cfg)``:
     ``sambay.py``'s state-space, window, full, GMU and cross-attention
     layers; ``latent_moe.py``'s latent attention and routed experts;
@@ -751,7 +1002,10 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
     pool through (``"pages"`` on the chip: a bf16 pool the family's
     kernel takes as stored); the float32 pass gathers everywhere
     (``expect_attention_f32``; a retention step reads no pool and says
-    ``"state"`` in both)."""
+    ``"state"`` in both); ``expect_sampler`` is what it must say of its
+    sampler's threshold search (``"search_kernel"`` on the chip for a
+    bank of eight slots: the kernel is in the decode program at the
+    family's own vocabulary; a greedy row asks it for nothing)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -796,6 +1050,7 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
                 kv = engine.kv_cache_stats()
                 assert kv["reserved_bytes"] > 0, kv
                 info[f"decode_attention_{name}"] = kv["decode_attention"]
+                info["sampler"] = kv["sampler"]
             finally:
                 gw.close()
             fwd = jax.jit(lambda p, t: family.forward(c, p, t))
@@ -815,6 +1070,7 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
             t0 = time.perf_counter()
     assert info["worst_gap_float32"] <= tol_f32, info
     assert info["decode_attention_float32"] == expect_attention_f32, info
+    assert expect_sampler in (None, info["sampler"]), info
     assert expect_attention in (
         None, info[f"decode_attention_{np.dtype(cfg.dtype).name}"]), info
     return info
@@ -863,8 +1119,13 @@ def main():
         **WIDTHS, max_seq_len=SERVE_ENGINE["max_len"], remat=False)
     jobs = make_jobs(serve_cfg.vocab_size, SERVE_SHAPES)
     _run("pages_kernel", phase_pages_kernel)
+    _run("sampler_search", phase_sampler_search)
+    _run("serve_warm_setup", phase_serve_warm_setup,
+         llama.LlamaConfig(**WARM_SETUP_WIDTHS,
+                           max_seq_len=WARM_SETUP_ENGINE["max_len"],
+                           remat=False), **WARM_SETUP_ENGINE)
     streams = _run("serve", phase_serve, serve_cfg, jobs, **SERVE_ENGINE,
-                   expect_attention="pages")
+                   expect_attention="pages", expect_sampler="search_kernel")
     _run("serve_f32", phase_serve,
          replace(serve_cfg, dtype=jnp.float32), jobs, **SERVE_ENGINE,
          precision="highest", must_match=True,
@@ -879,8 +1140,8 @@ def main():
     _run("serve_sambay", phase_serve_family, sambay_cfg,
          make_jobs(sambay_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
                    shared_prefix=0),
-         max_slots=4, max_len=2048, min_bucket=256,
-         expect_attention="pages")
+         max_slots=8, max_len=2048, min_bucket=256,
+         expect_attention="pages", expect_sampler="search_kernel")
 
     # the third, at its published widths and a small depth (one dense
     # and two expert layers, all 128 experts): the longer prompt is
@@ -891,8 +1152,8 @@ def main():
     _run("serve_latent_moe", phase_serve_family, moe_cfg,
          make_jobs(moe_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
                    shared_prefix=0),
-         max_slots=4, max_len=2048, min_bucket=256, prefill_chunk=1024,
-         expect_attention="pages")
+         max_slots=8, max_len=2048, min_bucket=256, prefill_chunk=1024,
+         expect_attention="pages", expect_sampler="search_kernel")
 
     # the fourth, at its published widths and a small depth (two
     # power-retention layers): the longer prompt is prefilled in two
@@ -905,9 +1166,10 @@ def main():
     _run("serve_retention", phase_serve_family, ret_cfg,
          make_jobs(ret_cfg.vocab_size, SAMBAY_SHAPES, per_shape=2,
                    shared_prefix=0),
-         max_slots=4, max_len=2048, min_bucket=256, prefill_chunk=1024,
+         max_slots=8, max_len=2048, min_bucket=256, prefill_chunk=1024,
          expect_attention="state_kernel",
-         expect_attention_f32="state_kernel")
+         expect_attention_f32="state_kernel",
+         expect_sampler="search_kernel")
 
     if jax.device_count() >= 4:
         # the same two phases over a mesh with more than one

@@ -565,7 +565,7 @@ def decode_slots_paged(cfg: LatentMoEConfig, params, kv, sv, active,
     del mesh
     logits, pool, counts = decode_logits(cfg, params, kv, sv, active,
                                          page_table)
-    new_rngs, sampled = jax.vmap(llama._sample_slot)(
+    new_rngs, sampled = llama._sample_slots(
         sv["rngs"], logits, temperature, top_k, top_p)
     # the busiest experts' loads as a share of the assignments, in
     # millionths (the read-back is one int32 array)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.threshold import thresholds
 from ..ops.attention import (flash_attention, dense_attention,
                              ring_attention, ulysses_attention,
                              slot_decode_attention,
@@ -871,7 +872,8 @@ def decode_step(cfg: LlamaConfig, params, token, cache,
 
 
 @jax.named_scope(SAMPLER_SCOPE)
-def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None):
+def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None,
+                  mesh: Optional[Mesh] = None):
     """THE sampler — one shared helper for :func:`generate` and the
     continuous-batching serving engine (``mxtpu.serve``). lg: (b, V)
     f32 logits → (b,) int32 tokens.
@@ -888,22 +890,29 @@ def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None):
       rows selecting argmax. This is how the serving engine runs
       requests with different sampling configs through ONE compiled
       decode program, with tokens bit-matching the static path: the
-      top-k threshold is the same kth VALUE, the nucleus keep-mask the
-      same formula, so the masked logits agree and
+      top-k threshold is the same kth VALUE, the nucleus cut-off the
+      same search, so the masked logits agree and
       ``jax.random.categorical`` sees identical inputs.
 
     Nucleus semantics (both modes): keep the smallest prefix of the
-    sorted distribution whose mass reaches p — probabilities computed
-    ONCE, and the survivor set applied as a value threshold (the kept
-    minimum) rather than a full-vocab scatter.
+    sorted distribution whose mass reaches p (the top token always
+    survives, a tie-class is kept or cut as a whole), applied as a
+    value threshold rather than a full-vocab scatter.
 
-    The order comes from a sort of the VALUES (:func:`_sort_descending`),
-    never from a permutation: both thresholds read sorted values only,
-    and an ``argsort`` with a ``take_along_axis`` through it is a
-    vocabulary-sized gather a row, which the TPU carries out one float
-    at a time. The traced mode sorts ONCE: the top-k threshold cuts a
-    suffix of the sorted row, so the same threshold applied to that
-    row is the sorted order the nucleus wants."""
+    No order is built. The sampler needs two numbers a row, the k-th
+    largest value and the nucleus cut-off, and both are monotone in the
+    threshold: the COUNT of values at or above ``v`` and the MASS of
+    values above ``v`` only fall as ``v`` rises. ``ops.threshold``
+    finds each by bisection on the float32's ordered bit pattern, 32
+    probes of a compare, a select and a sum over the row (on a TPU one
+    Pallas kernel with the rows resident in VMEM, for a block of eight
+    rows or more; ``jnp`` elsewhere and for fewer),
+    exact for any distribution, and skips a search that no row asks for
+    (``top_k`` off, ``top_p`` 1, a greedy row). A sort of a 200064-wide
+    row was the costliest operation of a decode step (PR 33), and a
+    permutation with a ``take_along_axis`` through it a
+    vocabulary-sized gather a row besides. ``mesh``: the mesh the
+    logits are sharded over, if any (the kernel is not partitioned)."""
     static = (isinstance(temperature, (int, float))
               and (top_k is None or isinstance(top_k, int))
               and (top_p is None or isinstance(top_p, (int, float))))
@@ -916,70 +925,76 @@ def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None):
             kth = lax.top_k(lg, top_k)[0][..., -1:]
             lg = jnp.where(lg < kth, -jnp.inf, lg)
         if top_p is not None and top_p < 1.0:
-            lg = _nucleus_mask(lg, top_p)
+            lg = _nucleus_mask(lg, top_p, mesh)
         return jax.random.categorical(rng, lg, axis=-1) \
             .astype(jnp.int32)
+    greedy, slg = _masked_logits(lg, temperature, top_k, top_p, mesh)
+    sampled = jax.random.categorical(rng, slg, axis=-1) \
+        .astype(jnp.int32)
+    return jnp.where(greedy >= 0, greedy, sampled)
+
+
+def _masked_logits(lg, temperature, top_k, top_p, mesh=None):
+    """The traced mode's rows as ``categorical`` will see them. lg: (b,
+    V); temperature, top_k, top_p: None, scalars or (b,). Returns
+    (greedy (b,) int32: the argmax of a temperature-0 row, -1 for a row
+    that samples; the logits over the temperature, masked to the top-k
+    values and, of those, the top-p nucleus)."""
+    V = lg.shape[-1]
 
     def col(x, dtype):          # broadcast a scalar or (b,) over vocab
         x = jnp.asarray(x, dtype)
-        return x.reshape(x.shape + (1,) * (lg.ndim - x.ndim))
+        x = x.reshape(x.shape + (1,) * (lg.ndim - x.ndim))
+        return jnp.broadcast_to(x, lg.shape[:-1] + (1,))
 
     t_col = col(temperature, jnp.float32)
-    k_col = jnp.clip(col(V if top_k is None else top_k, jnp.int32),
-                     1, V)
-    p_col = col(1.0 if top_p is None else top_p, jnp.float32)
-
-    greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-    slg = lg / jnp.where(t_col == 0.0, 1.0, t_col)
+    samples = t_col != 0.0
+    # a greedy row asks for no threshold: its argmax is its token
+    k_col = jnp.where(samples, jnp.clip(
+        col(V if top_k is None else top_k, jnp.int32), 1, V), V)
+    p_col = jnp.where(samples,
+                      col(1.0 if top_p is None else top_p, jnp.float32),
+                      1.0)
+    greedy = jnp.where(jnp.squeeze(samples, -1), -1,
+                       jnp.argmax(lg, axis=-1).astype(jnp.int32))
+    slg = lg / jnp.where(samples, t_col, 1.0)
     # top-k as a value threshold: the kth-largest VALUE equals
-    # lax.top_k's kth element, so the mask matches the static path.
-    # The threshold cuts a suffix of the sorted row, so the same
-    # ``where`` on it IS the thresholded logits in sorted order: the
-    # nucleus needs no second sort
-    srt = _sort_descending(slg)
-    kth = jnp.take_along_axis(srt, jnp.broadcast_to(
-        k_col - 1, slg.shape[:-1] + (1,)), axis=-1)
-    slg = jnp.where(slg < kth, -jnp.inf, slg)
-    srt = jnp.where(srt < kth, -jnp.inf, srt)
-    slg = jnp.where(slg >= _nucleus_cutoff(srt, p_col), slg, -jnp.inf)
-    sampled = jax.random.categorical(rng, slg, axis=-1) \
-        .astype(jnp.int32)
-    return jnp.where(jnp.squeeze(t_col, -1) == 0.0, greedy, sampled)
+    # lax.top_k's kth element, so the mask matches the static path;
+    # the nucleus cut-off is found over the top-k survivors
+    kth, cutoff = thresholds(slg, k_col, p_col, mesh=mesh)
+    return greedy, jnp.where(slg >= jnp.maximum(kth, cutoff), slg,
+                             -jnp.inf)
 
 
-def _sample_slot(key, lg, temperature, top_k, top_p):
-    """One slot's decode-step sample, mirroring generate's step: split
-    the slot's chain, sample on (1, V). Returns (carry key, token)."""
-    with jax.named_scope(SAMPLER_SCOPE):
-        key, sub = jax.random.split(key)
-    tok = sample_logits(sub, lg[None], temperature=temperature,
-                        top_k=top_k, top_p=top_p)[0]
-    return key, tok
-
-
-def _sort_descending(lg):
-    """lg's rows, largest first: a sort of the VALUES — one operand, no
-    index beside it, stability not asked for (equal values are
-    interchangeable). Why not ``argsort``: see :func:`sample_logits`."""
-    return -lax.sort(-lg, dimension=-1, is_stable=False)
-
-
-def _nucleus_cutoff(sorted_lg, top_p):
-    """The smallest logit of the top-p nucleus, from a row that is
-    already sorted largest first: softmax ONCE over it, keep the
-    smallest prefix reaching p (the top token always survives)."""
-    probs = jax.nn.softmax(sorted_lg, axis=-1)
-    csum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = (csum - probs) < top_p
-    return jnp.min(jnp.where(keep_sorted, sorted_lg, jnp.inf),
-                   axis=-1, keepdims=True)
-
-
-def _nucleus_mask(lg, top_p):
+def _nucleus_mask(lg, top_p, mesh=None):
     """Mask lg to the top-p nucleus, the survivor set applied as a >=
     threshold on the kept minimum — no full-vocab scatter."""
-    cutoff = _nucleus_cutoff(_sort_descending(lg), top_p)
+    rows = lg.shape[:-1] + (1,)
+    _, cutoff = thresholds(lg, jnp.full(rows, lg.shape[-1], jnp.int32),
+                           jnp.full(rows, top_p, jnp.float32), mesh=mesh)
     return jnp.where(lg >= cutoff, lg, -jnp.inf)
+
+
+def _draw(key, greedy, row):
+    """One token of one chain: ``greedy`` where the row is greedy, else
+    ``categorical`` on the masked (V,) row, as :func:`sample_logits`
+    draws it from a (1, V) batch."""
+    tok = jax.random.categorical(key, row[None], axis=-1)[0] \
+        .astype(jnp.int32)
+    return jnp.where(greedy >= 0, greedy, tok)
+
+
+@jax.named_scope(SAMPLER_SCOPE)
+def _sample_slots(rngs, lg, temperature, top_k, top_p,
+                  mesh: Optional[Mesh] = None):
+    """A decode step's sample for a bank of slots, mirroring generate's
+    step slot by slot: the two thresholds of every row found at once
+    over the (S, V) block, then each slot splits its own chain and
+    draws from its own masked row, so equal masks give equal tokens.
+    Returns (carry keys (S, 2), tokens (S,))."""
+    greedy, masked = _masked_logits(lg, temperature, top_k, top_p, mesh)
+    keys = jax.vmap(jax.random.split)(rngs)
+    return keys[:, 0], jax.vmap(_draw)(keys[:, 1], greedy, masked)
 
 
 def generate(cfg: LlamaConfig, params, prompt, max_new_tokens: int,
@@ -1020,7 +1035,7 @@ def generate(cfg: LlamaConfig, params, prompt, max_new_tokens: int,
 
     def sample(rng, lg):
         return sample_logits(rng, lg, temperature=temperature,
-                             top_k=top_k, top_p=top_p)
+                             top_k=top_k, top_p=top_p, mesh=mesh)
 
     rng, sub = jax.random.split(rng)
     first = sample(sub, logits[:, -1])
@@ -1092,7 +1107,7 @@ def prefill_detached(cfg: LlamaConfig, params, tokens, true_len, rng,
                                   last_index=true_len - 1)
     rng, sub = jax.random.split(rng)
     tok = sample_logits(sub, logits[:, 0], temperature=temperature,
-                        top_k=top_k, top_p=top_p)
+                        top_k=top_k, top_p=top_p, mesh=mesh)
     k_block, v_block = tmp["k"][:, 0], tmp["v"][:, 0]
     if mesh is not None:
         # the block leaves the device for the wire — replicate it so
@@ -1135,7 +1150,7 @@ def prefill_detached_chunk(cfg: LlamaConfig, params, chunk, cache,
                                     mesh=mesh, last_index=li)
     rng, sub = jax.random.split(rng)
     tok = sample_logits(sub, logits[:, 0], temperature=temperature,
-                        top_k=top_k, top_p=top_p)
+                        top_k=top_k, top_p=top_p, mesh=mesh)
     pos0 = cache["pos"] - cw
     k_chunk = lax.dynamic_slice_in_dim(cache["k"][:, 0], pos0, cw,
                                        axis=2)
@@ -1471,8 +1486,8 @@ def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _lm_head(cfg, params, x)[:, 0]
 
-    new_rngs, sampled = jax.vmap(_sample_slot)(
-        sv["rngs"], logits, temperature, top_k, top_p)
+    new_rngs, sampled = _sample_slots(
+        sv["rngs"], logits, temperature, top_k, top_p, mesh)
     new_lengths = lengths + active.astype(jnp.int32)
     if mesh is not None:
         sampled = _mcon(mesh, sampled, None)
@@ -1580,7 +1595,7 @@ def prefill_slot_paged(cfg: LlamaConfig, params, tokens, true_len,
                                   last_index=true_len - prefix_len - 1)
     rng, sub = jax.random.split(rng)
     tok = sample_logits(sub, logits[:, 0], temperature=temperature,
-                        top_k=top_k, top_p=top_p)
+                        top_k=top_k, top_p=top_p, mesh=mesh)
     new_kv = _scatter_slot_pages(kv, pages_row, tmp["k"], tmp["v"],
                                  prefix_len, bucket, int8)
     z = jnp.zeros((), jnp.int32)
@@ -1766,23 +1781,32 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
         axis=1)                           # draft verified by emission i
     has = nxt >= 0
 
-    def one(key, lgs, nx, hs, t, kk, pp):
+    # every position's two thresholds at once over the (S W, V) block;
+    # the scan below only splits and draws
+    with jax.named_scope(SAMPLER_SCOPE):
+        per_pos = lambda a: jnp.repeat(a, W)
+        greedy, masked = _masked_logits(
+            logits.reshape(S * W, -1), per_pos(temperature),
+            per_pos(top_k), per_pos(top_p), mesh)
+        greedy, masked = greedy.reshape(S, W), masked.reshape(S, W, -1)
+
+    def one(key, rows, gr, nx, hs):
         def step(carry, inp):
             key, ok = carry
-            lg, nd, h = inp
-            key2, sub = jax.random.split(key)
-            tok = sample_logits(sub, lg[None], temperature=t,
-                                top_k=kk, top_p=pp)[0]
+            row, g, nd, h = inp
+            with jax.named_scope(SAMPLER_SCOPE):
+                key2, sub = jax.random.split(key)
+                tok = _draw(sub, g, row)
             emit = ok
             key = jnp.where(emit, key2, key)
             ok = ok & h & (tok == nd)
             return (key, ok), (tok, emit)
         (key, _), (tk, em) = lax.scan(
-            step, (key, jnp.bool_(True)), (lgs, nx, hs))
+            step, (key, jnp.bool_(True)), (rows, gr, nx, hs))
         return key, tk, em
 
     new_rngs, toks, emits = jax.vmap(one)(
-        sv["rngs"], logits, nxt, has, temperature, top_k, top_p)
+        sv["rngs"], masked, greedy, nxt, has)
     # dtype pinned: under x64 a default integer sum promotes to int64,
     # which would flip the lengths dtype and retrace every program
     n_emit = jnp.sum(emits, axis=1, dtype=jnp.int32)  # (S,) in 1..W

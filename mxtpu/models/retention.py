@@ -377,7 +377,7 @@ def decode_slots_paged(cfg: RetentionConfig, params, kv, sv, active,
     engine's empty one. Returns (sampled tokens (S,), new kv, new sv)."""
     del page_table, mesh
     logits, kv = decode_logits(cfg, params, kv, sv, active)
-    new_rngs, sampled = jax.vmap(llama._sample_slot)(
+    new_rngs, sampled = llama._sample_slots(
         sv["rngs"], logits, temperature, top_k, top_p)
     return sampled, kv, {
         "lengths": sv["lengths"].astype(jnp.int32)

@@ -732,8 +732,8 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
     x = _cross_decoder(cfg, params, x, memory, attend)
     logits = _final(cfg, params, x)[:, 0]
 
-    new_rngs, sampled = jax.vmap(llama._sample_slot)(
-        sv["rngs"], logits, temperature, top_k, top_p)
+    new_rngs, sampled = llama._sample_slots(
+        sv["rngs"], logits, temperature, top_k, top_p, mesh)
     new_kv = {"k": ck, "v": cv, "wk": wk, "wv": wv, "conv": conv,
               "ssm": ssm}
     return sampled, new_kv, {"lengths": lengths + active.astype(jnp.int32),

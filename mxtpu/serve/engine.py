@@ -63,6 +63,7 @@ import numpy as np
 from .. import telemetry
 from ..telemetry import distributed as dtrace
 from ..models import serving_family
+from ..ops.threshold import thresholds_path
 
 __all__ = ["Request", "KVHandoff", "ServeEngine", "bucket_for",
            "resume_key", "PageAllocator", "PrefixCache",
@@ -75,7 +76,7 @@ _WAIT_STEP_BUCKETS = (0.0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 1024)
 _engine_seq = itertools.count(1)     # atomic: engines build on threads
 
 
-def _engine_metrics(eid: str, attention: Dict[str, str]):
+def _engine_metrics(eid: str, attention: Dict[str, str], sampler: str):
     """Process-wide serve metrics (one handle set per engine; the
     registry interns children, so every engine shares the TOTALS).
     Point-in-time gauges are labelled per engine instead — two live
@@ -91,15 +92,19 @@ def _engine_metrics(eid: str, attention: Dict[str, str]):
             "serve_steps_total", "Decode steps dispatched"),
         # the same steps by what their program's attention was built on
         # (static per compiled program; ``attention`` maps "plain" and
-        # "verify" to the family's word for each)
+        # "verify" to the family's word for each) and by how their
+        # sampler finds its two thresholds
         **{"steps_" + step: telemetry.counter(
             "serve_decode_steps_total",
             "Decode steps dispatched, by the attention their program "
             "was built with: pages (the kernel reads live pages out of "
             "the pool), gathered (every slot's whole row copied out), "
             "state_kernel or state (a retention step over a fixed state "
-            "a slot: one Pallas kernel a layer, or the jnp form)",
-            attention=path) for step, path in attention.items()},
+            "a slot: one Pallas kernel a layer, or the jnp form); and by "
+            "its sampler's threshold search: search_kernel (one Pallas "
+            "kernel, the rows resident in VMEM) or search (the jnp form)",
+            attention=path, sampler=sampler)
+           for step, path in attention.items()},
         "queue": telemetry.gauge(
             "serve_queue_depth", "Requests queued, not yet admitted",
             engine=eid),
@@ -672,6 +677,11 @@ class ServeEngine:
             "plain": fam.decode_attention_path(cfg, state, mesh),
             "verify": fam.decode_attention_path(cfg, state, mesh,
                                                 verify=True)}
+        # how the decode programs' sampler finds its two thresholds
+        # over the bank's (slots, vocabulary) logits ("search_kernel" |
+        # "search"): ops.threshold's choice, exported as it is
+        self._sampler = thresholds_path(
+            (self.max_slots, cfg.vocab_size), np.float32, mesh=mesh)
         # the kv state is donated through every program (in-place in
         # HBM); the small vectors are not, so the previous step's
         # sampled tokens stay readable during the overlapped sync.
@@ -741,7 +751,7 @@ class ServeEngine:
                 self.prefill_chunk)
         eid = str(next(_engine_seq))
         self.engine_id = eid
-        self._m = _engine_metrics(eid, self._attention)
+        self._m = _engine_metrics(eid, self._attention, self._sampler)
         self._m_cancel: Dict[str, Any] = {}    # per-reason counters
         # span factories pre-bind their registry histograms — the
         # per-step/per-admission hot paths must not re-intern handles.
@@ -1779,9 +1789,13 @@ class ServeEngine:
         kernel over live pages), ``"gathered"``, or a retention step's
         ``"state_kernel"`` / ``"state"`` (no keys or values: the Pallas
         kernel over the bank, or the ``jnp`` form), as the family's
-        ``decode_attention_path`` gave it. Host arithmetic only (the mirrored
-        per-slot lengths; reading the device ``lengths`` vector here
-        would put a sync next to the decode loop — MXL004)."""
+        ``decode_attention_path`` gave it; ``"sampler"`` is how the
+        decode program's sampler finds its two thresholds,
+        ``"search_kernel"`` (the Pallas kernel) or ``"search"`` (the
+        ``jnp`` form), as ``ops.threshold.thresholds_path`` gave it.
+        Host arithmetic only (the mirrored per-slot lengths; reading the
+        device ``lengths`` vector here would put a sync next to the
+        decode loop — MXL004)."""
         with self._lock:
             active = int(self._active.sum())
             live = self._live_bytes()
@@ -1790,6 +1804,7 @@ class ServeEngine:
                    "state_bytes_per_slot": self._slot_state_bytes,
                    "paged": True,
                    "decode_attention": self._attention["plain"],
+                   "sampler": self._sampler,
                    "page_size": self.page_size,
                    "pages_total": self.n_pages - 1,
                    "pages_free": self._pages.free_pages,

@@ -83,3 +83,23 @@ def engine_factory(cfg, params, **kw):
     kw.setdefault("max_len", 32)
     kw.setdefault("min_bucket", 4)
     return lambda params=params: ServeEngine(cfg, params, **kw)
+
+
+def mass_before(slg, top_k):
+    """float64 ``numpy``, the sampler tests' nucleus oracle: for every
+    token of every row, the probability mass of the top-k survivors
+    whose value lies strictly above the token's (what the nucleus
+    compares with ``top_p``); a tie-class shares the mass above its
+    first member. slg: (b, V) the logits over the temperature; top_k:
+    (b,)."""
+    slg = np.asarray(slg, np.float64)
+    out = np.empty_like(slg)
+    for i, row in enumerate(slg):
+        order = np.argsort(-row, kind="stable")
+        srt = row[order]
+        srt = np.where(srt < srt[int(top_k[i]) - 1], -np.inf, srt)
+        probs = np.exp(srt - srt[0])
+        probs /= probs.sum()
+        before = np.cumsum(probs) - probs
+        out[i, order] = before[np.searchsorted(-srt, -srt, side="left")]
+    return out

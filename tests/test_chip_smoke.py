@@ -9,6 +9,7 @@ import sys
 from dataclasses import replace
 
 import jax
+import pytest
 
 import llama_refs
 from mxtpu import runtime
@@ -52,6 +53,59 @@ def test_pages_kernel_phase_runs_tiny_on_cpu():
         capacity=32, layers=2, interpret=True)
     assert info["lengths"] == [1, 32]      # the phase holds the numbers
     assert info["largest_output"] > 0.0
+
+
+def test_sampler_search_phase_runs_tiny_on_cpu():
+    """The search-against-the-frozen-sort leg, the kernel interpreted:
+    a vocabulary of no whole tile and one of two, ``top_p`` alone and
+    ``top_k`` on every other row; the phase holds the thresholds."""
+    info = chip_smoke.phase_sampler_search(
+        shapes=((5, 1031, 0.7), (8, 256, 0.6)), calls=1, interpret=True)
+    assert info["path"] == "interpreted"
+    assert info["cutoffs_equal_to_sort"] == "26/26"
+    assert info["worst_slack"] <= 2e-6
+    assert set(info["ms_5x1031"]) == {"sort", "search_jnp", "ships"}
+
+
+@pytest.mark.parametrize("floor", [0.0, 60.0], ids=["keeps_all", "keeps_none"])
+def test_serve_warm_setup_phase_runs_tiny_on_cpu(tmp_path, floor):
+    """The set-up leg at toy widths: two builds of the engine, a row a
+    program's first call in each. Against a cache directory that keeps
+    everything (threshold 0) the second build fetches every program it
+    asks the backend for and compiles none of its own again; against
+    one that keeps nothing (no toy program compiles for a minute) the
+    phase reads jax's own "not writing" lines, names the engine's
+    programs ``unwritten`` and does not fail them for missing again."""
+    old = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    try:
+        info = chip_smoke.phase_serve_warm_setup(
+            llama_refs.serve_config(), buckets=(4, 8), max_slots=2,
+            max_len=32, min_bucket=4, page_size=8, n_pages=25,
+            prefix_cache=True)
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+    assert list(info["first_calls_s"]) == list(info["second_calls_s"]) \
+        == ["b4", "b8"]
+    programs = ["jit(decode_slots_paged)", "jit(prefill_slot_paged_b4)",
+                "jit(prefill_slot_paged_b8)"]
+    assert info["sampler"] == "search"
+    assert set(programs) <= set(info["unwritten"]) if floor else \
+        info["unwritten"] == []
+    for name in programs:
+        # jax counts a miss where it writes what it compiled
+        assert info["first"][name]["miss"] == (0 if floor else 1), \
+            info["first"]
+        row = info["second"][name]
+        assert (row["hit"], row["miss"]) == ((0, 0) if floor else (1, 0)), \
+            info["second"]
+        assert {"trace_s", "lower_s", "backend_s"} <= set(row), row
 
 
 def test_latent_kernel_phase_runs_tiny_on_cpu():

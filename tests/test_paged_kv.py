@@ -452,8 +452,10 @@ def test_decode_attention_path_is_read_off_the_inputs(monkeypatch, case,
                                           ("tpu", "pages")])
 def test_engine_names_the_attention_its_program_was_built_with(
         monkeypatch, cfg, params, backend, path):
-    """``kv_cache_stats()`` and ``serve_decode_steps_total{attention}``
-    carry the family's word; the counter steps where
+    """``kv_cache_stats()`` and ``serve_decode_steps_total{attention,
+    sampler}`` carry the family's word and the threshold search's (the
+    Pallas kernel on a TPU for a bank of eight slots or more, the
+    ``jnp`` form elsewhere); the counter steps where
     ``serve_steps_total`` does."""
     from mxtpu import telemetry
     if backend == "tpu":
@@ -466,20 +468,25 @@ def test_engine_names_the_attention_its_program_was_built_with(
     eng = paged_engine(cfg, params)
     assert eng.kv_cache_stats()["decode_attention"] == path
     assert path == llama.decode_attention_path(cfg, eng._kv, None)
+    # a bank of two slots is no block of eight rows, on any backend
+    assert eng.kv_cache_stats()["sampler"] == "search"
     if backend == "tpu":
+        bank = paged_engine(cfg, params, max_slots=8)
+        assert bank.kv_cache_stats()["sampler"] == "search_kernel"
         return
     reg = telemetry.registry()
-    before = {a: reg.value("serve_decode_steps_total", attention=a)
+    before = {a: reg.value("serve_decode_steps_total", attention=a,
+                           sampler="search")
               for a in ("pages", "gathered")}
     steps = reg.value("serve_steps_total")
     eng.submit(Request(prompt=[5, 6, 7], max_new_tokens=4))
     eng.run()
     ran = reg.value("serve_steps_total") - steps
     assert ran >= 3
-    assert reg.value("serve_decode_steps_total",
-                     attention="gathered") - before["gathered"] == ran
-    assert reg.value("serve_decode_steps_total",
-                     attention="pages") == before["pages"]
+    assert reg.value("serve_decode_steps_total", attention="gathered",
+                     sampler="search") - before["gathered"] == ran
+    assert reg.value("serve_decode_steps_total", attention="pages",
+                     sampler="search") == before["pages"]
 
 
 # ---------------------------------------------------------------------------

@@ -473,7 +473,7 @@ def test_family_surface_and_a_pool_of_no_pages(served):
     assert not any(f'engine="{eng.engine_id}",kind="kv_pages"' in ln
                    for ln in prom)
     assert any(ln.startswith('mxtpu_serve_decode_steps_total{attention='
-                             '"state"}') for ln in prom)
+                             '"state",sampler="search"}') for ln in prom)
     # the bank's copy_page has nothing to copy
     bank, _ = _bank()
     assert retention.copy_page(bank, 0, 1) is bank

@@ -323,12 +323,13 @@ def test_programs_gather_once_and_cross_decode_one_position(params):
 def _agent_shapes(dtype=jnp.bfloat16, **widths):
     """(cfg, kv state as shapes): Phi-4-mini-flash's attention widths
     (40 query / 20 KV heads of 64, read as 40 over 10 paired heads of
-    128) at eight layers, a pool of 16-token pages under 4 slots."""
+    128) at eight layers, a pool of 16-token pages under 8 slots (a
+    whole block of the sampler's kernel)."""
     from dataclasses import replace
     cfg = replace(sambay.SambaYConfig(n_layers=8, max_seq_len=256,
                                       vocab_size=512, hidden_dim=256),
                   dtype=dtype, param_dtype=dtype, **widths)
-    state = jax.eval_shape(lambda: sambay.init_paged_cache(cfg, 4, 65, 16))
+    state = jax.eval_shape(lambda: sambay.init_paged_cache(cfg, 8, 65, 16))
     return cfg, {n: a for n, a in state.items()
                  if n not in ("lengths", "tokens", "rngs")}
 
@@ -343,8 +344,11 @@ def test_decode_attention_path_is_read_off_the_inputs(monkeypatch, case,
     TPU over bfloat16 pools whose paired heads are whole lane tiles the
     full layer and the cross layers' scan each hold ONE call of the rows
     kernel and nothing gathers the pool; everywhere else the pool is
-    gathered once for K and once for V and no kernel is traced."""
+    gathered once for K and once for V and no kernel is traced (but the
+    sampler's: its threshold search is ONE kernel call on a TPU, whatever
+    the pool, and none under a mesh)."""
     from mxtpu.ops.paged_attention import ROWS_KERNEL_NAME
+    from mxtpu.ops.threshold import KERNEL_NAME as SEARCH
     if case != "cpu_backend":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, kv = _agent_shapes(
@@ -369,7 +373,10 @@ def test_decode_attention_path_is_read_off_the_inputs(monkeypatch, case,
         abstract((S,), jnp.float32), abstract((S,), jnp.int32),
         abstract((S,), jnp.float32))
     eqns = list(_eqns(jaxpr.jaxpr))
-    walks = [e for e in eqns if e.primitive.name == "pallas_call"]
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    walks = [e for e in kernels if e.params["name"] != SEARCH]
+    assert len(kernels) - len(walks) == (
+        case != "cpu_backend" and mesh is None)
     gathers = [e for e in eqns if e.primitive.name == "gather"
                and e.invars[0].aval.shape == kv["k"].shape]
     if path == "pages":

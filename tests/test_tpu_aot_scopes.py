@@ -6,8 +6,9 @@ paged program moves the KV pool — the write updates the donated pool in
 place and the attention reads it (the plain step through ONE Pallas
 kernel call a layer that walks live pages, so that no gathered row
 exists; the verify step through the page gather), nothing else touches
-pool-sized bytes; and the sampler orders a vocabulary by ONE sort of its
-values, with no permutation to gather through. Code that asks
+pool-sized bytes; and the sampler builds no order of a vocabulary: no
+``sort`` and no permutation to gather through, its two thresholds come
+from ONE call of the search kernel. Code that asks
 ``jax.default_backend()`` here still sees the CPU (section 2 of the
 guide), and the rule that picks the kernel asks, so the fixtures that
 lower llama's programs answer ``"tpu"`` for it while they trace, as the
@@ -119,15 +120,17 @@ def compiled(one_chip):
     scalar = partial(arg, ())
     prefill = partial(llama.prefill_slot_paged, cfg)
     prefill.__name__ = "prefill_slot_paged"
+    with _as_on_a_tpu():
+        prefill = jax.jit(prefill, donate_argnums=(6,)).lower(
+            params, arg((1, BUCKET), jnp.int32), scalar(jnp.int32),
+            scalar(jnp.int32), arg((per_slot,), jnp.int32),
+            scalar(jnp.int32), kv, sv, arg((2,), jnp.uint32),
+            scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.float32))
     lowered = {
         "decode_slots_paged": decode,
         "decode_slots_spec": _lower_decode(llama, cfg, one_chip,
                                            drafts=3)[0],
-        "prefill_slot_paged": jax.jit(prefill, donate_argnums=(6,)).lower(
-            params, arg((1, BUCKET), jnp.int32), scalar(jnp.int32),
-            scalar(jnp.int32), arg((per_slot,), jnp.int32),
-            scalar(jnp.int32), kv, sv, arg((2,), jnp.uint32),
-            scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.float32)),
+        "prefill_slot_paged": prefill,
         "copy_page": jax.jit(llama.copy_page, donate_argnums=(0,)).lower(
             kv, scalar(jnp.int32), scalar(jnp.int32)),
     }
@@ -185,6 +188,7 @@ def test_tpu_fusions_land_under_the_model_scopes(decode_text, scope):
 
 
 # -- no paged program moves the pool ---------------------------------------
+_IN_SAMPLER = re.compile(r'op_name="[^"]*[/(]sampler[/)]')
 _COMPUTATION = re.compile(r"\s*(ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{$")
 _INSTRUCTION = re.compile(
     r"\s*(ROOT\s+)?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\((.*)")
@@ -298,7 +302,8 @@ def test_tpu_decode_attention_is_one_kernel_call_over_live_pages(
     from mxtpu.ops.paged_attention import KERNEL_NAME
     text = compiled["decode_slots_paged"].as_text()
     calls = [line for line in text.splitlines()
-             if "custom-call(" in line and "tpu_custom_call" in line]
+             if "custom-call(" in line and "tpu_custom_call" in line
+             and not _IN_SAMPLER.search(line)]
     assert len(calls) == 1, calls
     assert re.search(rf"%{KERNEL_NAME}[.\d]* = ", calls[0]), calls[0]
     assert re.search(r'op_name="[^"]*/while/body/[^"]*/attention/'
@@ -324,7 +329,8 @@ def test_tpu_sambay_reads_its_shared_pool_through_the_rows_kernel(
     from mxtpu.ops.paged_attention import ROWS_KERNEL_NAME
     text = sambay_decode.as_text()
     calls = [line for line in text.splitlines()
-             if "custom-call(" in line and "tpu_custom_call" in line]
+             if "custom-call(" in line and "tpu_custom_call" in line
+             and not _IN_SAMPLER.search(line)]
     assert len(calls) == 2, calls
     assert all(re.search(rf"%{ROWS_KERNEL_NAME}[.\d]* = ", c)
                for c in calls), calls
@@ -361,43 +367,61 @@ def test_tpu_page_gather_selects_only_indices(compiled):
                 int(d) for d in dims.split(",")) <= rows, line
 
 
-# -- the sampler sorts values, once, and gathers nothing -------------------
-_IN_SAMPLER = re.compile(r'op_name="[^"]*[/(]sampler[/)]')
-_SORT = re.compile(r" = (\(.*\)|\S+) sort\((.*?)\), dimensions=")
-
-
+# -- the sampler searches: no sort, no gather; the kernel in decode alone ----
 @pytest.mark.parametrize("program,rows,vocab", [
     ("decode_slots_paged", SLOTS, 32768), ("prefill_slot_paged", 1, 32768),
-    ("sambay.decode_slots_paged", SLOTS, 200064)])
-def test_tpu_sampler_sorts_values_once_and_gathers_nothing(
+    ("sambay.decode_slots_paged", SLOTS, 200064),
+    ("latent_moe.decode_slots_paged", SLOTS, 32768),
+    ("retention.decode_slots_paged", SLOTS, 32768)])
+def test_tpu_sampler_searches_and_neither_sorts_nor_gathers(
         request, program, rows, vocab):
-    """Under the scope ``sampler`` the compiled program holds exactly
-    one ``sort``, of one operand (the values; no ``iota`` rides along),
-    and no ``gather`` as large as the logits: the kth value is one
-    element a row. An ``argsort`` with ``take_along_axis`` compiled to
-    two stable two-operand sorts and two ``rows x vocab`` gathers, 153
-    ms of a 194 ms step at 200064 rows (PERF.md, PR 28)."""
-    if program.startswith("sambay."):
+    """Under the scope ``sampler`` the compiled program holds NO
+    ``sort`` (from PR 28 to PR 33 it held one, of the values: the
+    costliest operation of a decode step at 200064 rows, 3.9 ms; before
+    that two stable sorts with an ``iota`` and two ``rows x vocab``
+    gathers, 153 ms) and no ``gather`` larger than one element a row. A
+    decode program holds ONE call of the threshold search's kernel
+    (``ops.threshold``), fed the whole block of rows padded to eight
+    (both thresholds of every row come out of it; its VMEM request fits
+    at 200064 rows). A prefill program samples ONE row and holds no
+    kernel at all: fewer than eight rows take the ``jnp`` search, so the
+    kernel enters no program that did not hold one already (chat's four
+    buckets paid 10.6 s of warm set-up for it: PERF.md, PR 34-35)."""
+    # imported here: ``aot_instruction_lists.py`` compiles these fixtures
+    # over a parent tree too, which has no such module
+    from mxtpu.ops.threshold import KERNEL_NAME as SAMPLER_KERNEL_NAME
+    fixture, _, name = program.rpartition(".")
+    if fixture == "sambay":
         text = request.getfixturevalue("sambay_decode_text")
+    elif fixture:
+        text = request.getfixturevalue(fixture + "_decode")[2].as_text()
     else:
-        text = request.getfixturevalue("compiled")[program].as_text()
-    sorts, gathers = [], []
+        text = request.getfixturevalue("compiled")[name].as_text()
+    sorts, gathers, calls = [], [], []
     for line in text.splitlines():
+        if "tpu_custom_call" in line and "custom-call(" in line \
+                and SAMPLER_KERNEL_NAME in line:
+            assert _IN_SAMPLER.search(line), line
         if not _IN_SAMPLER.search(line):
             continue
-        m = _SORT.search(line)
-        if m:
-            sorts.append((m.group(1), m.group(2).count("%")))
+        if re.search(r" sort\(", line):
+            sorts.append(line)
         m = _INSTRUCTION.match(line)
         if m and m.group(5) == "gather":
             gathers.append(math.prod(
                 int(d) for d in m.group(4).split(",") if d))
-    assert [n for _, n in sorts] == [1], sorts
-    shape = sorts[0][0]
-    assert shape.startswith("f32[") and not shape.startswith("("), shape
-    assert math.prod(int(d) for d in re.match(
-        r"f32\[([\d,]*)\]", shape).group(1).split(",")) == rows * vocab
+        if "tpu_custom_call" in line and "custom-call(" in line:
+            calls.append(line)
+    assert not sorts, sorts
     assert all(n <= rows for n in gathers), gathers
+    if rows < 8:
+        assert not calls, calls
+        return
+    assert len(calls) == 1, calls
+    assert re.search(rf"%{SAMPLER_KERNEL_NAME}[.\d]* = ", calls[0]), calls
+    padded = -(-rows // 8) * 8
+    assert f"f32[{padded},{vocab}]" in calls[0].split(
+        "operand_layout_constraints")[1], calls[0]
 
 
 # -- the latent-attention, routed-expert family -------------------------------
