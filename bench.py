@@ -1645,9 +1645,10 @@ def _emit(rec):
 
 
 def main():
-    from mxtpu import runtime, telemetry
-    runtime.use_compile_cache()            # before anything compiles
-    telemetry.install_compile_listener()   # meta compile counts
+    from mxtpu import runtime
+    # before anything compiles; installs the compile listener too (the
+    # meta's compile counts)
+    runtime.use_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "gate":
         raise SystemExit(main_gate(sys.argv[2:]))
     only = sys.argv[1] if len(sys.argv) > 1 else "all"

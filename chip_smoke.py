@@ -22,7 +22,6 @@ from __future__ import annotations
 import contextlib
 import faulthandler
 import json
-import logging
 import os
 import re
 import sys
@@ -783,28 +782,8 @@ WARM_SETUP_WIDTHS = dict(vocab_size=32768, dim=4096, n_layers=2, n_heads=32,
                          n_kv_heads=8, hidden_dim=14336)
 WARM_SETUP_ENGINE = dict(max_slots=32, max_len=2048, min_bucket=128,
                          page_size=16, n_pages=2049, prefix_cache=True)
-_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
-                 "/jax/compilation_cache/cache_misses": "miss"}
-_PHASE_EVENTS = {
-    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
-    "/jax/core/compile/backend_compile_duration": "backend_s"}
-_ENGINE_PROGRAMS = ("jit(decode_slots", "jit(prefill_slot", "jit(copy_page")
-
-
-class _NotWritten(logging.Handler):
-    """The programs jax says it did not write to its persistent cache
-    (they compiled in under its threshold), as ``jit(<name>)``."""
-
-    def __init__(self):
-        super().__init__(logging.DEBUG)
-        self.programs = set()
-
-    def emit(self, record):
-        m = re.match(r"Not writing persistent cache entry for 'jit_(\w+)'",
-                     record.getMessage())
-        if m:
-            self.programs.add(f"jit({m.group(1)})")
+_BUILD_FIELDS = ("trace_s", "nested_traces", "lower_s", "backend_s",
+                 "cache", "first_call_s")
 
 
 def phase_serve_warm_setup(cfg, *, buckets=(128, 256, 512, 1024),
@@ -815,67 +794,40 @@ def phase_serve_warm_setup(cfg, *, buckets=(128, 256, 512, 1024),
     caches dropped in between, so that the second pass is what a warm
     run pays (trace, lower, fetch). A pass asks one request a prefill
     bucket, two tokens each (the first also runs the decode program and
-    ``copy_page``), and clocks each request; beside it each of the
-    engine's programs the backend was asked for, by jax's own name for
-    it: the seconds of its trace, of its lowering and in the backend
-    (compile or fetch), and whether the persistent cache held it. What
-    the first pass compiled and wrote, the second must find: a program
-    that misses there is compiled in every run of every cell that holds
-    it (PERF.md, PR 34-35). jax writes no executable that compiled in
-    under ``jax_persistent_cache_min_compile_time_secs`` and says so in
-    its debug log, which the phase reads: those programs are named
-    (``unwritten``: every run compiles them again), not failed."""
+    ``copy_page``), and clocks each request; beside it the program's own
+    record of each build (``telemetry.programs()``: the seconds of the
+    trace, the traces nested in it, the lowering and the backend, the
+    persistent cache's answer, the building call's wall time), and the
+    seconds of what was built outside the engine's programs (casts,
+    seeds: ``others``). What the first pass compiled and wrote
+    (``miss``), the second must find (``hit``): a program that misses
+    there is compiled in every run of every cell that holds it
+    (PERF.md, PR 34-35). jax writes no executable that compiled in
+    under ``jax_persistent_cache_min_compile_time_secs``: those read
+    ``unwritten`` (every run compiles them again) and are named, not
+    failed."""
     import jax
-    import jax.monitoring
     import numpy as np
+    from mxtpu import telemetry
     from mxtpu.models import llama
     from mxtpu.serve import Request, ServeEngine
 
     t0 = time.perf_counter()
-    seen = {"on": True, "cache": "-", "programs": {}}
-
-    def on_event(event, **kw):
-        if seen["on"] and event in _CACHE_EVENTS:
-            seen["cache"] = _CACHE_EVENTS[event]
-
-    def on_duration(event, secs, fun_name="?", **kw):
-        # tracing names a program ``f``, lowering and the backend
-        # ``jit(f)``; the small programs around the engine's (casts,
-        # seeds) share names, so they are one row. The hit or miss is
-        # counted inside the span the backend's event closes
-        if not (seen["on"] and event in _PHASE_EVENTS):
-            return
-        name = fun_name if fun_name.startswith("jit(") \
-            else f"jit({fun_name})"
-        if not name.startswith(_ENGINE_PROGRAMS):
-            name = "others"
-        row = seen["programs"].setdefault(
-            name, {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
-                   "hit": 0, "miss": 0})
-        phase = _PHASE_EVENTS[event]
-        row[phase] = round(row[phase] + secs, 2)
-        if phase == "backend_s":
-            if seen["cache"] != "-":     # "miss": compiled AND written
-                row[seen["cache"]] += 1
-            seen["cache"] = "-"
-
-    jax.monitoring.register_event_listener(on_event)
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
     params = jax.jit(lambda k: llama.init_params(cfg, k))(
         jax.random.PRNGKey(0))
     jax.block_until_ready(params)
     setup_s, t0 = time.perf_counter() - t0, time.perf_counter()
     rng = np.random.default_rng(2)
-    skipped = _NotWritten()
-    # jax's debug lines go to the phase alone, not on to stderr
-    compiler_log = logging.getLogger("jax._src.compiler")
-    level, onward = compiler_log.level, compiler_log.propagate
-    compiler_log.addHandler(skipped)
-    compiler_log.setLevel(logging.DEBUG)
-    compiler_log.propagate = False
+
+    def rounded(v):
+        return round(v, 2) if isinstance(v, float) else v
+
+    def others_s():
+        o = telemetry.programs().get("others")
+        return (o.trace_s, o.lower_s, o.backend_s) if o else (0.0,) * 3
 
     def one_pass():
-        seen["programs"] = {}
+        before, catalogued = others_s(), telemetry.programs()
         engine = ServeEngine(cfg, params, paged=True, **engine_kw)
         calls = {}
         for b in buckets:
@@ -886,25 +838,26 @@ def phase_serve_warm_setup(cfg, *, buckets=(128, 256, 512, 1024),
             engine.run()
             calls[f"b{b}"] = round(time.perf_counter() - t1, 2)
         assert engine.n_buckets == len(buckets), engine.n_buckets
-        return calls, seen["programs"], engine.kv_cache_stats()
+        # what this pass built: a build makes the catalog a new entry
+        rows = {n: {k: rounded(getattr(p, k)) for k in _BUILD_FIELDS}
+                for n, p in sorted(telemetry.programs().items())
+                if n.startswith("serve_") and p is not catalogued.get(n)}
+        rows["others"] = dict(zip(
+            ("trace_s", "lower_s", "backend_s"),
+            (round(a - b, 2) for a, b in zip(others_s(), before))))
+        return calls, rows, engine.kv_cache_stats()
 
-    try:
-        # both builds from ONE line: a kernel's payload holds its call
-        # sites, and another line here would be another cache key
-        passes = []
-        for _ in range(2):
-            jax.clear_caches()
-            passes.append(one_pass())
-        (first_calls, first, kv), (second_calls, second, _) = passes
-    finally:
-        seen["on"] = False
-        compiler_log.removeHandler(skipped)
-        compiler_log.setLevel(level)
-        compiler_log.propagate = onward
-    mine = [n for n in first if n != "others"]
-    unwritten = sorted(set(mine) & skipped.programs)
-    cold = sorted(n for n in mine if n not in skipped.programs
-                  and not second.get(n, {}).get("hit"))
+    # both builds from ONE line: a kernel's payload holds its call
+    # sites, and another line here would be another cache key
+    passes = []
+    for _ in range(2):
+        jax.clear_caches()
+        passes.append(one_pass())
+    (first_calls, first, kv), (second_calls, second, _) = passes
+    unwritten = sorted(n for n, r in first.items()
+                       if r.get("cache") == "unwritten")
+    cold = sorted(n for n, r in first.items() if r.get("cache") == "miss"
+                  and second[n]["cache"] != "hit")
     assert not cold, (
         f"compiled again on a second build against the same cache: {cold}",
         first, second)

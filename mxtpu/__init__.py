@@ -14,7 +14,10 @@ Typical use, unchanged from the reference except the context::
     import mxtpu as mx
     net.initialize(ctx=mx.tpu())
 """
-from . import base
+import time as _time
+_T_IMPORT = _time.perf_counter_ns()     # setup.import starts here
+
+from . import base                                          # noqa: E402
 
 # Dtype policy (TPU-native): 64-bit dtypes are demoted to 32-bit by default
 # — float64 has no TPU hardware path and int64 indexing costs bandwidth.
@@ -99,3 +102,7 @@ def __getattr__(name):
         globals()["kv"] = m
         return m
     raise AttributeError(f"module 'mxtpu' has no attribute {name!r}")
+
+
+_T_IMPORTED = _time.perf_counter_ns()   # and ends here: telemetry's
+# ``start_setup_record`` makes the span of the two stamps

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from .sharding import ShardingRules, batch_spec, key_str
 
 __all__ = ["TrainState", "init_state", "make_train_step", "make_eval_step"]
@@ -73,6 +74,7 @@ def opt_state_shardings(tx, params: Any, mesh: Mesh,
     return jax.tree_util.tree_map_with_path(spec_for, abs_opt)
 
 
+@telemetry.setup_phase("state_alloc")
 def init_state(params: Any, tx, mesh: Mesh,
                rules: ShardingRules, model_state: Any = ()) -> TrainState:
     """Place params per the rule table and build the optimizer state
@@ -109,6 +111,7 @@ def init_state(params: Any, tx, mesh: Mesh,
     return TrainState(params, opt_state, step, model_state)
 
 
+@telemetry.setup_phase("step_build")
 def make_train_step(loss_fn: Callable[..., Any], tx, mesh: Mesh,
                     rules: Optional[ShardingRules] = None,
                     has_rng: bool = False,
@@ -216,7 +219,6 @@ def make_train_step(loss_fn: Callable[..., Any], tx, mesh: Mesh,
             return new, loss, aux
         return new, loss
 
-    from .. import telemetry
     telemetry.install_compile_listener()
     # watched: every compile is cost-cataloged (program_flops/bytes →
     # roofline class) and every dispatch feeds the live MFU/goodput

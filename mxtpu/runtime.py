@@ -18,20 +18,27 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def use_compile_cache() -> str:
     """Point jax's persistent compilation cache somewhere that outlives
     the process, and return the directory in use. Entry points
-    (``chip_smoke.py``, ``bench.py``) call this before anything
-    compiles; ``import mxtpu`` does not.
+    (``chip_smoke.py``, ``bench.py``, the benchmark's ``run.py``) call
+    this before anything compiles; ``import mxtpu`` does not.
 
     ``JAX_COMPILATION_CACHE_DIR`` wins when set — jax reads it itself,
     so nothing is touched. Otherwise the cache lives at a FIXED path
     in the checkout (``<repo>/.jax_cache``): the same from every
     process, so a second run of the same command hits. jax's default
     thresholds stay: programs that compile in under a second are not
-    written."""
+    written.
+
+    Being the first call of every entry point, it is also where a
+    process's set-up starts to be told:
+    ``telemetry.start_setup_record()``."""
     import jax
+    from . import telemetry
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(_REPO, ".jax_cache"))
-    return jax.config.jax_compilation_cache_dir
+    cache_dir = jax.config.jax_compilation_cache_dir
+    telemetry.start_setup_record(cache_dir)
+    return cache_dir
 
 
 class Feature:

@@ -554,6 +554,7 @@ class ServeEngine:
     whatever the prompt's length, and the prompt's first token comes
     that many iterations later."""
 
+    @telemetry.setup_phase("engine_build")
     def __init__(self, cfg, params, *, max_slots: Optional[int] = None,
                  max_len: Optional[int] = None,
                  min_bucket: Optional[int] = None,
@@ -646,22 +647,26 @@ class ServeEngine:
             # over the whole accepted run rather than one token
             self.overlap = False
 
-        state = fam.init_paged_cache(
-            cfg, self.max_slots, self.n_pages, self.page_size,
-            mesh=mesh, int8=self.int8_pages)
-        # the small per-slot vectors; everything else is the donated
-        # state (llama: the K/V pools; sambay: a pool plus the fixed
-        # per-slot rings and recurrent state)
-        self._sv = {n: state.pop(n) for n in ("lengths", "tokens", "rngs")}
-        self._kv = state
-        # bytes of the donated state by kind: pages (the kinds named
-        # ``*_pages``: keys and values, or latent rows) grow with the
-        # tokens held; a family's other kinds are fixed blocks per slot
-        kinds = getattr(fam, "STATE_KINDS", {})
-        by_kind: Dict[str, int] = {}
-        for n, a in state.items():
-            k = kinds.get(n, "kv_pages")
-            by_kind[k] = by_kind.get(k, 0) + int(a.nbytes)
+        # host time: the allocation's programs (``others`` in the build
+        # catalog) and their dispatch; the device fills the pool behind
+        with telemetry.setup_span("state_alloc"):
+            state = fam.init_paged_cache(
+                cfg, self.max_slots, self.n_pages, self.page_size,
+                mesh=mesh, int8=self.int8_pages)
+            # the small per-slot vectors; everything else is the donated
+            # state (llama: the K/V pools; sambay: a pool plus the fixed
+            # per-slot rings and recurrent state)
+            self._sv = {n: state.pop(n)
+                        for n in ("lengths", "tokens", "rngs")}
+            self._kv = state
+            # bytes of the donated state by kind: pages (the kinds named
+            # ``*_pages``: keys and values, or latent rows) grow with the
+            # tokens held; a family's other kinds are fixed blocks per slot
+            kinds = getattr(fam, "STATE_KINDS", {})
+            by_kind: Dict[str, int] = {}
+            for n, a in state.items():
+                k = kinds.get(n, "kv_pages")
+                by_kind[k] = by_kind.get(k, 0) + int(a.nbytes)
         paged = sum(n for k, n in by_kind.items() if k.endswith("_pages"))
         if not paged:
             # no kind of this family's state grows with tokens

@@ -982,6 +982,7 @@ class Gateway:
                 telemetry.flight().record("gateway", "maintain_error")
 
     # -- front door / lifecycle ---------------------------------------------
+    @telemetry.setup_phase("gateway_start")
     def start_http(self, host: str = "127.0.0.1",
                    port: Optional[int] = None) -> int:
         """Bind + serve the HTTP front door on a daemon thread;

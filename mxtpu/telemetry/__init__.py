@@ -34,6 +34,8 @@ admission/latency/spans), the sharded train step + ``DevicePrefetcher``
 """
 from __future__ import annotations
 
+import functools
+import os
 from typing import Optional, Sequence
 
 from ..base import env_bool
@@ -57,6 +59,7 @@ __all__ = [
     "FlightRecorder", "Span", "WatchedFunction", "TraceContext",
     "RegistryServer", "SLOTracker",
     "counter", "gauge", "histogram", "span", "span_factory", "instant",
+    "setup_span", "setup_phase", "record_setup", "start_setup_record",
     "registry", "flight", "enabled", "enable", "reset",
     "prometheus", "summary", "dump_trace", "trace_events",
     "clear_trace", "current_depth", "describe_args", "watch",
@@ -179,6 +182,104 @@ def span_factory(name: str, histogram_name: Optional[str] = None,
     return make
 
 
+class _SetupSpan(Span):
+    """A ``setup.<phase>`` span; one that no other ``setup.*`` span
+    encloses also counts its seconds as named set-up time."""
+
+    def __exit__(self, *exc) -> bool:
+        out = super().__exit__(*exc)
+        _setup_named(self.parent, self.duration_ms / 1e3)
+        return out
+
+
+def _setup_named(parent: Optional[str], seconds: float) -> None:
+    if not (parent or "").startswith("setup."):
+        counter("setup_spanned_seconds_total",
+                "Seconds under the setup.* spans that no other "
+                "setup.* span encloses: the set-up time the program "
+                "can name").inc(seconds)
+
+
+def _setup_histogram(phase: str):
+    return histogram(f"span_setup_{phase}_ms",
+                     f"Span durations: setup.{phase}")
+
+
+def setup_span(phase: str, **args) -> Span:
+    """``with telemetry.setup_span("state_alloc"):`` -- a span
+    ``setup.<phase>`` around work done once before the first useful
+    step (``docs/observability.md`` lists the phases). In the ring with
+    its parent, in ``span_setup_<phase>_ms``, in the flight recorder."""
+    if not _enabled:
+        return Span("setup." + phase, record=False, **args)
+    return _SetupSpan("setup." + phase, histogram=_setup_histogram(phase),
+                      flight=_FLIGHT, **args)
+
+
+def setup_phase(phase: str):
+    """Decorator: every call of the function runs under
+    ``setup_span(phase)`` (a constructor, a builder)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with setup_span(phase):
+                return fn(*args, **kwargs)
+        return spanned
+    return wrap
+
+
+def record_setup(phase: str, start_us: int, end_us: int,
+                 parent: Optional[str] = None, **args) -> None:
+    """A ``setup.<phase>`` span that is already over, on the ring's
+    clock (``tracing.record_span``): the call that turned out to build
+    a program, a compile phase jax clocked itself."""
+    if not _enabled:
+        return
+    parent = parent or _tracing.current_span()
+    _tracing.record_span("setup." + phase, start_us, end_us,
+                         parent=parent, **args)
+    _setup_histogram(phase).observe((end_us - start_us) / 1e3)
+    _setup_named(parent, (end_us - start_us) / 1e6)
+
+
+_setup_started = False
+
+
+def start_setup_record(cache_dir: Optional[str] = None) -> None:
+    """Start telling where this process's set-up goes; an entry point's
+    first call (``runtime.use_compile_cache()`` makes it), once a
+    process. Installs the compile listener before anything can compile;
+    records ``setup.import`` (and the gauge ``import_seconds``) from the
+    two stamps ``mxtpu/__init__.py`` took; STARTS THE BACKEND, under
+    ``setup.backend_init`` (the process's first ``jax.devices()``: what
+    configures platforms or ``jax.distributed`` comes before this
+    call); and reads the size of jax's persistent cache directory once
+    (``compile_cache_entries``, ``compile_cache_bytes``)."""
+    global _setup_started
+    install_compile_listener()
+    if _setup_started or not _enabled:
+        return
+    _setup_started = True
+    import jax
+    import mxtpu
+    t0, t1 = mxtpu._T_IMPORT // 1000, mxtpu._T_IMPORTED // 1000
+    record_setup("import", t0, t1)
+    gauge("import_seconds", "Seconds `import mxtpu` took in this process "
+          "(the setup.import span)").set((t1 - t0) / 1e6)
+    with setup_span("backend_init"):
+        jax.devices()
+    sizes = []
+    try:
+        if cache_dir:
+            with os.scandir(cache_dir) as entries:
+                sizes = [e.stat().st_size for e in entries if e.is_file()]
+    except OSError:             # not made yet: jax makes it on a write
+        pass
+    gauge("compile_cache_entries", "Files in jax's persistent compilation "
+          "cache directory when the process started").set(len(sizes))
+    gauge("compile_cache_bytes", "Their bytes").set(sum(sizes))
+
+
 def instant(name: str, **args) -> None:
     """An instant trace event (no-op while disabled)."""
     if _enabled:
@@ -196,12 +297,14 @@ def summary() -> str:
 def reset() -> None:
     """Zero metrics, clear trace events and the flight ring (test
     isolation). Handles held by instrumentation stay valid."""
+    global _setup_started
     _REGISTRY.reset()
     clear_trace()
     _FLIGHT.clear()
     # perfscope's rolling windows + ledger entries are test-visible
     # state too (the cost catalog survives — program costs don't rot)
     perfscope.reset()
+    _setup_started = False
 
 
 # the distributed layer registers the tracing context provider at

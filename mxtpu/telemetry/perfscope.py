@@ -517,8 +517,9 @@ class PerfScope:
                 temp_bytes=mem.get("temp_bytes"),
                 peak_hbm_bytes=mem.get("peak_hbm_bytes"))
             self.register_cost(cost)
-            # the same executable names its instructions' scopes
-            _scopes.register(name, obj)
+            # the same executable names its instructions' scopes, and
+            # says what XLA built: Mosaic calls, fast-memory placement
+            _scopes.register(name, obj, temp_bytes=mem.get("temp_bytes"))
             return cost
         except Exception as e:
             w = self._window(name)

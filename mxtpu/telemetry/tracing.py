@@ -22,7 +22,8 @@ chrome://tracing and Perfetto both accept (their JSON importer
 tolerates a missing enclosing array).
 
 Nesting is tracked per thread: a span opened inside another span
-carries ``args.depth`` and chrome's flame view nests them by
+carries ``args.depth`` and ``args.parent`` (the enclosing span's name:
+the span that caused it), and chrome's flame view nests them by
 timestamp containment (same tid).
 """
 from __future__ import annotations
@@ -41,7 +42,7 @@ from .flight import process_role
 
 __all__ = ["span", "instant", "trace_events", "dump_trace",
            "clear_trace", "Span", "set_context_provider",
-           "stream_path"]
+           "stream_path", "record_span", "current_span"]
 
 _MAX_EVENTS = env_int(
     "MXTPU_TELEMETRY_TRACE_EVENTS", 100_000,
@@ -153,6 +154,15 @@ def _record(event: Dict[str, Any]) -> None:
         _stream(event)
 
 
+def _stack() -> List[str]:
+    """This thread's open spans' names, outermost first."""
+    try:
+        return _tls.stack
+    except AttributeError:
+        _tls.stack = []
+        return _tls.stack
+
+
 class Span:
     """One traced duration (context manager). ``duration_ms`` is
     populated on exit; ``args`` ride into the trace event verbatim."""
@@ -169,9 +179,10 @@ class Span:
         self._t0 = 0
 
     def __enter__(self) -> "Span":
-        depth = getattr(_tls, "depth", 0)
-        _tls.depth = depth + 1
-        self.depth = depth
+        stack = _stack()
+        self.depth = len(stack)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
         if self._annotation is not None:
             self._annotation.__enter__()
         self._t0 = _now_us()
@@ -181,11 +192,14 @@ class Span:
         t1 = _now_us()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
-        _tls.depth = max(0, getattr(_tls, "depth", 1) - 1)
+        stack = _stack()
+        if stack:
+            stack.pop()
         self.duration_ms = (t1 - self._t0) / 1000.0
         args = dict(self.args)
         if self.depth:
             args["depth"] = self.depth
+            args["parent"] = self.parent
         if self._record_event:
             _record({"name": self.name, "ph": "X", "ts": self._t0,
                      "dur": t1 - self._t0, "pid": os.getpid(),
@@ -218,7 +232,30 @@ def trace_events() -> List[Dict[str, Any]]:
 
 def current_depth() -> int:
     """This thread's open-span nesting depth."""
-    return getattr(_tls, "depth", 0)
+    return len(_stack())
+
+
+def current_span() -> Optional[str]:
+    """The name of this thread's innermost open span (None: none)."""
+    stack = _stack()
+    return stack[-1] if stack else None
+
+
+def record_span(name: str, start_us: int, end_us: int,
+                parent: Optional[str] = None, **args: Any) -> None:
+    """A span that has already ended, on the ring's clock
+    (``perf_counter`` microseconds): what is only known to have been
+    worth a span once it is over (the call that built a program), or
+    was clocked by someone else (jax's own compile phases). ``parent``
+    defaults to the thread's innermost open span. It is no
+    ``TraceAnnotation``: a profiler session cannot be told of the
+    past."""
+    parent = parent or current_span()
+    if parent is not None:
+        args["parent"] = parent
+    _record({"name": name, "ph": "X", "ts": start_us,
+             "dur": end_us - start_us, "pid": os.getpid(),
+             "tid": threading.get_ident(), "args": args})
 
 
 def dump_trace(path: str) -> str:

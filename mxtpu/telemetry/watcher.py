@@ -3,11 +3,16 @@ attributed runtime event.
 
 Two hooks, independent and complementary:
 
-1. **Global compile listener** (:func:`install`) — registers a
-   ``jax`` monitoring listener for backend-compile durations, so EVERY
-   compilation in the process increments ``jax_compile_total`` and
-   lands in the compile-seconds histogram + flight recorder. Cheap,
-   process-wide, no per-call overhead.
+1. **Global compile listener** (:func:`install`) — registers ``jax``
+   monitoring listeners for the three phases of a build (trace,
+   lowering to MLIR, backend compile or fetch) and the persistent
+   cache's answers, so EVERY compilation in the process increments
+   ``jax_compile_total`` and lands in the compile-seconds histogram +
+   flight recorder, and every phase's seconds go to the program that
+   was being called on the thread (``telemetry.programs()``, the
+   ``program_*_total`` series, the ``setup.trace`` / ``setup.lower`` /
+   ``setup.backend`` spans) or, outside every watched call, to
+   ``others``. Cheap, process-wide, no per-call overhead.
 2. **Per-program watcher** (:func:`watch`) — wraps one jitted callable
    and checks its jit-cache size around each call (the same
    ``_cache_size()`` counter the serve churn test gates on). When the
@@ -33,14 +38,130 @@ import time
 from typing import Any, Callable, List, Optional
 
 from . import perfscope as _perfscope
+from . import scopes as _scopes
 
 __all__ = ["install", "watch", "watch_jit", "WatchedFunction",
            "describe_args"]
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_PHASES = {_TRACE_EVENT: "trace", _LOWER_EVENT: "lower",
+           _COMPILE_EVENT: "backend"}
+# what the persistent cache says inside a backend event: asked, then
+# found, or compiled and written; asked and neither is a program jax
+# compiled under its thresholds (a second to compile) and did not write
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "unwritten",
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss"}
 _install_lock = threading.Lock()
 _installed = False
 _MAX_KEY_CHARS = 512
+# per thread: ``current`` the WatchedFunction being called (or
+# _CATALOGUING), ``open`` the phases jax has entered and not left,
+# ``cache`` the cache's answer inside the open backend phase
+_tls = threading.local()
+_CATALOGUING = object()
+_build_lock = threading.Lock()
+
+
+_PHASE_HELP = {
+    "trace": "Seconds tracing a program to a jaxpr (inclusive of the "
+             "functions traced inside it)",
+    "lower": "Seconds lowering a program's jaxpr to MLIR (a kernel's "
+             "Mosaic lowering included)",
+    "backend": "Seconds in the backend: compile, or fetch from the "
+               "persistent cache"}
+
+
+def _on_phase_open(event: str, value: float, **kw) -> None:
+    # jax records a scalar (the start time) as it enters a phase
+    if event in _PHASES:
+        _tls.open = getattr(_tls, "open", 0) + 1
+
+
+def _on_cache_event(event: str, **kw) -> None:
+    answer = _CACHE_EVENTS.get(event)
+    if answer is not None:
+        _tls.cache = answer
+
+
+def _on_phase(event: str, start: float, end: float,
+              fun_name: str = "", **kw) -> None:
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    try:
+        _phase_closed(phase, start, end, fun_name)
+    except Exception:       # a listener must never break jit
+        pass
+
+
+def _phase_closed(phase: str, start: float, end: float,
+                  fun_name: str) -> None:
+    from . import _metrics, flight as _fl, record_setup
+    from .registry import SECONDS_BUCKETS as _SECONDS
+    still_open = _tls.open = max(0, getattr(_tls, "open", 1) - 1)
+    secs = end - start
+    # resolve the registry PER EVENT (compiles are rare): capturing it
+    # at install time would freeze the no-op registry forever if
+    # telemetry was disabled then
+    m = _metrics()
+    cache = None
+    if phase == "backend":
+        cache, _tls.cache = getattr(_tls, "cache", None) or "off", None
+        m.counter("jax_compile_total",
+                  "Backend compilations observed process-wide "
+                  "(jax monitoring listener)").inc()
+        m.histogram("jax_compile_seconds", "Backend compile durations",
+                    buckets=_SECONDS).observe(secs)
+        _fl().record("compile", "backend_compile", dur_s=round(secs, 4))
+    current = getattr(_tls, "current", None)
+    if current is _CATALOGUING:
+        # reading a built program back retraces it from jax's cache:
+        # no build's seconds
+        return
+    name = current.name if current is not None else _scopes.OTHERS
+    # tracing calls a program ``f``, lowering and the backend ``jit(f)``
+    own = current is None or fun_name in (
+        current.fun_name, f"jit({current.fun_name})")
+    build = _scopes.building(name)
+    nested = phase == "trace" and (still_open or not own)
+    fetched = cache == "hit"
+    with _build_lock:           # ``others`` is every thread's
+        if nested:
+            build.nested_traces += 1
+            if still_open <= 1:     # the deeper ones lie inside these
+                build.nested_trace_s += secs
+        else:
+            setattr(build, phase + "_s", getattr(build, phase + "_s") + secs)
+        if cache is not None:
+            build.fetched += fetched
+            build.compiled += not fetched
+            if own:
+                build.cache = cache
+    if nested:
+        # a jnp function traced under the program's own trace (or under
+        # its lowering: a kernel's body); inside ``trace_s`` already
+        m.counter("program_nested_traces_total",
+                  "Traces of other functions inside a program's build",
+                  program=name).inc()
+        return
+    m.counter(f"program_{phase}_seconds_total", _PHASE_HELP[phase],
+              program=name).inc(secs)
+    if cache is not None:
+        m.counter("program_fetched_total" if fetched
+                  else "program_compiled_total",
+                  "Executables the persistent cache held" if fetched
+                  else "Executables the backend compiled (written to "
+                  "the persistent cache or not)", program=name).inc()
+    # on the ring's clock (perf_counter); jax stamps time.time()
+    to_ring = time.perf_counter() - time.time()
+    record_setup(phase, int((start + to_ring) * 1e6),
+                 int((end + to_ring) * 1e6),
+                 parent="setup.first_call" if current is not None else None,
+                 program=name, fun_name=fun_name)
 
 
 def install() -> bool:
@@ -54,29 +175,9 @@ def install() -> bool:
         # import raises — a listener that silently counts nothing
         # would make "zero compiles in the window" trivially true
         import jax.monitoring as _mon
-        from . import _metrics, flight as _fl
-        from .registry import SECONDS_BUCKETS as _SECONDS
-
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            if event != _COMPILE_EVENT:
-                return
-            try:
-                # resolve the registry PER EVENT (compiles are rare):
-                # capturing it at install time would freeze the no-op
-                # registry forever if telemetry was disabled then
-                m = _metrics()
-                m.counter("jax_compile_total",
-                          "Backend compilations observed process-wide "
-                          "(jax monitoring listener)").inc()
-                m.histogram("jax_compile_seconds",
-                            "Backend compile durations",
-                            buckets=_SECONDS).observe(duration)
-                _fl().record("compile", "backend_compile",
-                             dur_s=round(duration, 4))
-            except Exception:       # a listener must never break jit
-                pass
-
-        _mon.register_event_duration_secs_listener(_on_duration)
+        _mon.register_scalar_listener(_on_phase_open)
+        _mon.register_event_listener(_on_cache_event)
+        _mon.register_event_time_span_listener(_on_phase)
         _installed = True
         return True
 
@@ -125,6 +226,8 @@ class WatchedFunction:
                 "see the cache cannot attribute recompiles")
         self._fn = fn
         self.name = name
+        # what jax's compile events call it
+        self.fun_name = getattr(fn, "__name__", name)
         self.expected = expected
         self.compiles: List[str] = []       # cache key per compile
         if loop is not None:
@@ -133,24 +236,40 @@ class WatchedFunction:
     def __call__(self, *args, **kwargs):
         fn = self._fn
         before = fn._cache_size()
+        # the compile listener gives what jax builds inside this call
+        # to this program
+        _tls.current = self
         t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        t1 = time.perf_counter()
+        try:
+            out = fn(*args, **kwargs)
+        finally:
+            t1 = time.perf_counter()
+            _tls.current = None
         after = fn._cache_size()
         if after > before:
-            self._on_compile(args, kwargs, after)
+            self._on_compile(args, kwargs, after, t0, t1)
         # perfscope step accounting: inter-dispatch gaps drive the
         # live MFU/MBU/goodput gauges + the step-anomaly detector
         _perfscope.scope().on_call(self.name, t0, t1)
         return out
 
-    def _on_compile(self, args, kwargs, cache_size: int) -> None:
-        from . import _metrics, flight as _fl
-        # a fresh compiled variant: catalog its XLA cost model (the
-        # lowering is still cached, so this is analysis, not a second
-        # compile; profile_program never raises)
-        _perfscope.scope().profile_program(self._fn, self.name,
-                                           args, kwargs)
+    def _on_compile(self, args, kwargs, cache_size: int,
+                    t0: float, t1: float) -> None:
+        from . import _metrics, flight as _fl, record_setup, setup_span
+        record_setup("first_call", int(t0 * 1e6), int(t1 * 1e6),
+                     program=self.name)
+        # a fresh compiled variant: catalog its XLA cost model and its
+        # text (the lowering is still cached, so this is analysis, not
+        # a second compile; profile_program never raises). Under a span:
+        # what the instrument itself costs a set-up
+        with setup_span("catalog", program=self.name):
+            _tls.current = _CATALOGUING
+            try:
+                _perfscope.scope().profile_program(self._fn, self.name,
+                                                   args, kwargs)
+            finally:
+                _tls.current = None
+        _scopes.built(self.name, t1 - t0)
         key = describe_args(args, kwargs)
         self.compiles.append(key)
         m = _metrics()

@@ -74,8 +74,8 @@ def test_serve_warm_setup_phase_runs_tiny_on_cpu(tmp_path, floor):
     everything (threshold 0) the second build fetches every program it
     asks the backend for and compiles none of its own again; against
     one that keeps nothing (no toy program compiles for a minute) the
-    phase reads jax's own "not writing" lines, names the engine's
-    programs ``unwritten`` and does not fail them for missing again."""
+    program's record reads ``unwritten`` for the engine's programs, and
+    the phase names them and does not fail them for missing again."""
     old = {k: getattr(jax.config, k) for k in (
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
@@ -93,19 +93,22 @@ def test_serve_warm_setup_phase_runs_tiny_on_cpu(tmp_path, floor):
             jax.config.update(k, v)
     assert list(info["first_calls_s"]) == list(info["second_calls_s"]) \
         == ["b4", "b8"]
-    programs = ["jit(decode_slots_paged)", "jit(prefill_slot_paged_b4)",
-                "jit(prefill_slot_paged_b8)"]
+    programs = ["serve_decode", "serve_prefill_b4", "serve_prefill_b8"]
     assert info["sampler"] == "search"
     assert set(programs) <= set(info["unwritten"]) if floor else \
         info["unwritten"] == []
     for name in programs:
         # jax counts a miss where it writes what it compiled
-        assert info["first"][name]["miss"] == (0 if floor else 1), \
-            info["first"]
+        assert info["first"][name]["cache"] == (
+            "unwritten" if floor else "miss"), info["first"]
         row = info["second"][name]
-        assert (row["hit"], row["miss"]) == ((0, 0) if floor else (1, 0)), \
+        assert row["cache"] == ("unwritten" if floor else "hit"), \
             info["second"]
-        assert {"trace_s", "lower_s", "backend_s"} <= set(row), row
+        assert {"trace_s", "nested_traces", "lower_s", "backend_s",
+                "first_call_s"} <= set(row), row
+        assert row["nested_traces"] > 0 and row["first_call_s"] > 0
+    assert set(info["second"]["others"]) == {"trace_s", "lower_s",
+                                             "backend_s"}
 
 
 def test_latent_kernel_phase_runs_tiny_on_cpu():

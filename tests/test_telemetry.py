@@ -169,12 +169,41 @@ def test_span_nesting_and_trace_dump(tmp_path):
     assert o["tid"] == i["tid"]
     # child contained within parent on the same timeline
     assert o["ts"] <= i["ts"] and i["ts"] + i["dur"] <= o["ts"] + o["dur"]
-    assert i["args"] == {"bucket": 64, "depth": 1}
+    assert i["args"] == {"bucket": 64, "depth": 1, "parent": "t_outer"}
+    assert "parent" not in o["args"]
     # spans also feed their duration histograms
     assert tm.registry().get("span_t_outer_ms").count >= 1
     path = tm.dump_trace(str(tmp_path / "trace.json"))
     loaded = json.load(open(path))
     assert any(e["name"] == "t_inner" for e in loaded)
+
+
+def test_a_span_says_which_span_caused_it():
+    """``args.parent`` is the enclosing span on the thread, per thread,
+    also for a span recorded after it ended; at depth 0 it is absent."""
+    tm.clear_trace()
+    seen = {}
+
+    def other_thread():
+        with tm.span("t_alone"):
+            seen["inside"] = tm.tracing.current_span()
+    with tm.span("t_a"):
+        with tm.span("t_b"):
+            with tm.span("t_c"):
+                pass
+            tm.tracing.record_span("t_late", 10, 30, bucket=1)
+            t = threading.Thread(target=other_thread)
+            t.start()
+            t.join(10)
+        with tm.span("t_d"):
+            pass
+    assert tm.tracing.current_span() is None
+    args = {e["name"]: e["args"] for e in tm.trace_events()}
+    assert [args[n].get("parent") for n in
+            ("t_a", "t_b", "t_c", "t_d", "t_late", "t_alone")] == [
+        None, "t_a", "t_b", "t_a", "t_b", None]
+    assert args["t_late"] == {"bucket": 1, "parent": "t_b"}
+    assert seen == {"inside": "t_alone"}
 
 
 def test_trace_streaming_jsonl(tmp_path, monkeypatch):

@@ -170,6 +170,29 @@ def test_tpu_program_keeps_its_name_and_parses_fast(decode_text):
     assert len(scopes) > 500
 
 
+def test_tpu_catalog_counts_kernels_and_fast_memory(compiled):
+    """What the catalog keeps of the built program beside its scopes
+    (``telemetry.programs()``): the Mosaic calls are the two Pallas
+    kernels the decode step holds (the attention's walk over live pages
+    in the layer loop, the sampler's threshold search), and XLA placed
+    some of its buffers in the chip's fast memory (``S(1)`` in a
+    result's layout), fewer than the text says ``S(1)`` (a fusion's
+    inside names its operands and its root again) and within VMEM each.
+    ``copy_page`` holds no kernel."""
+    exe = compiled["decode_slots_paged"]
+    prog = tscopes.register("aot_decode", exe, temp_bytes=1.0)
+    text = exe.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert prog.custom_calls == len(kernels) == 2
+    assert 0 < prog.fast_mem_buffers < text.count("S(1)")
+    assert 0 < prog.fast_mem_bytes <= prog.fast_mem_buffers * 128 * 2 ** 20
+    assert (prog.module, prog.temp_bytes) == ("jit_decode_slots_paged", 1)
+    assert prog.scopes == tscopes.scope_map(text)[1]
+    copy = tscopes.register("aot_copy_page", compiled["copy_page"])
+    assert copy.custom_calls == 0
+
+
 @pytest.mark.parametrize("scope", ["sampler", "attention",
                                    "kv_write", "mlp", "norm", ""])
 def test_tpu_fusions_land_under_the_model_scopes(decode_text, scope):
