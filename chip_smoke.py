@@ -211,6 +211,7 @@ def phase_train(cfg, batch, seq, steps, mesh_axes=None,
     import optax
     from mxtpu.models import llama
     from mxtpu.parallel import mesh as pmesh, step as pstep
+    from mxtpu import telemetry
     from mxtpu.telemetry import perfscope
 
     t0 = time.perf_counter()
@@ -265,15 +266,23 @@ def phase_train(cfg, batch, seq, steps, mesh_axes=None,
         train_step._jitted, "train_step", (state, batch_d, None))
     assert again is not None and again.flops == cost.flops, again
     assert _compiles() == c1, "cataloging the step compiled a program"
+    prog = telemetry.programs()["train_step"]
 
     info = {"setup_s": setup_s, "run_s": run_s,
             "mesh": {a: n for a, n in mesh.shape.items() if n > 1}
             or {"dp": 1},
             "loss": [round(l, 3) for l in (losses[0], losses[-1])],
             "steps": steps, "attention": held,
+            # what the checkpointed layers keep (None: the policy is
+            # the config's own and no plan was made) and the compiled
+            # step's memory_analysis(), a device
+            "remat_plan": prog.remat_plan and list(prog.remat_plan),
+            "remat_saved_gb": round(prog.remat_saved_bytes / 1e9, 3),
             "catalog_per_device": {
                 "gflop": round(cost.flops / 1e9, 1),
                 "gb_accessed": round(cost.bytes_accessed / 1e9, 2),
+                "argument_gb": round((cost.argument_bytes or 0) / 1e9, 2),
+                "temp_gb": round((cost.temp_bytes or 0) / 1e9, 2),
                 "peak_hbm_gb": round(cost.peak_hbm_bytes / 1e9, 2)}}
     if kernels:
         info["mosaic_calls"] = len(kernels)

@@ -14,7 +14,13 @@ this is a TPU-first design rather than a rebuild:
   is what keeps Llama-8B trace/compile time sane (SURVEY.md §7.2.2).
 - **remat**: ``jax.checkpoint`` around each layer when
   ``cfg.remat=True`` trades FLOPs for HBM (the reference's
-  mirror/memonger had the same role).
+  mirror/memonger had the same role). How much is traded is read off
+  the device: at ``remat_policy=None`` a layer keeps, by name, as many
+  of its activations as the device's free memory holds
+  (:func:`remat_plan`) and the backward pass computes only the rest
+  again; a device that reports no memory (the CPU) keeps the layer's
+  input alone, as does any trace but ``make_train_step``'s, which
+  alone knows the state the device will hold.
 - **GQA + RoPE + SwiGLU + RMSNorm**, bf16 activations / f32 params,
   f32 logits for a stable softmax.
 - **parallelism-aware**: ``sharding_rules`` gives Megatron-style tp
@@ -24,6 +30,7 @@ this is a TPU-first design rather than a rebuild:
 """
 from __future__ import annotations
 
+import itertools
 import math
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -32,19 +39,25 @@ from typing import Any, ClassVar, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import telemetry
 from ..ops.threshold import thresholds
 from ..ops.attention import (flash_attention, dense_attention,
                              ring_attention, ulysses_attention,
                              slot_decode_attention,
                              paged_decode_attention, paged_decode_path,
-                             ATTENTION_SCOPE, KV_GATHER_SCOPE)
-from ..parallel.sharding import ShardingRules, constrain
+                             ATTENTION_SCOPE, KV_GATHER_SCOPE,
+                             ATTN_OUT_NAME, ATTN_STATS_NAME, _flash_path)
+from ..parallel.sharding import (ShardingRules, bytes_per_device,
+                                 constrain)
+from ..parallel.step import traced_state_bytes
 from ..parallel.sharding import mcon as _mcon
 
 __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
            "loss_fn", "chunked_softmax_xent", "sharding_rules",
+           "remat_plan", "REMAT_LADDER",
            "CONFIGS", "init_cache", "cache_specs", "prefill",
            "chunked_prefill", "decode_step", "generate",
            "quantize_params_int8", "int8_sharding_rules",
@@ -71,10 +84,14 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     attn_impl: str = "flash"         # flash | dense | ring | ulysses
     remat: bool = True
-    # None = full per-layer remat; "dots_no_batch" saves weight-matmul
-    # outputs and recomputes only elementwise/attention in the backward
-    # (MaxText-style "minimal" policy: ~25% less recompute FLOPs for a
-    # modest activation-memory increase)
+    # None = fit to the device: in ``make_train_step``'s step each layer
+    # saves the named activations of ``remat_plan`` that the device's
+    # free memory holds and the backward recomputes the rest (full
+    # per-layer remat where the device reports no memory, the CPU, and
+    # in any other trace); "dots_no_batch" saves
+    # weight-matmul outputs and recomputes only elementwise/attention
+    # in the backward (MaxText-style "minimal" policy: ~25% less
+    # recompute FLOPs for a modest activation-memory increase)
     remat_policy: Optional[str] = None
     scan_layers: bool = True
     tie_embeddings: bool = False
@@ -281,7 +298,9 @@ def _qkv(cfg: LlamaConfig, lp, h, cos, sin):
         q = q.transpose(0, 2, 1, 3)          # (b, h, s, hd)
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+    return (checkpoint_name(apply_rope(q, cos, sin), "attn_q"),
+            checkpoint_name(apply_rope(k, cos, sin), "attn_k"),
+            checkpoint_name(v, "attn_v"))
 
 
 @jax.named_scope("out_proj")
@@ -358,7 +377,8 @@ def _layer(cfg: LlamaConfig, mesh, cos, sin, x, lp):
     k = constrain(k, *_QKV)
     v = constrain(v, *_QKV)
     o = _attention(cfg, q, k, v, mesh)
-    x = x + constrain(_out_proj(cfg, lp, o), *_ACT)
+    x = checkpoint_name(x + constrain(_out_proj(cfg, lp, o), *_ACT),
+                        "attn_resid")
 
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     delta, aux = _ffn(cfg, lp, h, mesh)
@@ -390,10 +410,175 @@ def _ffn(cfg: LlamaConfig, lp, h, mesh, serving: bool = False):
                                capacity_factor=cfg.moe_capacity,
                                mesh=mesh)
         return out.reshape(b, s, d), aux
-    gate = jax.nn.silu(h @ _wq8(lp["w_gate"], dt))
-    up = h @ _wq8(lp["w_up"], dt)
+    # named before the activation: ``silu`` is cheap to do again
+    gate = jax.nn.silu(
+        checkpoint_name(h @ _wq8(lp["w_gate"], dt), "mlp_gate"))
+    up = checkpoint_name(h @ _wq8(lp["w_up"], dt), "mlp_up")
     return (gate * up) @ _wq8(lp["w_down"], dt), \
         jnp.zeros((), jnp.float32)
+
+
+# What a checkpointed layer can keep, in rungs: (rung, the names its
+# values carry). "mlp_gate" and "mlp_up" spare the backward pass a
+# 14336-wide product each (a third of a layer's operations each, 28 KiB
+# a token at Mistral-7B's widths in bf16; the gate before its ``silu``,
+# which is cheap to do again); "attn_out" the flash forward kernel (its
+# output and its two float32 statistics a head, 8.25 KiB; only the
+# Pallas path has them, so the rung is offered only there); "attn_qkv"
+# the Q, K, V and ``wo`` products (q, k and v as rotated and before
+# GQA's repeat, and the stream after attention: 20 KiB). The stream is
+# in one rung with q because alone it spared nothing on the chip and
+# cost memory (PERF.md §6, PR 37); k and v are there so that the rungs
+# stay few and coarse, and a plan does not turn on a few MB: at the
+# train cell's shapes the one picked holds while the free memory stays
+# within -0.37 / +0.42 GB of what the chip read.
+REMAT_LADDER = (
+    ("mlp_gate", ("mlp_gate",)),
+    ("mlp_up", ("mlp_up",)),
+    ("attn_out", (ATTN_OUT_NAME, ATTN_STATS_NAME)),
+    ("attn_qkv", ("attn_q", "attn_k", "attn_v", "attn_resid")),
+)
+
+
+def _rungs(cfg: LlamaConfig, tp: int = 1, seq_len: int = 0,
+           flash_kernel: bool = True) -> dict:
+    """rung -> (bytes, forward operations the backward pass is spared)
+    a token and layer on one device of a mesh whose ``tp`` axis splits
+    the heads and the MLP's width (the stream is whole on every one).
+    An operation is priced alike in every rung: on the train cell's four
+    chips two sets of equal operations, both MLP products and one of
+    them with the kernel and ``attn_qkv``, spared 8.92 and 9.05 ms a
+    layer (PERF.md §6, PR 37). ``seq_len`` prices the flash
+    forward kernel (causal: half of ``4 s`` a head lane), which only
+    ``flash_kernel`` has."""
+    it = jnp.dtype(cfg.dtype).itemsize
+    q = -(-cfg.n_heads // tp) * cfg.head_dim
+    kv = -(-cfg.n_kv_heads // tp) * cfg.head_dim
+    h = -(-cfg.hidden_dim // tp)
+    d = cfg.dim
+    out = {
+        "mlp_gate": (h * it, 2 * d * h),
+        "mlp_up": (h * it, 2 * d * h),
+        "attn_out": (q * it + 2 * q // cfg.head_dim * 4, 2 * seq_len * q),
+        "attn_qkv": ((q + 2 * kv + d) * it, 2 * d * (q + 2 * kv) + 2 * q * d),
+    }
+    if cfg.moe_experts:     # the experts' products carry no names
+        del out["mlp_gate"], out["mlp_up"]
+    if not flash_kernel:    # no other path names its output
+        del out["attn_out"]
+    return out
+
+
+def remat_plan(cfg: LlamaConfig, tokens_per_device: int,
+               free_bytes: Optional[int], tp: int = 1,
+               seq_len: int = 0, flash_kernel: bool = True) -> tuple:
+    """``(names, bytes)``: the names a checkpointed layer saves and what
+    they take on a device. Of the ladder's rungs, the set that spares
+    the backward pass the most operations (:func:`_rungs`) among those
+    whose bytes, for ``tokens_per_device`` tokens of every layer, fit
+    ``free_bytes`` (one device's; ``None`` where it is not known).
+    Arithmetic on the shapes alone, at most 2**4 sets: empty at ``None``
+    and at 0, never more than ``free_bytes``, never less spared for more
+    free."""
+    rungs = _rungs(cfg, tp, seq_len, flash_kernel)
+    token_layers = tokens_per_device * cfg.n_layers
+    best, spared, kept = (), 0, 0
+    for n in range(1, len(rungs) + 1):
+        for pick in itertools.combinations(rungs, n):
+            size = token_layers * sum(rungs[r][0] for r in pick)
+            ops = sum(rungs[r][1] for r in pick)
+            if ops > spared and size <= (free_bytes or 0):
+                best, spared, kept = pick, ops, size
+    return tuple(name for rung, names in REMAT_LADDER if rung in best
+                 for name in names), kept
+
+
+def _axis(mesh: Optional[Mesh], *names: str) -> int:
+    return math.prod(mesh.shape.get(n, 1) for n in names) if mesh else 1
+
+
+def _free_bytes(cfg: LlamaConfig, params, tokens_per_device: int,
+                mesh: Optional[Mesh]) -> Optional[int]:
+    """What one device has left for saved activations, or ``None`` where
+    that is not known: outside the trace of a train step
+    (``parallel.step.traced_state_bytes``: nothing says what state the
+    device will hold) and where the device reports no memory (the CPU).
+    Its limit, less the state (the larger of what lies on the device
+    now and what the step's state takes by its shapes: the same whether
+    the state is resident or only described), less the gradients and
+    the rest of what a step needs whatever a layer saves
+    (:func:`_step_reserve`), less 8% of the limit kept clear."""
+    state = traced_state_bytes()
+    if state is None:
+        return None
+    dev = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    try:
+        stats = dev.memory_stats()
+    except RuntimeError:    # a described device (an AOT compile): none
+        stats = None
+    if not stats or "bytes_limit" not in stats:
+        return None
+    limit = stats["bytes_limit"]
+    return int(limit - max(stats.get("bytes_in_use", 0), state)
+               - limit // 100 * 8
+               - _step_reserve(cfg, params, tokens_per_device, mesh))
+
+
+def _step_reserve(cfg: LlamaConfig, params, tokens_per_device: int,
+                  mesh: Optional[Mesh]) -> int:
+    """Bytes a train step needs on a device besides its state, whatever
+    its layers save, by shapes: the gradients (the parameters' bytes a
+    device under :func:`sharding_rules`); every layer's input (what a
+    checkpointed layer always keeps); the device's part of the layers'
+    weights once more in the compute dtype (XLA casts the stacked shards
+    before the loop and gathers the cast); two layers' weights gathered
+    whole over fsdp in the compute dtype; two chunks of the loss's
+    float32 logits. Set against the compiler's own peak for the train
+    cell (PERF.md, PR 37: 2.82 GB here beside the gradients, 2.6-3.2
+    there)."""
+    it = jnp.dtype(cfg.dtype).itemsize
+    tp, fsdp = _axis(mesh, "tp"), _axis(mesh, "fsdp")
+    grads = bytes_per_device(
+        params, sharding_rules(cfg).tree_specs(params), mesh)
+    layer = sum(p.size for p in jax.tree.leaves(params["layers"])) \
+        // cfg.n_layers // tp
+    chunk = _resolve_ce_chunk(cfg) or cfg.vocab_size
+    return int(grads
+               + tokens_per_device * cfg.n_layers * cfg.dim * it
+               + layer * cfg.n_layers // fsdp * it
+               + 2 * layer * it
+               + 2 * tokens_per_device * chunk * 4)
+
+
+def _checkpointed(cfg: LlamaConfig, layer, params, tokens,
+                  mesh: Optional[Mesh]):
+    """``layer`` under ``jax.checkpoint`` as ``cfg.remat_policy`` says.
+    At ``None`` the plan is made here, once a trace, from what can be
+    seen: the widths, the tokens a device holds under the mesh, which
+    attention runs, the device's free memory; an empty plan is plain
+    ``jax.checkpoint``. A plan made for a train step goes on its
+    record."""
+    if cfg.remat_policy == "dots_no_batch":
+        return jax.checkpoint(
+            layer, policy=jax.checkpoint_policies
+            .dots_with_no_batch_dims_saveable)
+    if cfg.remat_policy is not None:
+        raise ValueError(
+            f"unknown remat_policy {cfg.remat_policy!r} "
+            "(use None or 'dots_no_batch')")
+    tp, seq = _axis(mesh, "tp"), tokens.shape[-1]
+    tokens_per_device = -(-tokens.size // _axis(mesh, "dp", "fsdp", "sp"))
+    kernel = cfg.attn_impl == "flash" and _flash_path(
+        (1, cfg.n_heads, seq, cfg.head_dim), seq) == "pallas"
+    plan, kept = remat_plan(
+        cfg, tokens_per_device,
+        _free_bytes(cfg, params, tokens_per_device, mesh), tp, seq, kernel)
+    if traced_state_bytes() is not None:
+        telemetry.record_remat_plan(plan, kept)
+    if not plan:
+        return jax.checkpoint(layer)
+    return jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(*plan))
 
 
 def forward_hidden(cfg: LlamaConfig, params, tokens,
@@ -409,16 +594,7 @@ def forward_hidden(cfg: LlamaConfig, params, tokens,
 
     layer = partial(_layer, cfg, mesh, cos, sin)
     if cfg.remat:
-        if cfg.remat_policy == "dots_no_batch":
-            layer = jax.checkpoint(
-                layer, policy=jax.checkpoint_policies
-                .dots_with_no_batch_dims_saveable)
-        elif cfg.remat_policy is None:
-            layer = jax.checkpoint(layer)
-        else:
-            raise ValueError(
-                f"unknown remat_policy {cfg.remat_policy!r} "
-                "(use None or 'dots_no_batch')")
+        layer = _checkpointed(cfg, layer, params, tokens, mesh)
 
     if cfg.scan_layers:
         def body(x, lp):

@@ -9,6 +9,7 @@ maxtext "logical axis rules" pattern, kept deliberately small.
 """
 from __future__ import annotations
 
+import math
 import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -17,7 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["P", "ShardingRules", "named", "shard_pytree", "constrain",
            "mcon", "replicated", "batch_spec", "key_str",
-           "global_device_put"]
+           "global_device_put", "bytes_per_device"]
 
 
 def global_device_put(arr, sharding: "NamedSharding"):
@@ -118,6 +119,21 @@ def shard_pytree(tree: Any, mesh: Mesh, rules: "ShardingRules",
     specs = rules.tree_specs(tree, prefix)
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs)
+
+
+def bytes_per_device(tree: Any, specs: Any, mesh: Optional[Mesh]) -> int:
+    """Bytes one device of ``mesh`` holds of ``tree`` laid out by
+    ``specs`` (a matching tree of ``PartitionSpec`` or
+    ``NamedSharding``): each leaf's bytes over the sizes of the axes its
+    spec names. Shapes alone, so the leaves may be abstract."""
+    def split(spec) -> int:
+        axes = [a for e in getattr(spec, "spec", spec) if e
+                for a in ((e,) if isinstance(e, str) else e)]
+        return math.prod(mesh.shape.get(a, 1) for a in axes) if mesh else 1
+    flat = jax.tree.leaves(
+        specs, is_leaf=lambda s: isinstance(s, (P, NamedSharding)))
+    return sum(math.prod(x.shape) * x.dtype.itemsize // split(s)
+               for x, s in zip(jax.tree.leaves(tree), flat))
 
 
 def _filter_spec(spec, axis_names) -> P:

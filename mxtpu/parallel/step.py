@@ -14,6 +14,8 @@ in HBM — the rebuild of MXNet's mutable in-place ``sgd_update``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -22,9 +24,41 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry
-from .sharding import ShardingRules, batch_spec, key_str
+from .sharding import (ShardingRules, batch_spec, bytes_per_device,
+                       key_str)
 
-__all__ = ["TrainState", "init_state", "make_train_step", "make_eval_step"]
+__all__ = ["TrainState", "init_state", "make_train_step", "make_eval_step",
+           "traced_state_bytes"]
+
+_state_bytes = contextvars.ContextVar("train_state_bytes", default=None)
+
+
+def traced_state_bytes() -> Optional[int]:
+    """While :func:`make_train_step`'s step is being traced: the bytes
+    one device holds of the state it was handed (parameters, optimizer
+    state, model state, as the rule table lays them out), counted from
+    shapes, so it is the same for a state that is resident and for one
+    that is only described. ``None`` outside such a trace. A model reads
+    it to size what its backward pass keeps (``models/llama.py``
+    ``remat_plan``)."""
+    return _state_bytes.get()
+
+
+@contextlib.contextmanager
+def _tracing(state: "TrainState", tx, mesh: Mesh, rules: ShardingRules):
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tuple(state))
+    params, opt_state, step, mstate = shapes
+    token = _state_bytes.set(
+        bytes_per_device(params, rules.tree_specs(params), mesh)
+        + bytes_per_device(
+            opt_state, opt_state_shardings(tx, params, mesh, rules), mesh)
+        + bytes_per_device(mstate, rules.tree_specs(mstate), mesh)
+        + step.dtype.itemsize)
+    try:
+        yield
+    finally:
+        _state_bytes.reset(token)
 
 
 class TrainState(NamedTuple):
@@ -160,6 +194,10 @@ def make_train_step(loss_fn: Callable[..., Any], tx, mesh: Mesh,
     grad_fn = jax.value_and_grad(_loss, has_aux=has_aux)
 
     def _step(state: TrainState, batch, rng):
+        with _tracing(state, tx, mesh, rules):
+            return _traced_step(state, batch, rng)
+
+    def _traced_step(state: TrainState, batch, rng):
         mstate = state.model_state
         if grad_accum > 1:
             def body(carry, xs):

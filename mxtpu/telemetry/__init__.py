@@ -53,6 +53,7 @@ from .perfscope import goodput_gauge, profile_program
 from .scopes import Program, programs
 from .watcher import WatchedFunction, describe_args, watch, watch_jit
 from .watcher import install as install_compile_listener
+from . import watcher as _watcher
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
@@ -60,6 +61,7 @@ __all__ = [
     "RegistryServer", "SLOTracker",
     "counter", "gauge", "histogram", "span", "span_factory", "instant",
     "setup_span", "setup_phase", "record_setup", "start_setup_record",
+    "record_remat_plan",
     "registry", "flight", "enabled", "enable", "reset",
     "prometheus", "summary", "dump_trace", "trace_events",
     "clear_trace", "current_depth", "describe_args", "watch",
@@ -278,6 +280,19 @@ def start_setup_record(cache_dir: Optional[str] = None) -> None:
     gauge("compile_cache_entries", "Files in jax's persistent compilation "
           "cache directory when the process started").set(len(sizes))
     gauge("compile_cache_bytes", "Their bytes").set(sum(sizes))
+
+
+def record_remat_plan(saved: Sequence[str], saved_bytes: int) -> None:
+    """A model has decided, while a train step was traced, which named
+    activations its checkpointed layers keep (``models/llama.py``
+    ``remat_plan``): ``saved`` the names (none: every layer is
+    recomputed whole), ``saved_bytes`` their bytes a device. Goes to the
+    gauge ``train_remat_saved_bytes`` and onto the record of the program
+    being built (``programs()[...].remat_plan``)."""
+    gauge("train_remat_saved_bytes",
+          "Bytes a device of the activations the checkpointed layers "
+          "keep for the backward pass").set(saved_bytes)
+    _watcher.note_remat_plan(tuple(saved), int(saved_bytes))
 
 
 def instant(name: str, **args) -> None:

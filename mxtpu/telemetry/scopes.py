@@ -238,7 +238,9 @@ class Program:
     ``fast_mem_buffers`` / ``fast_mem_bytes`` what XLA placed in fast
     memory, ``temp_bytes`` from the executable's ``memory_analysis()``
     (``perfscope.catalog()`` has the rest of it). ``parse_s`` is what
-    reading the text cost."""
+    reading the text cost. ``remat_plan`` / ``remat_saved_bytes``: the
+    names a train step's checkpointed layers keep and their bytes a
+    device (``None``: the build made no plan)."""
     name: str
     module: str = ""
     scopes: Dict[str, Tuple[str, bool]] = field(default_factory=dict)
@@ -256,6 +258,8 @@ class Program:
     fast_mem_buffers: int = 0
     fast_mem_bytes: int = 0
     temp_bytes: Optional[int] = None
+    remat_plan: Optional[Tuple[str, ...]] = None
+    remat_saved_bytes: int = 0
 
 
 _lock = threading.Lock()
@@ -314,6 +318,9 @@ def built(name: str, first_call_s: float) -> None:
         setattr(prog, f, getattr(new, f) + (0 if fresh else getattr(prog, f)))
     if fresh:
         prog.cache = new.cache
+    if new.remat_plan is not None:
+        prog.remat_plan = new.remat_plan
+        prog.remat_saved_bytes = new.remat_saved_bytes
 
 
 def programs() -> Dict[str, Program]:

@@ -211,6 +211,16 @@ def describe_args(args: tuple, kwargs: dict) -> str:
     return key
 
 
+def note_remat_plan(saved: tuple, saved_bytes: int) -> None:
+    """The remat plan a model made while the calling thread's watched
+    program was traced, onto that build's record (outside a watched
+    call: nowhere)."""
+    current = getattr(_tls, "current", None)
+    if current is not None and current is not _CATALOGUING:
+        build = _scopes.building(current.name)
+        build.remat_plan, build.remat_saved_bytes = saved, saved_bytes
+
+
 class WatchedFunction:
     """A jitted callable with compile attribution. Transparent:
     attributes (``_cache_size``, ``lower``, ...) delegate to the

@@ -15,7 +15,10 @@ lower llama's programs answer ``"tpu"`` for it while they trace, as the
 chip these compile for will. The only test file that
 describes a TPU topology (one process may hold libtpu: the
 on-chip-measurement guide, section 2), and only inside fixtures."""
+import base64
 import contextlib
+import hashlib
+import json
 import math
 import os
 import re
@@ -42,15 +45,19 @@ SLOTS, PAGE, N_PAGES, LAYERS, BUCKET = 8, 16, 1031, 4, 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -596,3 +603,196 @@ def test_tpu_grouped_product_fits_vmem_in_both_types(one_chip, dtype, k, n):
             arg((192, k), dtype), arg((896, k, n), dtype),
             arg((896,), jnp.int32))})["gmm"]
     assert "tpu_custom_call" in exe.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the train step under each remat plan, FSDP over the four described chips
+# ---------------------------------------------------------------------------
+with open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "grid", "configs",
+        "mistral-7b-v0.3-d8-train.json")) as _f:
+    # how the train cell's readers find the Pallas kernels in a trace
+    FLASH_KERNELS = json.load(_f)["programs"]["flash_kernels"]
+TRAIN_PLANS = ((), ("mlp_gate", "mlp_up"),
+               ("mlp_gate", "mlp_up", "attn_out"),
+               ("mlp_gate", "mlp_up", "attn_out", "attn_qkv"))
+
+
+@pytest.fixture(scope="module")
+def train_steps(topo):
+    """plan -> (compiled ``train_step``, its catalog entry) at two
+    layers, Mistral's head shapes and cut widths, 2 x 512 tokens a chip,
+    the state sharded four ways as the train cell's is; the plan
+    forced."""
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxtpu.parallel import step as pstep
+    from mxtpu.parallel.sharding import batch_spec
+    cfg = llama.LlamaConfig(vocab_size=8192, dim=1024, n_layers=2,
+                            n_heads=8, n_kv_heads=2, hidden_dim=2048,
+                            max_seq_len=512, dtype=jnp.bfloat16)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1, 1, 1, 1),
+                ("dp", "fsdp", "pp", "ep", "sp", "tp"))
+    rules, tx = llama.sharding_rules(cfg), optax.adamw(3e-4)
+
+    def on(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+    shapes = jax.eval_shape(partial(llama.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    params = on(shapes, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), rules.tree_specs(shapes),
+        is_leaf=lambda s: isinstance(s, P)))
+    opt = on(jax.eval_shape(tx.init, shapes),
+             pstep.opt_state_shardings(tx, shapes, mesh, rules))
+    state = pstep.TrainState(params, opt, jax.ShapeDtypeStruct(
+        (), jnp.int32, sharding=NamedSharding(mesh, P())), ())
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (8, 512), jnp.int32, sharding=NamedSharding(mesh, batch_spec(mesh)))}
+    ladder = dict(llama.REMAT_LADDER)
+    lowered = {}
+    # the library's flash kernel mixes int32 with the default integer
+    # and Mosaic refuses a bf16 product at "highest": lowered as the
+    # chip's processes run, without conftest's x64 and precision
+    with pytest.MonkeyPatch.context() as mp, _as_on_a_tpu(), \
+            jax.enable_x64(False), jax.default_matmul_precision("default"):
+        for plan in TRAIN_PLANS:
+            names = tuple(n for r in plan for n in ladder[r])
+            mp.setattr(llama, "remat_plan",
+                       lambda *a, names=names: (names, 0))
+            step = pstep.make_train_step(llama.loss_fn(cfg, mesh=mesh), tx,
+                                         mesh, rules)
+            lowered[plan] = step._jitted.lower(state, batch, None)
+    return _compile_all(lowered)
+
+
+def _rematerialised(compiled):
+    _, named, _ = tscopes._read(compiled.as_text())
+    return sum(1 for op in named.values() if tscopes.scope_path(op)[1])
+
+
+@pytest.mark.parametrize("less,more", zip(TRAIN_PLANS, TRAIN_PLANS[1:]),
+                         ids=["mlp", "attn_out", "the_rest"])
+def test_tpu_each_rung_trades_recomputation_for_memory(train_steps, less,
+                                                       more):
+    """Rung by rung the backward pass holds fewer rematerialised
+    instructions and the compiled step more temporaries."""
+    a, b = train_steps[less], train_steps[more]
+    assert _rematerialised(b) < _rematerialised(a)
+    assert b.memory_analysis().temp_size_in_bytes \
+        > a.memory_analysis().temp_size_in_bytes
+    # (the compiler's peak follows only in the large: at these widths
+    # the last rung's 4 MB is inside what buffer assignment moves)
+    assert train_steps[TRAIN_PLANS[-1]].memory_analysis() \
+        .peak_memory_in_bytes > train_steps[()].memory_analysis() \
+        .peak_memory_in_bytes
+
+
+@pytest.mark.parametrize("plan", TRAIN_PLANS, ids=lambda p: "+".join(p)
+                         or "none")
+def test_tpu_saved_attention_runs_no_second_forward_kernel(train_steps,
+                                                           plan):
+    """The step holds the flash forward kernel in the forward loop, and
+    dkv and dq in the backward loop. A layer recomputed whole runs the
+    forward kernel there again (four Mosaic calls in the text); one that
+    kept ``attn_out`` and ``attn_stats`` does not (three): the backward
+    rule of ``ops.attention._pallas_flash`` reads what the policy kept."""
+    text = train_steps[plan].as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    assert calls == (3 if "attn_out" in plan else 4)
+    assert text.count("flash_mha_bwd_dkv") and text.count("flash_mha_bwd_dq")
+    # the forward kernel under the name a trace shows it by, which the
+    # train cell's ``programs.flash_kernels`` finds it by: the library's
+    # own jitted entry gave it, ``ops.attention._pallas_flash_fwd``'s
+    # scope gives it now. Once in the layers' forward loop, and once
+    # more in the backward loop where the layer does not keep its output
+    forward = re.findall(r"^\s*%?(flash_attention[\w.\-]*) = [^\n]*"
+                         r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert len(forward) == (1 if "attn_out" in plan else 2), forward
+    assert all(re.match(FLASH_KERNELS, n) for n in forward)
+    kernels = re.findall(r'^\s*%?([\w.\-]+) = [^\n]*'
+                         r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert all(re.match(FLASH_KERNELS, n) for n in kernels), kernels
+
+
+# ---------------------------------------------------------------------------
+# the serve programs the chip runs, against the parent's
+# ---------------------------------------------------------------------------
+def _program_hash(compiled):
+    """A compiled program's text as a hash can hold it: without the
+    instructions' metadata and the file tables, and each Mosaic kernel's
+    body by its MLIR printed without locations (the bytecode carries the
+    Python call sites it was traced through)."""
+    from jax._src.lib.mlir import ir
+
+    def kernel(match):
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(match.group(1))) \
+                .operation.get_asm(enable_debug_info=False)
+        return '"body":"%s"' % hashlib.sha256(asm.encode()).hexdigest()
+    text = re.sub(r", metadata=\{[^}]*\}", "", compiled.as_text())
+    text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', kernel, text)
+    text = "\n".join(
+        line for line in text.splitlines() if not re.match(
+            r"^\s*(\d+ [\"{]|FileNames|FunctionNames|FileLocations"
+            r"|StackFrames)", line))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.fixture(scope="module")
+def sambay_prefill(one_chip):
+    """The agent cell's family: a 256-token bucket of
+    ``prefill_slot_paged``, whose full layer and cross layers run
+    ``flash_attention``'s Pallas arm, at the decode fixture's widths."""
+    cfg = replace(sambay.CONFIGS["tiny"], vocab_size=8192, dim=512,
+                  n_layers=8, n_heads=8, n_kv_heads=4, hidden_dim=512,
+                  sliding_window=128, max_seq_len=512, dtype=jnp.bfloat16,
+                  param_dtype=jnp.bfloat16)
+    _, params, kv, sv = _lower_decode(sambay, cfg, one_chip)
+    arg = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    scalar = partial(arg, ())
+    prefill = partial(sambay.prefill_slot_paged, cfg)
+    prefill.__name__ = "prefill_slot_paged"
+    # as the chip's processes run, without conftest's x64 and precision
+    # (``train_steps`` says why)
+    with _as_on_a_tpu(), jax.enable_x64(False), \
+            jax.default_matmul_precision("default"):
+        lowered = jax.jit(prefill, donate_argnums=(6,)).lower(
+            params, arg((1, 256), jnp.int32), scalar(jnp.int32),
+            scalar(jnp.int32), arg((cfg.max_seq_len // PAGE,), jnp.int32),
+            scalar(jnp.int32), kv, sv, arg((2,), jnp.uint32),
+            scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.float32))
+    return _compile_all({"prefill": lowered})["prefill"]
+
+
+# of the parent's programs (commit a222295), compiled by this file's
+# fixtures in the parent's tree
+PARENT_PROGRAMS = {
+    "sambay_prefill": "efc129a16255ffb8",
+    "prefill_slot_paged": "d9672642aeb4c8ad",
+    "decode_slots_paged": "ffc001e06cfef18c",
+}
+
+
+@pytest.mark.parametrize("program", list(PARENT_PROGRAMS))
+def test_tpu_serve_programs_compile_to_the_parents(request, compiled,
+                                                   program):
+    """``flash_attention``'s Pallas arm went behind a ``custom_vjp`` of
+    this repo's own, with GQA's repeat inside it, and ``_qkv`` /
+    ``_out_proj`` / ``_ffn`` name their values for a checkpoint: none of
+    it is differentiated or checkpointed in a serve program, so what the
+    chip is handed is the parent's program, kernel for kernel. The CPU's
+    lowered text (``test_remat_plan.py``) cannot say so: there
+    ``flash_attention`` takes the blockwise arm."""
+    exe = request.getfixturevalue("sambay_prefill") \
+        if program == "sambay_prefill" else compiled[program]
+    if program == "sambay_prefill":
+        forward = re.findall(
+            r"^\s*%?(flash_attention[\w.\-]*) = [^\n]*"
+            r'custom_call_target="tpu_custom_call"', exe.as_text(), re.M)
+        assert forward, "the bucket took another arm than the kernel's"
+    assert _program_hash(exe) == PARENT_PROGRAMS[program]
