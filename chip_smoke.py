@@ -952,7 +952,9 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
     """A serving family other than llama (``models.serving_family(cfg)``:
     ``sambay.py``'s state-space, window, full, GMU and cross-attention
     layers; ``latent_moe.py``'s latent attention and routed experts;
-    ``retention.py``'s power-retention layers, whose pool has no pages)
+    ``retention.py``'s power-retention layers, whose pool has no pages;
+    ``blockdiff_moe.py``'s blocks of diffusion, whose streams are
+    replayed pass by pass: ``_block_replay_gap``)
     through a paged ``ServeEngine`` behind ``Gateway.start_http``, once
     in the config's bf16 and once in float32 at ``highest`` precision,
     against its own ``forward``: ``jobs`` are asked greedily, and each
@@ -1020,6 +1022,10 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
             for job, rec in zip(jobs, results):
                 assert rec is not None and rec["status"] == 200 and \
                     len(rec["tokens"]) == job["mnew"], rec
+                if hasattr(family, "block_step_slots_paged"):
+                    worst = max(worst, _block_replay_gap(
+                        c, fwd, params, job["prompt"], rec["tokens"]))
+                    continue
                 n0 = len(job["prompt"])
                 seq = job["prompt"] + rec["tokens"]
                 seq = seq + [0] * (-len(seq) % 128)
@@ -1036,6 +1042,45 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
     assert expect_attention in (
         None, info[f"decode_attention_{np.dtype(cfg.dtype).name}"]), info
     return info
+
+
+def _block_replay_gap(cfg, fwd, params, prompt, tokens):
+    """A block-diffusion stream against the family's own ``forward`` (no
+    cache, no pages): the blocks are replayed pass by pass, teacher-forced
+    with the tokens the engine emitted. A pass feeds the block as it
+    then stands (``[MASK]`` where not yet filled) behind the prefix; the
+    ``per_pass`` most confident masked positions take the emitted
+    tokens, each of which lies ``gap`` under its position's largest
+    logit. Returns the worst gap (0 where the engine took the argmax of
+    these logits at every fill, in this order)."""
+    import jax.numpy as jnp
+    import numpy as np
+    B, mask_id = cfg.block_length, cfg.mask_token_id
+    seq, done, worst = list(prompt), 0, 0.0
+    start = len(seq) // B * B
+    pad = -(-(len(prompt) + len(tokens) + B) // 128) * 128
+    while done < len(tokens):
+        block = seq[start:]
+        masked = list(range(len(block), B))
+        want = dict(zip(masked, tokens[done:done + len(masked)]))
+        block = block + [mask_id] * len(masked)
+        while masked:
+            fed = seq[:start] + block
+            lg = np.array(fwd(params, jnp.asarray(
+                fed + [0] * (pad - len(fed)), jnp.int32)[None])[
+                    0, start:start + B], np.float64)
+            lg[:, mask_id] = -np.inf
+            top = lg.max(-1)
+            conf = 1.0 / np.exp(lg - top[:, None]).sum(-1)
+            for i in sorted(masked, key=lambda i: (-conf[i], i))[
+                    :cfg.per_pass]:
+                # the last block's positions past the request's count
+                # were drawn and cut: the replay's own candidate there
+                block[i] = want.get(i, int(lg[i].argmax()))
+                worst = max(worst, float(top[i] - lg[i][block[i]]))
+                masked.remove(i)
+        seq, done, start = seq[:start] + block, done + len(want), start + B
+    return worst
 
 
 # -- the run ----------------------------------------------------------------
@@ -1132,6 +1177,19 @@ def main():
          expect_attention="state_kernel",
          expect_attention_f32="state_kernel",
          expect_sampler="search_kernel")
+
+    # the fifth, at its published widths and a small depth (two
+    # Qwen3-MoE layers, all 128 experts): the longer prompt is prefilled
+    # in two chunks under the block-causal mask, then blocks of four are
+    # denoised and committed, a step's 8 x 4 rows through the walk over
+    # live pages; each stream is replayed against ``forward``
+    from mxtpu.models import blockdiff_moe
+    bd_cfg = blockdiff_moe.BlockDiffMoEConfig(n_layers=2, max_seq_len=2048)
+    _run("serve_blockdiff", phase_serve_family, bd_cfg,
+         make_jobs(bd_cfg.vocab_size, ((301, 16, 0.0), (1102, 12, 0.0)),
+                   per_shape=2, shared_prefix=0),
+         max_slots=8, max_len=2048, min_bucket=256, prefill_chunk=1024,
+         expect_attention="pages", expect_sampler="search_kernel")
 
     if jax.device_count() >= 4:
         # the same two phases over a mesh with more than one

@@ -29,6 +29,8 @@ __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
            "paged_decode_attention", "paged_decode_path",
            "gathered_decode_attention", "latent_decode_path",
            "rows_decode_path", "gathered_rows_decode_attention",
+           "block_decode_path", "paged_block_attention",
+           "block_causal_rows_attention",
            "paged_latent_decode_attention",
            "gathered_latent_decode_attention", "latent_prefill_attention"]
 
@@ -41,6 +43,9 @@ KV_GATHER_SCOPE = "kv_gather"
 # latent (MLA) attention, models/latent_moe.py: its own name, so that a
 # trace tells a latent layer's time from a per-head layer's
 MLA_SCOPE = "mla_attention"
+# a block-diffusion step's attention (models/blockdiff_moe.py): a block
+# of query rows a slot over the cache and the block itself
+BLOCK_SCOPE = "block_attention"
 
 
 def _repeat_kv(q, k, v):
@@ -638,17 +643,61 @@ def gathered_rows_decode_attention(q, k_pages, v_pages, page_table, lengths,
     (under ``kv_gather``), then :func:`slot_decode_attention`. What
     ``paged_attention_rows`` is held against, in the tests and on the
     chip."""
-    slots, per_slot = page_table.shape
-    page_size, width = k_pages.shape[-2:]
-    kvh = width // q.shape[-1]
+    hd = q.shape[-1]
+    return slot_decode_attention(
+        q, _gather_token_rows(k_pages, layer, page_table, hd),
+        _gather_token_rows(v_pages, layer, page_table, hd), lengths,
+        scale=scale, kv_block=kv_block)
 
-    @jax.named_scope(KV_GATHER_SCOPE)
-    def flat(pool):
-        g = pool.at[layer, page_table].get(mode="promise_in_bounds")
-        return (g.reshape(slots, per_slot * page_size, kvh, width // kvh)
-                 .transpose(0, 2, 1, 3))
-    return slot_decode_attention(q, flat(k_pages), flat(v_pages), lengths,
-                                 scale=scale, kv_block=kv_block)
+
+@jax.named_scope(KV_GATHER_SCOPE)
+def _gather_token_rows(pool, layer, page_table, hd: int):
+    """Every slot's whole row of pages out of a pool of token rows (L,
+    n_pages, page_size, kvh * hd), turned head-major: (slots, kvh,
+    capacity, hd). The gathered arms' copy of capacity."""
+    slots, per_slot = page_table.shape
+    page_size, width = pool.shape[-2:]
+    g = pool.at[layer, page_table].get(mode="promise_in_bounds")
+    return (g.reshape(slots, per_slot * page_size, width // hd, hd)
+             .transpose(0, 2, 1, 3))
+
+
+def block_decode_path(q_shape, pool_shape, pool_dtype, *, mesh=None) -> str:
+    """Which attention a step over BLOCKS of query rows (q (slots,
+    n_heads, rows, hd), every row of a slot seeing the same keys) reads
+    pools of token rows through, decided as :func:`rows_decode_path`
+    decides: ``"pages"`` (``ops.paged_attention.paged_attention_block``:
+    the block's rows folded into the query-head group, the walk over
+    live pages as it is) on a TPU over pools it takes as stored
+    (``takes_block``) and no mesh; ``"gathered"`` everywhere else."""
+    from .paged_attention import takes_block
+    if (jax.default_backend() == "tpu" and mesh is None
+            and takes_block(q_shape, pool_shape, pool_dtype)):
+        return "pages"
+    return "gathered"
+
+
+def paged_block_attention(q, k_pages, v_pages, page_table, lengths, *,
+                          layer, scale: Optional[float] = None, mesh=None):
+    """A block of query rows a slot over pools of token rows, by the
+    path :func:`block_decode_path` names. q: (slots, n_heads, rows, hd);
+    lengths: (slots,) the keys every row of the slot sees (the caller
+    has written the block's own). The gathered arm is
+    :func:`gathered_rows_decode_attention`'s: every slot's whole row of
+    pages copied out under ``kv_gather``, then
+    :func:`slot_decode_attention`, which takes any number of query rows
+    under one length."""
+    if block_decode_path(q.shape, k_pages.shape, k_pages.dtype,
+                         mesh=mesh) == "pages":
+        from .paged_attention import paged_attention_block
+        with jax.named_scope(BLOCK_SCOPE):
+            return paged_attention_block(q, k_pages, v_pages, page_table,
+                                         lengths, layer=layer, scale=scale)
+    hd = q.shape[-1]
+    k = _gather_token_rows(k_pages, layer, page_table, hd)
+    v = _gather_token_rows(v_pages, layer, page_table, hd)
+    with jax.named_scope(BLOCK_SCOPE):
+        return slot_decode_attention(q, k, v, lengths, scale=scale)
 
 
 def latent_prefill_attention(q_nope, q_rope, rows, wkvb, *, layer,
@@ -709,6 +758,68 @@ def latent_prefill_attention(q_nope, q_rope, rows, wkvb, *, layer,
     n_blocks = (q_offset + s + kv_block - 1) // kv_block
     m, l, o = lax.fori_loop(z, n_blocks, body, init)
     return _finalize(m, l, o, jnp.float32)
+
+
+def block_causal_rows_attention(q, k_rows, v_rows, *, layer, q_offset,
+                                block: int, scale: Optional[float] = None,
+                                kv_block: int = 512):
+    """Block-causal attention of a run of queries over stores of token
+    rows: position i sees position j iff ``j // block <= i // block``
+    (causal across blocks of ``block`` positions, bidirectional inside
+    one), which a ``causal`` flag cannot say. XLA's arithmetic
+    (:func:`latent_prefill_attention`'s loop): a block of keys at a
+    time, online softmax in float32.
+
+    q: (b, H, s, hd), the queries at positions ``q_offset .. q_offset +
+    s`` (``q_offset`` a traced scalar). k_rows, v_rows: the whole (L, b,
+    capacity, G hd) row stores, a token's ``G`` KV heads end to end,
+    read at ``layer``; position p's row at index p; ``capacity`` a
+    multiple of ``kv_block``. Only the key blocks below ``q_offset + s``
+    rounded up to ``block`` are read. Returns (b, H, s, hd) float32."""
+    b, H, s, hd = q.shape
+    G = k_rows.shape[-1] // hd
+    rep = H // G
+    scale = float(scale if scale is not None else 1.0 / math.sqrt(hd))
+    q_offset = jnp.asarray(q_offset, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    # the first position a query does NOT see: the end of its own block
+    seen = ((q_offset + jnp.arange(s, dtype=jnp.int32)) // block + 1) * block
+    at = jnp.arange(kv_block, dtype=jnp.int32)
+    z = jnp.zeros((), jnp.int32)
+    qg = q.reshape(b, G, rep, s, hd)
+
+    def heads(rows, i):
+        blk = lax.dynamic_slice(
+            rows, (layer, z, i * kv_block, z),
+            (1, b, kv_block, rows.shape[-1]))[0]
+        return blk.reshape(b, kv_block, G, hd)
+
+    def body(i, carry):
+        m, l, o = carry
+        i = i.astype(jnp.int32)       # under x64 the loop counts in 64
+        k, v = heads(k_rows, i), heads(v_rows, i)
+        sc = jnp.einsum("bgrqd,bkgd->bgrqk", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+        allowed = ((i * kv_block + at)[None, :]
+                   < seen[:, None])[None, None, None]
+        sc = jnp.where(allowed, sc, _NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        corr = jnp.exp(m - m_new)
+        p = jnp.where(allowed, jnp.exp(sc - m_new[..., None]), 0.0)
+        l_new = l * corr + p.sum(axis=-1)
+        o_new = o * corr[..., None] + jnp.einsum(
+            "bgrqk,bkgd->bgrqd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, o_new
+
+    init = (jnp.full((b, G, rep, s), _NEG_INF, jnp.float32),
+            jnp.zeros((b, G, rep, s), jnp.float32),
+            jnp.zeros((b, G, rep, s, hd), jnp.float32))
+    last = (q_offset + s + block - 1) // block * block
+    n_blocks = jnp.minimum((last + kv_block - 1) // kv_block,
+                           k_rows.shape[2] // kv_block)
+    m, l, o = lax.fori_loop(z, n_blocks, body, init)
+    return _finalize(m, l, o, jnp.float32).reshape(b, H, s, hd)
 
 
 def ring_attention(q, k, v, *, axis_name: str = "sp",

@@ -52,6 +52,13 @@ zero row-wide query, so that ONE product of a chunk's rows with all the
 query heads scores every head against its own keys (the zeros cost
 nothing: the MXU latches the same key tiles either way); the value
 product is as wide as a row, and each head keeps its own lanes of it.
+
+A BLOCK of query rows a slot that all see the cache and each other
+(``models/blockdiff_moe.py``: a block of diffusion, its keys and values
+written before the read) is that walk once more with nothing added
+(:func:`paged_attention_block`): the block's rows ride as further query
+heads of their KV head, ``rows x rep`` to a group, over ``length + rows``
+keys, and no mask is needed.
 """
 from __future__ import annotations
 
@@ -67,7 +74,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention_pages", "takes", "KERNEL_NAME",
            "paged_latent_pages", "takes_latent", "LATENT_KERNEL_NAME",
-           "paged_attention_rows", "takes_rows", "ROWS_KERNEL_NAME"]
+           "paged_attention_rows", "takes_rows", "ROWS_KERNEL_NAME",
+           "paged_attention_block", "takes_block"]
 
 # the kernels' names as a device trace prints them
 KERNEL_NAME = "paged_decode_attention_pages"
@@ -450,3 +458,36 @@ def paged_attention_rows(q, k_pages, v_pages, page_table, lengths, *, layer,
                                    lane_heads=width // hd),
                  ROWS_KERNEL_NAME, q, (k_pages, v_pages), (page_size, width),
                  ppb, layer, page_table, lengths, interpret)
+
+
+def takes_block(q_shape, pool_shape, pool_dtype) -> bool:
+    """Whether :func:`paged_attention_block` takes a block of query rows
+    a slot, q (slots, n_heads, rows, hd), over pools of token rows as
+    they are stored: :func:`takes_rows` of the block folded into the
+    query heads (the queries, ``rows`` times as many, still fit VMEM)."""
+    return len(q_shape) == 4 and takes_rows(
+        (q_shape[0], q_shape[1] * q_shape[2], 1, q_shape[3]), pool_shape,
+        pool_dtype)
+
+
+def paged_attention_block(q, k_pages, v_pages, page_table, lengths, *, layer,
+                          scale: Optional[float] = None,
+                          block_pages: Optional[int] = None,
+                          chunk_pages: Optional[int] = None,
+                          interpret: bool = False):
+    """Attention of a BLOCK of query rows a slot over the live pages of
+    two pools of token rows. q: (slots, n_heads, rows, hd); the pools,
+    the page table and ``layer`` as :func:`paged_attention_rows` takes
+    them; lengths: (slots,) int, the keys EVERY row of the slot's block
+    sees (the cache and the block itself, whose keys and values the
+    caller has written): no row is masked from another. Query head h's
+    ``rows`` rows are ``rows`` further heads of h's KV head (a reshape:
+    head h, row b is head ``h * rows + b`` of a group ``rows`` times as
+    large), so this is :func:`paged_attention_rows`'s walk as it is.
+    Returns (slots, n_heads, rows, hd) in q's dtype."""
+    slots, hq, rows, hd = q.shape
+    out = paged_attention_rows(
+        q.reshape(slots, hq * rows, 1, hd), k_pages, v_pages, page_table,
+        lengths, layer=layer, scale=scale, block_pages=block_pages,
+        chunk_pages=chunk_pages, interpret=interpret)
+    return out.reshape(q.shape)

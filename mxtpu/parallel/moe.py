@@ -34,7 +34,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["init_moe_params", "moe_ffn", "moe_ffn_dense",
-           "load_balance_loss", "route_sigmoid", "moe_ffn_dropless"]
+           "load_balance_loss", "route_sigmoid", "route_softmax",
+           "moe_ffn_dropless"]
 
 # the named scopes of a routed expert layer (models/latent_moe.py opens
 # each at the top level of its layer): the router; the dispatch (sort,
@@ -256,6 +257,23 @@ def route_sigmoid(x, w_router, bias, *, top_k: int, renorm: bool = True,
     if renorm:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * scale
+
+
+@jax.named_scope(ROUTER_SCOPE)
+def route_softmax(x, w_router, *, top_k: int, renorm: bool = True):
+    """The softmax router (Qwen3-MoE's): ``p = softmax(float32(x) W_r)``
+    over the experts; the ``top_k`` experts by ``p``; their weights
+    ``p`` itself, divided by their sum if ``renorm`` (``norm_topk_prob``).
+    :func:`route_sigmoid`'s contract: the product in float32 at
+    ``highest`` precision whatever x's type. x: (T, d); w_router: (d,
+    E). Returns (idx (T, top_k) int32, weights (T, top_k) float32)."""
+    p = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    w, idx = lax.top_k(p, top_k)
+    if renorm:
+        w = w / w.sum(-1, keepdims=True)
+    return idx.astype(jnp.int32), w
 
 
 # rows of a tile of the grouped product (``megablox.gmm``), and the most

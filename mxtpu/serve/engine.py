@@ -26,6 +26,16 @@ SOSP '23):
   page table (a small int32 operand) make request churn invisible to
   the compiled shape. The engine asserts this via
   :attr:`compile_count`.
+- **a step yields 0..W tokens a slot**: one token (the plain step), the
+  accepted run of a speculative verify (1..k+1), or nothing or a block
+  (a block-diffusion family's ``block_step_slots_paged``: a denoise pass
+  emits nothing, a commit pass the block's new tokens). The device
+  tells, beside the tokens, which of them each slot emitted, and a
+  slot's length grew by as many; ``_process`` is the one path that
+  mirrors the lengths and emits, at the readback the next step
+  overlaps. A
+  request's first token is whichever emission comes first (a
+  block-diffusion prefill yields none), and TTFT is observed there.
 - **overlapped host sync**: the classic serving-latency bug is a host
   readback inside the decode loop blocking the accelerator every token
   (mxlint MXL004 flags the pattern). Here step ``t``'s tokens are read
@@ -501,14 +511,19 @@ class KVHandoff:
 
 @dataclass
 class _Dispatch:
-    """One in-flight decode step: the device handle plus the host-side
+    """One in-flight decode step: the device handles plus the host-side
     snapshot needed to attribute its tokens after the overlapped
-    sync. A speculative step carries (S, W) token/valid matrices in
-    ``sampled``/``emits`` instead of the plain (S,) tokens, plus the
-    per-slot proposed-draft counts for the accept-rate accounting."""
-    sampled: Any                                   # device (S,) int32
+    sync. ``sampled`` holds W tokens a slot, row-major, with the
+    family's ``STEP_COUNTS`` values behind them: (S,) of a plain step,
+    (S, W) of a speculative verify, and of a block step (2 S B +
+    counts,): the tokens, then 1 for each that the step emitted.
+    ``emits`` (S, W) bool is the verify's say of which of a slot's W it
+    emitted (None: what ``sampled`` holds). A slot's length advanced by
+    what it emitted. A speculative step also carries the per-slot
+    proposed-draft counts for the accept-rate accounting."""
+    sampled: Any                                   # device int32
     slots: List[Tuple[int, int]]                   # (slot, rid) active
-    firsts: List[Tuple[int, Any]]                  # (rid, device (1,))
+    firsts: List[Tuple[int, Any]]                  # (rid, device (0..1,))
     emits: Any = None                              # spec: device (S, W)
     proposed: Optional[np.ndarray] = None          # spec: (S,) host
 
@@ -529,7 +544,7 @@ class ServeEngine:
 
     Args: ``cfg``/``params`` — a config and parameter pytree of a
     family in ``models.SERVING_FAMILIES`` (llama, sambay, latent_moe,
-    retention): the engine
+    retention, blockdiff_moe): the engine
     takes every program it runs from that family's module, found once
     here from ``cfg.family``, and refuses at construction the options
     the family's ``SERVE_UNSUPPORTED`` names, with the mechanism in
@@ -653,11 +668,13 @@ class ServeEngine:
             state = fam.init_paged_cache(
                 cfg, self.max_slots, self.n_pages, self.page_size,
                 mesh=mesh, int8=self.int8_pages)
-            # the small per-slot vectors; everything else is the donated
-            # state (llama: the K/V pools; sambay: a pool plus the fixed
-            # per-slot rings and recurrent state)
-            self._sv = {n: state.pop(n)
-                        for n in ("lengths", "tokens", "rngs")}
+            # the small per-slot vectors (every family's three, or the
+            # family's own list: a block-diffusion slot also holds its
+            # block); everything else is the donated state (llama: the
+            # K/V pools; sambay: a pool plus the fixed per-slot rings
+            # and recurrent state)
+            self._sv = {n: state.pop(n) for n in getattr(
+                fam, "SLOT_VARS", ("lengths", "tokens", "rngs"))}
             self._kv = state
             # bytes of the donated state by kind: pages (the kinds named
             # ``*_pages``: keys and values, or latent rows) grow with the
@@ -696,9 +713,15 @@ class ServeEngine:
         # watch_jit(): each program is compiled under the name of the
         # model function it runs, so a trace reads
         # jit_decode_slots_paged, not jit__unknown
+        # a family whose step yields nothing or a block a slot brings
+        # its own step program, whose tokens are followed by which of
+        # them it emitted (_Dispatch); every other family's yields one
+        step = getattr(fam, "block_step_slots_paged", None)
+        self._block_step = step is not None
+        step = step or fam.decode_slots_paged
         self._decode = telemetry.watch_jit(
-            partial(fam.decode_slots_paged, cfg, mesh=mesh),
-            "serve_decode", "decode_slots_paged", loop="serve",
+            partial(step, cfg, mesh=mesh),
+            "serve_decode", step.__name__, loop="serve",
             donate_argnums=(1,))
         # what the family's decode program counts on the device: the
         # values ride behind the sampled tokens in the array _process
@@ -757,6 +780,10 @@ class ServeEngine:
         eid = str(next(_engine_seq))
         self.engine_id = eid
         self._m = _engine_metrics(eid, self._attention, self._sampler)
+        # what a family states once about its step (a block's length, ..)
+        for name, text, value in getattr(fam, "serve_gauges",
+                                         lambda cfg: ())(cfg):
+            telemetry.gauge(name, text, engine=eid).set(value)
         self._m_cancel: Dict[str, Any] = {}    # per-reason counters
         # span factories pre-bind their registry histograms — the
         # per-step/per-admission hot paths must not re-intern handles.
@@ -795,11 +822,13 @@ class ServeEngine:
         self._spec_steps = 0
 
         # KV occupancy accounting: host-mirrored per-slot lengths (a
-        # prefill seats the prompt length; every decode dispatch adds
-        # one entry per active slot — exactly the device's `lengths`
-        # vector, tracked WITHOUT reading it back: a device sync here
-        # would block the decode loop every token, MXL004). Reserved
-        # bytes count the pool's global logical size across the mesh.
+        # prefill seats the prompt length; every step's readback adds
+        # what the device says the slot emitted — the device's
+        # `lengths` vector a step behind (a block-diffusion slot's plus
+        # the prompt's remainder, which waits in its first block),
+        # tracked WITHOUT reading it back: a device sync here would
+        # block the decode loop every token, MXL004). Reserved bytes
+        # count the pool's global logical size across the mesh.
         self._slot_len = np.zeros(S, np.int64)
         for k, nbytes in by_kind.items():
             telemetry.gauge(
@@ -862,17 +891,28 @@ class ServeEngine:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got "
                 f"{request.max_new_tokens}")
-        if prompt.size + request.max_new_tokens > self.max_len:
+        if self._positions(prompt.size,
+                           request.max_new_tokens) > self.max_len:
             raise ValueError(
                 f"prompt ({prompt.size}) + max_new_tokens "
                 f"({request.max_new_tokens}) exceeds max_len "
                 f"{self.max_len}")
+        self._refuse({"resume_key": request.rng is not None})
         if request.top_k is not None and request.top_k < 1:
             raise ValueError(f"top_k must be >= 1, got {request.top_k}")
         if request.top_p is not None and not 0.0 < request.top_p <= 1.0:
             raise ValueError(
                 f"top_p must be in (0, 1], got {request.top_p}")
         return self._enqueue(request)
+
+    def _positions(self, prompt_len: int, max_new_tokens: int) -> int:
+        """How many positions of its pages a request can write: the
+        family's answer (a block-diffusion step writes whole blocks), or
+        every token once."""
+        fn = getattr(self._family, "positions_written", None)
+        total = int(prompt_len) + int(max_new_tokens)
+        return total if fn is None else int(fn(self.cfg, int(prompt_len),
+                                               int(max_new_tokens)))
 
     def _refuse(self, asked: Dict[str, Any]) -> None:
         """Raise for the first option in ``asked`` that is set and that
@@ -1099,7 +1139,7 @@ class ServeEngine:
         ps = self.page_size
         cap = self._pages_per_slot * ps
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-        total = int(prompt.size) + int(req.max_new_tokens)
+        total = self._positions(prompt.size, req.max_new_tokens)
         n_total = -(-total // ps)
         entry, m = None, 0
         ignore_handoff = False
@@ -1525,14 +1565,9 @@ class ServeEngine:
             slots = [(s, rid) for s, rid in enumerate(self._slot_rid)
                      if self._active[s] and rid is not None
                      and not self._prefilling[s]]
-            if emits is None:
-                # the decode program appends one cache entry per
-                # active slot; mirror that on the host (no readback —
-                # MXL004). A speculative step advances by the accepted
-                # run, known only after the sync — _process (always
-                # synchronous in spec mode) mirrors it there
-                for s, _rid in slots:
-                    self._slot_len[s] += 1
+        # how far each slot's length advanced is the device's to say (a
+        # speculative step's accepted run, a block's commit): _process
+        # mirrors it at the readback
         return _Dispatch(sampled, slots, firsts, emits=emits,
                          proposed=proposed)
 
@@ -1540,7 +1575,12 @@ class ServeEngine:
         self._results[rid].append(token)
         self._m["tokens"].inc()
         last = self._last_tok.get(rid)
-        if last is not None:
+        if last is None:
+            # a request's first emission, wherever it comes from (a
+            # prefill's first token, a block-diffusion slot's first
+            # commit pass)
+            self._observe_ttft(rid, now)
+        else:
             gap_ms = 1e3 * (now - last)
             self._lat.observe(gap_ms)
             self._m["latency"].observe(gap_ms)
@@ -1565,18 +1605,29 @@ class ServeEngine:
         self._m["ttft_first_wait"].observe(1e3 * (now - dispatched))
 
     def _process(self, disp: _Dispatch) -> None:
+        """One step's readback and emissions: the ONE path for a step
+        that yields 0..W tokens a slot (``_Dispatch`` says what each
+        kind of step hands over)."""
         # the device sync happens OUTSIDE the lock — a submitter must
         # never block behind a device readback. serve.readback is the
         # time the host waits for the device
         with self._span_readback():
-            sampled = np.asarray(disp.sampled) if disp.slots else None
+            ran = bool(disp.slots)
+            sampled = np.asarray(disp.sampled) if ran else None
             emits = (np.asarray(disp.emits)
-                     if disp.emits is not None and disp.slots else None)
-            firsts = [(rid, int(np.asarray(dev)[0]))
+                     if disp.emits is not None and ran else None)
+            firsts = [(rid, np.asarray(dev).reshape(-1))
                       for rid, dev in disp.firsts]
         now = time.perf_counter()
-        if sampled is not None and self._step_counts:
-            sampled, counts = np.split(sampled, [self.max_slots])
+        if sampled is not None:
+            # W tokens a slot, the family's step counts behind them
+            sampled = sampled.reshape(-1)
+            n = len(self._step_counts)
+            sampled, counts = np.split(sampled, [sampled.size - n])
+            if self._block_step:
+                sampled, emits = np.split(sampled, 2)
+                emits = emits.reshape(self.max_slots, -1) > 0
+            sampled = sampled.reshape(self.max_slots, -1)
             for (series, per), value in zip(self._step_counts, counts):
                 if per:
                     series.observe(float(value) / per)
@@ -1586,47 +1637,44 @@ class ServeEngine:
             rid2slot = ({rid: s for s, rid in
                          enumerate(self._slot_rid) if rid is not None}
                         if self.speculate_k else {})
-            for rid, tok in firsts:
-                if rid not in self._cancelled:
-                    self._observe_ttft(rid, now)
-                    self._emit(rid, tok, now)
+            for rid, toks in firsts:
+                if rid in self._cancelled:
+                    continue
+                for tok in toks:
+                    self._emit(rid, int(tok), now)
                     s = rid2slot.get(rid)
                     if s is not None:
-                        self._hist[s].append(tok)
-            if disp.slots:
-                for slot, rid in disp.slots:
-                    if emits is not None:
-                        # speculative step: the device advanced this
-                        # slot by its accepted run — mirror the length
-                        # and emit the run in order (the emission loop
-                        # stops at max_new_tokens/cancel; the device's
-                        # over-advance on a finishing slot is inert —
-                        # the slot is freed below and reseeded at its
-                        # next admission)
-                        n = int(emits[slot].sum())
-                        self._slot_len[slot] += n
-                        prop = int(disp.proposed[slot])
-                        self._spec_proposed += prop
-                        self._spec_accepted += n - 1
-                        if prop:
-                            self._m["spec_proposed"].inc(prop)
-                            self._m["spec_accepted"].inc(n - 1)
-                        self._m["spec_len"].observe(n)
-                        for i in range(n):
-                            # a pruned rid (non-retained, finalized)
-                            # reads as done — never emit for it
-                            if self._done.get(rid, True) \
-                                    or rid in self._cancelled:
-                                break
-                            tok = int(sampled[slot, i])
-                            self._emit(rid, tok, now)
-                            self._hist[slot].append(tok)
-                    elif not self._done.get(rid, True) \
-                            and rid not in self._cancelled:
-                        tok = int(sampled[slot])
-                        self._emit(rid, tok, now)
-                        if self.speculate_k:
-                            self._hist[slot].append(tok)
+                        self._hist[s].append(int(tok))
+            for slot, rid in disp.slots:
+                toks = sampled[slot]
+                if emits is not None:
+                    toks = toks[emits[slot]]
+                if self._slot_rid[slot] == rid:
+                    # the device advanced this slot (a finished
+                    # request's slot is freed below, and may hold its
+                    # successor by now: its over-advance was inert and
+                    # the successor's prefill reseeded the length)
+                    self._slot_len[slot] += len(toks)
+                if disp.proposed is not None:
+                    # speculative step: 1 + the accepted run
+                    n, prop = len(toks), int(disp.proposed[slot])
+                    self._spec_proposed += prop
+                    self._spec_accepted += n - 1
+                    if prop:
+                        self._m["spec_proposed"].inc(prop)
+                        self._m["spec_accepted"].inc(n - 1)
+                    self._m["spec_len"].observe(n)
+                for tok in toks:
+                    # the emission loop stops at max_new_tokens/cancel
+                    # (a pruned rid — non-retained, finalized — reads
+                    # as done: never emit for it); what the device ran
+                    # past it is inert
+                    if self._done.get(rid, True) \
+                            or rid in self._cancelled:
+                        break
+                    self._emit(rid, int(tok), now)
+                    if self.speculate_k:
+                        self._hist[slot].append(int(tok))
             for slot, rid in enumerate(self._slot_rid):
                 if rid is None:
                     continue

@@ -186,6 +186,23 @@ def test_serve_retention_phase_runs_tiny_on_cpu():
     assert info["worst_gap_float32"] <= 1e-3
 
 
+def test_serve_blockdiff_phase_runs_tiny_on_cpu():
+    """The fifth family's leg: blocks of diffusion over routed experts
+    at toy widths, a prompt with a remainder, the longer one in two
+    chunks, each stream replayed against ``forward``; both passes
+    float32 here."""
+    from mxtpu.models import blockdiff_moe
+    cfg = blockdiff_moe.CONFIGS["tiny"]
+    jobs = chip_smoke.make_jobs(cfg.vocab_size,
+                                ((11, 6, 0.0), (30, 9, 0.0)),
+                                per_shape=1, shared_prefix=0)
+    info = chip_smoke.phase_serve_family(
+        cfg, jobs, max_slots=2, max_len=96, min_bucket=16, page_size=8,
+        prefill_chunk=16)
+    assert info["requests"] == 4
+    assert info["worst_gap_float32"] <= 1e-3
+
+
 def test_main_fails_without_a_chip():
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py")],
