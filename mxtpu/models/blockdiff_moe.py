@@ -21,11 +21,11 @@ the block's ``B`` positions (tokens or ``[MASK]``) against the stored
 keys and values and each other, draws a candidate at every masked
 position and keeps the ``B / denoising_steps`` most confident
 (``low_confidence_static``; ``low_confidence_dynamic`` keeps every one
-over ``confidence_threshold`` if that is more); when no mask is left a
-*commit pass* runs the same forward over the final tokens, whose keys and
-values stay, and the block's new tokens are the request's next tokens.
-So a pass yields NO token or a whole block, and a block of ``B`` tokens
-costs ``denoising_steps + 1`` forward passes.
+over ``confidence_threshold`` if that is more); when no mask is left the
+block's new tokens are the request's next tokens, and the final tokens'
+keys and values are what stays. So a pass yields NO token or a whole
+block. The release runs a *commit pass* for those keys, the same forward
+over the final tokens; here they ride in the next block's first pass.
 
 The serving surface is latent_moe's (llama's plus the chunk surface),
 with :func:`block_step_slots_paged` in ``decode_slots_paged``'s place:
@@ -34,15 +34,22 @@ keys and values at ``length .. length + B - 1`` first (what lies past a
 slot's length is excluded by every later read and overwritten in place,
 as the speculative step's rejected drafts are: a denoise pass's write
 needs no undo) and attends over ``length + B`` keys, so that no mask is
-needed inside the block. The pools are sambay's: K and V each (L,
-n_pages, page_size, n_kv_heads head_dim), a token's heads end to end in
-whole lane tiles; the block's rows ride as further query heads of their
-KV head through the walk over live pages that is there
-(``ops.paged_attention.paged_attention_block``). The slot variables
-gain the block: its tokens, which of them are still masked, which are
-new (a prompt's remainder opens the first block unmasked), the pass
-index. A prefill stores the keys and values of a prompt's WHOLE blocks,
-seats the remainder as the first block and returns no token.
+needed inside the block. The pass that takes a block's last mask emits
+it, advances the length by ``B`` and opens the next block with ``carry``
+set: in the slot's next pass ``B`` more rows, the finished block's final
+tokens at ``length - B ..``, write their keys and values in place (these
+are final) and attend ``length`` keys, beside the new block's rows, in
+the same walk: a block costs ``denoising_steps`` passes, not one more,
+and a request's last block no carried rows at all. The pools are
+sambay's: K and V each (L, n_pages, page_size, n_kv_heads head_dim), a
+token's heads end to end in whole lane tiles; the rows ride as further
+query heads of their KV head through the walk over live pages that is
+there (``ops.paged_attention.paged_attention_block``). The slot
+variables gain the block: its tokens (the finished block's while it is
+carried), which of them are still masked, which are new (a prompt's
+remainder opens the first block unmasked), the pass index, ``carry``. A
+prefill stores the keys and values of a prompt's WHOLE blocks, seats the
+remainder as the first block and returns no token.
 """
 from __future__ import annotations
 
@@ -106,23 +113,28 @@ SERVE_UNSUPPORTED = {
 STATE_KINDS = {"k": "kv_pages", "v": "kv_pages"}
 # the per-slot vectors the engine keeps beside the donated state: every
 # family's three, and the block (``tokens`` is (slots, B) here)
-SLOT_VARS = ("lengths", "tokens", "rngs", "masked", "fresh", "passes")
+SLOT_VARS = ("lengths", "tokens", "rngs", "masked", "fresh", "passes",
+             "carry")
 # what the step program counts on the device (``latent_moe.STEP_COUNTS``'
 # contract: the values ride behind the tokens in the program's first
 # output, in this order, as int32)
 STEP_COUNTS = latent_moe.STEP_COUNTS + (
     {"name": "serve_block_passes_total",
-     "help": "Forward passes of a block-diffusion step, a slot at a time "
-             "(denoise and commit passes of the slots that ran)"},
+     "help": "Denoise passes of a block-diffusion step, a slot at a time "
+             "(a block's keys are made final in the next block's first "
+             "pass: no pass of their own)"},
     {"name": "serve_blocks_committed_total",
-     "help": "Blocks committed (their keys and values kept, their new "
-             "tokens emitted)"},
+     "help": "Blocks committed (their last mask taken, their new tokens "
+             "emitted)"},
     {"name": "serve_block_unmasked_total",
      "help": "Masked positions that took their candidate in a denoise "
              "pass"},
     {"name": "serve_block_threshold_transfers_total",
      "help": "Of those, the ones taken because their confidence passed "
              "the threshold (low_confidence_dynamic)"},
+    {"name": "serve_block_carried_total",
+     "help": "Slot-passes that made a finished block's keys and values "
+             "final as carried rows beside the next block's"},
 )
 
 _F32 = jnp.float32
@@ -403,7 +415,8 @@ def serve_gauges(cfg: BlockDiffMoEConfig):
     return (("serve_block_length", "Positions of a block of diffusion: "
              "the most tokens a slot's step yields", cfg.block_length),
             ("serve_denoising_steps", "Denoise passes a full block takes "
-             "before its commit pass", cfg.denoising_steps))
+             "(its keys are made final in the next block's first)",
+             cfg.denoising_steps))
 
 
 def decode_attention_path(cfg, kv, mesh=None, *, verify: bool = False) -> str:
@@ -432,7 +445,9 @@ def init_paged_cache(cfg: BlockDiffMoEConfig, max_slots: int, n_pages: int,
     are final), ``rngs``, and the block: ``tokens`` (slots, B), ``masked``
     (slots, B) which of them are still ``[MASK]``, ``fresh`` (slots, B)
     which are the request's new tokens (a prompt's remainder is not),
-    ``passes`` the pass index inside the block. Page tables stay on the
+    ``passes`` the pass index inside the block, ``carry`` whether the
+    block just finished, in ``tokens`` while the next is all ``[MASK]``,
+    still has its final keys and values to write. Page tables stay on the
     host."""
     if mesh is not None or int8:
         raise ValueError("blockdiff_moe: " + SERVE_UNSUPPORTED[
@@ -450,7 +465,8 @@ def init_paged_cache(cfg: BlockDiffMoEConfig, max_slots: int, n_pages: int,
         "rngs": jnp.zeros((max_slots, 2), jnp.uint32),
         "masked": jnp.ones((max_slots, B), bool),
         "fresh": jnp.ones((max_slots, B), bool),
-        "passes": jnp.zeros((max_slots,), _I32)}
+        "passes": jnp.zeros((max_slots,), _I32),
+        "carry": jnp.zeros((max_slots,), bool)}
 
 
 copy_page = llama.copy_page
@@ -461,22 +477,34 @@ def decode_logits(cfg: BlockDiffMoEConfig, params, kv, sv, active,
     """A step up to its logits: every slot's block (its tokens, ``[MASK]``
     where ``masked``) at positions ``length .. length + B - 1``, its keys
     and values written there first, each row attending over ``length +
-    B`` keys. Returns (logits (S, B, V) float32, the pools with the
-    blocks written, counts (3,) int32 over the active slots' rows). A
-    slot that is not ``active`` flows through (fixed shape), writes to
-    scratch page 0, attends nothing and is routed to no expert."""
+    B`` keys. Where ``carry`` is set, the finished block's final tokens
+    (``tokens``, while every position of the new block is masked) run
+    as ``B`` more rows at ``length - B ..``: their keys and values,
+    written there first, are final, and each attends ``length`` keys.
+    Returns (logits (S, B, V) float32 of the blocks' rows alone, the
+    pools with both written, counts (3,) int32 over the rows that ran).
+    A slot that is not ``active`` flows through (fixed shape), writes to
+    scratch page 0, attends nothing and is routed to no expert; so do
+    the carried rows of a slot whose ``carry`` is clear."""
     ck, cv = kv["k"], kv["v"]
     ps, B = ck.shape[2], cfg.block_length
     nslots = page_table.shape[0]
     cap = page_table.shape[1] * ps
     at = jnp.arange(B, dtype=_I32)
-    base = jnp.minimum(sv["lengths"].astype(_I32), cap - B)
-    pos = base[:, None] + at                              # (S, B)
-    # a block never straddles a page: one page a slot
-    phys = jnp.broadcast_to(
-        page_table[jnp.arange(nslots), base // ps][:, None], pos.shape)
+    lengths = sv["lengths"].astype(_I32)
+    base = jnp.minimum(lengths, cap - B)
+    carried = active & sv["carry"]
+    back = jnp.clip(lengths - B, 0, cap - B)
+    # the carried rows, then the block's (S, 2 B); a block never
+    # straddles a page: one page a slot and block
+    pos = jnp.concatenate([back[:, None] + at, base[:, None] + at], 1)
+    slots = jnp.arange(nslots)
+    phys = jnp.repeat(jnp.stack(
+        [jnp.where(carried, page_table[slots, back // ps], 0),
+         page_table[slots, base // ps]], 1), B, axis=1)
     off = pos % ps
-    seen = jnp.where(active, base + B, 0)
+    seen = jnp.stack([jnp.where(carried, lengths, 0),
+                      jnp.where(active, base + B, 0)], 1)
     cos, sin = _rope_tables(cfg, pos)
 
     def attend(q, k, v, layer, pools):
@@ -484,11 +512,13 @@ def decode_logits(cfg: BlockDiffMoEConfig, params, kv, sv, active,
         return paged_block_attention(q, *pools, page_table, seen,
                                      layer=layer, scale=cfg.scale), pools
 
-    toks = jnp.where(sv["masked"], _I32(cfg.mask_token_id), sv["tokens"])
+    toks = jnp.concatenate([sv["tokens"], jnp.where(
+        sv["masked"], _I32(cfg.mask_token_id), sv["tokens"])], 1)
+    valid = jnp.repeat(jnp.stack([carried, active], 1), B, axis=1)
     x, (ck, cv), counts, _ = _scan_layers(
         cfg, params, llama._embed(cfg, params, toks), (ck, cv), cos, sin,
-        attend, jnp.broadcast_to(active[:, None], pos.shape))
-    return _final(cfg, params, x), {"k": ck, "v": cv}, counts
+        attend, valid)
+    return _final(cfg, params, x[:, B:]), {"k": ck, "v": cv}, counts
 
 
 def unmask(cfg: BlockDiffMoEConfig, logits, masked, keys, temperature,
@@ -540,23 +570,24 @@ def unmask(cfg: BlockDiffMoEConfig, logits, masked, keys, temperature,
 def block_step_slots_paged(cfg: BlockDiffMoEConfig, params, kv, sv, active,
                            page_table, temperature, top_k, top_p, mesh=None):
     """ONE pass over the bank, every slot whatever its phase
-    (``llama.decode_slots_paged``'s arguments). A slot whose block still
-    has a mask makes a DENOISE pass: the masked positions chosen by
-    :func:`unmask` take their candidates, nothing is emitted, the length
-    stays (the keys and values this pass wrote are tentative: the next
-    pass overwrites them in place). A slot with no mask left makes its
-    COMMIT pass: the keys and values just written are the final tokens'
-    and stay, the length advances by ``B``, the block's ``fresh`` tokens
-    are emitted and the next block opens, all ``[MASK]``. Every pass
-    splits the slot's chain once.
+    (``llama.decode_slots_paged``'s arguments): a DENOISE pass of every
+    slot that runs. The masked positions chosen by :func:`unmask` take
+    their candidates; the keys and values this pass wrote for the block
+    are tentative (the next pass overwrites them in place). A slot whose
+    block has no mask left after the pass FINISHES it: the block's
+    ``fresh`` tokens are emitted, the length advances by ``B``, the next
+    block opens all ``[MASK]`` and ``carry`` is set, so that the slot's
+    next pass makes the finished block's keys final beside the new
+    block's first pass (:func:`decode_logits`). Every pass splits the
+    slot's chain once.
 
-    Returns (out (2 S B + 7,) int32, new kv, new sv), as
+    Returns (out (2 S B + 8,) int32, new kv, new sv), as
     ``llama.decode_slots_paged`` does; ``out`` holds the blocks' tokens
     row-major, then for each of them 1 where it is the request's next
-    token (a commit pass's ``fresh`` positions, in order: a slot's length
-    advances by ``B`` in the pass that emits, and the tokens it emits
-    with the prompt's remainder are those ``B``), then ``STEP_COUNTS``'
-    values."""
+    token (a finished block's ``fresh`` positions, in order: a slot's
+    length advances by ``B`` in the pass that emits, and the tokens it
+    emits with the prompt's remainder are those ``B``), then
+    ``STEP_COUNTS``' values."""
     del mesh
     B = cfg.block_length
     logits, pools, counts = decode_logits(cfg, params, kv, sv, active,
@@ -567,35 +598,37 @@ def block_step_slots_paged(cfg: BlockDiffMoEConfig, params, kv, sv, active,
     x0, _, take, by_threshold = unmask(cfg, logits, masked, keys[:, 1],
                                        temperature, top_k, top_p)
     with jax.named_scope(UNMASK_SCOPE):
-        commit = ~masked.any(-1)
+        left = masked & ~take
+        done = ~left.any(-1) & active
         tokens = jnp.where(take, x0, sv["tokens"])
-        emit = (commit & active)[:, None] & sv["fresh"]
-        grew = jnp.where(commit & active, _I32(B), _I32(0))
+        emit = done[:, None] & sv["fresh"]
         took = (take & active[:, None]).sum(-1, dtype=_I32)
         share = (counts[2].astype(_F32) * 1e6
                  / jnp.maximum(counts[0], 1).astype(_F32)).astype(_I32)
         out = jnp.concatenate([
             tokens.reshape(-1).astype(_I32), emit.reshape(-1).astype(_I32),
             counts[:2], share[None],
-            jnp.stack([active.sum(dtype=_I32),
-                       (commit & active).sum(dtype=_I32),
-                       took.sum(), jnp.where(by_threshold, took, 0).sum()]
+            jnp.stack([(active & masked.any(-1)).sum(dtype=_I32),
+                       done.sum(dtype=_I32),
+                       took.sum(), jnp.where(by_threshold, took, 0).sum(),
+                       (active & sv["carry"]).sum(dtype=_I32)]
                       ).astype(_I32)])
-        opened = commit[:, None]
+        opened = done[:, None]
 
         def ran_only(new, old):     # a slot that did not run keeps its own
             return jnp.where(active.reshape((-1,) + (1,) * (old.ndim - 1)),
                              new.astype(old.dtype), old)
         new_sv = {
-            "lengths": sv["lengths"].astype(_I32) + grew,
+            "lengths": sv["lengths"].astype(_I32) + jnp.where(
+                done, _I32(B), _I32(0)),
             "tokens": ran_only(tokens, sv["tokens"]),
             "rngs": ran_only(keys[:, 0], sv["rngs"]),
-            "masked": ran_only(jnp.where(opened, True, masked & ~take),
-                               masked),
+            "masked": ran_only(jnp.where(opened, True, left), masked),
             "fresh": ran_only(jnp.where(opened, True, sv["fresh"]),
                               sv["fresh"]),
-            "passes": ran_only(jnp.where(commit, 0, sv["passes"] + 1),
-                               sv["passes"])}
+            "passes": ran_only(jnp.where(done, 0, sv["passes"] + 1),
+                               sv["passes"]),
+            "carry": ran_only(done, sv["carry"])}
     return out, pools, new_sv
 
 
@@ -642,7 +675,8 @@ def _seat_block(cfg, tokens, start, n_valid, rows, pages_row, slot, kv, sv,
         "lengths": put("lengths", whole),
         "tokens": put("tokens", jnp.where(here, cfg.mask_token_id, tail)),
         "rngs": put("rngs", rng), "masked": put("masked", here),
-        "fresh": put("fresh", here), "passes": put("passes", z)}
+        "fresh": put("fresh", here), "passes": put("passes", z),
+        "carry": put("carry", False)}
     return jnp.zeros((0,), _I32), _seat_rows(kv, rows, pages_row), new_sv
 
 

@@ -664,7 +664,7 @@ def _gather_token_rows(pool, layer, page_table, hd: int):
 
 def block_decode_path(q_shape, pool_shape, pool_dtype, *, mesh=None) -> str:
     """Which attention a step over BLOCKS of query rows (q (slots,
-    n_heads, rows, hd), every row of a slot seeing the same keys) reads
+    n_heads, rows, hd), every row of a block seeing the same keys) reads
     pools of token rows through, decided as :func:`rows_decode_path`
     decides: ``"pages"`` (``ops.paged_attention.paged_attention_block``:
     the block's rows folded into the query-head group, the walk over
@@ -679,14 +679,15 @@ def block_decode_path(q_shape, pool_shape, pool_dtype, *, mesh=None) -> str:
 
 def paged_block_attention(q, k_pages, v_pages, page_table, lengths, *,
                           layer, scale: Optional[float] = None, mesh=None):
-    """A block of query rows a slot over pools of token rows, by the
-    path :func:`block_decode_path` names. q: (slots, n_heads, rows, hd);
-    lengths: (slots,) the keys every row of the slot sees (the caller
-    has written the block's own). The gathered arm is
+    """Two blocks of query rows a slot over pools of token rows, by the
+    path :func:`block_decode_path` names. q: (slots, n_heads, rows, hd),
+    the first ``rows / 2`` rows a slot one block and the rest the other;
+    lengths: (slots, 2), the keys every row of block g sees, ``[0,
+    lengths[s, g])`` (the caller has written the blocks' own; a block of
+    length 0 comes out zeros). The gathered arm is
     :func:`gathered_rows_decode_attention`'s: every slot's whole row of
     pages copied out under ``kv_gather``, then
-    :func:`slot_decode_attention`, which takes any number of query rows
-    under one length."""
+    :func:`slot_decode_attention` with a length a row."""
     if block_decode_path(q.shape, k_pages.shape, k_pages.dtype,
                          mesh=mesh) == "pages":
         from .paged_attention import paged_attention_block
@@ -696,6 +697,7 @@ def paged_block_attention(q, k_pages, v_pages, page_table, lengths, *,
     hd = q.shape[-1]
     k = _gather_token_rows(k_pages, layer, page_table, hd)
     v = _gather_token_rows(v_pages, layer, page_table, hd)
+    lengths = jnp.repeat(lengths, q.shape[2] // 2, axis=1)
     with jax.named_scope(BLOCK_SCOPE):
         return slot_decode_attention(q, k, v, lengths, scale=scale)
 

@@ -53,12 +53,15 @@ query heads scores every head against its own keys (the zeros cost
 nothing: the MXU latches the same key tiles either way); the value
 product is as wide as a row, and each head keeps its own lanes of it.
 
-A BLOCK of query rows a slot that all see the cache and each other
-(``models/blockdiff_moe.py``: a block of diffusion, its keys and values
-written before the read) is that walk once more with nothing added
-(:func:`paged_attention_block`): the block's rows ride as further query
-heads of their KV head, ``rows x rep`` to a group, over ``length + rows``
-keys, and no mask is needed.
+BLOCKS of query rows a slot that see the cache and each other
+(``models/blockdiff_moe.py``: the block being denoised and the one just
+finished, whose final keys the same pass makes; their keys and values
+written before the read) are that walk once more
+(:func:`paged_attention_block`): a block's rows ride as further query
+heads of their KV head, ``rows x rep`` to a group, each block's over
+its own length (the finished block's a block short of the next's), and
+a slot with no finished block to carry runs the next block's rows
+alone.
 """
 from __future__ import annotations
 
@@ -149,12 +152,16 @@ def _latent_kernel(layer_ref, table_ref, lengths_ref,
 
 def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
           *, scale: float, page_size: int, kvh: int, cpages: int,
-          lane_heads: int = 1):
+          lane_heads: int = 1, groups: int = 1):
     """The kernels' body. ``keys`` and ``values`` are (pool in HBM,
     (2, ppb, rows a page, lanes a row) VMEM buffer, DMA semaphores): two
     pools, or one pool twice, whose pages are then copied once. ``kvh``
     KV heads take turns in a page's rows; ``lane_heads`` lie end to end
-    in a row's lanes (one of the two is 1)."""
+    in a row's lanes (one of the two is 1). ``groups`` = 2 (rows of
+    ``lane_heads`` only): a slot's queries are two groups of rows, one
+    after the other, and ``lengths_ref`` is (slots, 2), the keys each
+    group sees; the walk reaches the second's, which is the larger, and
+    a first group whose length is 0 is not computed at all (zeros)."""
     k_hbm, kbuf, ksem = keys
     v_hbm, vbuf, vsem = values
     pools = (keys,) if values is keys else (keys, values)
@@ -171,8 +178,9 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
                  else lax.Precision.HIGHEST)
 
     def live_pages(slot):
-        return jnp.minimum(_cdiv(lengths_ref[slot], page_size),
-                           _i32(per_slot))
+        reach = (lengths_ref[slot] if groups == 1
+                 else lengths_ref[slot, groups - 1])
+        return jnp.minimum(_cdiv(reach, page_size), _i32(per_slot))
 
     def pages_in(slot, b):
         """How many pages of block ``b`` of ``slot`` hold keys: only
@@ -216,16 +224,14 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
         ) == lax.div(lax.broadcasted_iota(jnp.int32, (hq, width), 0),
                      _i32(hq // lane_heads))
 
-    def slot(s, buf):
-        length = lengths_ref[s]
-        # a slot of length 0 still takes one (empty) block: nothing is
-        # read, nothing computed, and the buffers alternate all the same
-        n_blocks = jnp.maximum(_cdiv(live_pages(s), ppb), _i32(1))
-        q = q_ref[s]                                        # (hq, hd)
-        if lane_heads > 1:      # each head in its own lanes of a row
-            q = jnp.where(own_lanes,
-                          jnp.concatenate([q] * lane_heads, axis=1),
-                          jnp.zeros((), q.dtype))
+    def fold(s, buf, length, n_blocks, q, lanes, valid):
+        """Slot ``s``'s live pages, ``n_blocks`` DMA blocks of them
+        under ``length`` keys, folded into queries q (rows, lanes of a
+        row); ``valid(left, seen)``: which (rows, crow) scores of a chunk
+        are kept, ``left`` the chunk's keys under ``length`` and
+        ``seen()`` the keys before the chunk. Returns (the rows' outputs,
+        the buffer the next slot's first block is in)."""
+        rows = q.shape[0]
 
         def block(b, carry):
             m, l, acc, buf = carry
@@ -254,11 +260,13 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
                 scores = lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())), precision=precision,
                     preferred_element_type=jnp.float32) * _f32(scale)
-                scores = jnp.where(own_head & (col < left), scores,
-                                   _f32(_NEG_INF))
+                scores = jnp.where(
+                    valid(left, lambda: b * _i32(ppb * page_size)
+                          + c * _i32(crow)), scores, _f32(_NEG_INF))
                 # every row has a live key of its own head here (the
-                # loop stops at the last live chunk), so a masked score
-                # leaves exp() as 0 with no second mask
+                # loop stops at the last live chunk, and a first group
+                # that runs sees key 0), so a masked score leaves exp()
+                # as 0 with no second mask
                 m_new = jnp.maximum(
                     m, jnp.max(scores, axis=1, keepdims=True))
                 p = jnp.exp(scores - m_new)
@@ -277,19 +285,78 @@ def _walk(layer_ref, table_ref, lengths_ref, q_ref, o_ref, keys, values,
 
         m, l, acc, buf = lax.fori_loop(
             _i32(0), n_blocks, block,
-            (jnp.full((hq, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((hq, 1), jnp.float32),
-             jnp.zeros((hq, width), jnp.float32), buf))
+            (jnp.full((rows, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, width), jnp.float32), buf))
         l = jnp.where(l == _f32(0), _f32(1), l)   # length 0: zeros
         out = acc / l
         if lane_heads > 1:      # each head's own lanes of the row-wide sum
-            out = jnp.where(own_lanes, out, _f32(0))
+            out = jnp.where(lanes, out, _f32(0))
             out = functools.reduce(jnp.add, (
                 out[:, g * hd:(g + 1) * hd] for g in range(lane_heads)))
+        return out, buf
+
+    def in_lanes(q, lanes):     # each head in its own lanes of a row
+        return jnp.where(lanes, jnp.concatenate([q] * lane_heads, axis=1),
+                         jnp.zeros((), q.dtype))
+
+    def slot(s, buf):
+        length = lengths_ref[s]
+        # a slot of length 0 still takes one (empty) block: nothing is
+        # read, nothing computed, and the buffers alternate all the same
+        n_blocks = jnp.maximum(_cdiv(live_pages(s), ppb), _i32(1))
+        q = q_ref[s]                                        # (hq, hd)
+        lanes = None
+        if lane_heads > 1:
+            q, lanes = in_lanes(q, own_lanes), own_lanes
+        out, buf = fold(s, buf, length, n_blocks, q, lanes,
+                        lambda left, seen: own_head & (col < left))
         o_ref[s] = out.astype(o_ref.dtype)
         return buf
 
-    lax.fori_loop(_i32(0), _i32(n_slots), slot, _i32(0))
+    if groups == 2:
+        # the second group alone: (hq / 2) rows, as a walk of its own
+        # heads would lay them out; both: row r is head r % (hq / 2) of
+        # group r // (hq / 2), whose KV head is the same in either group
+        half = hq // 2
+        both_rows = lax.broadcasted_iota(jnp.int32, (hq, 1), 0)
+
+        def lanes_of(rows):
+            return lax.div(
+                lax.broadcasted_iota(jnp.int32, (rows, width), 1), _i32(hd)
+            ) == lax.rem(lax.div(
+                lax.broadcasted_iota(jnp.int32, (rows, width), 0),
+                _i32(half // lane_heads)), _i32(lane_heads))
+        both_lanes, one_lanes = lanes_of(hq), lanes_of(half)
+        one_col = lax.broadcasted_iota(jnp.int32, (half, crow), 1)
+
+        def two_groups(s, buf):
+            first, length = lengths_ref[s, 0], lengths_ref[s, 1]
+            n_blocks = jnp.maximum(_cdiv(live_pages(s), ppb), _i32(1))
+
+            def both(buf):
+                # a row's own length: the first group's or the second's
+                reach = jnp.where(both_rows < _i32(half), first, length)
+                out, buf = fold(s, buf, length, n_blocks,
+                                in_lanes(q_ref[s], both_lanes), both_lanes,
+                                lambda left, seen: col < reach - seen())
+                o_ref[s] = out.astype(o_ref.dtype)
+                return buf
+
+            def second(buf):
+                out, buf = fold(
+                    s, buf, length, n_blocks,
+                    in_lanes(q_ref[s, pl.ds(half, half)], one_lanes),
+                    one_lanes, lambda left, seen: one_col < left)
+                o_ref[s, pl.ds(0, half)] = jnp.zeros((half, hd),
+                                                     o_ref.dtype)
+                o_ref[s, pl.ds(half, half)] = out.astype(o_ref.dtype)
+                return buf
+
+            return lax.cond(first > _i32(0), both, second, buf)
+
+    lax.fori_loop(_i32(0), _i32(n_slots),
+                  slot if groups == 1 else two_groups, _i32(0))
 
 
 def _blocks(page_bytes: int, per_slot: int, block_pages, chunk_pages):
@@ -461,9 +528,9 @@ def paged_attention_rows(q, k_pages, v_pages, page_table, lengths, *, layer,
 
 
 def takes_block(q_shape, pool_shape, pool_dtype) -> bool:
-    """Whether :func:`paged_attention_block` takes a block of query rows
+    """Whether :func:`paged_attention_block` takes blocks of query rows
     a slot, q (slots, n_heads, rows, hd), over pools of token rows as
-    they are stored: :func:`takes_rows` of the block folded into the
+    they are stored: :func:`takes_rows` of the rows folded into the
     query heads (the queries, ``rows`` times as many, still fit VMEM)."""
     return len(q_shape) == 4 and takes_rows(
         (q_shape[0], q_shape[1] * q_shape[2], 1, q_shape[3]), pool_shape,
@@ -475,19 +542,34 @@ def paged_attention_block(q, k_pages, v_pages, page_table, lengths, *, layer,
                           block_pages: Optional[int] = None,
                           chunk_pages: Optional[int] = None,
                           interpret: bool = False):
-    """Attention of a BLOCK of query rows a slot over the live pages of
-    two pools of token rows. q: (slots, n_heads, rows, hd); the pools,
-    the page table and ``layer`` as :func:`paged_attention_rows` takes
-    them; lengths: (slots,) int, the keys EVERY row of the slot's block
-    sees (the cache and the block itself, whose keys and values the
-    caller has written): no row is masked from another. Query head h's
-    ``rows`` rows are ``rows`` further heads of h's KV head (a reshape:
-    head h, row b is head ``h * rows + b`` of a group ``rows`` times as
-    large), so this is :func:`paged_attention_rows`'s walk as it is.
-    Returns (slots, n_heads, rows, hd) in q's dtype."""
+    """Attention of TWO BLOCKS of query rows a slot over the live pages
+    of two pools of token rows. q: (slots, n_heads, rows, hd), the first
+    ``rows / 2`` rows a slot one block and the rest the other; the
+    pools, the page table and ``layer`` as :func:`paged_attention_rows`
+    takes them; lengths: (slots, 2) int, the keys every row of block g
+    sees, ``[0, lengths[s, g])`` (the cache and the blocks, whose keys
+    and values the caller has written): inside a block no row is masked
+    from another, and the second block sees the more. A block's rows are
+    further heads of their KV head (head h, row b of block g is head
+    ``h * rows / 2 + b`` of group g), so this is
+    :func:`paged_attention_rows`'s walk, ONE walk of the second block's
+    pages for both; where a slot's first length is 0 its first block is
+    not computed (zeros), and the walk costs what the second's alone
+    costs. Returns (slots, n_heads, rows, hd) in q's dtype."""
     slots, hq, rows, hd = q.shape
-    out = paged_attention_rows(
-        q.reshape(slots, hq * rows, 1, hd), k_pages, v_pages, page_table,
-        lengths, layer=layer, scale=scale, block_pages=block_pages,
-        chunk_pages=chunk_pages, interpret=interpret)
-    return out.reshape(q.shape)
+    if lengths.shape != (slots, 2) or rows % 2:
+        raise ValueError("two blocks of rows a slot, one length each")
+    n_layers, n_pages, page_size, width = k_pages.shape
+    # group-major: group g's heads in order, then the other group's
+    grouped = q.reshape(slots, hq, 2, rows // 2, hd).transpose(0, 2, 1, 3, 4)
+    ppb, cpages = _blocks(page_size * width * k_pages.dtype.itemsize,
+                          page_table.shape[1], block_pages, chunk_pages)
+    scale = scale if scale is not None else 1.0 / math.sqrt(hd)
+    out = _call(functools.partial(_kernel, scale=float(scale),
+                                  page_size=page_size, kvh=1, cpages=cpages,
+                                  lane_heads=width // hd, groups=2),
+                ROWS_KERNEL_NAME, grouped.reshape(slots, -1, 1, hd),
+                (k_pages, v_pages), (page_size, width), ppb, layer,
+                page_table, lengths, interpret)
+    return out.reshape(slots, 2, hq, rows // 2, hd).transpose(
+        0, 2, 1, 3, 4).reshape(q.shape)

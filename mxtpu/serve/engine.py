@@ -29,7 +29,7 @@ SOSP '23):
 - **a step yields 0..W tokens a slot**: one token (the plain step), the
   accepted run of a speculative verify (1..k+1), or nothing or a block
   (a block-diffusion family's ``block_step_slots_paged``: a denoise pass
-  emits nothing, a commit pass the block's new tokens). The device
+  emits nothing, or the block's new tokens if it took its last mask). The device
   tells, beside the tokens, which of them each slot emitted, and a
   slot's length grew by as many; ``_process`` is the one path that
   mirrors the lengths and emits, at the readback the next step
@@ -1578,7 +1578,7 @@ class ServeEngine:
         if last is None:
             # a request's first emission, wherever it comes from (a
             # prefill's first token, a block-diffusion slot's first
-            # commit pass)
+            # finished block)
             self._observe_ttft(rid, now)
         else:
             gap_ms = 1e3 * (now - last)

@@ -156,13 +156,17 @@ def test_prefill_attention_is_block_causal_at_an_offset():
                                atol=1e-5)
 
 
+@pytest.mark.parametrize("carried", [False, True], ids=["alone", "carried"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_block_pages_kernel_matches_the_gathered_path(dtype):
-    """A block of query rows a slot rides through the rows walk
-    (interpreted here) as further query heads of their KV head: the
-    gathered path's numbers up to the order of summation; zeros for a
-    slot of length 0; nothing of a page past the length (NaN there)."""
+def test_block_pages_kernel_matches_the_gathered_path(dtype, carried):
+    """Two blocks of query rows a slot (a finished block's and the
+    next's) ride through the rows walk (interpreted here) as further
+    query heads of their KV head, each over its own length, a block
+    apart: the gathered path's numbers up to the order of summation;
+    zeros for a slot of length 0 and for a first block of length 0
+    beside a second that runs (every slot's, ``alone``); nothing of a
+    page past the length (NaN there)."""
     from mxtpu.ops.paged_attention import (paged_attention_block,
                                            takes_block)
     S, H, G, hd, per_slot, ps, L = 4, 8, 2, 128, 6, 16, 2
@@ -174,12 +178,14 @@ def test_block_pages_kernel_matches_the_gathered_path(dtype):
         pool = rng.standard_normal(shape).astype(np.float32)
         pool[:, 0], pool[:, nan_page] = 0.0, np.nan
         pools.append(jnp.asarray(pool, dtype))
-    q = jnp.asarray(rng.standard_normal((S, H, B, hd)), dtype)
-    lengths = np.asarray([0, 4, 36, 96], np.int32)
+    q = jnp.asarray(rng.standard_normal((S, H, 2 * B, hd)), dtype)
+    reach = np.asarray([0, 4, 36, 96], np.int32)
+    lengths = np.stack([np.asarray([0, 0, 32, 92] if carried else [0] * S,
+                                   np.int32), reach], 1)
     table = (1 + rng.permutation(S * per_slot)).astype(
         np.int32).reshape(S, per_slot)
     clean = table.copy()
-    for s_, n in enumerate(lengths):
+    for s_, n in enumerate(reach):
         table[s_, -(-int(n) // ps):] = nan_page
         clean[s_, -(-int(n) // ps):] = 0
     kernel = jax.jit(partial(paged_attention_block, layer=jnp.int32(1),
@@ -190,10 +196,12 @@ def test_block_pages_kernel_matches_the_gathered_path(dtype):
     want = np.asarray(paged_block_attention(
         q, *pools, jnp.asarray(clean), jnp.asarray(lengths), layer=1,
         scale=0.1), np.float32)
-    assert got.shape == (S, H, B, hd)
+    assert got.shape == (S, H, 2 * B, hd)
     assert np.isfinite(got).all() and not got[0].any()
     tol = 1e-5 if dtype == jnp.float32 else 4 * 2.0 ** -8 * np.abs(want).max()
     np.testing.assert_allclose(got, want, atol=tol)
+    assert not got[1, :, :B].any() and got[1, :, B:].any()
+    assert got[2:, :, :B].any() == carried
     # the published shapes ride the walk as stored; a (.., 4, 128) pool
     # of half tiles would not
     assert takes_block((32, 32, 4, 128), (6, 6401, 16, 512), jnp.bfloat16)
@@ -286,10 +294,11 @@ def test_a_sampled_candidate_is_worth_its_probability_in_the_nucleus():
 def test_every_pass_through_the_pages_matches_one_full_forward(params,
                                                                chunked):
     """Prefill (the prompt's whole blocks), the remainder seated as the
-    first block, denoise passes over tentative keys and the commit
-    through ``ServeEngine``'s pages: before every step the logits the
-    step program is about to read (``decode_logits`` over the engine's
-    own state) are the reference's full forward over the prefix and the
+    first block, denoise passes over tentative keys and the finished
+    block's keys made final in the next block's first pass, through
+    ``ServeEngine``'s pages: before every step the logits the step
+    program is about to read (``decode_logits`` over the engine's own
+    state) are the reference's full forward over the prefix and the
     block as the slot holds it."""
     prompt = _prompts(11, [21])[0]           # 5 blocks and a remainder of 1
     eng = ServeEngine(CFG, params, overlap=False,
@@ -316,7 +325,9 @@ def test_every_pass_through_the_pages_matches_one_full_forward(params,
         np.testing.assert_allclose(got, want, atol=LOGIT_TOL)
         eng._loop_iter(None)
         passes += 1
-    assert passes >= 2 * (CFG.denoising_steps + 1)
+    # three blocks, the first holding the prompt's remainder: a block
+    # costs its masks' passes (one each), no commit pass
+    assert 1 + passes == CFG.denoising_steps * 3 - 1
     assert eng._results[rid] == ref.generate(MODEL, params, prompt, 9)
 
 
@@ -354,14 +365,210 @@ def test_slots_out_of_phase_and_reseated_stream_what_they_stream_alone(
     assert st["pages_used"] == 0 and st["decode_attention"] == "gathered"
 
 
+def _copy(tree):
+    # np.array copies: a donated buffer is reused in place after the call
+    return {n: np.array(a, copy=True) for n, a in tree.items()}
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "sync"])
+@pytest.mark.parametrize("remainder", [0, 1, 2, 3])
+def test_the_fold_through_the_pages(params, remainder, overlap):
+    """The finished block's keys are made final by the pass that opens
+    the next block: three requests over three slots, seated out of phase
+    (other remainders, another length, one admitted late), every pass of
+    the engine's own step program watched. Before it: (a) each running
+    slot's block-row logits (``decode_logits`` over the engine's state)
+    are the reference's full forward over the slot's prefix and its
+    block, in the first pass of a block whose predecessor is carried
+    too. After it: (c) the pools hold what they held but where the pass
+    wrote, which is a running slot's block, its carried block if
+    ``carry`` was set, and scratch page 0: an idle slot's and a slot's
+    whose ``carry`` is clear committed rows bit for bit; an idle slot's
+    block state as it was. (b) Each stream is ``ref.generate``'s, the
+    reference's block loop with its commit; a request of n blocks, the
+    first holding the remainder, takes ``n B - remainder`` passes of its
+    slot, and one more with ``overlap`` (the pass dispatched before the
+    last one is read back)."""
+    prompts = _prompts(70 + remainder, (8 + remainder, 13 + remainder,
+                                         6 + remainder))
+    news = [9, 7, 6]
+    eng = ServeEngine(CFG, params, overlap=overlap,
+                      **{**ENGINE, "max_slots": 4})
+    rids = [eng.submit(Request(prompt=p, max_new_tokens=n,
+                               arrival_step=3 * (i == 2)))
+            for i, (p, n) in enumerate(zip(prompts, news))]
+    prompt_of = dict(zip(rids, prompts))
+    peek = jax.jit(partial(bd.decode_logits, CFG))
+    inner = eng._decode
+    held, ran = {}, dict.fromkeys(rids, 0)
+    tally = {"carried": 0, "checked": 0}
+
+    def watched(p, kv, sv, active, pt, *sampling):
+        got = np.asarray(peek(p, kv, sv, active, pt)[0])
+        state, pools, pt = _copy(sv), _copy(kv), np.array(pt, copy=True)
+        ps = pools["k"].shape[2]
+        for slot in np.flatnonzero(active):
+            rid = eng._slot_rid[slot]
+            ran[rid] += 1
+            prefix = held.setdefault(rid, list(prompt_of[rid]))
+            length = int(state["lengths"][slot])
+            fed = np.where(state["masked"][slot], CFG.mask_token_id,
+                           state["tokens"][slot])
+            want = ref.logits(MODEL, params, prefix[:length] + fed.tolist(),
+                              rows=jnp.arange(length, length + B))
+            tally["carried"] += int(state["carry"][slot])
+            if length + B > bd.positions_written(
+                    CFG, len(prompt_of[rid]), news[rids.index(rid)]):
+                continue   # the pass past the end: its block has no page
+            np.testing.assert_allclose(got[slot], want, atol=LOGIT_TOL)
+            tally["checked"] += 1
+        out, kv, sv = inner(p, kv, sv, active, pt, *sampling)
+        after, new = _copy(kv), _copy(sv)
+        wrote = np.zeros(pools["k"].shape[1:3], bool)
+        wrote[0] = True                              # the scratch page
+        for slot in np.flatnonzero(active):
+            length = int(state["lengths"][slot])
+            for at in range(length, length + B):
+                wrote[pt[slot, at // ps], at % ps] = True
+            if state["carry"][slot]:
+                for at in range(length - B, length):
+                    wrote[pt[slot, at // ps], at % ps] = True
+            if new["lengths"][slot] > length:       # the block finished
+                rid = eng._slot_rid[slot]
+                held[rid][length:length + B] = new["tokens"][slot].tolist()
+        for n in pools:
+            np.testing.assert_array_equal(after[n][:, ~wrote],
+                                          pools[n][:, ~wrote])
+        for slot in np.flatnonzero(~active):
+            for n in state:
+                np.testing.assert_array_equal(new[n][slot], state[n][slot])
+        return out, kv, sv
+    eng._decode = watched
+    out = eng.run()
+    for rid, prompt, n in zip(rids, prompts, news):
+        assert out[rid].tolist() == ref.generate(MODEL, params, prompt, n)
+        r = len(prompt) % B
+        blocks = -(-(n + r) // B)
+        assert ran[rid] == blocks * B - r + overlap
+    # the first pass of every block after a request's first is a carry
+    # pass (and, with overlap, the pass past a request's end)
+    assert tally["carried"] == sum(
+        -(-(n + len(p) % B) // B) - 1 + overlap
+        for p, n in zip(prompts, news))
+    assert tally["checked"] == sum(ran.values()) - overlap * len(rids)
+
+
+def test_a_carry_pass_keeps_what_it_does_not_write(params):
+    """The step program alone, over a bank of three slots: slot 0 is
+    seated and never runs (its ``carry`` forced on: a slot that does not
+    run carries nothing), slot 1 runs with ``carry`` clear, slot 2 runs
+    its carry pass. The pools' rows of every slot's committed prefix are
+    kept bit for bit but the carried block's, which become what a commit
+    pass over the same tokens would write: the reference's keys of the
+    finished block, seen through the next pass's logits."""
+    S, ps, per_slot = 3, 8, 6
+    state = bd.init_paged_cache(CFG, S, 1 + S * per_slot, ps)
+    sv = {n: state.pop(n) for n in bd.SLOT_VARS}
+    kv = state
+    table = (1 + np.arange(S * per_slot, dtype=np.int32)).reshape(
+        S, per_slot)
+    prefill = jax.jit(partial(bd.prefill_slot_paged, CFG))
+    step = jax.jit(partial(bd.block_step_slots_paged, CFG))
+    greedy = (np.zeros(S, np.float32), np.full(S, CFG.vocab_size, np.int32),
+              np.ones(S, np.float32))
+    prompts = _prompts(81, (13, 10, 15))
+    for slot, prompt in enumerate(prompts):
+        toks = np.zeros((1, 16), np.int32)
+        toks[0, :len(prompt)] = prompt
+        _, kv, sv = prefill(params, toks, len(prompt), 0, table[slot],
+                            slot, kv, sv, jax.random.PRNGKey(slot),
+                            *(a[0] for a in greedy))
+    active = np.array([False, True, True])
+    # slot 2 finishes its first block (remainder 3: one pass), then
+    # carries it in its next pass
+    _, kv, sv = step(params, kv, sv, active, table, *greedy)
+    assert np.asarray(sv["lengths"]).tolist() == [12, 8, 16]
+    sv = dict(sv, carry=sv["carry"].at[0].set(True))
+    state, pools = _copy(sv), _copy(kv)
+    assert state["carry"].tolist() == [True, False, True]
+    fed = np.where(state["masked"][2], CFG.mask_token_id, state["tokens"][2])
+    length = int(state["lengths"][2])
+    done = list(prompts[2][:12]) + state["tokens"][2].tolist()
+    got = bd.decode_logits(CFG, params, kv, sv, active, table)[0][2]
+    np.testing.assert_allclose(
+        got, ref.logits(MODEL, params, done + fed.tolist(),
+                        rows=jnp.arange(length, length + B)), atol=LOGIT_TOL)
+    _, kv, new = step(params, kv, sv, active, table, *greedy)
+    after = _copy(kv)
+    for slot in range(S):
+        keep = int(state["lengths"][slot]) - (B if slot == 2 else 0)
+        rows = [(table[slot, at // ps], at % ps) for at in range(keep)]
+        for n in pools:
+            for page, off in rows:
+                np.testing.assert_array_equal(after[n][:, page, off],
+                                              pools[n][:, page, off])
+    for n in state:
+        np.testing.assert_array_equal(np.asarray(new[n])[0], state[n][0])
+    # slot 1 took its first block's last mask: its next pass carries
+    assert np.asarray(new["carry"]).tolist() == [True, True, False]
+
+
+def test_overlap_dispatches_the_next_pass_before_reading_this_one(params):
+    """``_loop_iter`` with ``overlap``: pass t + 1 is dispatched before
+    ``_process`` reads pass t back, every iteration, so nothing in
+    ``_dispatch`` waits for a readback; a block emitted at its last
+    denoise pass reaches ``_emit`` in the next iteration, inside the
+    readback of the pass that emitted it."""
+    eng = ServeEngine(CFG, params, overlap=True, **ENGINE)
+    order, now = [], {"it": 0}
+    decode, process, emit = eng._decode, eng._process, eng._emit
+
+    def dispatched(*args):
+        order.append(("dispatch", now["it"]))
+        return decode(*args)
+
+    def processed(disp):
+        order.append(("process", disp.it))
+        return process(disp)
+
+    def emitted(rid, token, t):
+        order.append(("emit", now["it"]))
+        return emit(rid, token, t)
+    eng._decode, eng._process, eng._emit = dispatched, processed, emitted
+    for p, n in zip(_prompts(91, (9, 14)), (7, 10)):
+        eng.submit(Request(prompt=p, max_new_tokens=n))
+    prev = None
+    while eng._queue or eng._active.any() or prev is not None:
+        prev = eng._loop_iter(prev)
+        if prev is not None:
+            prev.it = now["it"]
+        now["it"] += 1
+    its = [e for e in order if e[0] != "emit"]
+    for i, (kind, it) in enumerate(its):
+        if kind == "process":
+            # read back in the iteration after its dispatch, behind the
+            # next dispatch if there is one
+            assert any(k == "dispatch" and j == it for k, j in its[:i])
+            later = [j for k, j in its[:i] if k == "dispatch" and j > it]
+            assert later in ([it + 1], []), (i, its)
+    processing = None
+    for kind, it in order:
+        if kind == "process":
+            processing = it
+        elif kind == "emit":
+            assert processing == it - 1
+    assert sum(k == "emit" for k, _ in order) == 17
+
+
 @pytest.mark.parametrize("steps", [2, 1])
 def test_fewer_denoising_steps_through_the_engine(params, steps):
     cfg = replace(CFG, denoising_steps=steps)
     prompt = _prompts(21, [10])[0]
     eng, (got,) = _serve(params, [prompt], [11], cfg=cfg)
     assert got == ref.generate(model_of(cfg), params, prompt, 11)
-    # a full block costs steps + 1 passes of its slot
-    assert eng.steps_run <= 4 * (steps + 1) + 1
+    # a full block costs steps passes of its slot (the remainder's first
+    # block fewer), and the pass the overlap runs past the request's end
+    assert eng.steps_run <= 4 * steps + 1
 
 
 def test_sampled_streams_do_not_depend_on_the_company(params):
@@ -435,22 +642,26 @@ def test_plan_pages_rounds_a_request_up_to_whole_blocks(params):
 def test_ttft_is_observed_at_the_first_block_and_counters_count(params):
     telemetry.reset()
     prompts = _prompts(51, (9, 12))
-    eng, got = _serve(params, prompts, [8, 8])
+    # overlap off: no pass runs past a request's end, so the counts are
+    # exact
+    eng, got = _serve(params, prompts, [8, 8], overlap=False)
     value = _metric
     # a prefill yields no token: the first emission is the first
-    # block's commit, and every request is observed once there
+    # block's last pass, and every request is observed once there
     assert value("mxtpu_serve_ttft_first_wait_ms_count") == 2
     assert value("mxtpu_serve_ttft_queue_ms_count") == 2
     assert value("mxtpu_serve_tokens_total") == 16
-    # 9 = two blocks + 1: the first block has 3 to fill (3 + 1 passes),
-    # then 4 + 1 and, cut at 8 tokens, 4 + 1 of which 1 token counts;
-    # 12: three full blocks' worth, 2 emitted... counted on the device
+    # 9 = two blocks + 1: the first block has 3 to fill (3 passes), then
+    # 4 and, cut at 8 tokens, 4 of which 1 token counts; 12: three whole
+    # blocks, two of them emitted. Every pass a denoise pass; every
+    # finished block's keys made final in its successor's first pass,
+    # but for each request's last block
     passes = value("mxtpu_serve_block_passes_total")
     blocks = value("mxtpu_serve_blocks_committed_total")
-    assert blocks >= 5 and passes >= 4 * blocks
-    assert passes <= (CFG.denoising_steps + 1) * blocks + 2 * (
-        CFG.denoising_steps + 1)
-    assert value("mxtpu_serve_block_unmasked_total") >= 16
+    assert blocks == 3 + 2
+    assert passes == (3 + 4 + 4) + (4 + 4)
+    assert value("mxtpu_serve_block_carried_total") == blocks - 2
+    assert value("mxtpu_serve_block_unmasked_total") == passes
     assert value("mxtpu_serve_block_threshold_transfers_total") == 0
     assert value("mxtpu_serve_moe_assignments_total") > 0
     assert value("mxtpu_serve_block_length") == B

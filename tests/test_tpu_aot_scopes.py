@@ -769,12 +769,37 @@ def sambay_prefill(one_chip):
     return _compile_all({"prefill": lowered})["prefill"]
 
 
-# of the parent's programs (commit a222295), compiled by this file's
-# fixtures in the parent's tree
+def test_tpu_block_walk_compiles_with_a_carried_group(one_chip):
+    """The sdar cell's attention at its widths (32 slots of 32 query
+    heads over 4 KV heads of 128, pools (6, 6401, 16, 512)): the block of
+    4 rows a slot and, where a slot carries, the finished block's 4 more,
+    in ONE walk of the rows kernel, compiled for a v5e. Mosaic refused
+    the first form of the two blocks' body (a static slice of a mask
+    vector), which interpret mode ran."""
+    from mxtpu.ops.paged_attention import (ROWS_KERNEL_NAME,
+                                           paged_attention_block)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = arg((6, 6401, 16, 512), jnp.bfloat16)
+    exe = jax.jit(partial(paged_attention_block, scale=0.088)).lower(
+        arg((32, 32, 8, 128), jnp.bfloat16), pool, pool,
+        arg((32, 200), jnp.int32), arg((32, 2), jnp.int32),
+        layer=arg((), jnp.int32)).compile()
+    calls = re.findall(r'^\s*%?([\w.\-]+) = [^\n]*'
+                       r'custom_call_target="tpu_custom_call"',
+                       exe.as_text(), re.M)
+    assert len(calls) == 1 and calls[0].startswith(ROWS_KERNEL_NAME)
+
+
+# of the parent's programs (commit a222295; sambay's decode: 72c1960,
+# before the rows walk learned a block's two groups of rows), compiled by
+# this file's fixtures in the parent's tree
 PARENT_PROGRAMS = {
     "sambay_prefill": "efc129a16255ffb8",
     "prefill_slot_paged": "d9672642aeb4c8ad",
     "decode_slots_paged": "ffc001e06cfef18c",
+    "sambay_decode": "18cce40c6e5497e8",
 }
 
 
@@ -787,9 +812,12 @@ def test_tpu_serve_programs_compile_to_the_parents(request, compiled,
     it is differentiated or checkpointed in a serve program, so what the
     chip is handed is the parent's program, kernel for kernel. The CPU's
     lowered text (``test_remat_plan.py``) cannot say so: there
-    ``flash_attention`` takes the blockwise arm."""
-    exe = request.getfixturevalue("sambay_prefill") \
-        if program == "sambay_prefill" else compiled[program]
+    ``flash_attention`` takes the blockwise arm. Sambay's decode step
+    holds the rows walk, which learned two blocks of rows a slot
+    (``paged_attention_block``): at one length a slot its body is the
+    parent's, op for op."""
+    exe = request.getfixturevalue(program) \
+        if program.startswith("sambay_") else compiled[program]
     if program == "sambay_prefill":
         forward = re.findall(
             r"^\s*%?(flash_attention[\w.\-]*) = [^\n]*"
