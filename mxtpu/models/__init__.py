@@ -12,6 +12,7 @@ from . import bert
 from . import blockdiff_moe
 from . import latent_moe
 from . import llama
+from . import nemotron_h
 from . import resnet
 from . import retention
 from . import sambay
@@ -19,21 +20,22 @@ from .bert import BertConfig
 from .blockdiff_moe import BlockDiffMoEConfig
 from .latent_moe import LatentMoEConfig
 from .llama import LlamaConfig
+from .nemotron_h import NemotronHConfig
 from .resnet import ResNetConfig
 from .retention import RetentionConfig
 from .sambay import SambaYConfig
 
 __all__ = ["llama", "resnet", "sambay", "latent_moe", "retention",
-           "blockdiff_moe",
+           "blockdiff_moe", "nemotron_h",
            "LlamaConfig", "ResNetConfig", "SambaYConfig", "LatentMoEConfig",
-           "RetentionConfig", "BlockDiffMoEConfig",
+           "RetentionConfig", "BlockDiffMoEConfig", "NemotronHConfig",
            "SERVING_FAMILIES", "serving_family"]
 
 # the families ``serve.ServeEngine`` can be given, by the ``family`` of
 # their config class; each module has llama.py's serving surface
 SERVING_FAMILIES = {"llama": llama, "sambay": sambay,
                     "latent_moe": latent_moe, "retention": retention,
-                    "blockdiff_moe": blockdiff_moe}
+                    "blockdiff_moe": blockdiff_moe, "nemotron_h": nemotron_h}
 
 
 def serving_family(cfg):

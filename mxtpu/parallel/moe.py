@@ -35,7 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["init_moe_params", "moe_ffn", "moe_ffn_dense",
            "load_balance_loss", "route_sigmoid", "route_softmax",
-           "moe_ffn_dropless"]
+           "moe_ffn_dropless", "relu2"]
 
 # the named scopes of a routed expert layer (models/latent_moe.py opens
 # each at the top level of its layer): the router; the dispatch (sort,
@@ -44,6 +44,19 @@ ROUTER_SCOPE = "moe_router"
 DISPATCH_SCOPE = "moe_dispatch"
 EXPERTS_SCOPE = "moe_experts"
 SHARED_SCOPE = "moe_shared"
+
+
+def relu2(h):
+    """The activation of a NON-gated expert (two matrices, up and down:
+    Nemotron-H's ``relu2``): ``relu(h)^2``. An expert bank without
+    ``w_gate`` is such a bank, everywhere in this file: ``w_up_t`` (E, f,
+    d), the up projection stored TRANSPOSED, and ``w_down`` (E, f, d),
+    ``down(relu2(up x)) = relu2(x w_up_t^T) w_down``. Both keep the model
+    width minor: a v5e lays an (E, d, f) array whose ``f`` is not whole
+    lane tiles (Nemotron-H's 1856) out with ``d`` minor, and the grouped
+    product, which takes its operand row-major, then copies the whole
+    bank first (4.9 GB at that model's widths)."""
+    return jnp.square(jax.nn.relu(h))
 
 
 def init_moe_params(key, dim: int, hidden: int, n_experts: int,
@@ -95,12 +108,17 @@ def _route(params, x, K: int, C: int):
 
 
 def _experts(params, xin, mesh):
-    """SwiGLU expert bank over (E, C, d) dispatched activations."""
+    """SwiGLU expert bank over (E, C, d) dispatched activations (relu²
+    up/down where the bank has no ``w_gate``)."""
     dt = xin.dtype
     xin = _con(mesh, xin, "ep", None, None)
-    h = jax.nn.silu(jnp.einsum("ecd,edh->ech", xin,
-                               params["w_gate"].astype(dt))) * \
-        jnp.einsum("ecd,edh->ech", xin, params["w_up"].astype(dt))
+    if "w_gate" not in params:
+        h = relu2(jnp.einsum("ecd,ehd->ech", xin,
+                             params["w_up_t"].astype(dt)))
+    else:
+        h = jax.nn.silu(jnp.einsum("ecd,edh->ech", xin,
+                                   params["w_gate"].astype(dt))) * \
+            jnp.einsum("ecd,edh->ech", xin, params["w_up"].astype(dt))
     h = _con(mesh, h, "ep", None, None)
     eout = jnp.einsum("ech,ehd->ecd", h, params["w_down"].astype(dt))
     return _con(mesh, eout, "ep", None, None)
@@ -207,9 +225,12 @@ def moe_ffn_dense(params, x, *, top_k: int = 2,
     w = jnp.zeros((T, E), jnp.float32).at[
         jnp.arange(T)[:, None], idx].set(gate_vals)
 
-    h = jax.nn.silu(jnp.einsum("td,edh->teh", x,
-                               params["w_gate"].astype(dt))) * \
-        jnp.einsum("td,edh->teh", x, params["w_up"].astype(dt))
+    if "w_gate" not in params:             # relu² up/down experts
+        h = relu2(jnp.einsum("td,ehd->teh", x, params["w_up_t"].astype(dt)))
+    else:
+        h = jax.nn.silu(jnp.einsum("td,edh->teh", x,
+                                   params["w_gate"].astype(dt))) * \
+            jnp.einsum("td,edh->teh", x, params["w_up"].astype(dt))
     h = _con(mesh, h, ("dp", "fsdp"), "ep", None)
     eout = jnp.einsum("teh,ehd->ted", h, params["w_down"].astype(dt))
     out = jnp.einsum("ted,te->td", eout, w.astype(dt))
@@ -286,16 +307,23 @@ def route_softmax(x, w_router, *, top_k: int, renorm: bool = True):
 # float32 group's whole matrix, twice double-buffered, is past the
 # chip's VMEM: ``chip_smoke.py``'s float32 pass died of it)
 _GMM_TILE = (192, 2048, 2048)
+# and the most bytes one tile of a group's matrix may hold: a wider
+# matrix (Nemotron-H's 2688 x 1856: 10 MB in bfloat16) is cut, along its
+# longer side, into the widest whole lane tiles that divide it and fit.
+# Every tile above (3 MB at kanana's and SDAR's widths, either type)
+# lies under it
+_GMM_TILE_BYTES = 4 << 20
 
 
 def grouped_matmul_kernel(lhs, rhs, group_sizes, tm: int,
-                          interpret: bool = False):
+                          interpret: bool = False, transposed: bool = False):
     """``megablox.gmm``, the Pallas grouped matmul that ships with jax:
     it visits the (row tile, group) pairs that hold rows and reads no
     other group's matrix. lhs: (m, k), m a multiple of ``tm``, rows
-    sorted by group; rhs: (G, k, n); group_sizes: (G,) int32. Rows past
-    the groups' total come out undefined. ``interpret`` runs it in
-    Pallas' interpret mode (the tests, on a CPU)."""
+    sorted by group; rhs: (G, k, n), or (G, n, k) if ``transposed``;
+    group_sizes: (G,) int32. Rows past the groups' total come out
+    undefined. ``interpret`` runs it in Pallas' interpret mode (the
+    tests, on a CPU)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     # a bfloat16 product names its precision: Mosaic refuses one at
     # float32 contract precision, which a process-wide
@@ -306,20 +334,37 @@ def grouped_matmul_kernel(lhs, rhs, group_sizes, tm: int,
     # library's own arithmetic hands the kernel a 64-bit scalar, which
     # the TPU compiler does not take
     tk, tn = (2 * t // rhs.dtype.itemsize for t in _GMM_TILE[1:])
+    k, n = rhs.shape[2:0:-1] if transposed else rhs.shape[1:]
+    tk, tn = _fit_tile(min(tk, k), min(tn, n), (k, n), rhs.dtype.itemsize)
     with ambient, jax.enable_x64(False):
         return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-                   tiling=(tm, min(tk, rhs.shape[1]), min(tn, rhs.shape[2])),
-                   interpret=interpret)
+                   tiling=(tm, tk, tn), interpret=interpret,
+                   transpose_rhs=transposed)
 
 
-def _grouped_dot(lhs, rhs, group_sizes, tm: int):
-    """``lhs[rows of group g] @ rhs[g]`` for every group with rows. On a
-    TPU :func:`grouped_matmul_kernel`; elsewhere ``lax.ragged_dot``,
-    XLA's plain lowering of the same product, which the kernel is
-    tested against (decided from the backend alone, as
-    ``ops.attention._flash_path`` is)."""
+def _fit_tile(tk: int, tn: int, dims, itemsize: int):
+    """(tk, tn) as they are where the tile holds at most
+    ``_GMM_TILE_BYTES``; else the longer side cut, to the next narrower
+    whole lane tiles that divide its whole dimension, until it does."""
+    while tk * tn * itemsize > _GMM_TILE_BYTES:
+        side = 0 if tk >= tn else 1
+        t, dim = (tk, tn)[side], dims[side]
+        cut = next(c for c in range(t - 128, 0, -128) if dim % c == 0)
+        tk, tn = (cut, tn) if side == 0 else (tk, cut)
+    return tk, tn
+
+
+def _grouped_dot(lhs, rhs, group_sizes, tm: int, transposed: bool = False):
+    """``lhs[rows of group g] @ rhs[g]`` (``rhs[g]^T`` if ``transposed``)
+    for every group with rows. On a TPU :func:`grouped_matmul_kernel`;
+    elsewhere ``lax.ragged_dot``, XLA's plain lowering of the same
+    product, which the kernel is tested against (decided from the
+    backend alone, as ``ops.attention._flash_path`` is)."""
     if jax.default_backend() == "tpu":
-        return grouped_matmul_kernel(lhs, rhs, group_sizes, tm)
+        return grouped_matmul_kernel(lhs, rhs, group_sizes, tm,
+                                     transposed=transposed)
+    if transposed:
+        rhs = jnp.swapaxes(rhs, 1, 2)
     return lax.ragged_dot(lhs, rhs, group_sizes)
 
 
@@ -331,8 +376,10 @@ def moe_ffn_dropless(bank, x, idx, weights, *, layer=None, valid=None):
     read), and the rows go back to their tokens, weighted, and are
     summed.
 
-    bank: ``w_gate``/``w_up`` (E, d, f) and ``w_down`` (E, f, d), or the
-    whole stack's (L, E, ..) with ``layer`` (a traced scalar) saying
+    bank: ``w_gate``/``w_up`` (E, d, f) and ``w_down`` (E, f, d) — or,
+    without ``w_gate``, non-gated experts, ``w_up_t`` and ``w_down``
+    (:func:`relu2` says how they are stored) —, or the whole stack's (L,
+    E, ..) with ``layer`` (a traced scalar) saying
     which layer's experts to use: the stack is then handed to the
     product as it is stored, as L x E groups of which this layer's have
     rows (a layer's slab cut out of it would be a copy of it). x:
@@ -342,8 +389,10 @@ def moe_ffn_dropless(bank, x, idx, weights, *, layer=None, valid=None):
     int32)."""
     T, d = x.shape
     K = idx.shape[1]
-    wg, wu, wd = bank["w_gate"], bank["w_up"], bank["w_down"]
-    E = wg.shape[-3]
+    gated = "w_gate" in bank
+    wg, wu, wd = bank.get("w_gate"), bank["w_up" if gated else "w_up_t"], \
+        bank["w_down"]
+    E = wu.shape[-3]
     # the sorted rows, padded to whole tiles of the grouped product
     tm = min(_GMM_TILE[0], -(-T * K // 8) * 8)
     rows = -(-T * K // tm) * tm
@@ -358,8 +407,8 @@ def moe_ffn_dropless(bank, x, idx, weights, *, layer=None, valid=None):
                  ).sum(0, dtype=jnp.int32)
         groups = sizes
         if layer is not None:
-            L = wg.shape[0]
-            wg, wu, wd = (a.reshape((L * E,) + a.shape[2:])
+            L = wu.shape[0]
+            wg, wu, wd = (a if a is None else a.reshape((L * E,) + a.shape[2:])
                           for a in (wg, wu, wd))
             groups = lax.dynamic_update_slice(
                 jnp.zeros((L * E,), jnp.int32), sizes,
@@ -367,8 +416,11 @@ def moe_ffn_dropless(bank, x, idx, weights, *, layer=None, valid=None):
         # a padding row reads the last token: it belongs to no group
         xs = x[jnp.minimum(order // K, T - 1)]
     with jax.named_scope(EXPERTS_SCOPE):
-        h = jax.nn.silu(_grouped_dot(xs, wg, groups, tm)) \
-            * _grouped_dot(xs, wu, groups, tm)
+        if gated:
+            h = jax.nn.silu(_grouped_dot(xs, wg, groups, tm)) \
+                * _grouped_dot(xs, wu, groups, tm)
+        else:
+            h = relu2(_grouped_dot(xs, wu, groups, tm, transposed=True))
         ys = _grouped_dot(h, wd, groups, tm)
     with jax.named_scope(DISPATCH_SCOPE):
         # rows past the last group hold no expert's output

@@ -1887,6 +1887,15 @@ class ServeEngine:
                             if self._kv_reserved else 0.0)
         return out
 
+    def device_state(self) -> Dict[str, Any]:
+        """The bank as the last dispatched program left it, by the
+        family's names (``init_paged_cache``'s: the donated state and the
+        per-slot vectors), for a check of what a served slot holds. The
+        next program donates these arrays: read them while the engine is
+        idle, and copy what is kept before more work is submitted (the
+        loop thread alone writes them, under no lock)."""
+        return {**self._kv, **self._sv}
+
     def latency_stats(self) -> Dict[str, float]:
         """Per-token latency: p50/p99 over the gaps between a
         request's consecutive tokens (ms), from this engine's private

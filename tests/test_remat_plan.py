@@ -18,7 +18,7 @@ import optax
 import pytest
 
 from mxtpu import telemetry
-from mxtpu.models import latent_moe, llama, retention, sambay
+from mxtpu.models import blockdiff_moe, latent_moe, llama, retention, sambay
 from mxtpu.parallel import mesh as pmesh, step as pstep
 
 KIB = 1024
@@ -371,7 +371,7 @@ def _hash(lowered):
 
 SLOTS, PAGE, N_PAGES, BUCKET = 4, 16, 32, 32
 FAMILIES = {"llama": llama, "sambay": sambay, "latent_moe": latent_moe,
-            "retention": retention}
+            "retention": retention, "blockdiff_moe": blockdiff_moe}
 
 
 def _serve_program(family, name):
@@ -382,12 +382,12 @@ def _serve_program(family, name):
                             jax.random.PRNGKey(0))
     state = jax.eval_shape(
         lambda: family.init_paged_cache(cfg, SLOTS, N_PAGES, PAGE))
-    per_slot = ("lengths", "tokens", "rngs")
+    per_slot = getattr(family, "SLOT_VARS", ("lengths", "tokens", "rngs"))
     kv = {n: a for n, a in state.items() if n not in per_slot}
     sv = {n: state[n] for n in per_slot}
     pages = cfg.max_seq_len // PAGE
-    if name == "decode_slots_paged":
-        return jax.jit(partial(family.decode_slots_paged, cfg),
+    if name != "prefill_slot_paged":
+        return jax.jit(partial(getattr(family, name), cfg),
                        donate_argnums=(1,)).lower(
             params, kv, sv, S((SLOTS,), jnp.bool_),
             S((SLOTS, pages), jnp.int32), S((SLOTS,), jnp.float32),
@@ -410,6 +410,9 @@ SERVE_HASHES = {
     ("latent_moe", "prefill_slot_paged"): "b4025e6b5d5f672c",
     ("retention", "decode_slots_paged"): "76b59a7516b5d713",
     ("retention", "prefill_slot_paged"): "3434601c8dd32d68",
+    # of commit 641b7c7, before the expert bank learned a non-gated form
+    ("blockdiff_moe", "block_step_slots_paged"): "b169c62c572e1515",
+    ("blockdiff_moe", "prefill_slot_paged"): "3ac2427f15c0ba0a",
 }
 
 

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxtpu.models import latent_moe, llama, retention, sambay
+from mxtpu.models import latent_moe, llama, nemotron_h, retention, sambay
 from mxtpu.ops.retention import STEP_KERNEL_NAME
 from mxtpu.telemetry import scopes as tscopes
 
@@ -605,6 +605,95 @@ def test_tpu_grouped_product_fits_vmem_in_both_types(one_chip, dtype, k, n):
     assert "tpu_custom_call" in exe.as_text()
 
 
+@pytest.mark.parametrize("transposed,k,n", [(True, 2688, 1856),
+                                             (False, 1856, 2688)],
+                         ids=["up_t", "down"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_tpu_non_gated_products_fit_vmem_in_both_types(one_chip, dtype,
+                                                       transposed, k, n):
+    """``megablox.gmm`` at Nemotron-H's expert widths, a decode step's
+    192 rows over 4 x 128 groups: the up matrix stored (f, d) and read
+    transposed, the down one (f, d) as it is; 1856 is not whole lane
+    tiles, so a tile is the widest whole-lane divisor of the other side
+    that keeps it under ``_GMM_TILE_BYTES``."""
+    from mxtpu.parallel import moe
+    arg = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    shape = (512, n, k) if transposed else (512, k, n)
+    exe = _compile_all({"gmm": jax.jit(partial(
+        moe.grouped_matmul_kernel, tm=192, transposed=transposed)).lower(
+            arg((192, k), dtype), arg(shape, dtype),
+            arg((512,), jnp.int32))})["gmm"]
+    assert "tpu_custom_call" in exe.as_text()
+
+
+# -- the Mamba-2, relu²-expert hybrid ------------------------------------------------
+@pytest.fixture(scope="module")
+def nemotron_decode(one_chip):
+    """``nemotron_h.decode_slots_paged`` at the published widths (Mamba-2:
+    64 heads of 64, 8 groups of state 128; relu² experts 2688 x 1856 top-6
+    and a shared one of 3712; 32 query heads over 2 KV heads of 128), the
+    pattern's first period ``MEMEM*EME``, 16 experts and a 32768-row
+    vocabulary: 8 slots' Mamba state is 67 MB, an expert layer's up
+    matrices 160 MB, as shapes."""
+    cfg = replace(nemotron_h.NemotronHConfig(), pattern="MEMEM*EME",
+                  n_routed_experts=16, vocab_size=32768, max_seq_len=512)
+    decode, _, kv, _ = _lower_decode(nemotron_h, cfg, one_chip)
+    return cfg, kv, _compile_all({"decode": decode})["decode"]
+
+
+def test_tpu_nemotron_decode_leaves_the_state_and_the_bank_in_place(
+        nemotron_decode):
+    """The step reads and writes the Mamba-2 state in the donated bank
+    where it lies, and the expert bank reaches the grouped products as it
+    is stored: no state-sized and no bank-sized temporary. An (E, 2688,
+    1856) up matrix is laid out 2688-minor by the chip, and the products'
+    row-major operand was a copy of the whole bank (4.9 GB at the cell's
+    size), so it is stored (E, 1856, 2688) and read transposed."""
+    cfg, kv, exe = nemotron_decode
+    assert kv["ssm"].shape == (4, SLOTS, 64, 64, 128)
+    assert kv["ssm"].dtype == jnp.float32
+    assert kv["k"].shape == (1, N_PAGES, PAGE, 256)
+    state = sum(math.prod(a.shape) * a.dtype.itemsize for a in kv.values())
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= state
+    up = cfg.n_routed_experts * cfg.dim * cfg.moe_hidden_dim * 2
+    layer_state = math.prod(kv["ssm"].shape[1:]) * 4
+    assert mem.temp_size_in_bytes < min(layer_state, up / 2), mem
+    text = exe.as_text()
+    calls = [ln for ln in text.splitlines()
+             if re.match(r"\s*%?gmm[.\d]* = ", ln)]
+    assert len(calls) == 8 and all("tpu_custom_call" in ln for ln in calls)
+    assert sum("bf16[64,1856,2688]" in ln for ln in calls) == 8
+
+
+@pytest.mark.parametrize("scope", ["ssm", "ssm/ssm_state", "moe_router",
+                                   "moe_dispatch", "moe_experts",
+                                   "moe_shared", "attention", "kv_write",
+                                   "sampler"])
+def test_tpu_nemotron_decode_operations_land_under_its_scopes(
+        nemotron_decode, scope):
+    """As for llama's program: what a trace will show of the step lands
+    under the family's scopes; the recurrence under ``ssm_state`` inside
+    ``ssm``, the grouped products under ``moe_experts``, the one walk of
+    the attention layer's live pages under ``attention``, nothing
+    gathered."""
+    from mxtpu.ops.paged_attention import ROWS_KERNEL_NAME
+    _, scopes = tscopes.scope_map(nemotron_decode[2].as_text())
+    top = lambda path: "/".join(path.split("/")[:2 if path.startswith(
+        "ssm/") else 1])
+    shown = {name: top(path) for name, (path, _) in
+             scopes.items() if re.search(
+                 rf"fusion|^copy|custom-call|^gmm|^{ROWS_KERNEL_NAME}",
+                 name)}
+    walks = [n for n in shown if n.startswith(ROWS_KERNEL_NAME)]
+    assert [shown[n] for n in walks] == ["attention"]
+    assert "kv_gather" not in set(shown.values())
+    assert scope in set(shown.values()), sorted(set(shown.values()))
+    assert {shown[n] for n in shown if n.startswith("gmm")} == {
+        "moe_experts"}
+
+
 # ---------------------------------------------------------------------------
 # the train step under each remat plan, FSDP over the four described chips
 # ---------------------------------------------------------------------------
@@ -800,6 +889,9 @@ PARENT_PROGRAMS = {
     "prefill_slot_paged": "d9672642aeb4c8ad",
     "decode_slots_paged": "ffc001e06cfef18c",
     "sambay_decode": "18cce40c6e5497e8",
+    # of commit 641b7c7, before the expert bank learned a non-gated form
+    # and a tile's byte budget: its grouped products, kernel for kernel
+    "latent_moe_decode": "c95d4c62f116b14a",
 }
 
 
@@ -817,7 +909,9 @@ def test_tpu_serve_programs_compile_to_the_parents(request, compiled,
     (``paged_attention_block``): at one length a slot its body is the
     parent's, op for op."""
     exe = request.getfixturevalue(program) \
-        if program.startswith("sambay_") else compiled[program]
+        if program.startswith(("sambay_", "latent_moe_")) \
+        else compiled[program]
+    exe = exe[-1] if isinstance(exe, tuple) else exe
     if program == "sambay_prefill":
         forward = re.findall(
             r"^\s*%?(flash_attention[\w.\-]*) = [^\n]*"
