@@ -31,7 +31,9 @@ grows with the sequence; the cross layers read it too: on a TPU every
 read walks the slot's live pages where they lie, ``ops.paged_attention.
 paged_attention_rows``; elsewhere the rows are gathered once a step), a
 ring of the
-last ``sliding_window`` keys and values per window layer and slot, and
+last ``sliding_window`` keys and values per window layer and slot (on a
+TPU walked by the same kernel, as pages of token rows where it lies;
+elsewhere relaid out head-major each step), and
 the convolution tail and float32 scan state per Mamba layer and slot.
 A prompt needs the self-decoder only, so the prefill program runs the
 cross-decoder on the last position alone. The serving surface is
@@ -101,6 +103,12 @@ STATE_KINDS = {"k": "kv_pages", "v": "kv_pages",
                "conv": "ssm", "ssm": "ssm"}
 
 _F32 = jnp.float32
+# tokens of a window ring to a page of the rows walk on the pages path
+# (``_ring_pages``): whole (16, 128) tiles, a divisor of the window. On
+# one v5e at Phi-4-mini-flash's rings (32 slots x 512 x 1280, bf16) a
+# layer's walk took 0.1193 ms at 32, 0.1216 at 16, 0.1207 at 64 and
+# 0.1199 at 128 (the head-major copy and XLA's read: 0.309)
+_RING_PAGE = 32
 
 
 @dataclass(frozen=True)
@@ -576,21 +584,51 @@ def prefill_logits(cfg: SambaYConfig, params, tokens, true_len,
 # ---------------------------------------------------------------------------
 # serving state and programs
 # ---------------------------------------------------------------------------
+def _ring_pages(shape):
+    """The shape of a window ring (n_pairs, slots, sliding_window,
+    width) seen as a pool of token rows for the rows walk, (n_pairs,
+    slots * sliding_window / _RING_PAGE, _RING_PAGE, width): a reshape
+    that moves nothing (a page is whole (16, 128) tiles); slot s's ring
+    is pages ``s * sliding_window / _RING_PAGE ..`` in order
+    (:func:`_ring_table`)."""
+    P, S, W, width = shape
+    return P, S * W // _RING_PAGE, _RING_PAGE, width
+
+
+def _ring_table(slots, window):
+    """The rings' fixed page table, (slots, window / _RING_PAGE): slot s
+    owns its ring's pages in order."""
+    per_slot = window // _RING_PAGE
+    return jnp.arange(slots * per_slot, dtype=jnp.int32).reshape(
+        slots, per_slot)
+
+
 def decode_attention_path(cfg, kv, mesh=None, *, verify: bool = False) -> str:
-    """Which attention :func:`decode_slots_paged` builds its eight reads
-    of the one shared pool on, over the state ``kv`` (arrays or shapes):
-    ``ops.attention.rows_decode_path``'s answer for what the program
-    hands it. ``"pages"``: the full layer and each cross layer walk the
-    live pages where they lie (``ops.paged_attention.
-    paged_attention_rows``), and nothing is gathered; ``"gathered"``:
-    the pool's rows are copied out once a step (:func:`_gather_rows`)
-    and read eight times. Static per compiled program; the engine
+    """Which attention :func:`decode_slots_paged` builds its reads of
+    keys and values on, over the state ``kv`` (arrays or shapes):
+    ``ops.attention.rows_decode_path``'s answer for the shared pool and
+    for the window rings as pages of token rows (:func:`_ring_pages`),
+    which have the pool's width and dtype. ``"pages"`` where both walk:
+    every read of the step walks the rows where they lie
+    (``ops.paged_attention.paged_attention_rows``) — the full layer and
+    each cross layer the slot's live pages of the pool, each window
+    layer the slot's ring — and nothing is gathered, sliced or relaid
+    out; ``"gathered"`` everywhere else (a window that is not a multiple
+    of ``_RING_PAGE``, a CPU, float32, a mesh): the pool's rows are
+    copied out once a step (:func:`_gather_rows`) and read eight times,
+    and each window layer's ring is sliced and relaid out head-major for
+    ``slot_decode_attention``. Static per compiled program; the engine
     exports it (``serve_decode_steps_total{attention}``,
     ``kv_cache_stats()``)."""
     del verify
-    return rows_decode_path(
-        (kv["wk"].shape[1], cfg.n_heads, 1, 2 * cfg.head_dim),
-        kv["k"].shape, kv["k"].dtype, mesh=mesh)
+    ring = kv["wk"]
+    if ring.shape[2] % _RING_PAGE:
+        return "gathered"
+    q = (ring.shape[1], cfg.n_heads, 1, 2 * cfg.head_dim)
+    walks = {rows_decode_path(q, shape, dtype, mesh=mesh)
+             for shape, dtype in ((kv["k"].shape, kv["k"].dtype),
+                                  (_ring_pages(ring.shape), ring.dtype))}
+    return "pages" if walks == {"pages"} else "gathered"
 
 
 def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
@@ -603,7 +641,9 @@ def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
     out as (.., 10, 128) or (.., 20, 64) would be padded by the chip's
     (8, 128) tiles, or relaid out around every write); ``wk``/``wv``
     the window layers' rings, (n_pairs, slots, sliding_window,
-    n_kv_heads hd), position p at index ``p % sliding_window``;
+    n_kv_heads hd), position p at index ``p % sliding_window``, token
+    rows too: on the decode step's pages path a ring is walked as pages
+    of them where it lies (:func:`_ring_pages`), never relaid out;
     ``conv`` (n_pairs+1, slots, d_conv-1, d_inner) and ``ssm``
     (n_pairs+1, slots, d_inner, d_state) float32, the Mamba layers'
     state. Plus the per-slot ``lengths``/``tokens``/
@@ -666,7 +706,12 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
     Mamba+window pairs, the GMU+cross pairs) around layers "16/17", so
     the program does not grow with depth. Inactive slots compute on
     whatever their state holds and write to scratch page 0; a prefill
-    overwrites all of a slot's state."""
+    overwrites all of a slot's state. On the pages path
+    (:func:`decode_attention_path`) every read walks the rows where they
+    lie: each window layer its carried ring, seen as pages of token rows
+    (:func:`_ring_pages`, :func:`_ring_table`) and read at the scan's
+    layer, with no slice and no head-major copy; the full layer and the
+    cross layers the pool's live pages."""
     ps = kv["k"].shape[2]
     W = cfg.sliding_window
     cap = page_table.shape[1] * ps
@@ -676,6 +721,24 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
     at = jnp.arange(nslots)
     lam_win, lam_full, _ = _lam_inits(cfg)
     x = llama._embed(cfg, params, sv["tokens"][:, None])
+    walk = decode_attention_path(cfg, kv, mesh) == "pages"
+    if walk:
+        from ..ops.paged_attention import paged_attention_rows
+        ring_table = _ring_table(nslots, W)
+
+    def window_read(q, wk, wv, layer):
+        """The window layer's attention over its ring, every key of
+        which lies in the window: indices ``[0, min(pos + 1, W))``."""
+        if walk:
+            return paged_attention_rows(
+                q, wk.reshape(_ring_pages(wk.shape)),
+                wv.reshape(_ring_pages(wv.shape)), ring_table,
+                jnp.minimum(pos + 1, W), layer=layer, scale=_scale(cfg))
+        ring = partial(lax.dynamic_index_in_dim, index=layer, axis=0,
+                       keepdims=False)
+        return slot_decode_attention(
+            q, _kv_heads(cfg, ring(wk)), _kv_heads(cfg, ring(wv)),
+            jnp.minimum(pos + 1, W), scale=_scale(cfg))
 
     def pair(carry, xs):
         x, conv, ssm, wk, wv = carry
@@ -691,12 +754,7 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
             wk = wk.at[layer, at, pos % W].set(k[:, 0])
             wv = wv.at[layer, at, pos % W].set(v[:, 0])
         with jax.named_scope(WINDOW_SCOPE):
-            ring = partial(lax.dynamic_index_in_dim, index=layer, axis=0,
-                           keepdims=False)
-            o = slot_decode_attention(
-                q, _kv_heads(cfg, ring(wk)), _kv_heads(cfg, ring(wv)),
-                jnp.minimum(pos + 1, W), scale=_scale(cfg))
-            out = _diff_out(cfg, ap, o, lam0)
+            out = _diff_out(cfg, ap, window_read(q, wk, wv, layer), lam0)
         x = _residual_mlp(cfg, ap, x + out)
         return (x, conv, ssm, wk, wv), None
 
@@ -714,10 +772,9 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
     phys = page_table[at, pos // ps]
     ck, cv = llama._write_pages(kv["k"], kv["v"], k[:, 0], v[:, 0], 0,
                                 phys, pos % ps)
-    if decode_attention_path(cfg, kv, mesh) == "pages":
+    if walk:
         # eight walks of the live pages: each cross layer's query hangs
         # on the layer before it, so they cannot share one
-        from ..ops.paged_attention import paged_attention_rows
         attend = partial(paged_attention_rows, k_pages=ck, v_pages=cv,
                          page_table=page_table, lengths=pos + 1, layer=0,
                          scale=_scale(cfg))

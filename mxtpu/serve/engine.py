@@ -107,8 +107,9 @@ def _engine_metrics(eid: str, attention: Dict[str, str], sampler: str):
         **{"steps_" + step: telemetry.counter(
             "serve_decode_steps_total",
             "Decode steps dispatched, by the attention their program "
-            "was built with: pages (the kernel reads live pages out of "
-            "the pool), gathered (every slot's whole row copied out), "
+            "was built with: pages (every read of keys and values walks "
+            "them where they lie: the pool's live pages, and sambay's "
+            "window rings), gathered (every slot's whole row copied out), "
             "state_kernel or state (a retention step over a fixed state "
             "a slot: one Pallas kernel a layer, or the jnp form); and by "
             "its sampler's threshold search: search_kernel (one Pallas "
@@ -1838,8 +1839,10 @@ class ServeEngine:
         cache and speculation tallies — surfaced in the gateway
         ``/state`` block. ``"paged"`` is always True (readers outside
         the repo may hold the key); ``"decode_attention"`` is what the
-        decode program's attention was built on, ``"pages"`` (the
-        kernel over live pages), ``"gathered"``, or a retention step's
+        decode program's attention was built on, ``"pages"`` (every
+        read of keys and values walks them where they lie: the kernel
+        over live pages, and for sambay over each window ring too),
+        ``"gathered"``, or a retention step's
         ``"state_kernel"`` / ``"state"`` (no keys or values: the Pallas
         kernel over the bank, or the ``jnp`` form), as the family's
         ``decode_attention_path`` gave it; ``"sampler"`` is how the

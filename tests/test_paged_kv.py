@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mxtpu.models import llama
+from mxtpu.models import llama, sambay
 from mxtpu.ops.attention import paged_decode_attention, \
     slot_decode_attention
 from mxtpu.serve import Request, ServeEngine
@@ -384,6 +384,82 @@ def test_pages_kernel_matches_the_gathered_path(pages_kernel,
                        layer=jnp.int32(layer))
         np.testing.assert_array_equal(np.asarray(again[0], np.float32),
                                       np.asarray(again[2], np.float32))
+
+
+# sambay's window rings through the same walk: the four slots' tokens so
+# far (the ring holds the last ``sliding_window`` of them, position p at
+# index p % window), one of length 1 in the first case
+_RING_TOKENS = {"below_window": [1, 37, 200, 511],
+                "at_window": [512, 512, 512, 512],
+                "past_window": [513, 700, 1024, 1500]}
+
+
+@pytest.fixture(scope="module")
+def ring_walk():
+    """(kernel, arm), jitted: a window layer's read of its ring on the
+    pages path (the rows walk over the rings' page view and fixed table,
+    at the layer given) and on the gathered one (the layer sliced, relaid
+    out head-major, ``slot_decode_attention``)."""
+    from mxtpu.ops.paged_attention import paged_attention_rows
+    cfg = sambay.SambaYConfig()
+
+    def kernel(q, wk, wv, lengths, layer):
+        S, W = wk.shape[1:3]
+        view = sambay._ring_pages(wk.shape)
+        return paged_attention_rows(
+            q, wk.reshape(view), wv.reshape(view), sambay._ring_table(S, W),
+            lengths, layer=layer, scale=sambay._scale(cfg), interpret=True)
+
+    def arm(q, wk, wv, lengths, layer):
+        return slot_decode_attention(
+            q, sambay._kv_heads(cfg, wk[layer]),
+            sambay._kv_heads(cfg, wv[layer]), lengths,
+            scale=sambay._scale(cfg))
+    return cfg, jax.jit(kernel), jax.jit(arm)
+
+
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("case", list(_RING_TOKENS))
+def test_ring_walk_matches_the_relaid_ring(ring_walk, case, rep):
+    """A window ring read where it lies, as pages of token rows under
+    the iota table, gives what its head-major copy gives to
+    ``slot_decode_attention`` (bfloat16 at Phi-4-mini-flash's widths: ten
+    paired KV heads of 128 under 10 or 40 query heads, a window of 512,
+    two layers read at the second), below the window, at it, and past it
+    where the ring has wrapped; and both are attention over the slot's
+    last ``window`` keys in their own order."""
+    cfg, kernel, arm = ring_walk
+    W, kvp, hd = cfg.sliding_window, cfg.kv_pairs, 2 * cfg.head_dim
+    S = len(_RING_TOKENS[case])
+    rng = np.random.default_rng(10 * list(_RING_TOKENS).index(case) + rep)
+    tokens = np.asarray(_RING_TOKENS[case])
+    seq_k, seq_v = (rng.standard_normal((S, tokens.max(), kvp * hd))
+                    .astype(np.float32) for _ in range(2))
+    rings = rng.standard_normal((2, 2, S, W, kvp * hd)).astype(np.float32)
+    for s, n in enumerate(tokens):
+        last = np.arange(max(0, n - W), n)
+        rings[0, 1, s, last % W] = seq_k[s, last]
+        rings[1, 1, s, last % W] = seq_v[s, last]
+    wk, wv = (jnp.asarray(r, jnp.bfloat16) for r in rings)
+    q = jnp.asarray(rng.standard_normal((S, kvp * rep, 1, hd)), jnp.bfloat16)
+    lengths = jnp.asarray(np.minimum(tokens, W), jnp.int32)
+    out = kernel(q, wk, wv, lengths, jnp.int32(1))
+    want = arm(q, wk, wv, lengths, jnp.int32(1))
+    assert out.shape == want.shape and out.dtype == want.dtype
+    out, want = (np.asarray(a, np.float32) for a in (out, want))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
+    # the window in time order, as the sequence had it
+    for s, n in enumerate(tokens):
+        last = np.arange(max(0, n - W), n)
+        k, v = (jnp.asarray(a[s, last], jnp.bfloat16)
+                .reshape(1, len(last), kvp, hd).transpose(0, 2, 1, 3)
+                for a in (seq_k, seq_v))
+        plain = slot_decode_attention(q[s:s + 1], k, v,
+                                      jnp.asarray([len(last)]),
+                                      scale=sambay._scale(cfg))
+        np.testing.assert_allclose(out[s], np.asarray(plain[0], np.float32),
+                                   rtol=2e-2, atol=2e-2)
 
 
 # ---------------------------------------------------------------------------

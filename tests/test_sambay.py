@@ -337,23 +337,30 @@ def _agent_shapes(dtype=jnp.bfloat16, **widths):
 @pytest.mark.parametrize("case,path", [
     ("agent_cell_on_a_tpu", "pages"), ("float32_pool", "gathered"),
     ("mesh", "gathered"), ("cpu_backend", "gathered"),
-    ("paired_heads_of_64_lanes", "gathered")])
+    ("paired_heads_of_64_lanes", "gathered"),
+    ("window_not_whole_ring_pages", "gathered")])
 def test_decode_attention_path_is_read_off_the_inputs(monkeypatch, case,
                                                       path):
     """Backend, shapes and dtypes decide, statically, as for llama: on a
-    TPU over bfloat16 pools whose paired heads are whole lane tiles the
-    full layer and the cross layers' scan each hold ONE call of the rows
-    kernel and nothing gathers the pool; everywhere else the pool is
-    gathered once for K and once for V and no kernel is traced (but the
-    sampler's: its threshold search is ONE kernel call on a TPU, whatever
-    the pool, and none under a mesh)."""
+    TPU over bfloat16 pools whose paired heads are whole lane tiles, and
+    a window of whole ring pages, the window layers' scan, the full layer
+    and the cross layers' scan each hold ONE call of the rows kernel:
+    the window walk reads the carried rings through their page views,
+    nothing transposes a layer's ring and nothing gathers the pool;
+    everywhere else the pool is gathered once for K and once for V, each
+    window layer's ring is relaid out head-major, and no kernel is traced
+    (but the sampler's: its threshold search is ONE kernel call on a
+    TPU, whatever the pool, and none under a mesh)."""
     from mxtpu.ops.paged_attention import ROWS_KERNEL_NAME
     from mxtpu.ops.threshold import KERNEL_NAME as SEARCH
     if case != "cpu_backend":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    widths = {"paired_heads_of_64_lanes": dict(dim=1280),
+              "window_not_whole_ring_pages":
+                  dict(sliding_window=sambay._RING_PAGE * 8 + 8)}
     cfg, kv = _agent_shapes(
         jnp.float32 if case == "float32_pool" else jnp.bfloat16,
-        **(dict(dim=1280) if case == "paired_heads_of_64_lanes" else {}))
+        **widths.get(case, {}))
     mesh = None
     if case == "mesh":
         from mxtpu.parallel import create_mesh
@@ -379,15 +386,26 @@ def test_decode_attention_path_is_read_off_the_inputs(monkeypatch, case,
         case != "cpu_backend" and mesh is None)
     gathers = [e for e in eqns if e.primitive.name == "gather"
                and e.invars[0].aval.shape == kv["k"].shape]
+    _, S, W, width = kv["wk"].shape
+    # a layer's ring turned head-major, (S, W, kv_pairs, 2 hd) -> ..
+    relaid = [e for e in eqns if e.primitive.name == "transpose"
+              and e.invars[0].aval.size == S * W * width]
     if path == "pages":
-        assert len(walks) == 2 and not gathers
+        assert len(walks) == 3 and not gathers and not relaid
         assert all(e.params["name"] == ROWS_KERNEL_NAME
                    for e in walks)
-        # the pools as they are stored are the kernel's own operands
-        assert all([v.aval.shape for v in e.invars[-2:]]
-                   == 2 * [kv["k"].shape] for e in walks)
+        # the pools as they are stored, and the carried rings seen as
+        # pages of token rows, are the kernel's own operands
+        operands = sorted([v.aval.shape for v in e.invars[-2:]]
+                          for e in walks)
+        ring = sambay._ring_pages(kv["wk"].shape)
+        assert operands == sorted(2 * [2 * [kv["k"].shape]]
+                                  + [2 * [ring]])
     else:
         assert not walks and len(gathers) == 2
+        # ``_kv_heads`` of a layer's ring, K and V
+        assert [e.params["permutation"] for e in relaid].count(
+            (0, 2, 1, 3)) == 2
 
 
 def test_engine_names_the_rows_kernel_on_a_tpu(monkeypatch):

@@ -360,7 +360,8 @@ def test_tpu_sambay_reads_its_shared_pool_through_the_rows_kernel(
     text = sambay_decode.as_text()
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line
-             and not _IN_SAMPLER.search(line)]
+             and not _IN_SAMPLER.search(line)
+             and not _IN_WINDOW.search(line)]
     assert len(calls) == 2, calls
     assert all(re.search(rf"%{ROWS_KERNEL_NAME}[.\d]* = ", c)
                for c in calls), calls
@@ -378,6 +379,41 @@ def test_tpu_sambay_reads_its_shared_pool_through_the_rows_kernel(
     assert _rows(text, row, (128, 256)) == []
     mem = sambay_decode.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * N_PAGES * PAGE * 256 * 2
+
+
+_IN_WINDOW = re.compile(r'op_name="[^"]*[/(]window_attention[/)]')
+# the fixture's rings: two window layers, 8 slots of 128 rows of 256 lanes
+_RING = (2, SLOTS, 128, 256)
+
+
+def test_tpu_sambay_walks_its_window_rings_where_they_lie(sambay_decode):
+    """Each window layer reads its ring through ONE call of the rows
+    kernel in the pairs' scan body, under ``window_attention``, fed by the
+    carried rings as pages of token rows (a bitcast of them); nothing
+    under ``window_attention`` copies, slices or transposes a layer's
+    ring: before, each layer's K and V were sliced out and relaid out
+    head-major, 42 MB each at the agent cell's shapes, ~4 ms of a 22 ms
+    step."""
+    from mxtpu.ops.paged_attention import ROWS_KERNEL_NAME
+    text = sambay_decode.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line
+             and _IN_WINDOW.search(line)]
+    assert len(calls) == 1, calls
+    assert re.search(rf"%{ROWS_KERNEL_NAME}[.\d]* = ", calls[0]), calls[0]
+    assert re.search(rf'op_name="[^"]*/while/body/([^/]*/)?window_attention/'
+                     rf"{ROWS_KERNEL_NAME}", calls[0]), calls[0]
+    pages = "bf16[{},{},{},{}]".format(*sambay._ring_pages(_RING))
+    assert calls[0].split("operand_layout_constraints")[1].count(pages) \
+        == 2, calls[0]
+    _, scopes = tscopes.scope_map(text)
+    layer = math.prod(_RING[1:])
+    moved = [f"{comp}: {name} = {opcode}[{n}]"
+             for comp, instrs in _executed(_computations(text)).items()
+             for name, n, opcode, *_ in instrs
+             if n >= layer and opcode not in _NOT_RUN
+             and "window_attention" in scopes.get(name, ("",))[0].split("/")]
+    assert moved == []
 
 
 def test_tpu_page_gather_selects_only_indices(compiled):
@@ -881,14 +917,16 @@ def test_tpu_block_walk_compiles_with_a_carried_group(one_chip):
     assert len(calls) == 1 and calls[0].startswith(ROWS_KERNEL_NAME)
 
 
-# of the parent's programs (commit a222295; sambay's decode: 72c1960,
-# before the rows walk learned a block's two groups of rows), compiled by
-# this file's fixtures in the parent's tree
+# of the parent's programs (commit a222295), compiled by this file's
+# fixtures in the parent's tree
 PARENT_PROGRAMS = {
     "sambay_prefill": "efc129a16255ffb8",
     "prefill_slot_paged": "d9672642aeb4c8ad",
     "decode_slots_paged": "ffc001e06cfef18c",
-    "sambay_decode": "18cce40c6e5497e8",
+    # changed by design when the window layers began to walk their rings
+    # as pages of token rows (``test_tpu_sambay_walks_its_window_rings_
+    # where_they_lie``); the program as that change compiled it
+    "sambay_decode": "075d14ff7fa50fb2",
     # of commit 641b7c7, before the expert bank learned a non-gated form
     # and a tile's byte budget: its grouped products, kernel for kernel
     "latent_moe_decode": "c95d4c62f116b14a",
@@ -904,10 +942,9 @@ def test_tpu_serve_programs_compile_to_the_parents(request, compiled,
     it is differentiated or checkpointed in a serve program, so what the
     chip is handed is the parent's program, kernel for kernel. The CPU's
     lowered text (``test_remat_plan.py``) cannot say so: there
-    ``flash_attention`` takes the blockwise arm. Sambay's decode step
-    holds the rows walk, which learned two blocks of rows a slot
-    (``paged_attention_block``): at one length a slot its body is the
-    parent's, op for op."""
+    ``flash_attention`` takes the blockwise arm. Sambay's decode step is
+    pinned to the program it became when its window layers began to walk
+    their rings: an edit elsewhere in the repo that moves it shows here."""
     exe = request.getfixturevalue(program) \
         if program.startswith(("sambay_", "latent_moe_")) \
         else compiled[program]
