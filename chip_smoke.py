@@ -1015,6 +1015,9 @@ def phase_serve_family(cfg, jobs, *, tol_f32=1e-3, expect_attention=None,
                 assert kv["reserved_bytes"] > 0, kv
                 info[f"decode_attention_{name}"] = kv["decode_attention"]
                 info["sampler"] = kv["sampler"]
+                if "prefill_window_attention" in kv:
+                    info[f"prefill_window_attention_{name}"] = \
+                        kv["prefill_window_attention"]
             finally:
                 gw.close()
             fwd = jax.jit(lambda p, t: family.forward(c, p, t))

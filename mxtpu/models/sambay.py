@@ -50,7 +50,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, ClassVar, Optional
+from typing import Any, ClassVar, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +59,8 @@ from jax import lax
 from ..ops.attention import (ATTENTION_SCOPE, KV_GATHER_SCOPE,
                              blockwise_attention, flash_attention,
                              rows_decode_path, slot_decode_attention,
-                             window_attention)
+                             window_attention_path,
+                             window_prefill_attention)
 from ..ops.ssm import causal_conv1d, selective_scan, selective_scan_step
 from . import llama
 from .llama import KV_WRITE_SCOPE
@@ -68,7 +69,7 @@ __all__ = ["SambaYConfig", "CONFIGS", "init_params", "forward",
            "init_paged_cache", "prefill_slot_paged",
            "init_prefill_stage", "prefill_slot_paged_chunk",
            "prefill_slot_paged_last", "decode_slots_paged", "copy_page",
-           "decode_attention_path"]
+           "decode_attention_path", "prefill_paths"]
 
 # the named scopes of this family's programs, besides the ones it
 # shares with llama.py (embed and lm_head are llama.py's own functions;
@@ -443,7 +444,8 @@ def _ring_of(k, true_len, window):
     return k[:, jnp.clip(p, 0, k.shape[1] - 1)]
 
 
-def _self_decoder(cfg: SambaYConfig, params, x, true_len, carry=None):
+def _self_decoder(cfg: SambaYConfig, params, x, true_len, carry=None,
+                  mesh=None):
     """Layers 0 .. 2p+1 over x (b, s, dim). Returns (x, the memory the
     GMUs read (b, s, d_inner), the full layer's keys and values
     (b, s, n_kv_heads hd), the serving state after position
@@ -460,7 +462,10 @@ def _self_decoder(cfg: SambaYConfig, params, x, true_len, carry=None):
     the stage's ``k``/``v`` at ``start`` and attends over all of it,
     causally by absolute position, and the whole stage comes back as
     its keys and values. At ``start`` 0 nothing before the chunk is
-    seen, whatever the stage holds."""
+    seen, whatever the stage holds. A window layer's attention takes
+    the form ``ops.attention.window_attention_path`` names for its
+    shapes (:func:`prefill_paths`): on a TPU in bf16 and without
+    a ``mesh``, one Pallas call whose score blocks stay in VMEM."""
     lam_win, lam_full, _ = _lam_inits(cfg)
     W, P = cfg.sliding_window, cfg.n_pairs
     if carry is None:
@@ -484,17 +489,17 @@ def _self_decoder(cfg: SambaYConfig, params, x, true_len, carry=None):
         with jax.named_scope(WINDOW_SCOPE):
             k, v = _diff_kv(cfg, ap, h)
             q = _diff_q(cfg, ap, h)
-            seen, k_start = true_len, 0
+            seen, front, k_start = true_len, 0, 0
             if old:
-                # the ring in front: W more keys, W empty queries
+                # the ring in front: W more keys, no query row of theirs
                 k = jnp.concatenate([old[2].astype(k.dtype), k], axis=1)
                 v = jnp.concatenate([old[3].astype(v.dtype), v], axis=1)
-                q = jnp.pad(q, ((0, 0), (0, 0), (W, 0), (0, 0)))
-                seen, k_start = W + true_len, jnp.where(fresh, W, 0)
-            o = window_attention(
+                seen, front, k_start = W + true_len, W, jnp.where(fresh, W, 0)
+            o = window_prefill_attention(
                 q, _kv_heads(cfg, k), _kv_heads(cfg, v), window=W,
-                scale=_scale(cfg), block=min(512, W), k_start=k_start)
-            out = _diff_out(cfg, ap, o[:, :, -x.shape[1]:], lam0)
+                scale=_scale(cfg), q_offset=front, k_start=k_start,
+                mesh=mesh)
+            out = _diff_out(cfg, ap, o, lam0)
             rings = (_ring_of(k, seen, W), _ring_of(v, seen, W))
         x = _residual_mlp(cfg, ap, x + out)
         return x, (tail, state) + rings
@@ -559,7 +564,7 @@ def forward(cfg: SambaYConfig, params, tokens):
 
 
 def prefill_logits(cfg: SambaYConfig, params, tokens, true_len,
-                   carry=None):
+                   carry=None, mesh=None):
     """What the prefill program computes for a prompt END-padded to
     tokens (1, bucket): the self-decoder over the prompt, the
     cross-decoder on position ``true_len - 1`` alone. Returns (logits
@@ -569,7 +574,8 @@ def prefill_logits(cfg: SambaYConfig, params, tokens, true_len,
     keys and values the stage's, the whole prompt's."""
     true_len = jnp.asarray(true_len, jnp.int32)
     x, memory, k, v, state = _self_decoder(
-        cfg, params, llama._embed(cfg, params, tokens), true_len, carry)
+        cfg, params, llama._embed(cfg, params, tokens), true_len, carry,
+        mesh)
     last = partial(lax.dynamic_slice_in_dim, start_index=true_len - 1,
                    slice_size=1, axis=1)
     kh, vh = _kv_heads(cfg, k), _kv_heads(cfg, v)
@@ -629,6 +635,26 @@ def decode_attention_path(cfg, kv, mesh=None, *, verify: bool = False) -> str:
              for shape, dtype in ((kv["k"].shape, kv["k"].dtype),
                                   (_ring_pages(ring.shape), ring.dtype))}
     return "pages" if walks == {"pages"} else "gathered"
+
+
+def prefill_paths(cfg: SambaYConfig, rows: int, *, carried: bool,
+                  mesh=None) -> Dict[str, str]:
+    """The forms a prefill program over ``rows`` tokens is built on, by
+    the name ``ServeEngine.kv_cache_stats()`` reports each under: here
+    ``"prefill_window_attention"``, the window layers' attention,
+    ``ops.attention.window_attention_path``'s answer for their shapes
+    (the chunk's queries behind a ring of ``sliding_window`` keys where
+    the program is ``carried``, ``prefill_slot_paged_chunk`` /
+    ``_last``; none where it is not, ``prefill_slot_paged``,
+    ``forward``). ``"pallas"``: each window layer is ONE call of
+    ``ops.window_attention.window_attention_band``
+    (``window_attention_band.<n>`` in a trace, under the scope
+    ``window_attention``); ``"blockwise"``: XLA's online softmax. Static
+    per compiled program."""
+    hd2, front = 2 * cfg.head_dim, cfg.sliding_window if carried else 0
+    return {"prefill_window_attention": window_attention_path(
+        (1, cfg.n_heads, rows, hd2), (1, cfg.kv_pairs, front + rows, hd2),
+        cfg.dtype, window=cfg.sliding_window, q_offset=front, mesh=mesh)}
 
 
 def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
@@ -846,10 +872,11 @@ def prefill_slot_paged(cfg: SambaYConfig, params, tokens, true_len,
     bucket; the padding moves no state), the cross-decoder on the last
     position alone; the slot's state is overwritten whole. Returns
     (first token (1,), new kv, new sv)."""
-    del prefix_len, mesh
+    del prefix_len
     true_len = jnp.asarray(true_len, jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
-    logits, k, v, state = prefill_logits(cfg, params, tokens, true_len)
+    logits, k, v, state = prefill_logits(cfg, params, tokens, true_len,
+                                         mesh=mesh)
     return _seat_first(cfg, logits, k, v, state, true_len, pages_row, slot,
                        kv, sv, rng, temperature, top_k, top_p)
 
@@ -879,11 +906,10 @@ def prefill_slot_paged_chunk(cfg: SambaYConfig, params, tokens, start,
     the stage as the chunks before left it (``start`` 0: from nothing)
     to the stage the next chunk goes on from. The slot bank is not
     touched."""
-    del mesh
     carry = dict(stage, start=jnp.asarray(start, jnp.int32))
     _, _, k, v, state = _self_decoder(
         cfg, params, llama._embed(cfg, params, tokens), tokens.shape[1],
-        carry)
+        carry, mesh)
     return {n: a.astype(stage[n].dtype)
             for n, a in dict(state, k=k, v=v).items()}
 
@@ -898,10 +924,10 @@ def prefill_slot_paged_last(cfg: SambaYConfig, params, tokens, start,
     the admission's end as ``prefill_slot_paged``'s: the state seated
     into ``slot`` whole, the first token sampled. Returns (first token
     (1,), new kv, new sv)."""
-    del mesh
     start = jnp.asarray(start, jnp.int32)
     logits, k, v, state = prefill_logits(
-        cfg, params, tokens, n_valid, carry=dict(stage, start=start))
+        cfg, params, tokens, n_valid, carry=dict(stage, start=start),
+        mesh=mesh)
     return _seat_first(cfg, logits, k, v, state, start + n_valid,
                        pages_row, jnp.asarray(slot, jnp.int32), kv, sv, rng,
                        temperature, top_k, top_p)

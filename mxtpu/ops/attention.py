@@ -25,6 +25,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["dense_attention", "blockwise_attention", "flash_attention",
            "ulysses_attention", "window_attention",
+           "window_attention_path", "window_prefill_attention",
            "ring_attention", "slot_decode_attention",
            "paged_decode_attention", "paged_decode_path",
            "gathered_decode_attention", "latent_decode_path",
@@ -292,7 +293,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
 def window_attention(q, k, v, *, window: int,
                      scale: Optional[float] = None, block: int = 512,
-                     k_start=0):
+                     k_start=0, q_offset: int = 0):
     """Causal sliding-window attention for a prefill: query ``t`` sees
     keys ``(t - window, t]``, itself and the ``window - 1`` before it.
     Blockwise online softmax (:func:`_online_block`) in which a block of
@@ -303,7 +304,16 @@ def window_attention(q, k, v, *, window: int,
     (b, hk, s, dv) (GQA: ``h % hk == 0``). Keys before position
     ``k_start`` (an int, traced or not) are not seen: a chunk of a longer
     sequence puts the window's worth of keys before it in front, and
-    at the sequence's start there are none."""
+    at the sequence's start there are none. With ``q_offset`` the
+    queries are only the rows that have an output, at key positions
+    ``q_offset ..`` (k: (b, hk, q_offset + s, d)): the keys in front get
+    empty query rows here, which are computed and dropped. The XLA form
+    of :func:`window_prefill_attention`, and the tests' reference for
+    its kernel."""
+    if q_offset:
+        q = jnp.pad(q, ((0, 0), (0, 0), (q_offset, 0), (0, 0)))
+        return window_attention(q, k, v, window=window, scale=scale,
+                                block=block, k_start=k_start)[:, :, q_offset:]
     k, v = _repeat_kv(q, k, v)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     b, h, s, d = q.shape
@@ -340,6 +350,50 @@ def window_attention(q, k, v, *, window: int,
 
     out = lax.map(one, jnp.arange((s + pad) // block))
     return out.transpose(1, 2, 0, 3, 4).reshape(b, h, s + pad, dv)[:, :, :s]
+
+
+def window_attention_path(q_shape, kv_shape, dtype, *, window: int,
+                          q_offset: int = 0, mesh=None) -> str:
+    """Which form :func:`window_prefill_attention` runs for these inputs
+    on this backend, decided like :func:`paged_decode_path` from the
+    backend, shapes and dtypes: ``"pallas"`` (``ops.window_attention.
+    window_attention_band``: one Mosaic call, every score block in
+    VMEM, the queries' own rows only, grouped K and V never repeated,
+    only the band of key blocks a window reaches) on a TPU over
+    bfloat16 operands it takes (``takes_window``) and no mesh (the
+    kernel is not partitioned); ``"blockwise"`` (:func:`window_attention`,
+    XLA's online softmax) everywhere else: float32 (the tests compare
+    it at full precision), a CPU or GPU, a mesh, a block that does not
+    divide the rows, the offset and the window."""
+    from .window_attention import takes_window
+    if (jax.default_backend() == "tpu" and mesh is None
+            and takes_window(q_shape, kv_shape, dtype, window=window,
+                             q_offset=q_offset)):
+        return "pallas"
+    return "blockwise"
+
+
+def window_prefill_attention(q, k, v, *, window: int,
+                             scale: Optional[float] = None,
+                             q_offset: int = 0, k_start=0, mesh=None):
+    """A prefill's causal sliding-window attention by the form
+    :func:`window_attention_path` names: query rows (b, h, s, d) at key
+    positions ``q_offset ..`` over keys k (b, hk, q_offset + s, d) and
+    values v (GQA: ``h % hk == 0``), row t seeing keys ``(q_offset + t -
+    window, q_offset + t]`` from ``k_start`` on (an int, traced or
+    not). Returns (b, h, s, dv); the two forms agree to the rounding of
+    the softmax weights (the kernel's are the values' dtype, as XLA's
+    default-precision product rounds them on the TPU) and the order of
+    the sums."""
+    dtype = jnp.result_type(q, k, v)
+    if window_attention_path(q.shape, k.shape, dtype, window=window,
+                             q_offset=q_offset, mesh=mesh) == "pallas":
+        from .window_attention import window_attention_band
+        return window_attention_band(q, k, v, window=window, scale=scale,
+                                     q_offset=q_offset, k_start=k_start)
+    return window_attention(q, k, v, window=window, scale=scale,
+                            block=min(512, window), k_start=k_start,
+                            q_offset=q_offset)
 
 
 @jax.named_scope(ATTENTION_SCOPE)

@@ -705,6 +705,14 @@ class ServeEngine:
         # "search"): ops.threshold's choice, exported as it is
         self._sampler = thresholds_path(
             (self.max_slots, cfg.vocab_size), np.float32, mesh=mesh)
+        # the forms a family states its prefill programs are built on,
+        # by name: for the chunk programs' rows, or the largest
+        # bucket's where prompts are not chunked; its choices, exported
+        # as they are (kv_cache_stats())
+        self._prefill_paths = getattr(
+            fam, "prefill_paths", lambda *a, **k: {})(
+                cfg, self.prefill_chunk or self.max_len,
+                carried=bool(self.prefill_chunk), mesh=mesh)
         # the kv state is donated through every program (in-place in
         # HBM); the small vectors are not, so the previous step's
         # sampled tokens stay readable during the overlapped sync.
@@ -1848,10 +1856,15 @@ class ServeEngine:
         ``decode_attention_path`` gave it; ``"sampler"`` is how the
         decode program's sampler finds its two thresholds,
         ``"search_kernel"`` (the Pallas kernel) or ``"search"`` (the
-        ``jnp`` form), as ``ops.threshold.thresholds_path`` gave it.
-        Host arithmetic only (the mirrored per-slot lengths; reading the
-        device ``lengths`` vector here would put a sync next to the
-        decode loop — MXL004)."""
+        ``jnp`` form), as ``ops.threshold.thresholds_path`` gave it;
+        a family that has ``prefill_paths`` adds its answers, by name
+        (sambay: ``"prefill_window_attention"``, ``"pallas"`` or
+        ``"blockwise"``): the forms of the chunk programs, or of the
+        largest bucket's program where prompts are not chunked; a
+        smaller bucket's program may hold another (sambay's below 128
+        rows take XLA's window form). Host arithmetic only (the
+        mirrored per-slot lengths; reading the device ``lengths`` vector
+        here would put a sync next to the decode loop — MXL004)."""
         with self._lock:
             active = int(self._active.sum())
             live = self._live_bytes()
@@ -1875,6 +1888,7 @@ class ServeEngine:
                    "top_prefixes": (self._prefix.top()
                                     if self._prefix is not None
                                     else [])}
+            out.update(self._prefill_paths)
             if self.speculate_k:
                 prop = self._spec_proposed
                 out.update({

@@ -189,6 +189,58 @@ def test_window_attention_with_keys_in_front(k_start):
     np.testing.assert_allclose(got[:, :, W:], want[:, :, W:], atol=1e-5)
 
 
+# -- the window layers' prefill kernel (Pallas' TPU interpret mode) ----------
+# the agent cell's head layout (40 query heads over 10 paired KV heads of
+# 128 lanes, bf16) at cut lengths: a window of 128 before chunks of 256
+# (the cell's 512 and 1024), blocks of 128
+BAND_W, BAND_ROWS = 128, 256
+
+
+@pytest.mark.parametrize("case", ["first_chunk", "later_chunk",
+                                  "padded_last_chunk", "whole_prompt"])
+def test_window_band_kernel_matches_masked_dense(case):
+    """``ops.window_attention.window_attention_band`` against dense
+    attention under the window's mask and against the XLA form
+    (``window_attention``), in four of the forms sambay's prefill
+    programs hand it: a prompt's first chunk (the ring in front is
+    hidden by ``k_start``), a later chunk (the ring is seen), a last
+    chunk END-padded past ``n_valid`` rows (causality alone keeps the
+    padding's keys out of every valid row), and a whole prompt with
+    no ring. The queries are the chunk's own rows; K and V stay
+    grouped."""
+    from mxtpu.ops.window_attention import window_attention_band
+    W, rows = BAND_W, BAND_ROWS
+    front = 0 if case == "whole_prompt" else W
+    k_start = W if case == "first_chunk" else 0
+    n_valid = rows - 37 if case == "padded_last_chunk" else rows
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+
+    def bf16(key, shape):
+        return jax.random.normal(key, shape).astype(jnp.bfloat16)
+    q = bf16(keys[0], (1, 40, rows, 128))
+    k = bf16(keys[1], (1, 10, front + rows, 128))
+    v = bf16(keys[2], (1, 10, front + rows, 128))
+    got = window_attention_band(q, k, v, window=W, q_offset=front,
+                                k_start=k_start, interpret=True)
+    assert got.shape == q.shape and got.dtype == jnp.bfloat16
+    # the reference sees the valid rows and their keys alone
+    n = front + n_valid
+    at = jnp.arange(n)
+    mask = ((at[None, :] <= at[:, None]) & (at[None, :] > at[:, None] - W)
+            & (at[None, :] >= k_start))
+    f32 = lambda a: a.astype(jnp.float32)
+    qp = jnp.pad(f32(q[:, :, :n_valid]), ((0, 0), (0, 0), (front, 0), (0, 0)))
+    want = dense_attention(qp, f32(k[:, :, :n]), f32(v[:, :, :n]),
+                           mask=mask[None, None])[:, :, front:]
+    xla = window_attention(q, k, v, window=W, block=W, k_start=k_start,
+                           q_offset=front)
+    got = f32(got[:, :, :n_valid])
+    # bf16 outputs of values ~N(0, 1): half a unit in the last place
+    np.testing.assert_allclose(got, want, atol=2e-2)
+    np.testing.assert_allclose(got, f32(xla[:, :, :n_valid]), atol=2e-2)
+    assert float(jnp.abs(got - want).mean()) < 2e-3
+
+
 # -- the model against the reference -----------------------------------------
 @pytest.mark.parametrize("seed", [1, 2])
 def test_forward_matches_reference_logits(seed):
@@ -417,6 +469,77 @@ def test_engine_names_the_rows_kernel_on_a_tpu(monkeypatch):
     eng = ServeEngine(cfg, p, max_slots=2, max_len=256, min_bucket=128,
                       page_size=16)
     assert eng.kv_cache_stats()["decode_attention"] == "pages"
+
+
+@pytest.mark.parametrize("case,path", [
+    ("agent_chunk_on_a_tpu", "pallas"), ("whole_prompt_on_a_tpu", "pallas"),
+    ("float32", "blockwise"), ("mesh", "blockwise"),
+    ("cpu_backend", "blockwise"), ("rows_not_whole_blocks", "blockwise")])
+def test_prefill_window_path_is_read_off_the_inputs(monkeypatch, case,
+                                                    path):
+    """Backend, shapes, dtype and mesh decide, statically: on a TPU in
+    bf16, without a mesh and at rows, ring and window that a block
+    divides, the window layers' scan in a chunk program (and in a whole
+    prompt's) holds ONE call of the band kernel, fed the chunk's own
+    query rows; everywhere else none, and XLA's form pads the queries
+    by the ring in front."""
+    from mxtpu.ops.window_attention import WINDOW_KERNEL_NAME
+    if case != "cpu_backend":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, _ = _agent_shapes(
+        jnp.float32 if case == "float32" else jnp.bfloat16)
+    mesh = None
+    if case == "mesh":
+        from mxtpu.parallel import create_mesh
+        mesh = create_mesh(tp=2, devices=jax.devices()[:2])
+    carried = case not in ("whole_prompt_on_a_tpu", "rows_not_whole_blocks")
+    rows = 200 if case == "rows_not_whole_blocks" else 512
+    assert sambay.prefill_paths(cfg, rows, carried=carried, mesh=mesh) == {
+        "prefill_window_attention": path}
+    params = jax.eval_shape(partial(sambay.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((1, rows), jnp.int32)
+    if carried:
+        stage = jax.eval_shape(
+            lambda: sambay.init_prefill_stage(cfg, 1024, rows))
+        jaxpr = jax.make_jaxpr(partial(
+            sambay.prefill_slot_paged_chunk, cfg, mesh=mesh))(
+                params, tokens, jnp.int32(0), stage)
+    else:
+        # the full layer's flash kernel traces without conftest's x64, as
+        # on the chip
+        with jax.enable_x64(False):
+            jaxpr = jax.make_jaxpr(lambda p, t: sambay.prefill_logits(
+                cfg, p, t, rows, mesh=mesh))(params, tokens)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    bands = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"] == WINDOW_KERNEL_NAME]
+    hd2 = 2 * cfg.head_dim
+    if path == "pallas":
+        assert len(bands) == 1
+        q, k, v = (x.aval.shape for x in bands[0].invars[-3:])
+        front = cfg.sliding_window if carried else 0
+        assert q == (1, cfg.n_heads, rows, hd2)
+        assert k == v == (1, cfg.kv_pairs, front + rows, hd2)
+    else:
+        assert not bands
+
+
+@pytest.mark.parametrize("chunk,backend,form", [
+    (512, "tpu", "pallas"), (0, "tpu", "pallas"), (512, "cpu", "blockwise")])
+def test_engine_names_the_window_prefill_form(monkeypatch, chunk, backend,
+                                              form):
+    """``kv_cache_stats()`` says what the window layers' prefill attention
+    was built on, for the chunk programs' rows or, unchunked, the
+    largest bucket's (construction compiles nothing)."""
+    from dataclasses import replace
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg, _ = _agent_shapes()
+    cfg = replace(cfg, max_seq_len=1024)
+    p = jax.jit(partial(sambay.init_params, cfg))(jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, p, max_slots=2, max_len=1024, min_bucket=128,
+                      page_size=16, prefill_chunk=chunk or None)
+    assert eng.kv_cache_stats()["prefill_window_attention"] == form
 
 
 # -- through the engine ------------------------------------------------------

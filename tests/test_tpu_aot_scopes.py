@@ -416,6 +416,77 @@ def test_tpu_sambay_walks_its_window_rings_where_they_lie(sambay_decode):
     assert moved == []
 
 
+CHUNK = 1024        # the agent cell's prefill chunk
+
+
+@pytest.fixture(scope="module")
+def sambay_last_chunk(one_chip):
+    """The agent cell's last-chunk program (``prefill_slot_paged_last``)
+    at Phi-4-mini-flash's attention widths and window (40 query heads
+    over 20 KV heads of 64, read as 10 paired heads of 128; a ring of
+    512 before a chunk of 1024), two Mamba+window pairs deep, FFN and
+    vocabulary cut, compiled for a v5e as the chip's processes build
+    it (without conftest's x64 and precision)."""
+    cfg = sambay.SambaYConfig(n_layers=8, hidden_dim=512, vocab_size=8192,
+                              max_seq_len=2048)
+    arg = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+    params = on_chip(jax.eval_shape(partial(sambay.init_params, cfg),
+                                    jax.random.PRNGKey(0)))
+    state = jax.eval_shape(
+        lambda: sambay.init_paged_cache(cfg, SLOTS, N_PAGES, PAGE))
+    per_slot = ("lengths", "tokens", "rngs")
+    kv = on_chip({n: a for n, a in state.items() if n not in per_slot})
+    sv = on_chip({n: state[n] for n in per_slot})
+    stage = on_chip(jax.eval_shape(
+        lambda: sambay.init_prefill_stage(cfg, cfg.max_seq_len, CHUNK)))
+    last = partial(sambay.prefill_slot_paged_last, cfg)
+    last.__name__ = "prefill_slot_paged_last"
+    scalar = partial(arg, ())
+    with _as_on_a_tpu(), jax.enable_x64(False), \
+            jax.default_matmul_precision("default"):
+        lowered = jax.jit(last, donate_argnums=(7,)).lower(
+            params, arg((1, CHUNK), jnp.int32), scalar(jnp.int32),
+            scalar(jnp.int32), stage,
+            arg((cfg.max_seq_len // PAGE,), jnp.int32), scalar(jnp.int32),
+            kv, sv, arg((2,), jnp.uint32), scalar(jnp.float32),
+            scalar(jnp.int32), scalar(jnp.float32))
+    return cfg, _compile_all({"last": lowered})["last"]
+
+
+def test_tpu_sambay_chunk_reads_its_windows_through_one_kernel(
+        sambay_last_chunk):
+    """Each window layer's prefill attention is ONE call of the band
+    kernel in the pairs' scan body, under ``window_attention``, and no
+    instruction under that scope holds a score block of XLA's form (40
+    heads x 512 x 512 float32: 42 MB each, 48 a chunk at the cell's
+    depth, written to HBM and read back between fusions), K or V
+    repeated to the 40 query heads over the ring and the chunk (40 x
+    1536 x 128), or the queries padded by the ring's empty rows (the
+    same size)."""
+    from mxtpu.ops.window_attention import WINDOW_KERNEL_NAME
+    cfg, exe = sambay_last_chunk
+    text = exe.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert re.search(rf"%{WINDOW_KERNEL_NAME}[.\d]* = ", calls[0]), calls[0]
+    assert re.search(r'op_name="[^"]*/while/body/([^/]*/)?window_attention/'
+                     + WINDOW_KERNEL_NAME, calls[0]), calls[0]
+    W, H, hd2 = cfg.sliding_window, cfg.n_heads, 2 * cfg.head_dim
+    banned = {H * 512 * 512: "a score block", H * (W + CHUNK) * hd2:
+              "K or V repeated, or the queries padded, over ring + chunk"}
+    _, scopes = tscopes.scope_map(text)
+    found = [f"{comp}: {name} = {opcode}: {banned[n]}"
+             for comp, instrs in _executed(_computations(text)).items()
+             for name, n, opcode, *_ in instrs
+             if n in banned and opcode not in _NOT_RUN
+             and "window_attention" in scopes.get(name, ("",))[0].split("/")]
+    assert found == []
+
+
 def test_tpu_page_gather_selects_only_indices(compiled):
     """The gather promises its bounds: under ``kv_gather`` nothing
     selects over gathered rows (``jnp.take``'s default fill was a
@@ -920,7 +991,10 @@ def test_tpu_block_walk_compiles_with_a_carried_group(one_chip):
 # of the parent's programs (commit a222295), compiled by this file's
 # fixtures in the parent's tree
 PARENT_PROGRAMS = {
-    "sambay_prefill": "efc129a16255ffb8",
+    # changed by design when each window layer's prefill attention became
+    # ONE call of the band kernel (``test_tpu_sambay_chunk_reads_its_
+    # windows_through_one_kernel``); the program as that change compiled it
+    "sambay_prefill": "9c9cd496e0698229",
     "prefill_slot_paged": "d9672642aeb4c8ad",
     "decode_slots_paged": "ffc001e06cfef18c",
     # changed by design when the window layers began to walk their rings
@@ -944,7 +1018,9 @@ def test_tpu_serve_programs_compile_to_the_parents(request, compiled,
     lowered text (``test_remat_plan.py``) cannot say so: there
     ``flash_attention`` takes the blockwise arm. Sambay's decode step is
     pinned to the program it became when its window layers began to walk
-    their rings: an edit elsewhere in the repo that moves it shows here."""
+    their rings, and its prefill bucket to the one whose window layers
+    read through the band kernel: an edit elsewhere in the repo that
+    moves either shows here."""
     exe = request.getfixturevalue(program) \
         if program.startswith(("sambay_", "latent_moe_")) \
         else compiled[program]
@@ -954,4 +1030,7 @@ def test_tpu_serve_programs_compile_to_the_parents(request, compiled,
             r"^\s*%?(flash_attention[\w.\-]*) = [^\n]*"
             r'custom_call_target="tpu_custom_call"', exe.as_text(), re.M)
         assert forward, "the bucket took another arm than the kernel's"
+        from mxtpu.ops.window_attention import WINDOW_KERNEL_NAME
+        assert re.search(rf"^\s*%?{WINDOW_KERNEL_NAME}[\w.\-]* = ",
+                         exe.as_text(), re.M)
     assert _program_hash(exe) == PARENT_PROGRAMS[program]
