@@ -7,6 +7,8 @@ parity and, in addition, provides functional cores here: pure
 ``forward(cfg, params, ...)`` functions over parameter pytrees that
 compose directly with ``mxtpu.parallel`` (sharding rules, jitted train
 step, remat, scan-over-layers) — the idiomatic shape for pjit/XLA.
+``serving`` holds what every serving family shares, and the contract
+between a family module and ``serve.ServeEngine``.
 """
 from . import bert
 from . import blockdiff_moe
@@ -16,6 +18,7 @@ from . import nemotron_h
 from . import resnet
 from . import retention
 from . import sambay
+from . import serving
 from .bert import BertConfig
 from .blockdiff_moe import BlockDiffMoEConfig
 from .latent_moe import LatentMoEConfig
@@ -26,13 +29,13 @@ from .retention import RetentionConfig
 from .sambay import SambaYConfig
 
 __all__ = ["llama", "resnet", "sambay", "latent_moe", "retention",
-           "blockdiff_moe", "nemotron_h",
+           "blockdiff_moe", "nemotron_h", "serving",
            "LlamaConfig", "ResNetConfig", "SambaYConfig", "LatentMoEConfig",
            "RetentionConfig", "BlockDiffMoEConfig", "NemotronHConfig",
            "SERVING_FAMILIES", "serving_family"]
 
 # the families ``serve.ServeEngine`` can be given, by the ``family`` of
-# their config class; each module has llama.py's serving surface
+# their config class; each module keeps ``serving``'s contract
 SERVING_FAMILIES = {"llama": llama, "sambay": sambay,
                     "latent_moe": latent_moe, "retention": retention,
                     "blockdiff_moe": blockdiff_moe, "nemotron_h": nemotron_h}

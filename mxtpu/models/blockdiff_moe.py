@@ -27,9 +27,10 @@ keys and values are what stays. So a pass yields NO token or a whole
 block. The release runs a *commit pass* for those keys, the same forward
 over the final tokens; here they ride in the next block's first pass.
 
-The serving surface is latent_moe's (llama's plus the chunk surface),
-with :func:`block_step_slots_paged` in ``decode_slots_paged``'s place:
-ONE program for every slot whatever its phase. It writes the block's
+The module keeps ``models/serving.py``'s family contract, chunks
+included, with :func:`block_step_slots_paged` in
+``decode_slots_paged``'s place: ONE program for every slot whatever its
+phase. It writes the block's
 keys and values at ``length .. length + B - 1`` first (what lies past a
 slot's length is excluded by every later read and overwritten in place,
 as the speculative step's rejected drafts are: a denoise pass's write
@@ -64,8 +65,9 @@ from jax import lax
 from ..ops.attention import (BLOCK_SCOPE, block_causal_rows_attention,
                              block_decode_path, paged_block_attention)
 from ..parallel import moe
-from . import latent_moe, llama, retention
-from .llama import KV_WRITE_SCOPE, SAMPLER_SCOPE, rms_norm
+from . import latent_moe, llama, retention, serving
+from .llama import final_logits, rms_norm
+from .serving import KV_WRITE_SCOPE, SAMPLER_SCOPE, kv_block, normal
 
 __all__ = ["BlockDiffMoEConfig", "CONFIGS", "init_params", "forward",
            "layer_streams", "router_picks", "init_paged_cache",
@@ -225,7 +227,6 @@ def init_params(cfg: BlockDiffMoEConfig, rng: Optional[jax.Array] = None):
     ks = jax.random.split(rng, 8)
     d, D, L = cfg.param_dtype, cfg.dim, cfg.n_layers
     H, W, hd = cfg.n_heads * cfg.head_dim, cfg.kv_width, cfg.head_dim
-    normal = latent_moe._normal
     fan = 2 * L
     layers = {
         "attn_norm": jnp.ones((L, D), d), "ffn_norm": jnp.ones((L, D), d),
@@ -315,20 +316,10 @@ def _scan_layers(cfg: BlockDiffMoEConfig, params, x, state, cos, sin,
     return x, state, counts, {"picks": picks, "streams": streams}
 
 
-def _final(cfg, params, x):
-    return llama._lm_head(cfg, params, rms_norm(x, params["final_norm"],
-                                                cfg.norm_eps))
-
 
 # ---------------------------------------------------------------------------
 # whole sequences: forward and the prefills (block-causal)
 # ---------------------------------------------------------------------------
-def _kv_block(s: int) -> int:
-    """Keys a step of the prefill attention's loop reads, for a run of
-    s queries; a row store's capacity is a multiple of it."""
-    return min(512, s)
-
-
 def _sequence_layers(cfg: BlockDiffMoEConfig, params, tokens, start, rows,
                      n_valid):
     """Every layer over tokens (b, s) at positions ``start ..`` (a
@@ -354,7 +345,7 @@ def _sequence_layers(cfg: BlockDiffMoEConfig, params, tokens, start, rows,
             o = block_causal_rows_attention(
                 q, *rows, layer=layer, q_offset=start,
                 block=cfg.block_length, scale=cfg.scale,
-                kv_block=_kv_block(s))
+                kv_block=kv_block(s))
         return o, rows
 
     x, rows, _, seen = _scan_layers(
@@ -366,7 +357,7 @@ def _sequence_layers(cfg: BlockDiffMoEConfig, params, tokens, start, rows,
 def _fresh_rows(cfg: BlockDiffMoEConfig, b: int, s: int):
     """Empty row stores for a sequence of s tokens from position 0,
     their capacity a whole number of key blocks."""
-    blk = _kv_block(s)
+    blk = kv_block(s)
     shape = (cfg.n_layers, b, -(-s // blk) * blk, cfg.kv_width)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
@@ -378,7 +369,7 @@ def forward(cfg: BlockDiffMoEConfig, params, tokens):
     b, s = tokens.shape
     x, _, _ = _sequence_layers(cfg, params, tokens, 0,
                                _fresh_rows(cfg, b, s), s)
-    return _final(cfg, params, x)
+    return final_logits(cfg, params, x)
 
 
 def router_picks(cfg: BlockDiffMoEConfig, params, tokens):
@@ -449,9 +440,7 @@ def init_paged_cache(cfg: BlockDiffMoEConfig, max_slots: int, n_pages: int,
     block just finished, in ``tokens`` while the next is all ``[MASK]``,
     still has its final keys and values to write. Page tables stay on the
     host."""
-    if mesh is not None or int8:
-        raise ValueError("blockdiff_moe: " + SERVE_UNSUPPORTED[
-            "mesh" if mesh is not None else "int8_pages"])
+    serving.refuse("blockdiff_moe", SERVE_UNSUPPORTED, mesh, int8)
     if page_size % cfg.block_length:
         raise ValueError(
             f"a block of {cfg.block_length} must not straddle a page of "
@@ -460,16 +449,15 @@ def init_paged_cache(cfg: BlockDiffMoEConfig, max_slots: int, n_pages: int,
     B = cfg.block_length
     return {
         "k": jnp.zeros(pool, cfg.dtype), "v": jnp.zeros(pool, cfg.dtype),
-        "lengths": jnp.zeros((max_slots,), _I32),
+        **serving.slot_vectors(max_slots),
         "tokens": jnp.zeros((max_slots, B), _I32),
-        "rngs": jnp.zeros((max_slots, 2), jnp.uint32),
         "masked": jnp.ones((max_slots, B), bool),
         "fresh": jnp.ones((max_slots, B), bool),
         "passes": jnp.zeros((max_slots,), _I32),
         "carry": jnp.zeros((max_slots,), bool)}
 
 
-copy_page = llama.copy_page
+copy_page = serving.copy_page
 
 
 def decode_logits(cfg: BlockDiffMoEConfig, params, kv, sv, active,
@@ -508,7 +496,7 @@ def decode_logits(cfg: BlockDiffMoEConfig, params, kv, sv, active,
     cos, sin = _rope_tables(cfg, pos)
 
     def attend(q, k, v, layer, pools):
-        pools = llama._write_pages(*pools, k, v, layer, phys, off)
+        pools = serving.write_pages(*pools, k, v, layer, phys, off)
         return paged_block_attention(q, *pools, page_table, seen,
                                      layer=layer, scale=cfg.scale), pools
 
@@ -518,7 +506,7 @@ def decode_logits(cfg: BlockDiffMoEConfig, params, kv, sv, active,
     x, (ck, cv), counts, _ = _scan_layers(
         cfg, params, llama._embed(cfg, params, toks), (ck, cv), cos, sin,
         attend, valid)
-    return _final(cfg, params, x[:, B:]), {"k": ck, "v": cv}, counts
+    return final_logits(cfg, params, x[:, B:]), {"k": ck, "v": cv}, counts
 
 
 def unmask(cfg: BlockDiffMoEConfig, logits, masked, keys, temperature,
@@ -543,10 +531,10 @@ def unmask(cfg: BlockDiffMoEConfig, logits, masked, keys, temperature,
         # a select, not a scatter: it fuses into the rows' first reader
         lg = jnp.where(jnp.arange(V) == cfg.mask_token_id, -jnp.inf,
                        logits.reshape(S * B, V))
-        greedy, cut = llama._masked_logits(
+        greedy, cut = serving._masked_logits(
             lg, rows(temperature), rows(top_k), rows(top_p))
         sub = jax.vmap(lambda k: jax.random.split(k, B))(keys)
-        x0 = jax.vmap(llama._draw)(sub.reshape(S * B, 2), greedy, cut)
+        x0 = jax.vmap(serving._draw)(sub.reshape(S * B, 2), greedy, cut)
     with jax.named_scope(UNMASK_SCOPE):
         src = jnp.where((greedy < 0)[:, None], cut, lg)
         took = jnp.take_along_axis(src, x0[:, None], axis=-1)[:, 0]
@@ -603,8 +591,7 @@ def block_step_slots_paged(cfg: BlockDiffMoEConfig, params, kv, sv, active,
         tokens = jnp.where(take, x0, sv["tokens"])
         emit = done[:, None] & sv["fresh"]
         took = (take & active[:, None]).sum(-1, dtype=_I32)
-        share = (counts[2].astype(_F32) * 1e6
-                 / jnp.maximum(counts[0], 1).astype(_F32)).astype(_I32)
+        share = serving.busiest_share(counts)
         out = jnp.concatenate([
             tokens.reshape(-1).astype(_I32), emit.reshape(-1).astype(_I32),
             counts[:2], share[None],
@@ -632,37 +619,46 @@ def block_step_slots_paged(cfg: BlockDiffMoEConfig, params, kv, sv, active,
     return out, pools, new_sv
 
 
-@jax.named_scope(KV_WRITE_SCOPE)
-def _seat_rows(kv, rows, pages_row):
-    """A prefilled prompt's rows (k, v), each (L, 1, capacity, G hd),
-    into the slot's pages; entries of ``pages_row`` past the granted
-    ones name scratch page 0, which is never attended."""
-    out = {}
-    for name, store in zip(("k", "v"), rows):
-        pool = kv[name]
-        ps = pool.shape[2]
-        store = store[:, 0]
-        store = jnp.pad(store, ((0, 0), (0, -store.shape[1] % ps), (0, 0)))
-        pages = store.reshape(store.shape[0], -1, ps, store.shape[-1])
-        n = min(pages.shape[1], pages_row.shape[0])
-        out[name] = pool.at[:, pages_row[:n]].set(
-            pages[:, :n].astype(pool.dtype))
-    return out
+# -- admission: the run, and the end that seats a block and samples nothing -
+def _whole_blocks(cfg, start, n_valid):
+    """Of a run of ``n_valid`` tokens from ``start``, how many lie in
+    the prompt's whole blocks: their keys are final and are stored; the
+    remainder's are computed again with its block. A count known when
+    the program is traced is a whole chunk's: whole blocks from a block
+    boundary (``init_prefill_stage``'s rule)."""
+    if isinstance(n_valid, int):
+        return n_valid
+    B = cfg.block_length
+    return (start + n_valid) // B * B - start
 
 
-def _seat_block(cfg, tokens, start, n_valid, rows, pages_row, slot, kv, sv,
-                rng):
+def _run(cfg: BlockDiffMoEConfig, params, tokens, start, n_valid, stage,
+         mesh):
+    """``serving``'s run: every layer over the prompt's whole blocks
+    into the stage's row stores; no head, the end reads the tokens."""
+    del mesh
+    rows = (_fresh_rows(cfg, *tokens.shape) if stage is None
+            else (stage["k"], stage["v"]))
+    _, (k, v), _ = _sequence_layers(cfg, params, tokens,
+                                    0 if stage is None else start, rows,
+                                    _whole_blocks(cfg, start, n_valid))
+    return (tokens, start), {"k": k, "v": v}
+
+
+def _seat_block(cfg, head, state, true_len, pages_row, slot, kv, sv, rng,
+                temperature, top_k, top_p):
     """The end of an admission: the prompt's whole blocks are in
-    ``rows``; they are seated into the slot's pages, the slot's length
-    is their count, and the prompt's remainder (``(start + n_valid) %
-    B`` tokens) opens the first block unmasked beside ``[MASK]``s. No
-    head runs and NO token comes out: the first tokens are the first
+    ``state``; they are seated into the slot's pages, the slot's length
+    is their count, and the prompt's remainder (``true_len % B``
+    tokens) opens the first block unmasked beside ``[MASK]``s. No head
+    runs and NO token comes out: the first tokens are the first
     block's, passes later. Returns (no tokens (0,) int32, new kv, new
     sv)."""
+    del temperature, top_k, top_p
+    tokens, start = head
     B = cfg.block_length
-    total = start + n_valid
-    whole = total // B * B
-    left = total - whole
+    whole = true_len // B * B
+    left = true_len - whole
     tail = lax.dynamic_slice_in_dim(tokens[0], whole - start, B)
     here = jnp.arange(B) >= left
     z = jnp.zeros((), _I32)
@@ -677,34 +673,19 @@ def _seat_block(cfg, tokens, start, n_valid, rows, pages_row, slot, kv, sv,
         "rngs": put("rngs", rng), "masked": put("masked", here),
         "fresh": put("fresh", here), "passes": put("passes", z),
         "carry": put("carry", False)}
-    return jnp.zeros((0,), _I32), _seat_rows(kv, rows, pages_row), new_sv
-
-
-def _whole_blocks(cfg, start, n_valid):
-    """Of a run of ``n_valid`` tokens from ``start``, how many lie in
-    the prompt's whole blocks: their keys are final and are stored; the
-    remainder's are computed again with its block."""
-    B = cfg.block_length
-    return (start + n_valid) // B * B - start
+    return jnp.zeros((0,), _I32), serving.seat_pools(kv, state, pages_row), \
+        new_sv
 
 
 def prefill_slot_paged(cfg: BlockDiffMoEConfig, params, tokens, true_len,
                        prefix_len, pages_row, slot, kv, sv, rng,
                        temperature, top_k, top_p, mesh=None):
-    """Admission: ``llama.prefill_slot_paged``'s arguments, cold only
-    (the engine refuses a prefix cache for this family). Every layer
-    over the prompt's whole blocks under the block-causal mask (the
-    prompt END-padded to its bucket; the remainder and the padding are
-    routed nowhere and their rows never attended), no head. Returns (no
-    tokens (0,), new kv, new sv)."""
-    del prefix_len, temperature, top_k, top_p, mesh
-    true_len = jnp.asarray(true_len, _I32)
-    z = jnp.zeros((), _I32)
-    _, rows, _ = _sequence_layers(
-        cfg, params, tokens, 0, _fresh_rows(cfg, *tokens.shape),
-        _whole_blocks(cfg, z, true_len))
-    return _seat_block(cfg, tokens, z, true_len, rows, pages_row,
-                       jnp.asarray(slot, _I32), kv, sv, rng)
+    """Admission of a whole prompt, cold only: the last chunk of a prompt
+    from position 0 of a fresh stage (``serving.prefill_last``)."""
+    return serving.prefill_last(
+        _run, _seat_block, cfg, params, tokens, jnp.zeros((), _I32),
+        true_len, None, pages_row, slot, kv, sv, rng, temperature, top_k,
+        top_p, mesh)
 
 
 # -- a prompt in chunks: the stall a running request sees is one chunk's ----
@@ -713,10 +694,10 @@ def init_prefill_stage(cfg: BlockDiffMoEConfig, capacity: int, chunk: int):
     its keys and values until its last chunk seats them, outside the
     pools (a step in between runs over every slot's pages): ``k``/``v``
     (L, 1, capacity rounded up to whole chunks, G hd)."""
-    if chunk % cfg.block_length or chunk % _kv_block(chunk):
+    if chunk % cfg.block_length or chunk % kv_block(chunk):
         raise ValueError(
             f"a prefill chunk ({chunk}) is a whole number of blocks "
-            f"({cfg.block_length}) and of key blocks ({_kv_block(chunk)})")
+            f"({cfg.block_length}) and of key blocks ({kv_block(chunk)})")
     shape = (cfg.n_layers, 1, -(-capacity // chunk) * chunk, cfg.kv_width)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
@@ -724,30 +705,15 @@ def init_prefill_stage(cfg: BlockDiffMoEConfig, capacity: int, chunk: int):
 
 def prefill_slot_paged_chunk(cfg: BlockDiffMoEConfig, params, tokens, start,
                              stage, mesh=None):
-    """One whole chunk of a prompt that is not its last: every layer
-    over tokens (1, chunk) at positions ``start ..``, each attending
-    block-causally to the rows the chunks before left in the stage and
-    to its own. The pools are not touched."""
-    del mesh
-    _, (k, v), _ = _sequence_layers(cfg, params, tokens, start,
-                                    (stage["k"], stage["v"]),
-                                    tokens.shape[1])
-    return {"k": k, "v": v}
+    """A chunk that is not the prompt's last (``serving.prefill_chunk``)."""
+    return serving.prefill_chunk(_run, cfg, params, tokens, start, stage,
+                                 mesh)
 
 
 def prefill_slot_paged_last(cfg: BlockDiffMoEConfig, params, tokens, start,
                             n_valid, stage, pages_row, slot, kv, sv, rng,
                             temperature, top_k, top_p, mesh=None):
-    """A prompt's last chunk, ``n_valid`` tokens END-padded to tokens
-    (1, chunk), at positions ``start ..``; then the admission's end as
-    ``prefill_slot_paged``'s: the whole prompt's whole blocks seated
-    into the slot's pages, the remainder seated as the first block.
-    Returns (no tokens (0,), new kv, new sv)."""
-    del temperature, top_k, top_p, mesh
-    start = jnp.asarray(start, _I32)
-    n_valid = jnp.asarray(n_valid, _I32)
-    _, rows, _ = _sequence_layers(
-        cfg, params, tokens, start, (stage["k"], stage["v"]),
-        _whole_blocks(cfg, start, n_valid))
-    return _seat_block(cfg, tokens, start, n_valid, rows, pages_row,
-                       jnp.asarray(slot, _I32), kv, sv, rng)
+    """A prompt's last chunk and its seat (``serving.prefill_last``)."""
+    return serving.prefill_last(
+        _run, _seat_block, cfg, params, tokens, start, n_valid, stage,
+        pages_row, slot, kv, sv, rng, temperature, top_k, top_p, mesh)

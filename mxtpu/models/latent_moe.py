@@ -32,12 +32,10 @@ dropless dispatch runs each chosen expert on its tokens
 (``parallel.moe.moe_ffn_dropless``: sort, grouped product, unsort), and
 ``S`` is one SwiGLU of ``n_shared_experts x moe_hidden_dim``.
 
-The serving surface is ``llama.py``'s (``init_params``, ``forward``,
-``init_paged_cache``, ``decode_attention_path``, ``decode_slots_paged``,
-``prefill_slot_paged``, ``copy_page``) plus sambay's chunk surface
-(``init_prefill_stage``, ``prefill_slot_paged_chunk``, ``.._last``): a
-chunk's rows wait in the stage, where the chunks after it read them, and
-the last chunk seats the whole prompt into the slot's pages. The decode
+The module keeps ``models/serving.py``'s family contract, prompts in
+chunks included: a chunk's rows wait in the stage, where the chunks
+after it read them, and the last chunk seats the whole prompt into the
+slot's pages (``serving.seat_pools``). The decode
 program also counts, on the device, what the router did with the step's
 tokens (``STEP_COUNTS``), and hands the counts out behind the sampled
 tokens in the one array the engine reads back.
@@ -46,6 +44,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, ClassVar, Optional
 
 import jax
@@ -56,8 +55,9 @@ from ..ops.attention import (MLA_SCOPE, latent_decode_path,
                              latent_prefill_attention,
                              paged_latent_decode_attention)
 from ..parallel import moe
-from . import llama
-from .llama import KV_WRITE_SCOPE, rms_norm
+from . import llama, serving
+from .llama import final_logits, rms_norm
+from .serving import KV_WRITE_SCOPE, kv_block, normal
 
 __all__ = ["LatentMoEConfig", "CONFIGS", "init_params", "forward",
            "init_paged_cache", "prefill_slot_paged", "init_prefill_stage",
@@ -194,30 +194,26 @@ CONFIGS = {
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
-def _normal(key, shape, fan_in, dtype):
-    return jax.random.normal(key, shape, dtype) / math.sqrt(fan_in)
-
-
 def _init_attn(key, cfg: LatentMoEConfig, n: int):
     ks = jax.random.split(key, 4)
     d, D, H, R = cfg.param_dtype, cfg.dim, cfg.n_heads, cfg.kv_lora_rank
     return {
         "attn_norm": jnp.ones((n, D), d), "ffn_norm": jnp.ones((n, D), d),
         "kv_norm": jnp.ones((n, R), d),
-        "wq": _normal(ks[0], (n, D, H * cfg.qk_head_dim), D, d),
-        "wkva": _normal(ks[1], (n, D, cfg.row_dim), D, d),
+        "wq": normal(ks[0], (n, D, H * cfg.qk_head_dim), D, d),
+        "wkva": normal(ks[1], (n, D, cfg.row_dim), D, d),
         # per head [k_nope; v], as the published kv_b_proj lays it out
-        "wkvb": _normal(ks[2], (n, R, H * (cfg.qk_nope_head_dim
+        "wkvb": normal(ks[2], (n, R, H * (cfg.qk_nope_head_dim
                                            + cfg.v_head_dim)), R, d),
-        "wo": _normal(ks[3], (n, H * cfg.v_head_dim, D),
+        "wo": normal(ks[3], (n, H * cfg.v_head_dim, D),
                       H * cfg.v_head_dim * 2 * cfg.n_layers, d)}
 
 
 def _init_swiglu(key, D, F, down_fan, dtype, lead=(), prefix="w"):
     k1, k2, k3 = jax.random.split(key, 3)
-    return {prefix + "_gate": _normal(k1, lead + (D, F), D, dtype),
-            prefix + "_up": _normal(k2, lead + (D, F), D, dtype),
-            prefix + "_down": _normal(k3, lead + (F, D), down_fan, dtype)}
+    return {prefix + "_gate": normal(k1, lead + (D, F), D, dtype),
+            prefix + "_up": normal(k2, lead + (D, F), D, dtype),
+            prefix + "_down": normal(k3, lead + (F, D), down_fan, dtype)}
 
 
 def init_params(cfg: LatentMoEConfig, rng: Optional[jax.Array] = None):
@@ -243,13 +239,13 @@ def init_params(cfg: LatentMoEConfig, rng: Optional[jax.Array] = None):
     layers = _init_attn(ks[2], cfg, nm)
     layers.update(_init_swiglu(ks[3], D, F, F * fan, d, (nm, E)))
     layers.update(_init_swiglu(ks[4], D, Fs, Fs * fan, d, (nm,), "ws"))
-    layers["router"] = _normal(ks[5], (nm, D, E), D, d)
+    layers["router"] = normal(ks[5], (nm, D, E), D, d)
     layers["router_bias"] = 0.01 * jax.random.normal(ks[6], (nm, E), _F32)
-    params = {"tok_embed": _normal(ks[7], (cfg.vocab_size, D), D, d),
+    params = {"tok_embed": normal(ks[7], (cfg.vocab_size, D), D, d),
               "dense": dense, "moe": layers,
               "final_norm": jnp.ones((D,), d)}
     if not cfg.tie_embeddings:
-        params["lm_head"] = _normal(ks[8], (D, cfg.vocab_size), D, d)
+        params["lm_head"] = normal(ks[8], (D, cfg.vocab_size), D, d)
     return params
 
 
@@ -382,20 +378,10 @@ def _scan_layers(cfg: LatentMoEConfig, params, x, state, attend, valid):
                               "streams": jnp.concatenate([ins_d, ins_m])}
 
 
-def _final(cfg, params, x):
-    return llama._lm_head(cfg, params, rms_norm(x, params["final_norm"],
-                                                cfg.norm_eps))
-
 
 # ---------------------------------------------------------------------------
 # whole sequences: forward and the prefills (decompressed attention)
 # ---------------------------------------------------------------------------
-def _kv_block(s: int) -> int:
-    """Keys a step of the prefill attention's loop reads, for a run of
-    s queries; the row store's capacity is a multiple of it."""
-    return min(512, s)
-
-
 def _sequence_layers(cfg: LatentMoEConfig, params, tokens, start, rows,
                      n_valid):
     """Every layer over tokens (b, s) at positions ``start ..`` (a
@@ -421,7 +407,7 @@ def _sequence_layers(cfg: LatentMoEConfig, params, tokens, start, rows,
             o = latent_prefill_attention(
                 q_nope, q_rope, rows, _wkvb(cfg, lp), layer=layer,
                 q_offset=start, scale=cfg.scale,
-                kv_block=_kv_block(s))
+                kv_block=kv_block(s))
         return o.astype(cfg.dtype), rows
 
     x, rows, _, seen = _scan_layers(
@@ -432,7 +418,7 @@ def _sequence_layers(cfg: LatentMoEConfig, params, tokens, start, rows,
 def _fresh_rows(cfg: LatentMoEConfig, b: int, s: int):
     """An empty row store for a sequence of s tokens from position 0,
     its capacity a whole number of key blocks."""
-    blk = _kv_block(s)
+    blk = kv_block(s)
     return jnp.zeros((cfg.n_layers, b, -(-s // blk) * blk,
                       cfg.row_stored), cfg.dtype)
 
@@ -443,7 +429,7 @@ def forward(cfg: LatentMoEConfig, params, tokens):
     b, s = tokens.shape
     x, _, _ = _sequence_layers(cfg, params, tokens, 0,
                                _fresh_rows(cfg, b, s), s)
-    return _final(cfg, params, x)
+    return final_logits(cfg, params, x)
 
 
 def router_picks(cfg: LatentMoEConfig, params, tokens):
@@ -488,28 +474,15 @@ def init_paged_cache(cfg: LatentMoEConfig, max_slots: int, n_pages: int,
     lead, which is what the decode write indexes) with ONE row a token
     and layer, ``[c; rotated k_rope; zeros to whole lane tiles]``
     (``LatentMoEConfig.row_stored`` says why), read by every head; plus the
-    per-slot ``lengths``/``tokens``/``rngs`` of every family. Page
-    tables stay on the host."""
-    if mesh is not None or int8:
-        raise ValueError("latent_moe: " + SERVE_UNSUPPORTED[
-            "mesh" if mesh is not None else "int8_pages"])
-    return {
-        "latent": jnp.zeros((cfg.n_layers, n_pages, page_size,
-                             cfg.row_stored), cfg.dtype),
-        "lengths": jnp.zeros((max_slots,), jnp.int32),
-        "tokens": jnp.zeros((max_slots,), jnp.int32),
-        "rngs": jnp.zeros((max_slots, 2), jnp.uint32)}
+    slot vectors (``serving.slot_vectors``). Page tables stay on the
+    host."""
+    serving.refuse("latent_moe", SERVE_UNSUPPORTED, mesh, int8)
+    return {"latent": jnp.zeros((cfg.n_layers, n_pages, page_size,
+                                 cfg.row_stored), cfg.dtype),
+            **serving.slot_vectors(max_slots)}
 
 
-def copy_page(kv, src, dst):
-    """Pool page ``src`` onto page ``dst`` (``llama.copy_page``'s
-    contract; one program for any pair)."""
-    src = jnp.asarray(src, jnp.int32)
-    dst = jnp.asarray(dst, jnp.int32)
-    page = lax.dynamic_index_in_dim(kv["latent"], src, axis=1,
-                                    keepdims=False)
-    return dict(kv, latent=lax.dynamic_update_index_in_dim(
-        kv["latent"], page, dst, axis=1))
+copy_page = serving.copy_page
 
 
 def decode_logits(cfg: LatentMoEConfig, params, kv, sv, active,
@@ -550,7 +523,7 @@ def decode_logits(cfg: LatentMoEConfig, params, kv, sv, active,
     x = llama._embed(cfg, params, sv["tokens"][:, None])
     x, pool, counts, _ = _scan_layers(cfg, params, x, pool, attend,
                                       active[:, None])
-    return _final(cfg, params, x)[:, 0], pool, counts
+    return final_logits(cfg, params, x)[:, 0], pool, counts
 
 
 def decode_slots_paged(cfg: LatentMoEConfig, params, kv, sv, active,
@@ -565,72 +538,32 @@ def decode_slots_paged(cfg: LatentMoEConfig, params, kv, sv, active,
     del mesh
     logits, pool, counts = decode_logits(cfg, params, kv, sv, active,
                                          page_table)
-    new_rngs, sampled = llama._sample_slots(
-        sv["rngs"], logits, temperature, top_k, top_p)
-    # the busiest experts' loads as a share of the assignments, in
-    # millionths (the read-back is one int32 array)
-    share = (counts[2].astype(_F32) * 1e6
-             / jnp.maximum(counts[0], 1).astype(_F32)).astype(jnp.int32)
-    out = jnp.concatenate([sampled, counts[:2], share[None]])
-    return out, {"latent": pool}, {
-        "lengths": sv["lengths"].astype(jnp.int32)
-        + active.astype(jnp.int32),
-        "tokens": sampled, "rngs": new_rngs}
+    return serving.decode_step(logits, {"latent": pool}, sv, active,
+                               temperature, top_k, top_p, counts)
 
 
-@jax.named_scope(KV_WRITE_SCOPE)
-def _seat_rows(kv, rows, pages_row):
-    """A prefilled prompt's rows (L, 1, capacity, row_stored) into the
-    slot's pages; entries of ``pages_row`` past the granted ones name
-    scratch page 0, which is never attended."""
-    pool = kv["latent"]
-    ps = pool.shape[2]
-    rows = rows[:, 0]
-    pad = -rows.shape[1] % ps
-    rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
-    pages = rows.reshape(rows.shape[0], -1, ps, rows.shape[-1])
-    return {"latent": pool.at[:, pages_row[:pages.shape[1]]].set(
-        pages.astype(pool.dtype))}
+# -- admission: the run, and the first-token seat every token family shares -
+def _run(cfg: LatentMoEConfig, params, tokens, start, n_valid, stage, mesh):
+    """``serving``'s run: every layer over the tokens, its rows into the
+    stage's store; the head reads the last valid position alone."""
+    del mesh
+    rows = (_fresh_rows(cfg, *tokens.shape) if stage is None
+            else stage["latent"])
+    x, rows, _ = _sequence_layers(cfg, params, tokens, start, rows, n_valid)
+    return serving.last_logits(final_logits, cfg, params, x, n_valid), \
+        {"latent": rows}
 
 
-def _seat_first(cfg, params, x, rows, n_valid, true_len, pages_row, slot,
-                kv, sv, rng, temperature, top_k, top_p):
-    """The end of an admission: the logits of position ``n_valid - 1``
-    of x (1, s, dim), the first token sampled from them, the prompt's
-    rows seated, the slot's length, token and rng chain set. Returns
-    (first token (1,), new kv, new sv)."""
-    last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-    logits = _final(cfg, params, last)[:, 0]
-    rng, sub = jax.random.split(rng)
-    tok = llama.sample_logits(sub, logits, temperature=temperature,
-                              top_k=top_k, top_p=top_p)
-    z = jnp.zeros((), jnp.int32)
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None], (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], tok.astype(sv["tokens"].dtype), (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype), (slot, z))}
-    return tok, _seat_rows(kv, rows, pages_row), new_sv
+_end = partial(serving._seat_first, serving.seat_pools, vectors_first=True)
 
 
 def prefill_slot_paged(cfg: LatentMoEConfig, params, tokens, true_len,
                        prefix_len, pages_row, slot, kv, sv, rng,
                        temperature, top_k, top_p, mesh=None):
-    """Admission: ``llama.prefill_slot_paged``'s contract, cold only
-    (``prefix_len`` is 0: the engine refuses a prefix cache for this
-    family). Every layer over the prompt (END-padded to its bucket; a
-    padding token is routed nowhere and its row is never attended), the
-    head on the last position alone. Returns (first token (1,), new kv,
-    new sv)."""
-    del prefix_len, mesh
-    true_len = jnp.asarray(true_len, jnp.int32)
-    x, rows, _ = _sequence_layers(
-        cfg, params, tokens, 0, _fresh_rows(cfg, *tokens.shape), true_len)
-    return _seat_first(cfg, params, x, rows, true_len, true_len, pages_row,
-                       jnp.asarray(slot, jnp.int32), kv, sv, rng,
-                       temperature, top_k, top_p)
+    """Admission of a whole prompt (``serving.prefill_whole``)."""
+    return serving.prefill_whole(
+        _run, _end, cfg, params, tokens, true_len, prefix_len, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)
 
 
 # -- a prompt in chunks: the stall a running request sees is one chunk's ----
@@ -639,40 +572,26 @@ def init_prefill_stage(cfg: LatentMoEConfig, capacity: int, chunk: int):
     its cache rows until its last chunk seats them, outside the pool (a
     decode step in between runs over every slot's pages): (L, 1,
     capacity, row_stored)."""
-    if capacity % chunk or chunk % _kv_block(chunk):
+    if capacity % chunk or chunk % kv_block(chunk):
         raise ValueError(
             f"a prefill chunk ({chunk}) divides the slot's capacity "
             f"({capacity}) and is a whole number of key blocks "
-            f"({_kv_block(chunk)})")
+            f"({kv_block(chunk)})")
     return {"latent": jnp.zeros(
         (cfg.n_layers, 1, capacity, cfg.row_stored), cfg.dtype)}
 
 
 def prefill_slot_paged_chunk(cfg: LatentMoEConfig, params, tokens, start,
                              stage, mesh=None):
-    """One whole chunk of a prompt that is not its last: every layer
-    over tokens (1, chunk) at positions ``start ..``, each attending to
-    the rows the chunks before left in the stage and to its own. The
-    pool is not touched."""
-    del mesh
-    _, rows, _ = _sequence_layers(cfg, params, tokens, start,
-                                  stage["latent"], tokens.shape[1])
-    return {"latent": rows}
+    """A chunk that is not the prompt's last (``serving.prefill_chunk``)."""
+    return serving.prefill_chunk(_run, cfg, params, tokens, start, stage,
+                                 mesh)
 
 
 def prefill_slot_paged_last(cfg: LatentMoEConfig, params, tokens, start,
                             n_valid, stage, pages_row, slot, kv, sv, rng,
                             temperature, top_k, top_p, mesh=None):
-    """A prompt's last chunk, ``n_valid`` tokens END-padded to tokens
-    (1, chunk), at positions ``start ..``; then the admission's end as
-    ``prefill_slot_paged``'s: the whole prompt's rows seated into the
-    slot's pages, the first token sampled. Returns (first token (1,),
-    new kv, new sv)."""
-    del mesh
-    start = jnp.asarray(start, jnp.int32)
-    n_valid = jnp.asarray(n_valid, jnp.int32)
-    x, rows, _ = _sequence_layers(cfg, params, tokens, start,
-                                  stage["latent"], n_valid)
-    return _seat_first(cfg, params, x, rows, n_valid, start + n_valid,
-                       pages_row, jnp.asarray(slot, jnp.int32), kv, sv, rng,
-                       temperature, top_k, top_p)
+    """A prompt's last chunk and its seat (``serving.prefill_last``)."""
+    return serving.prefill_last(
+        _run, _end, cfg, params, tokens, start, n_valid, stage, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)

@@ -43,7 +43,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import telemetry
-from ..ops.threshold import thresholds
 from ..ops.attention import (flash_attention, dense_attention,
                              ring_attention, ulysses_attention,
                              slot_decode_attention,
@@ -54,6 +53,9 @@ from ..parallel.sharding import (ShardingRules, bytes_per_device,
                                  constrain)
 from ..parallel.step import traced_state_bytes
 from ..parallel.sharding import mcon as _mcon
+from . import serving
+from .serving import (KV_WRITE_SCOPE, SAMPLER_SCOPE, copy_page,
+                      sample_logits)
 
 __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
            "loss_fn", "chunked_softmax_xent", "sharding_rules",
@@ -63,7 +65,7 @@ __all__ = ["LlamaConfig", "init_params", "forward", "forward_hidden",
            "quantize_params_int8", "int8_sharding_rules",
            "sample_logits", "prefill_detached",
            "prefill_detached_chunk", "paged_cache_specs", "init_paged_cache",
-           "decode_slots_paged", "prefill_slot_paged",
+           "decode_slots_paged", "prefill_slot_paged", "final_logits",
            "inject_paged_kv", "copy_page", "decode_slots_spec",
            "decode_attention_path"]
 
@@ -244,8 +246,7 @@ _QKV = P(("dp", "fsdp"), "tp", "sp", None)      # (batch, heads, seq, hd)
 # operations back to them, so train and serve report under one set of
 # names. They do not nest (but for the int8 prefill's read of old pages
 # inside kv_write), so an operation's scope is the first of its path.
-KV_WRITE_SCOPE = "kv_write"
-SAMPLER_SCOPE = "sampler"
+# (kv_write and sampler are ``models/serving.py``'s: every family's.)
 
 
 @jax.named_scope("norm")
@@ -319,6 +320,13 @@ def _lm_head(cfg: LlamaConfig, params, x):
           else _wq8(params["lm_head"], cfg.dtype))
     return jnp.einsum("bsd,dv->bsv", x, hw,
                       preferred_element_type=jnp.float32)
+
+
+def final_logits(cfg, params, x):
+    """The final RMSNorm and the head: x (b, s, dim) -> logits (b, s,
+    V) float32 (every family whose head is llama's)."""
+    return _lm_head(cfg, params, rms_norm(x, params["final_norm"],
+                                          cfg.norm_eps))
 
 
 @jax.named_scope(ATTENTION_SCOPE)
@@ -1047,132 +1055,6 @@ def decode_step(cfg: LlamaConfig, params, token, cache,
     return logits[:, 0], cache
 
 
-@jax.named_scope(SAMPLER_SCOPE)
-def sample_logits(rng, lg, temperature=0.0, top_k=None, top_p=None,
-                  mesh: Optional[Mesh] = None):
-    """THE sampler — one shared helper for :func:`generate` and the
-    continuous-batching serving engine (``mxtpu.serve``). lg: (b, V)
-    f32 logits → (b,) int32 tokens.
-
-    Two calling modes, numerically aligned token-for-token:
-
-    - **static** (all of temperature/top_k/top_p are Python numbers or
-      None): specializes the jitted graph per config — greedy compiles
-      to a bare argmax, top-k uses ``lax.top_k`` — the fast path
-      ``generate``'s one-program decode loop wants.
-    - **traced** (any of them a jax/numpy array): one graph serves
-      every per-row mix — temperature (b,), top_k (b,) ints (vocab
-      size disables), top_p (b,) (1.0 disables), with temperature 0
-      rows selecting argmax. This is how the serving engine runs
-      requests with different sampling configs through ONE compiled
-      decode program, with tokens bit-matching the static path: the
-      top-k threshold is the same kth VALUE, the nucleus cut-off the
-      same search, so the masked logits agree and
-      ``jax.random.categorical`` sees identical inputs.
-
-    Nucleus semantics (both modes): keep the smallest prefix of the
-    sorted distribution whose mass reaches p (the top token always
-    survives, a tie-class is kept or cut as a whole), applied as a
-    value threshold rather than a full-vocab scatter.
-
-    No order is built. The sampler needs two numbers a row, the k-th
-    largest value and the nucleus cut-off, and both are monotone in the
-    threshold: the COUNT of values at or above ``v`` and the MASS of
-    values above ``v`` only fall as ``v`` rises. ``ops.threshold``
-    finds each by bisection on the float32's ordered bit pattern, 32
-    probes of a compare, a select and a sum over the row (on a TPU one
-    Pallas kernel with the rows resident in VMEM, for a block of eight
-    rows or more; ``jnp`` elsewhere and for fewer),
-    exact for any distribution, and skips a search that no row asks for
-    (``top_k`` off, ``top_p`` 1, a greedy row). A sort of a 200064-wide
-    row was the costliest operation of a decode step (PR 33), and a
-    permutation with a ``take_along_axis`` through it a
-    vocabulary-sized gather a row besides. ``mesh``: the mesh the
-    logits are sharded over, if any (the kernel is not partitioned)."""
-    static = (isinstance(temperature, (int, float))
-              and (top_k is None or isinstance(top_k, int))
-              and (top_p is None or isinstance(top_p, (int, float))))
-    V = lg.shape[-1]
-    if static:
-        if temperature == 0.0:
-            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        lg = lg / temperature
-        if top_k is not None and top_k < V:
-            kth = lax.top_k(lg, top_k)[0][..., -1:]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        if top_p is not None and top_p < 1.0:
-            lg = _nucleus_mask(lg, top_p, mesh)
-        return jax.random.categorical(rng, lg, axis=-1) \
-            .astype(jnp.int32)
-    greedy, slg = _masked_logits(lg, temperature, top_k, top_p, mesh)
-    sampled = jax.random.categorical(rng, slg, axis=-1) \
-        .astype(jnp.int32)
-    return jnp.where(greedy >= 0, greedy, sampled)
-
-
-def _masked_logits(lg, temperature, top_k, top_p, mesh=None):
-    """The traced mode's rows as ``categorical`` will see them. lg: (b,
-    V); temperature, top_k, top_p: None, scalars or (b,). Returns
-    (greedy (b,) int32: the argmax of a temperature-0 row, -1 for a row
-    that samples; the logits over the temperature, masked to the top-k
-    values and, of those, the top-p nucleus)."""
-    V = lg.shape[-1]
-
-    def col(x, dtype):          # broadcast a scalar or (b,) over vocab
-        x = jnp.asarray(x, dtype)
-        x = x.reshape(x.shape + (1,) * (lg.ndim - x.ndim))
-        return jnp.broadcast_to(x, lg.shape[:-1] + (1,))
-
-    t_col = col(temperature, jnp.float32)
-    samples = t_col != 0.0
-    # a greedy row asks for no threshold: its argmax is its token
-    k_col = jnp.where(samples, jnp.clip(
-        col(V if top_k is None else top_k, jnp.int32), 1, V), V)
-    p_col = jnp.where(samples,
-                      col(1.0 if top_p is None else top_p, jnp.float32),
-                      1.0)
-    greedy = jnp.where(jnp.squeeze(samples, -1), -1,
-                       jnp.argmax(lg, axis=-1).astype(jnp.int32))
-    slg = lg / jnp.where(samples, t_col, 1.0)
-    # top-k as a value threshold: the kth-largest VALUE equals
-    # lax.top_k's kth element, so the mask matches the static path;
-    # the nucleus cut-off is found over the top-k survivors
-    kth, cutoff = thresholds(slg, k_col, p_col, mesh=mesh)
-    return greedy, jnp.where(slg >= jnp.maximum(kth, cutoff), slg,
-                             -jnp.inf)
-
-
-def _nucleus_mask(lg, top_p, mesh=None):
-    """Mask lg to the top-p nucleus, the survivor set applied as a >=
-    threshold on the kept minimum — no full-vocab scatter."""
-    rows = lg.shape[:-1] + (1,)
-    _, cutoff = thresholds(lg, jnp.full(rows, lg.shape[-1], jnp.int32),
-                           jnp.full(rows, top_p, jnp.float32), mesh=mesh)
-    return jnp.where(lg >= cutoff, lg, -jnp.inf)
-
-
-def _draw(key, greedy, row):
-    """One token of one chain: ``greedy`` where the row is greedy, else
-    ``categorical`` on the masked (V,) row, as :func:`sample_logits`
-    draws it from a (1, V) batch."""
-    tok = jax.random.categorical(key, row[None], axis=-1)[0] \
-        .astype(jnp.int32)
-    return jnp.where(greedy >= 0, greedy, tok)
-
-
-@jax.named_scope(SAMPLER_SCOPE)
-def _sample_slots(rngs, lg, temperature, top_k, top_p,
-                  mesh: Optional[Mesh] = None):
-    """A decode step's sample for a bank of slots, mirroring generate's
-    step slot by slot: the two thresholds of every row found at once
-    over the (S, V) block, then each slot splits its own chain and
-    draws from its own masked row, so equal masks give equal tokens.
-    Returns (carry keys (S, 2), tokens (S,))."""
-    greedy, masked = _masked_logits(lg, temperature, top_k, top_p, mesh)
-    keys = jax.vmap(jax.random.split)(rngs)
-    return keys[:, 0], jax.vmap(_draw)(keys[:, 1], greedy, masked)
-
-
 def generate(cfg: LlamaConfig, params, prompt, max_new_tokens: int,
              *, temperature: float = 0.0,
              top_k: Optional[int] = None,
@@ -1417,11 +1299,7 @@ def init_paged_cache(cfg: LlamaConfig, max_slots: int, n_pages: int,
         else:
             pools = {"k": jnp.zeros(shape, cfg.dtype),
                      "v": jnp.zeros(shape, cfg.dtype)}
-        pools.update({
-            "lengths": jnp.zeros((max_slots,), jnp.int32),
-            "tokens": jnp.zeros((max_slots,), jnp.int32),
-            "rngs": jnp.zeros((max_slots, 2), jnp.uint32)})
-        return pools
+        return dict(pools, **serving.slot_vectors(max_slots))
 
     if mesh is None:
         return build()
@@ -1467,20 +1345,8 @@ def _gather_slot_pages(pool, scales, pages_row, dt):
 
 
 @jax.named_scope(KV_WRITE_SCOPE)
-def _write_pages(ck, cv, knew, vnew, layer, phys, off):
-    """The new tokens' K/V into the whole (L, n_pages, ps, kvh, hd)
-    pools: token i of layer ``layer`` at in-page offset ``off[i]`` of
-    page ``phys[i]``. One scatter whose indexed dimensions are the
-    pool's leading ones, so the donated (and loop-carried) pool is
-    updated in place in the layout it is stored in."""
-    ck = ck.at[layer, phys, off].set(knew.astype(ck.dtype))
-    cv = cv.at[layer, phys, off].set(vnew.astype(cv.dtype))
-    return ck, cv
-
-
-@jax.named_scope(KV_WRITE_SCOPE)
 def _write_pages_q8(ck, cv, cks, cvs, knew, vnew, layer, phys, off):
-    """:func:`_write_pages` for an int8 pool: quantise per token, write
+    """``serving.write_pages`` for an int8 pool: quantise per token, write
     the bytes and their scales."""
     kq, ksc = _q8_token(knew)
     vq, vsc = _q8_token(vnew)
@@ -1562,7 +1428,7 @@ def _layer_slots_paged(cfg: LlamaConfig, cos, sin, pos, phys, off,
         vf = _gather_slot_pages_batch(cv, cvs, layer, page_table, dt)
         o = slot_decode_attention(q, kf, vf, pos + 1)
     else:
-        ck, cv = _write_pages(ck, cv, knew, vnew, layer, phys, off)
+        ck, cv = serving.write_pages(ck, cv, knew, vnew, layer, phys, off)
         if mesh is not None:
             from jax.sharding import NamedSharding
             ck = lax.with_sharding_constraint(
@@ -1659,18 +1525,15 @@ def decode_slots_paged(cfg: LlamaConfig, params, kv, sv, active,
     x, new_kv = _scan_paged_layers(cfg, params, kv, x, partial(
         _layer_slots_paged, cfg, cos, sin, pos, phys, off, page_table,
         mesh, kvspec))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _lm_head(cfg, params, x)[:, 0]
-
-    new_rngs, sampled = _sample_slots(
-        sv["rngs"], logits, temperature, top_k, top_p, mesh)
-    new_lengths = lengths + active.astype(jnp.int32)
+    sampled, new_kv, new_sv = serving.decode_step(
+        final_logits(cfg, params, x)[:, 0], new_kv, sv, active, temperature,
+        top_k, top_p, mesh=mesh)
     if mesh is not None:
         sampled = _mcon(mesh, sampled, None)
-        new_lengths = _mcon(mesh, new_lengths, None)
-        new_rngs = _mcon(mesh, new_rngs, None, None)
-    return sampled, new_kv, \
-        {"lengths": new_lengths, "tokens": sampled, "rngs": new_rngs}
+        new_sv = {"lengths": _mcon(mesh, new_sv["lengths"], None),
+                  "tokens": sampled,
+                  "rngs": _mcon(mesh, new_sv["rngs"], None, None)}
+    return sampled, new_kv, new_sv
 
 
 @jax.named_scope(KV_WRITE_SCOPE)
@@ -1686,9 +1549,7 @@ def _scatter_slot_pages(kv, pages_row, tmp_k, tmp_v, prefix_len,
     positions keep their RAW stored bytes — quantize∘dequant is not
     idempotent, so round-tripping shared pages would corrupt them."""
     cap = tmp_k.shape[3]
-    to_pages = partial(_rows_to_pages, ps=kv["k"].shape[2])
     kd, vd = tmp_k[:, 0], tmp_v[:, 0]     # (L, kvh, cap, hd)
-    out = dict(kv)
     if int8:
         kq, ksc = _q8_token(kd)           # (L, kvh, cap, hd)/(L,kvh,cap)
         vq, vsc = _q8_token(vd)
@@ -1702,16 +1563,18 @@ def _scatter_slot_pages(kv, pages_row, tmp_k, tmp_v, prefix_len,
         vq = jnp.where(written[None, None, :, None], vq, old_v)
         ksc = jnp.where(written[None, None, :], ksc, old_ks)
         vsc = jnp.where(written[None, None, :], vsc, old_vs)
-        out["k"] = kv["k"].at[:, pages_row].set(to_pages(kq))
-        out["v"] = kv["v"].at[:, pages_row].set(to_pages(vq))
-        out["ks"] = kv["ks"].at[:, pages_row].set(to_pages(ksc))
-        out["vs"] = kv["vs"].at[:, pages_row].set(to_pages(vsc))
-    else:
-        out["k"] = kv["k"].at[:, pages_row].set(
-            to_pages(kd.astype(kv["k"].dtype)))
-        out["v"] = kv["v"].at[:, pages_row].set(
-            to_pages(vd.astype(kv["v"].dtype)))
-    return out
+        return _set_pages(kv, pages_row, {"k": kq, "v": vq, "ks": ksc,
+                                          "vs": vsc})
+    return _set_pages(kv, pages_row, {"k": kd, "v": vd})
+
+
+def _set_pages(kv, dst, rows):
+    """Contiguous head-major rows (L, kvh, n, ..) by pool name, in the
+    pool's dtype, over the pools' pages ``dst``: the other arrays of
+    ``kv`` as they are."""
+    ps = kv["k"].shape[2]
+    return dict(kv, **{n: kv[n].at[:, dst].set(
+        _rows_to_pages(a.astype(kv[n].dtype), ps)) for n, a in rows.items()})
 
 
 @jax.named_scope(KV_GATHER_SCOPE)
@@ -1774,26 +1637,20 @@ def prefill_slot_paged(cfg: LlamaConfig, params, tokens, true_len,
                         top_k=top_k, top_p=top_p, mesh=mesh)
     new_kv = _scatter_slot_pages(kv, pages_row, tmp["k"], tmp["v"],
                                  prefix_len, bucket, int8)
-    z = jnp.zeros((), jnp.int32)
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None], (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], tok.astype(sv["tokens"].dtype), (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype), (slot, z)),
-    }
+    new_sv = serving.seat_slot(sv, slot, true_len, tok, rng)
     if mesh is not None:
-        from jax.sharding import NamedSharding
-        specs = paged_cache_specs(cfg, mesh)
-        new_kv = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n]))
-            for n, a in new_kv.items()}
-        new_sv = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n]))
-            for n, a in new_sv.items()}
+        new_kv, new_sv = _pinned(cfg, mesh, new_kv), _pinned(cfg, mesh,
+                                                             new_sv)
         tok = _mcon(mesh, tok, None)
     return tok, new_kv, new_sv
+
+
+def _pinned(cfg: LlamaConfig, mesh: Mesh, tree):
+    """Paged state or slot vectors pinned to :func:`paged_cache_specs`."""
+    from jax.sharding import NamedSharding
+    specs = paged_cache_specs(cfg, mesh)
+    return {n: lax.with_sharding_constraint(a, NamedSharding(mesh, specs[n]))
+            for n, a in tree.items()}
 
 
 def inject_paged_kv(cfg: LlamaConfig, k_block, v_block, true_len,
@@ -1821,58 +1678,16 @@ def inject_paged_kv(cfg: LlamaConfig, k_block, v_block, true_len,
     slot = jnp.asarray(slot, jnp.int32)
     token = jnp.asarray(token, jnp.int32)
     dst = pages_row[:n_blk]
-    to_pages = partial(_rows_to_pages, ps=ps)
-
-    out = dict(kv)
     if int8:
         kq, ksc = _q8_token(k_block)
         vq, vsc = _q8_token(v_block)
-        out["k"] = kv["k"].at[:, dst].set(to_pages(kq))
-        out["v"] = kv["v"].at[:, dst].set(to_pages(vq))
-        out["ks"] = kv["ks"].at[:, dst].set(to_pages(ksc))
-        out["vs"] = kv["vs"].at[:, dst].set(to_pages(vsc))
+        out = _set_pages(kv, dst, {"k": kq, "v": vq, "ks": ksc, "vs": vsc})
     else:
-        out["k"] = kv["k"].at[:, dst].set(
-            to_pages(k_block.astype(kv["k"].dtype)))
-        out["v"] = kv["v"].at[:, dst].set(
-            to_pages(v_block.astype(kv["v"].dtype)))
-    z = jnp.zeros((), jnp.int32)
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None], (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], token[None].astype(sv["tokens"].dtype),
-            (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype), (slot, z)),
-    }
+        out = _set_pages(kv, dst, {"k": k_block, "v": v_block})
+    new_sv = serving.seat_slot(sv, slot, true_len, token[None], rng)
     if mesh is not None:
-        from jax.sharding import NamedSharding
-        specs = paged_cache_specs(cfg, mesh)
-        out = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n])) for n, a in out.items()}
-        new_sv = {n: lax.with_sharding_constraint(
-            a, NamedSharding(mesh, specs[n]))
-            for n, a in new_sv.items()}
+        out, new_sv = _pinned(cfg, mesh, out), _pinned(cfg, mesh, new_sv)
     return out, new_sv
-
-
-def copy_page(kv, src, dst):
-    """Copy pool page ``src`` onto page ``dst`` across every pool array
-    — the engine's copy-on-write fork primitive (one compiled program
-    for any src/dst: both are traced scalars). Only pool arrays (page
-    axis 1) are touched; per-slot vectors pass through untouched."""
-    src = jnp.asarray(src, jnp.int32)
-    dst = jnp.asarray(dst, jnp.int32)
-    out = dict(kv)
-    for n in ("k", "v", "ks", "vs"):
-        if n in kv:
-            a = kv[n]
-            page = lax.dynamic_index_in_dim(a, src, axis=1,
-                                            keepdims=False)
-            out[n] = lax.dynamic_update_index_in_dim(a, page, dst,
-                                                     axis=1)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1946,8 +1761,7 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
     x, new_kv = _scan_paged_layers(cfg, params, kv, x, partial(
         _layer_slots_paged, cfg, cos, sin, wpos, phys, off,
         page_table, mesh, kvspec))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = _lm_head(cfg, params, x)                 # (S, W, V)
+    logits = final_logits(cfg, params, x)             # (S, W, V)
 
     # accept oracle: scan the W per-position logits down the slot's rng
     # chain. ok carries "all earlier drafts matched"; the key advances
@@ -1961,7 +1775,7 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
     # the scan below only splits and draws
     with jax.named_scope(SAMPLER_SCOPE):
         per_pos = lambda a: jnp.repeat(a, W)
-        greedy, masked = _masked_logits(
+        greedy, masked = serving._masked_logits(
             logits.reshape(S * W, -1), per_pos(temperature),
             per_pos(top_k), per_pos(top_p), mesh)
         greedy, masked = greedy.reshape(S, W), masked.reshape(S, W, -1)
@@ -1972,7 +1786,7 @@ def decode_slots_spec(cfg: LlamaConfig, params, kv, sv, active,
             row, g, nd, h = inp
             with jax.named_scope(SAMPLER_SCOPE):
                 key2, sub = jax.random.split(key)
-                tok = _draw(sub, g, row)
+                tok = serving._draw(sub, g, row)
             emit = ok
             key = jnp.where(emit, key2, key)
             ok = ok & h & (tok == nd)

@@ -42,11 +42,8 @@ that grows with the sequence: a token's KV heads end to end, 256 values
 at the published widths, whole lane tiles, read in place by
 ``ops.paged_attention.paged_attention_rows`` on a TPU), and a fixed
 convolution tail and float32 SSM state a Mamba layer and slot, read and
-written in place by the decode step. The serving surface is
-``llama.py``'s (``init_params``, ``forward``, ``init_paged_cache``,
-``decode_attention_path``, ``decode_slots_paged``,
-``prefill_slot_paged``, ``copy_page``) and sambay's chunk surface
-(``init_prefill_stage``, ``prefill_slot_paged_chunk``, ``.._last``): a
+written in place by the decode step. The module keeps
+``models/serving.py``'s family contract, prompts in chunks included: a
 chunk's keys and values, convolution tails and states wait in the stage,
 and the last chunk seats them into the slot. The decode program counts
 what the router did, as ``latent_moe``'s does (``STEP_COUNTS``). The
@@ -57,6 +54,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, ClassVar, Optional
 
 import jax
@@ -67,9 +65,10 @@ from ..ops.attention import (ATTENTION_SCOPE, block_causal_rows_attention,
                              gathered_rows_decode_attention, rows_decode_path)
 from ..ops.ssm import causal_conv1d, ssd_chunked, ssd_step
 from ..parallel import moe
-from . import llama
+from . import llama, serving
 from .latent_moe import STEP_COUNTS
-from .llama import KV_WRITE_SCOPE, rms_norm
+from .llama import final_logits, rms_norm
+from .serving import KV_WRITE_SCOPE, kv_block, neg_exp, normal
 
 __all__ = ["NemotronHConfig", "CONFIGS", "init_params", "forward",
            "init_paged_cache", "prefill_slot_paged", "init_prefill_stage",
@@ -186,10 +185,6 @@ CONFIGS = {
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
-def _normal(key, shape, fan_in, dtype):
-    return jax.random.normal(key, shape, dtype) / math.sqrt(fan_in)
-
-
 def _init_mamba(key, cfg: NemotronHConfig, n: int, fan_out: int):
     ks = jax.random.split(key, 5)
     d, D, H = cfg.param_dtype, cfg.dim, cfg.mamba_heads
@@ -201,16 +196,16 @@ def _init_mamba(key, cfg: NemotronHConfig, n: int, fan_out: int):
         * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3)), 1e-4)
     return {
         "norm": jnp.ones((n, D), d),
-        "in_proj": _normal(ks[1], (n, D, cfg.d_inner + cfg.conv_dim + H),
+        "in_proj": normal(ks[1], (n, D, cfg.d_inner + cfg.conv_dim + H),
                            D, d),
-        "conv_w": _normal(ks[2], (n, cfg.conv_dim, cfg.d_conv), cfg.d_conv,
+        "conv_w": normal(ks[2], (n, cfg.conv_dim, cfg.d_conv), cfg.d_conv,
                           d),
         "conv_b": jnp.zeros((n, cfg.conv_dim), d),
         "dt_bias": step + jnp.log(-jnp.expm1(-step)),
         "A_log": jnp.log(jax.random.uniform(ks[3], (n, H), _F32, 1.0, 16.0)),
         "D": jnp.ones((n, H), _F32),
         "gnorm": jnp.ones((n, cfg.d_inner), d),
-        "out_proj": _normal(ks[4], (n, cfg.d_inner, D), cfg.d_inner * fan_out,
+        "out_proj": normal(ks[4], (n, cfg.d_inner, D), cfg.d_inner * fan_out,
                             d)}
 
 
@@ -231,14 +226,14 @@ def _init_moe(key, cfg: NemotronHConfig, n: int, fan_out: int):
     F, Fs = cfg.moe_hidden_dim, cfg.shared_hidden_dim
     return {
         "norm": jnp.ones((n, D), d),
-        "router": _normal(ks[0], (n, D, E), D, d),
+        "router": normal(ks[0], (n, D, E), D, d),
         # the selection bias, float32, normal x 0.01 (latent_moe's reason)
         "router_bias": 0.01 * jax.random.normal(ks[1], (n, E), _F32),
         # the up projection stored transposed (``parallel.moe.relu2``)
-        "w_up_t": _normal(ks[2], (n, E, F, D), D, d),
-        "w_down": _centred(_normal(ks[3], (n, E, F, D), F * fan_out, d)),
-        "ws_up": _normal(ks[4], (n, D, Fs), D, d),
-        "ws_down": _centred(_normal(ks[5], (n, Fs, D), Fs * fan_out, d))}
+        "w_up_t": normal(ks[2], (n, E, F, D), D, d),
+        "w_down": _centred(normal(ks[3], (n, E, F, D), F * fan_out, d)),
+        "ws_up": normal(ks[4], (n, D, Fs), D, d),
+        "ws_down": _centred(normal(ks[5], (n, Fs, D), Fs * fan_out, d))}
 
 
 def _init_attn(key, cfg: NemotronHConfig, n: int, fan_out: int):
@@ -246,10 +241,10 @@ def _init_attn(key, cfg: NemotronHConfig, n: int, fan_out: int):
     d, D, hd = cfg.param_dtype, cfg.dim, cfg.head_dim
     return {
         "norm": jnp.ones((n, D), d),
-        "wq": _normal(ks[0], (n, D, cfg.n_heads * hd), D, d),
-        "wk": _normal(ks[1], (n, D, cfg.kv_width), D, d),
-        "wv": _normal(ks[2], (n, D, cfg.kv_width), D, d),
-        "wo": _normal(ks[3], (n, cfg.n_heads * hd, D),
+        "wq": normal(ks[0], (n, D, cfg.n_heads * hd), D, d),
+        "wk": normal(ks[1], (n, D, cfg.kv_width), D, d),
+        "wv": normal(ks[2], (n, D, cfg.kv_width), D, d),
+        "wo": normal(ks[3], (n, cfg.n_heads * hd, D),
                       cfg.n_heads * hd * fan_out, d)}
 
 
@@ -264,14 +259,14 @@ def init_params(cfg: NemotronHConfig, rng: Optional[jax.Array] = None):
     ks = jax.random.split(rng, 5)
     fan = 2 * cfg.n_layers
     params = {
-        "tok_embed": _normal(ks[0], (cfg.vocab_size, cfg.dim), cfg.dim,
+        "tok_embed": normal(ks[0], (cfg.vocab_size, cfg.dim), cfg.dim,
                              cfg.param_dtype),
         "mamba": _init_mamba(ks[1], cfg, cfg.count("M"), fan),
         "moe": _init_moe(ks[2], cfg, cfg.count("E"), fan),
         "attn": _init_attn(ks[3], cfg, cfg.count("*"), fan),
         "final_norm": jnp.ones((cfg.dim,), cfg.param_dtype)}
     if not cfg.tie_embeddings:
-        params["lm_head"] = _normal(ks[4], (cfg.dim, cfg.vocab_size),
+        params["lm_head"] = normal(ks[4], (cfg.dim, cfg.vocab_size),
                                     cfg.dim, cfg.param_dtype)
     return params
 
@@ -281,10 +276,6 @@ def _layer(stack, i):
     which XLA reads inside the products that take it."""
     return {n: a[i] for n, a in stack.items()}
 
-
-def _final(cfg, params, x):
-    return llama._lm_head(cfg, params, rms_norm(x, params["final_norm"],
-                                                cfg.norm_eps))
 
 
 # ---------------------------------------------------------------------------
@@ -315,10 +306,6 @@ def _mamba_out(cfg: NemotronHConfig, lp, y, z):
     g = g * lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + cfg.norm_eps)
     g = g.reshape(b, s, -1).astype(cfg.dtype) * lp["gnorm"].astype(cfg.dtype)
     return g @ lp["out_proj"]
-
-
-def _neg_exp(a_log):
-    return -jnp.exp(a_log.astype(_F32))
 
 
 def _shared_expert(lp, h):
@@ -373,12 +360,6 @@ def _out_proj(cfg: NemotronHConfig, lp, o):
 # ---------------------------------------------------------------------------
 # whole sequences: forward and the prefills
 # ---------------------------------------------------------------------------
-def _kv_block(s: int) -> int:
-    """Keys a step of the prefill attention's loop reads, for a run of s
-    queries; the stage's capacity is a multiple of it."""
-    return min(512, s)
-
-
 def _fresh_stage(cfg: NemotronHConfig, b: int, capacity: int):
     """Empty carried state of b sequences: the attention layers' rows
     (A, b, capacity, kv_width), the Mamba layers' tails and states."""
@@ -403,7 +384,7 @@ def _mamba_seq(cfg: NemotronHConfig, lp, x, n_valid, tail0, state0):
         xs, dt, B, C, z, padded = _mamba_inputs(cfg, lp, h, tail0)
         dt = jnp.where((jnp.arange(s) < n_valid)[None, :, None], dt, 0.0)
         with jax.named_scope(SSM_STATE_SCOPE):
-            y, state = ssd_chunked(xs, dt, _neg_exp(lp["A_log"]), B, C,
+            y, state = ssd_chunked(xs, dt, neg_exp(lp["A_log"]), B, C,
                                    lp["D"], state0, chunk=cfg.ssd_chunk)
         # the d_conv-1 inputs before position n_valid
         tail = lax.dynamic_slice_in_dim(padded, n_valid, cfg.d_conv - 1,
@@ -470,7 +451,7 @@ def _sequence(cfg: NemotronHConfig, params, tokens, start, n_valid, stage,
             with jax.named_scope(ATTENTION_SCOPE):
                 o = block_causal_rows_attention(
                     q, rows_k, rows_v, layer=i, q_offset=start, block=1,
-                    scale=cfg.scale, kv_block=_kv_block(s))
+                    scale=cfg.scale, kv_block=kv_block(s))
             x = x + _out_proj(cfg, lp, o)
     if seen is not None:
         seen["picks"] = jnp.stack(picks) if picks else None
@@ -485,7 +466,7 @@ def _padded(cfg: NemotronHConfig, tokens):
     key blocks for them."""
     b, s = tokens.shape
     tokens = jnp.pad(tokens, ((0, 0), (0, -s % cfg.ssd_chunk)))
-    blk = _kv_block(tokens.shape[1])
+    blk = kv_block(tokens.shape[1])
     return tokens, _fresh_stage(cfg, b, -(-tokens.shape[1] // blk) * blk)
 
 
@@ -504,7 +485,7 @@ def forward(cfg: NemotronHConfig, params, tokens):
     s = tokens.shape[1]
     padded, stage = _padded(cfg, tokens)
     x, _, _ = _sequence(cfg, params, padded, 0, s, stage)
-    return _final(cfg, params, x[:, :s])
+    return final_logits(cfg, params, x[:, :s])
 
 
 def router_picks(cfg: NemotronHConfig, params, tokens):
@@ -557,15 +538,13 @@ def init_paged_cache(cfg: NemotronHConfig, max_slots: int, n_pages: int,
     """Device state for the paged serving engine, two kinds side by side:
     ``k``/``v`` the attention layers' page pools, (A, n_pages,
     page_size, n_kv_heads hd), token-major (``llama.init_paged_cache``'s
-    layout, so ``copy_page`` is ``llama``'s) with a token's heads end to
+    layout, so ``copy_page`` is ``serving``'s) with a token's heads end to
     end (2 heads of 128 laid out as (.., 2, 128) would be padded to
     (8, 128) tiles); ``conv`` (M, slots, d_conv-1, conv_dim) and ``ssm``
     (M, slots, H, p, n) in ``state_dtype``, the Mamba layers' state. Plus
-    the per-slot ``lengths``/``tokens``/``rngs`` of every family. Page
-    tables stay on the host."""
-    if mesh is not None or int8:
-        raise ValueError("nemotron_h: " + SERVE_UNSUPPORTED[
-            "mesh" if mesh is not None else "int8_pages"])
+    the slot vectors (``serving.slot_vectors``). Page tables stay on the
+    host."""
+    serving.refuse("nemotron_h", SERVE_UNSUPPORTED, mesh, int8)
     pool = (cfg.count("*"), n_pages, page_size, cfg.kv_width)
     M = cfg.count("M")
     return {
@@ -574,12 +553,10 @@ def init_paged_cache(cfg: NemotronHConfig, max_slots: int, n_pages: int,
                           cfg.dtype),
         "ssm": jnp.zeros((M, max_slots, cfg.mamba_heads, cfg.mamba_head_dim,
                           cfg.d_state), cfg.state_dtype),
-        "lengths": jnp.zeros((max_slots,), jnp.int32),
-        "tokens": jnp.zeros((max_slots,), jnp.int32),
-        "rngs": jnp.zeros((max_slots, 2), jnp.uint32)}
+        **serving.slot_vectors(max_slots)}
 
 
-copy_page = llama.copy_page       # pools only; the fixed state has no pages
+copy_page = serving.copy_page     # pools only; the fixed state has no pages
 
 
 def _mamba_step(cfg: NemotronHConfig, lp, x, conv, ssm, i, active):
@@ -593,7 +570,7 @@ def _mamba_step(cfg: NemotronHConfig, lp, x, conv, ssm, i, active):
         xs, dt, B, C, z, padded = _mamba_inputs(cfg, lp, h, tail)
         dt = jnp.where(active[:, None], dt[:, 0], 0.0)
         with jax.named_scope(SSM_STATE_SCOPE):
-            y, state = ssd_step(ssm[i], xs[:, 0], dt, _neg_exp(lp["A_log"]),
+            y, state = ssd_step(ssm[i], xs[:, 0], dt, neg_exp(lp["A_log"]),
                                 B[:, 0], C[:, 0], lp["D"])
             ssm = ssm.at[i].set(state.astype(ssm.dtype))
         conv = conv.at[i].set(jnp.where(active[:, None, None], padded[:, 1:],
@@ -646,11 +623,11 @@ def decode_logits(cfg: NemotronHConfig, params, kv, sv, active, page_table,
             q, k, v = _qkv(cfg, lp, rms_norm(x, lp["norm"], cfg.norm_eps),
                            pos[:, None])
             with jax.named_scope(KV_WRITE_SCOPE):
-                ck, cv = llama._write_pages(ck, cv, k[:, 0], v[:, 0], i,
-                                            phys, pos % ps)
+                ck, cv = serving.write_pages(ck, cv, k[:, 0], v[:, 0], i,
+                                             phys, pos % ps)
             o = _attend(cfg, q, ck, cv, page_table, pos + 1, i, path)
             x = x + _out_proj(cfg, lp, o)
-    return _final(cfg, params, x)[:, 0], {"k": ck, "v": cv, "conv": conv,
+    return final_logits(cfg, params, x)[:, 0], {"k": ck, "v": cv, "conv": conv,
                                           "ssm": ssm}, counts
 
 
@@ -664,76 +641,44 @@ def decode_slots_paged(cfg: NemotronHConfig, params, kv, sv, active,
     3,) int32; new kv; new sv)."""
     logits, new_kv, counts = decode_logits(cfg, params, kv, sv, active,
                                            page_table, mesh)
-    new_rngs, sampled = llama._sample_slots(
-        sv["rngs"], logits, temperature, top_k, top_p)
-    share = (counts[2].astype(_F32) * 1e6
-             / jnp.maximum(counts[0], 1).astype(_F32)).astype(jnp.int32)
-    out = jnp.concatenate([sampled, counts[:2], share[None]])
-    return out, new_kv, {
-        "lengths": sv["lengths"].astype(jnp.int32) + active.astype(jnp.int32),
-        "tokens": sampled, "rngs": new_rngs}
+    return serving.decode_step(logits, new_kv, sv, active, temperature,
+                               top_k, top_p, counts)
+
+
+# -- admission: the run, and the first-token seat every token family shares -
+def _run(cfg: NemotronHConfig, params, tokens, start, n_valid, stage, mesh):
+    """``serving``'s run: every layer over the tokens (a whole prompt:
+    padded to whole SSD chunks); the head reads the last valid position."""
+    del mesh
+    if stage is None:
+        tokens, stage = _padded(cfg, tokens)
+    x, stage, _ = _sequence(cfg, params, tokens, start, n_valid, stage)
+    return serving.last_logits(final_logits, cfg, params, x, n_valid), stage
 
 
 @jax.named_scope(KV_WRITE_SCOPE)
-def _seat_state(kv, stage, pages_row, slot):
-    """A prefilled prompt's state into ``slot``: ALL of the slot's tails
-    and states (whatever request held them before), and the stage's
-    keys and values (A, 1, capacity, width) into the slot's pages;
-    entries of ``pages_row`` past the granted ones name scratch page 0,
-    which is never attended."""
-    ps = kv["k"].shape[2]
-    out = dict(kv)
-    for name in ("k", "v"):
-        rows = stage[name][:, 0]
-        rows = jnp.pad(rows, ((0, 0), (0, -rows.shape[1] % ps), (0, 0)))
-        pages = rows.reshape(rows.shape[0], rows.shape[1] // ps, ps,
-                             rows.shape[-1])
-        out[name] = kv[name].at[:, pages_row[:pages.shape[1]]].set(
-            pages.astype(kv[name].dtype))
+def _seat(kv, stage, pages_row, slot):
+    """A prefilled prompt's state into ``slot``: the stage's keys and
+    values into the slot's pages (``serving.seat_pages``), and ALL of the
+    slot's tails and states (whatever request held them before)."""
+    out = {n: serving.seat_pages(kv[n], stage[n], pages_row)
+           for n in ("k", "v")}
     for name in ("conv", "ssm"):
         out[name] = lax.dynamic_update_index_in_dim(
             kv[name], stage[name][:, 0].astype(kv[name].dtype), slot, 1)
     return out
 
 
-def _seat_first(cfg, params, x, stage, n_valid, true_len, pages_row, slot,
-                kv, sv, rng, temperature, top_k, top_p):
-    """The end of an admission: the logits of position ``n_valid - 1`` of
-    x (1, s, dim), the first token sampled from them, the prompt's state
-    seated, the slot's length, token and rng chain set. Returns (first
-    token (1,), new kv, new sv)."""
-    last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-    logits = _final(cfg, params, last)[:, 0]
-    rng, sub = jax.random.split(rng)
-    tok = llama.sample_logits(sub, logits, temperature=temperature,
-                              top_k=top_k, top_p=top_p)
-    z = jnp.zeros((), jnp.int32)
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None], (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], tok.astype(sv["tokens"].dtype), (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype), (slot, z))}
-    return tok, _seat_state(kv, stage, pages_row, slot), new_sv
+_end = partial(serving._seat_first, _seat, vectors_first=True)
 
 
 def prefill_slot_paged(cfg: NemotronHConfig, params, tokens, true_len,
                        prefix_len, pages_row, slot, kv, sv, rng,
                        temperature, top_k, top_p, mesh=None):
-    """Admission: ``llama.prefill_slot_paged``'s contract, cold only
-    (``prefix_len`` is 0: the engine refuses a prefix cache for this
-    family). Every layer over the prompt (END-padded to its bucket; the
-    padding moves no state and is routed nowhere), the head on the last
-    position alone; the slot's state is overwritten whole. Returns
-    (first token (1,), new kv, new sv)."""
-    del prefix_len, mesh
-    true_len = jnp.asarray(true_len, jnp.int32)
-    padded, stage = _padded(cfg, tokens)
-    x, stage, _ = _sequence(cfg, params, padded, 0, true_len, stage)
-    return _seat_first(cfg, params, x, stage, true_len, true_len, pages_row,
-                       jnp.asarray(slot, jnp.int32), kv, sv, rng,
-                       temperature, top_k, top_p)
+    """Admission of a whole prompt (``serving.prefill_whole``)."""
+    return serving.prefill_whole(
+        _run, _end, cfg, params, tokens, true_len, prefix_len, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)
 
 
 # -- a prompt in chunks: the stall a running request sees is one chunk's ----
@@ -743,39 +688,26 @@ def init_prefill_stage(cfg: NemotronHConfig, capacity: int, chunk: int):
     between computes on every slot): the attention layers' keys and
     values of up to ``capacity`` positions, one slot's tails and
     states. ``prefill_slot_paged_last`` seats it."""
-    if (capacity % chunk or chunk % _kv_block(chunk)
+    if (capacity % chunk or chunk % kv_block(chunk)
             or chunk % cfg.ssd_chunk):
         raise ValueError(
             f"a prefill chunk ({chunk}) divides the slot's capacity "
             f"({capacity}) and is a whole number of key blocks "
-            f"({_kv_block(chunk)}) and of SSD chunks ({cfg.ssd_chunk})")
+            f"({kv_block(chunk)}) and of SSD chunks ({cfg.ssd_chunk})")
     return _fresh_stage(cfg, 1, capacity)
 
 
 def prefill_slot_paged_chunk(cfg: NemotronHConfig, params, tokens, start,
                              stage, mesh=None):
-    """One whole chunk of a prompt that is not its last: every layer
-    over tokens (1, chunk) at positions ``start ..``, from the stage as
-    the chunks before left it (``start`` 0: from nothing) to the stage
-    the next chunk goes on from. The slot bank is not touched."""
-    del mesh
-    _, stage, _ = _sequence(cfg, params, tokens, start, tokens.shape[1],
-                            stage)
-    return stage
+    """A chunk that is not the prompt's last (``serving.prefill_chunk``)."""
+    return serving.prefill_chunk(_run, cfg, params, tokens, start, stage,
+                                 mesh)
 
 
 def prefill_slot_paged_last(cfg: NemotronHConfig, params, tokens, start,
                             n_valid, stage, pages_row, slot, kv, sv, rng,
                             temperature, top_k, top_p, mesh=None):
-    """A prompt's last chunk, ``n_valid`` tokens END-padded to tokens
-    (1, chunk), at positions ``start ..``; then the admission's end as
-    ``prefill_slot_paged``'s: the whole prompt's state seated into the
-    slot, the first token sampled. Returns (first token (1,), new kv,
-    new sv)."""
-    del mesh
-    start = jnp.asarray(start, jnp.int32)
-    n_valid = jnp.asarray(n_valid, jnp.int32)
-    x, stage, _ = _sequence(cfg, params, tokens, start, n_valid, stage)
-    return _seat_first(cfg, params, x, stage, n_valid, start + n_valid,
-                       pages_row, jnp.asarray(slot, jnp.int32), kv, sv, rng,
-                       temperature, top_k, top_p)
+    """A prompt's last chunk and its seat (``serving.prefill_last``)."""
+    return serving.prefill_last(
+        _run, _end, cfg, params, tokens, start, n_valid, stage, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)

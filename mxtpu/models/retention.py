@@ -13,20 +13,19 @@ head's query heads. NO layer holds keys or values: a sequence's whole
 cache is ``(S, z)``, ``n_kv_heads x F x (head_dim + 1)`` values a layer
 (F = ``ops.retention.sympow2_rows(head_dim)``), whatever its length.
 
-The serving surface is ``llama.py``'s (``init_params``, ``forward``,
-``init_paged_cache``, ``decode_attention_path``, ``decode_slots_paged``,
-``prefill_slot_paged``, ``copy_page``) plus sambay's chunk surface
-(``init_prefill_stage``, ``prefill_slot_paged_chunk``, ``.._last``: the
-stage carries ``(S, z)`` from chunk to chunk, and the last chunk seats
-it in the slot). The state has no pages: the engine admits by free
-slots, and the page table its programs are handed is empty and unread.
-RMSNorm, RoPE, the embedding, the SwiGLU, the output projection, the
-head and the sampler are ``llama.py``'s own functions.
+The module keeps ``models/serving.py``'s family contract, prompts in
+chunks included (the stage carries ``(S, z)`` from chunk to chunk, and
+the last chunk seats it in the slot). The state has no pages: the
+engine admits by free slots, and the page table its programs are handed
+is empty and unread. RMSNorm, RoPE, the embedding, the SwiGLU, the
+output projection and the head are ``llama.py``'s own functions, the
+sampler ``serving``'s.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, ClassVar, Optional, Tuple
 
 import jax
@@ -36,8 +35,8 @@ from jax import lax
 from ..ops.retention import (GATE_SCOPE, STATE_SCOPE, retention_chunk,
                              retention_step_bank, retention_step_path,
                              sympow2_rows)
-from . import llama
-from .llama import rms_norm
+from . import llama, serving
+from .llama import final_logits, rms_norm
 
 __all__ = ["RetentionConfig", "CONFIGS", "init_params", "forward",
            "layer_streams", "layer_keys", "init_paged_cache",
@@ -224,10 +223,6 @@ def _scan_layers(cfg: RetentionConfig, params, x, state, cos, sin, valid,
     return x, (S, z), seen
 
 
-def _final(cfg, params, x):
-    return llama._lm_head(cfg, params, rms_norm(x, params["final_norm"],
-                                                cfg.norm_eps))
-
 
 # ---------------------------------------------------------------------------
 # whole sequences: forward and the prefills (the chunked form)
@@ -281,7 +276,7 @@ def forward(cfg: RetentionConfig, params, tokens):
     b, s = tokens.shape
     x, _, _ = _sequence_layers(cfg, params, tokens, 0,
                                _empty_state(cfg, b), s)
-    return _final(cfg, params, x)
+    return final_logits(cfg, params, x)
 
 
 def layer_streams(cfg: RetentionConfig, params, tokens):
@@ -327,19 +322,12 @@ def init_paged_cache(cfg: RetentionConfig, max_slots: int, n_pages: int,
     """Device state for the serving engine: ``S`` (L, slots, n_kv_heads,
     head_dim, F) and ``z`` (L, slots, n_kv_heads, F) in
     ``cfg.state_dtype``, a fixed block a slot whatever its sequence's
-    length, plus the per-slot ``lengths``/``tokens``/``rngs`` of every
-    family. There is no page pool: ``n_pages`` and ``page_size`` size
-    nothing here."""
+    length, plus the slot vectors (``serving.slot_vectors``). There is
+    no page pool: ``n_pages`` and ``page_size`` size nothing here."""
     del n_pages, page_size
-    if mesh is not None or int8:
-        raise ValueError("retention: " + SERVE_UNSUPPORTED[
-            "mesh" if mesh is not None else "int8_pages"])
+    serving.refuse("retention", SERVE_UNSUPPORTED, mesh, int8)
     S, z = _empty_state(cfg, max_slots)
-    return {
-        "S": S, "z": z,
-        "lengths": jnp.zeros((max_slots,), jnp.int32),
-        "tokens": jnp.zeros((max_slots,), jnp.int32),
-        "rngs": jnp.zeros((max_slots, 2), jnp.uint32)}
+    return {"S": S, "z": z, **serving.slot_vectors(max_slots)}
 
 
 def copy_page(kv, src, dst):
@@ -366,7 +354,7 @@ def decode_logits(cfg: RetentionConfig, params, kv, sv, active):
     x, (S, z), _ = _scan_layers(
         cfg, params, llama._embed(cfg, params, sv["tokens"][:, None]),
         (kv["S"], kv["z"]), cos, sin, active[:, None], retain)
-    return _final(cfg, params, x)[:, 0], {"S": S, "z": z}
+    return final_logits(cfg, params, x)[:, 0], {"S": S, "z": z}
 
 
 def decode_slots_paged(cfg: RetentionConfig, params, kv, sv, active,
@@ -376,57 +364,50 @@ def decode_slots_paged(cfg: RetentionConfig, params, kv, sv, active,
     state is read and written where it lies; ``page_table`` is the
     engine's empty one. Returns (sampled tokens (S,), new kv, new sv)."""
     del page_table, mesh
-    logits, kv = decode_logits(cfg, params, kv, sv, active)
-    new_rngs, sampled = llama._sample_slots(
-        sv["rngs"], logits, temperature, top_k, top_p)
-    return sampled, kv, {
-        "lengths": sv["lengths"].astype(jnp.int32)
-        + active.astype(jnp.int32),
-        "tokens": sampled, "rngs": new_rngs}
+    return serving.decode_step(*decode_logits(cfg, params, kv, sv, active),
+                               sv, active, temperature, top_k, top_p)
 
 
-def _seat_first(cfg, params, x, state, n_valid, true_len, slot, kv, sv,
-                rng, temperature, top_k, top_p):
-    """The end of an admission: the logits of position ``n_valid - 1``
-    of x (1, s, dim), the first token sampled from them, the prompt's
-    state seated over whatever the slot held, the slot's length, token
-    and rng chain set. Returns (first token (1,), new kv, new sv)."""
-    last = lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
-    logits = _final(cfg, params, last)[:, 0]
-    rng, sub = jax.random.split(rng)
-    tok = llama.sample_logits(sub, logits, temperature=temperature,
-                              top_k=top_k, top_p=top_p)
+# -- admission: the run, and the first-token seat every token family shares -
+def _from_stage(stage, start):
+    """The stage's state, or an empty one for a prompt's first chunk
+    (the stage still holds the prompt before it)."""
+    return tuple(jnp.where(start > 0, stage[n], jnp.zeros((), stage[n].dtype))
+                 for n in ("S", "z"))
+
+
+def _run(cfg: RetentionConfig, params, tokens, start, n_valid, stage, mesh):
+    """``serving``'s run: every layer over the tokens, from the stage's
+    state; the head reads the last valid position alone."""
+    del mesh
+    state = (_empty_state(cfg, 1) if stage is None
+             else _from_stage(stage, start))
+    x, (S, z), _ = _sequence_layers(cfg, params, tokens, start, state,
+                                    n_valid)
+    return serving.last_logits(final_logits, cfg, params, x, n_valid), \
+        {"S": S, "z": z}
+
+
+def _seat(kv, state, pages_row, slot):
+    """The prompt's state over whatever the slot held (there are no
+    pages to seat)."""
+    del pages_row
     with jax.named_scope(STATE_SCOPE):
-        new_kv = {n: lax.dynamic_update_slice_in_dim(
-            kv[n], a.astype(kv[n].dtype), slot, axis=1)
-            for n, a in zip(("S", "z"), state)}
-    z = jnp.zeros((), jnp.int32)
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None], (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], tok.astype(sv["tokens"].dtype), (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype), (slot, z))}
-    return tok, new_kv, new_sv
+        return {n: lax.dynamic_update_slice_in_dim(
+            kv[n], state[n].astype(kv[n].dtype), slot, axis=1)
+            for n in ("S", "z")}
+
+
+_end = partial(serving._seat_first, _seat)
 
 
 def prefill_slot_paged(cfg: RetentionConfig, params, tokens, true_len,
                        prefix_len, pages_row, slot, kv, sv, rng,
                        temperature, top_k, top_p, mesh=None):
-    """Admission: ``llama.prefill_slot_paged``'s contract, cold only
-    (``prefix_len`` is 0: the engine refuses a prefix cache for this
-    family). Every layer over the prompt (END-padded to its bucket; the
-    padding moves no state), the head on the last position alone; the
-    slot's state is overwritten whole. Returns (first token (1,), new
-    kv, new sv)."""
-    del prefix_len, pages_row, mesh
-    true_len = jnp.asarray(true_len, jnp.int32)
-    x, state, _ = _sequence_layers(cfg, params, tokens, 0,
-                                   _empty_state(cfg, 1), true_len)
-    return _seat_first(cfg, params, x, state, true_len, true_len,
-                       jnp.asarray(slot, jnp.int32), kv, sv, rng,
-                       temperature, top_k, top_p)
+    """Admission of a whole prompt (``serving.prefill_whole``)."""
+    return serving.prefill_whole(
+        _run, _end, cfg, params, tokens, true_len, prefix_len, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)
 
 
 # -- a prompt in chunks: the stall a running request sees is one chunk's ----
@@ -440,40 +421,17 @@ def init_prefill_stage(cfg: RetentionConfig, capacity: int, chunk: int):
     return {"S": S, "z": z}
 
 
-def _from_stage(stage, start):
-    """The stage's state, or an empty one for a prompt's first chunk
-    (the stage still holds the prompt before it)."""
-    return tuple(jnp.where(start > 0, stage[n], jnp.zeros((), stage[n].dtype))
-                 for n in ("S", "z"))
-
-
 def prefill_slot_paged_chunk(cfg: RetentionConfig, params, tokens, start,
                              stage, mesh=None):
-    """One whole chunk of a prompt that is not its last: every layer
-    over tokens (1, chunk) at positions ``start ..``, from the state the
-    chunks before left in the stage (``start`` 0: from nothing) to the
-    state the next chunk goes on from. The slot bank is not touched."""
-    del mesh
-    start = jnp.asarray(start, jnp.int32)
-    _, (S, z), _ = _sequence_layers(cfg, params, tokens, start,
-                                    _from_stage(stage, start),
-                                    tokens.shape[1])
-    return {"S": S, "z": z}
+    """A chunk that is not the prompt's last (``serving.prefill_chunk``)."""
+    return serving.prefill_chunk(_run, cfg, params, tokens, start, stage,
+                                 mesh)
 
 
 def prefill_slot_paged_last(cfg: RetentionConfig, params, tokens, start,
                             n_valid, stage, pages_row, slot, kv, sv, rng,
                             temperature, top_k, top_p, mesh=None):
-    """A prompt's last chunk, ``n_valid`` tokens END-padded to tokens
-    (1, chunk), at positions ``start ..``; then the admission's end as
-    ``prefill_slot_paged``'s: the prompt's state seated into the slot,
-    the first token sampled. Returns (first token (1,), new kv, new
-    sv)."""
-    del pages_row, mesh
-    start = jnp.asarray(start, jnp.int32)
-    n_valid = jnp.asarray(n_valid, jnp.int32)
-    x, state, _ = _sequence_layers(cfg, params, tokens, start,
-                                   _from_stage(stage, start), n_valid)
-    return _seat_first(cfg, params, x, state, n_valid, start + n_valid,
-                       jnp.asarray(slot, jnp.int32), kv, sv, rng,
-                       temperature, top_k, top_p)
+    """A prompt's last chunk and its seat (``serving.prefill_last``)."""
+    return serving.prefill_last(
+        _run, _end, cfg, params, tokens, start, n_valid, stage, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)

@@ -36,14 +36,11 @@ TPU walked by the same kernel, as pages of token rows where it lies;
 elsewhere relaid out head-major each step), and
 the convolution tail and float32 scan state per Mamba layer and slot.
 A prompt needs the self-decoder only, so the prefill program runs the
-cross-decoder on the last position alone. The serving surface is
-``llama.py``'s: ``init_params``, ``forward``, ``init_paged_cache``,
-``prefill_slot_paged``, ``decode_slots_paged``, ``copy_page``. Because
-every kind of state here is carried — the scan's, the rings', the
-pool's — a prompt can also be prefilled a chunk at a time with decode
-steps in between (the engine's ``prefill_chunk``):
-``init_prefill_stage``, ``prefill_slot_paged_chunk`` for every chunk
-but the last, ``prefill_slot_paged_last`` for the last.
+cross-decoder on the last position alone. The module keeps
+``models/serving.py``'s family contract. Because every kind of state
+here is carried — the scan's, the rings', the pool's — a prompt can
+also be prefilled a chunk at a time with decode steps in between (the
+engine's ``prefill_chunk``).
 """
 from __future__ import annotations
 
@@ -62,8 +59,8 @@ from ..ops.attention import (ATTENTION_SCOPE, KV_GATHER_SCOPE,
                              window_attention_path,
                              window_prefill_attention)
 from ..ops.ssm import causal_conv1d, selective_scan, selective_scan_step
-from . import llama
-from .llama import KV_WRITE_SCOPE
+from . import llama, serving
+from .serving import KV_WRITE_SCOPE, neg_exp, normal
 
 __all__ = ["SambaYConfig", "CONFIGS", "init_params", "forward",
            "init_paged_cache", "prefill_slot_paged",
@@ -191,10 +188,6 @@ def lambda_init(layer):
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
-def _normal(key, shape, fan_in, dtype):
-    return jax.random.normal(key, shape, dtype) / math.sqrt(fan_in)
-
-
 def _init_block(key, cfg: SambaYConfig, n: int):
     """What every layer has: two LayerNorms and the MLP (gate and up
     fused)."""
@@ -202,8 +195,8 @@ def _init_block(key, cfg: SambaYConfig, n: int):
     d, D, H = cfg.param_dtype, cfg.dim, cfg.hidden_dim
     return {"norm1_w": jnp.ones((n, D), d), "norm1_b": jnp.zeros((n, D), d),
             "norm2_w": jnp.ones((n, D), d), "norm2_b": jnp.zeros((n, D), d),
-            "w_gate_up": _normal(k1, (n, D, 2 * H), D, d),
-            "w_down": _normal(k2, (n, H, D), H * 2 * cfg.n_layers, d)}
+            "w_gate_up": normal(k1, (n, D, 2 * H), D, d),
+            "w_down": normal(k2, (n, H, D), H * 2 * cfg.n_layers, d)}
 
 
 def _init_mamba(key, cfg: SambaYConfig, n: int):
@@ -216,16 +209,16 @@ def _init_mamba(key, cfg: SambaYConfig, n: int):
                    * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
     out = _init_block(ks[6], cfg, n)
     out.update({
-        "in_proj": _normal(ks[0], (n, D, 2 * Di), D, d),
-        "conv_w": _normal(ks[1], (n, Di, K), K, d),
+        "in_proj": normal(ks[0], (n, D, 2 * Di), D, d),
+        "conv_w": normal(ks[1], (n, Di, K), K, d),
         "conv_b": jnp.zeros((n, Di), d),
-        "x_proj": _normal(ks[2], (n, Di, R + 2 * N), Di, d),
-        "dt_proj": _normal(ks[3], (n, R, Di), R, d),
+        "x_proj": normal(ks[2], (n, Di, R + 2 * N), Di, d),
+        "dt_proj": normal(ks[3], (n, R, Di), R, d),
         "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(_F32),
         "A_log": jnp.broadcast_to(
             jnp.log(jnp.arange(1, N + 1, dtype=_F32)), (n, Di, N)),
         "D": jnp.ones((n, Di), _F32),
-        "out_proj": _normal(ks[4], (n, Di, D), Di * 2 * cfg.n_layers, d)})
+        "out_proj": normal(ks[4], (n, Di, D), Di * 2 * cfg.n_layers, d)})
     return out
 
 
@@ -234,15 +227,15 @@ def _init_attn(key, cfg: SambaYConfig, n: int, cross: bool = False):
     d, D, hd = cfg.param_dtype, cfg.dim, cfg.head_dim
     out = _init_block(ks[8], cfg, n)
     out.update({
-        "wq": _normal(ks[0], (n, D, cfg.n_heads * hd), D, d),
-        "wo": _normal(ks[3], (n, cfg.n_heads * hd, D),
+        "wq": normal(ks[0], (n, D, cfg.n_heads * hd), D, d),
+        "wo": normal(ks[3], (n, cfg.n_heads * hd, D),
                       cfg.n_heads * hd * 2 * cfg.n_layers, d),
         "subln_w": jnp.ones((n, 2 * hd), d)})
     for i, name in enumerate(("lam_q1", "lam_k1", "lam_q2", "lam_k2")):
         out[name] = 0.1 * jax.random.normal(ks[4 + i], (n, hd), _F32)
     if not cross:
-        out["wk"] = _normal(ks[1], (n, D, cfg.n_kv_heads * hd), D, d)
-        out["wv"] = _normal(ks[2], (n, D, cfg.n_kv_heads * hd), D, d)
+        out["wk"] = normal(ks[1], (n, D, cfg.n_kv_heads * hd), D, d)
+        out["wv"] = normal(ks[2], (n, D, cfg.n_kv_heads * hd), D, d)
     return out
 
 
@@ -250,8 +243,8 @@ def _init_gmu(key, cfg: SambaYConfig, n: int):
     k1, k2, k3 = jax.random.split(key, 3)
     d, D, Di = cfg.param_dtype, cfg.dim, cfg.d_inner
     out = _init_block(k3, cfg, n)
-    out.update({"in_proj": _normal(k1, (n, D, Di), D, d),
-                "out_proj": _normal(k2, (n, Di, D),
+    out.update({"in_proj": normal(k1, (n, D, Di), D, d),
+                "out_proj": normal(k2, (n, Di, D),
                                     Di * 2 * cfg.n_layers, d)})
     return out
 
@@ -266,7 +259,7 @@ def init_params(cfg: SambaYConfig, rng: Optional[jax.Array] = None):
     P, C = cfg.n_pairs, cfg.n_cross
     one = partial(jax.tree_util.tree_map, lambda a: a[0])
     params = {
-        "tok_embed": _normal(ks[0], (cfg.vocab_size, cfg.dim), cfg.dim,
+        "tok_embed": normal(ks[0], (cfg.vocab_size, cfg.dim), cfg.dim,
                              cfg.param_dtype),
         "pairs": {"mamba": _init_mamba(ks[1], cfg, P),
                   "attn": _init_attn(ks[2], cfg, P)},
@@ -278,7 +271,7 @@ def init_params(cfg: SambaYConfig, rng: Optional[jax.Array] = None):
         "final_norm_b": jnp.zeros((cfg.dim,), cfg.param_dtype),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = _normal(ks[7], (cfg.dim, cfg.vocab_size),
+        params["lm_head"] = normal(ks[7], (cfg.dim, cfg.vocab_size),
                                     cfg.dim, cfg.param_dtype)
     return params
 
@@ -348,10 +341,6 @@ def _mamba_out(cfg, lp, y, z):
     """The output gate and projection; y is the scan's output."""
     return (y * jax.nn.silu(z.astype(_F32))).astype(cfg.dtype) \
         @ lp["out_proj"]
-
-
-def _neg_exp(a_log):
-    return -jnp.exp(a_log.astype(_F32))
 
 
 @jax.named_scope(GMU_SCOPE)
@@ -425,7 +414,7 @@ def _mamba_seq(cfg: SambaYConfig, lp, x, true_len, tail0=None,
         u, z, dt, B, C, padded = _mamba_inputs(cfg, lp, h, tail0)
         dt = jnp.where((jnp.arange(s) < true_len)[None, :, None], dt, 0.0)
         y, state = selective_scan(
-            u, dt, _neg_exp(lp["A_log"]), B, C, lp["D"], state0,
+            u, dt, neg_exp(lp["A_log"]), B, C, lp["D"], state0,
             chunk=cfg.scan_chunk)
         # the d_conv-1 inputs before position true_len
         tail = lax.dynamic_slice_in_dim(padded, true_len,
@@ -663,7 +652,7 @@ def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
     side: ``k``/``v`` the full-attention layer's page pool, (1, n_pages,
     page_size, n_kv_heads hd), token-major with a layer axis of one
     (``llama.init_paged_cache``'s layout, so ``copy_page`` is
-    ``llama``'s) and a token's heads end to end (20 heads of 64 laid
+    ``serving``'s) and a token's heads end to end (20 heads of 64 laid
     out as (.., 10, 128) or (.., 20, 64) would be padded by the chip's
     (8, 128) tiles, or relaid out around every write); ``wk``/``wv``
     the window layers' rings, (n_pairs, slots, sliding_window,
@@ -672,11 +661,9 @@ def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
     of them where it lies (:func:`_ring_pages`), never relaid out;
     ``conv`` (n_pairs+1, slots, d_conv-1, d_inner) and ``ssm``
     (n_pairs+1, slots, d_inner, d_state) float32, the Mamba layers'
-    state. Plus the per-slot ``lengths``/``tokens``/
-    ``rngs`` of every family. Page tables stay on the host."""
-    if mesh is not None or int8:
-        raise ValueError("sambay: " + SERVE_UNSUPPORTED[
-            "mesh" if mesh is not None else "int8_pages"])
+    state. Plus the slot vectors (``serving.slot_vectors``). Page tables
+    stay on the host."""
+    serving.refuse("sambay", SERVE_UNSUPPORTED, mesh, int8)
     P, width = cfg.n_pairs, cfg.n_kv_heads * cfg.head_dim
     ring = (P, max_slots, cfg.sliding_window, width)
     pool = (1, n_pages, page_size, width)
@@ -687,12 +674,10 @@ def init_paged_cache(cfg: SambaYConfig, max_slots: int, n_pages: int,
                           cfg.dtype),
         "ssm": jnp.zeros((P + 1, max_slots, cfg.d_inner, cfg.d_state),
                          _F32),
-        "lengths": jnp.zeros((max_slots,), jnp.int32),
-        "tokens": jnp.zeros((max_slots,), jnp.int32),
-        "rngs": jnp.zeros((max_slots, 2), jnp.uint32)}
+        **serving.slot_vectors(max_slots)}
 
 
-copy_page = llama.copy_page       # pools only; the fixed state has no pages
+copy_page = serving.copy_page     # pools only; the fixed state has no pages
 
 
 def _mamba_step(cfg: SambaYConfig, lp, x, conv, ssm, layer):
@@ -704,7 +689,7 @@ def _mamba_step(cfg: SambaYConfig, lp, x, conv, ssm, layer):
         state = lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False)
         u, z, dt, B, C, padded = _mamba_inputs(cfg, lp, h, tail)
         y, state = selective_scan_step(
-            state, u[:, 0], dt[:, 0], _neg_exp(lp["A_log"]), B[:, 0],
+            state, u[:, 0], dt[:, 0], neg_exp(lp["A_log"]), B[:, 0],
             C[:, 0], lp["D"])
         conv = lax.dynamic_update_index_in_dim(conv, padded[:, 1:],
                                                layer, 0)
@@ -796,8 +781,8 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
         q = _diff_q(cfg, ap, h)
         k, v = _diff_kv(cfg, ap, h)
     phys = page_table[at, pos // ps]
-    ck, cv = llama._write_pages(kv["k"], kv["v"], k[:, 0], v[:, 0], 0,
-                                phys, pos % ps)
+    ck, cv = serving.write_pages(kv["k"], kv["v"], k[:, 0], v[:, 0], 0,
+                                 phys, pos % ps)
     if walk:
         # eight walks of the live pages: each cross layer's query hangs
         # on the layer before it, so they cannot share one
@@ -813,26 +798,39 @@ def decode_slots_paged(cfg: SambaYConfig, params, kv, sv, active,
         out = _diff_out(cfg, ap, attend(q), lam_full)
     x = _residual_mlp(cfg, ap, x + out)
     x = _cross_decoder(cfg, params, x, memory, attend)
-    logits = _final(cfg, params, x)[:, 0]
+    return serving.decode_step(_final(cfg, params, x)[:, 0], {
+        "k": ck, "v": cv, "wk": wk, "wv": wv, "conv": conv, "ssm": ssm},
+        sv, active, temperature, top_k, top_p, mesh=mesh)
 
-    new_rngs, sampled = llama._sample_slots(
-        sv["rngs"], logits, temperature, top_k, top_p, mesh)
-    new_kv = {"k": ck, "v": cv, "wk": wk, "wv": wv, "conv": conv,
-              "ssm": ssm}
-    return sampled, new_kv, {"lengths": lengths + active.astype(jnp.int32),
-                             "tokens": sampled, "rngs": new_rngs}
+
+# -- admission: the run, and the first-token seat every token family shares -
+def _run(cfg: SambaYConfig, params, tokens, start, n_valid, stage, mesh):
+    """``serving``'s run: the self-decoder, and for a last chunk or a
+    whole prompt the cross-decoder on its last position
+    (:func:`prefill_logits`), ahead of the slot's length in the program."""
+    carry = None if stage is None else dict(stage, start=start)
+    if isinstance(n_valid, int):        # a chunk that is not the last
+        _, _, k, v, state = _self_decoder(
+            cfg, params, llama._embed(cfg, params, tokens), n_valid, carry,
+            mesh)
+        return None, {n: a.astype(stage[n].dtype)
+                      for n, a in dict(state, k=k, v=v).items()}
+    logits, k, v, state = prefill_logits(cfg, params, tokens, n_valid,
+                                         carry, mesh)
+    return (lambda: logits), dict(state, k=k, v=v)
 
 
 @jax.named_scope(KV_WRITE_SCOPE)
-def _seat_state(kv, state, k, v, pages_row, slot):
-    """A prefilled prompt's state into ``slot``: ALL of the slot's
-    rings and recurrent state (whatever request held it before), and
-    the full layer's keys and values into the slot's pages."""
+def _seat(kv, state, pages_row, slot):
+    """A prefilled prompt's state into ``slot``: ALL of the slot's rings
+    and recurrent state, and the full layer's rows (1, capacity, width)
+    into the one-layer pool's pages (no layer axis: ``serving.seat_pages``
+    would add a reshape and another scatter to the program)."""
     ps = kv["k"].shape[2]
-    pad = -k.shape[1] % ps
+    pad = -state["k"].shape[1] % ps
     out = dict(kv)
-    for name, a in (("k", k), ("v", v)):
-        a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+    for name in ("k", "v"):
+        a = jnp.pad(state[name], ((0, 0), (0, pad), (0, 0)))
         pages = a.reshape(-1, ps, a.shape[-1])           # b is 1
         out[name] = kv[name].at[0, pages_row[:pages.shape[0]]].set(
             pages.astype(kv[name].dtype))
@@ -842,43 +840,16 @@ def _seat_state(kv, state, k, v, pages_row, slot):
     return out
 
 
-def _seat_first(cfg, logits, k, v, state, true_len, pages_row, slot, kv,
-                sv, rng, temperature, top_k, top_p):
-    """The end of an admission: sample the first token from the last
-    position's ``logits``, seat the prompt's state into ``slot``, set
-    the slot's length, token and rng chain. Returns (first token (1,),
-    new kv, new sv)."""
-    rng, sub = jax.random.split(rng)
-    tok = llama.sample_logits(sub, logits, temperature=temperature,
-                              top_k=top_k, top_p=top_p)
-    new_kv = _seat_state(kv, state, k, v, pages_row, slot)
-    z = jnp.zeros((), jnp.int32)
-    new_sv = {
-        "lengths": lax.dynamic_update_slice(
-            sv["lengths"].astype(jnp.int32), true_len[None], (slot,)),
-        "tokens": lax.dynamic_update_slice(
-            sv["tokens"], tok.astype(sv["tokens"].dtype), (slot,)),
-        "rngs": lax.dynamic_update_slice(
-            sv["rngs"], rng[None].astype(sv["rngs"].dtype), (slot, z))}
-    return tok, new_kv, new_sv
+_end = partial(serving._seat_first, _seat)
 
 
 def prefill_slot_paged(cfg: SambaYConfig, params, tokens, true_len,
                        prefix_len, pages_row, slot, kv, sv, rng,
                        temperature, top_k, top_p, mesh=None):
-    """Admission: ``llama.prefill_slot_paged``'s contract, cold only
-    (``prefix_len`` is 0: the engine refuses a prefix cache for this
-    family). The self-decoder runs over the prompt (END-padded to its
-    bucket; the padding moves no state), the cross-decoder on the last
-    position alone; the slot's state is overwritten whole. Returns
-    (first token (1,), new kv, new sv)."""
-    del prefix_len
-    true_len = jnp.asarray(true_len, jnp.int32)
-    slot = jnp.asarray(slot, jnp.int32)
-    logits, k, v, state = prefill_logits(cfg, params, tokens, true_len,
-                                         mesh=mesh)
-    return _seat_first(cfg, logits, k, v, state, true_len, pages_row, slot,
-                       kv, sv, rng, temperature, top_k, top_p)
+    """Admission of a whole prompt (``serving.prefill_whole``)."""
+    return serving.prefill_whole(
+        _run, _end, cfg, params, tokens, true_len, prefix_len, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)
 
 
 # -- a prompt in chunks: the stall a running request sees is one chunk's ----
@@ -901,33 +872,15 @@ def init_prefill_stage(cfg: SambaYConfig, capacity: int, chunk: int):
 
 def prefill_slot_paged_chunk(cfg: SambaYConfig, params, tokens, start,
                              stage, mesh=None):
-    """One whole chunk of a prompt that is not its last: the
-    self-decoder over tokens (1, chunk) at positions ``start ..``, from
-    the stage as the chunks before left it (``start`` 0: from nothing)
-    to the stage the next chunk goes on from. The slot bank is not
-    touched."""
-    carry = dict(stage, start=jnp.asarray(start, jnp.int32))
-    _, _, k, v, state = _self_decoder(
-        cfg, params, llama._embed(cfg, params, tokens), tokens.shape[1],
-        carry, mesh)
-    return {n: a.astype(stage[n].dtype)
-            for n, a in dict(state, k=k, v=v).items()}
+    """A chunk that is not the prompt's last (``serving.prefill_chunk``)."""
+    return serving.prefill_chunk(_run, cfg, params, tokens, start, stage,
+                                 mesh)
 
 
 def prefill_slot_paged_last(cfg: SambaYConfig, params, tokens, start,
                             n_valid, stage, pages_row, slot, kv, sv, rng,
                             temperature, top_k, top_p, mesh=None):
-    """A prompt's last chunk, ``n_valid`` tokens END-padded to tokens
-    (1, chunk), at positions ``start ..``: the self-decoder over the
-    chunk, the cross-decoder on the prompt's last position alone (its
-    queries over the stage's keys and values, the whole prompt's), then
-    the admission's end as ``prefill_slot_paged``'s: the state seated
-    into ``slot`` whole, the first token sampled. Returns (first token
-    (1,), new kv, new sv)."""
-    start = jnp.asarray(start, jnp.int32)
-    logits, k, v, state = prefill_logits(
-        cfg, params, tokens, n_valid, carry=dict(stage, start=start),
-        mesh=mesh)
-    return _seat_first(cfg, logits, k, v, state, start + n_valid,
-                       pages_row, jnp.asarray(slot, jnp.int32), kv, sv, rng,
-                       temperature, top_k, top_p)
+    """A prompt's last chunk and its seat (``serving.prefill_last``)."""
+    return serving.prefill_last(
+        _run, _end, cfg, params, tokens, start, n_valid, stage, pages_row,
+        slot, kv, sv, rng, temperature, top_k, top_p, mesh)

@@ -72,7 +72,7 @@ import numpy as np
 
 from .. import telemetry
 from ..telemetry import distributed as dtrace
-from ..models import serving_family
+from ..models import serving, serving_family
 from ..ops.threshold import thresholds_path
 
 __all__ = ["Request", "KVHandoff", "ServeEngine", "bucket_for",
@@ -545,12 +545,13 @@ class ServeEngine:
 
     Args: ``cfg``/``params`` — a config and parameter pytree of a
     family in ``models.SERVING_FAMILIES`` (llama, sambay, latent_moe,
-    retention, blockdiff_moe): the engine
-    takes every program it runs from that family's module, found once
-    here from ``cfg.family``, and refuses at construction the options
-    the family's ``SERVE_UNSUPPORTED`` names, with the mechanism in
-    the way (a llama weight-only int8 tree from ``quantize_params_int8``
-    rides the same programs). ``max_slots``/``max_len``/``min_bucket`` default
+    retention, blockdiff_moe, nemotron_h): the engine takes every
+    program it runs from that family's module (``models.serving``'s
+    contract), found once here from ``cfg.family``, and refuses at
+    construction the options the family's ``SERVE_UNSUPPORTED`` names,
+    with the mechanism in the way (a llama weight-only int8 tree from
+    ``quantize_params_int8`` rides the same programs).
+    ``max_slots``/``max_len``/``min_bucket`` default
     from ``MXTPU_SERVE_MAX_SLOTS`` / the config's ``max_seq_len`` /
     ``MXTPU_SERVE_MIN_BUCKET``. ``mesh`` serves sharded (pool per
     ``llama.paged_cache_specs``, params as placed by the training
@@ -563,8 +564,8 @@ class ServeEngine:
     bank that is gone: ``True`` is its one legal value, kept while the
     benchmark's drivers pass it (ROADMAP D12). ``prefill_chunk`` (a
     family with ``prefill_slot_paged_chunk``: sambay, latent_moe,
-    retention) prefills a
-    prompt that many tokens at a time, at most one chunk between two decode steps once
+    retention, blockdiff_moe, nemotron_h) prefills a prompt that many
+    tokens at a time, at most one chunk between two decode steps once
     the running requests are no fewer than the waiting ones: the gap a
     running request sees beside an admission is one chunk's time
     whatever the prompt's length, and the prompt's first token comes
@@ -653,7 +654,8 @@ class ServeEngine:
                 fam, "prefill_slot_paged_chunk"):
             raise ValueError(
                 "prefill_chunk needs a family whose prefill carries "
-                "its state from chunk to chunk (sambay); got "
+                "its state from chunk to chunk (a chunked family: sambay, "
+                "latent_moe, retention, blockdiff_moe, nemotron_h); got "
                 f"{cfg.family}")
         if self.speculate_k:
             # the host drafter conditions on every token emitted so
@@ -675,7 +677,7 @@ class ServeEngine:
             # K/V pools; sambay: a pool plus the fixed per-slot rings
             # and recurrent state)
             self._sv = {n: state.pop(n) for n in getattr(
-                fam, "SLOT_VARS", ("lengths", "tokens", "rngs"))}
+                fam, "SLOT_VARS", serving.SLOT_VARS)}
             self._kv = state
             # bytes of the donated state by kind: pages (the kinds named
             # ``*_pages``: keys and values, or latent rows) grow with the

@@ -23,7 +23,7 @@ import pytest
 from jax import lax
 
 import llama_refs
-from mxtpu.models import llama
+from mxtpu.models import llama, serving
 from mxtpu.ops import threshold
 
 V = 1031                # no multiple of 128: the kernel pads with -inf
@@ -149,12 +149,12 @@ def test_a_greedy_row_among_sampling_rows(kind):
     t = jnp.asarray([0.7, 0.0, 0.9, 0.0], jnp.float32)
     k = jnp.asarray([40, 1, V, 5], jnp.int32)
     p = jnp.asarray([0.95, 0.1, 0.5, 0.9], jnp.float32)
-    greedy, masked = llama._masked_logits(x, t, k, p)
+    greedy, masked = serving._masked_logits(x, t, k, p)
     np.testing.assert_array_equal(
         np.asarray(greedy), [-1, int(x[1].argmax()), -1, int(x[3].argmax())])
     np.testing.assert_array_equal(np.asarray(masked[1]), np.asarray(x[1]))
-    _, alone_masked = llama._masked_logits(x[::2], t[::2], k[::2],
-                                           p[::2])
+    _, alone_masked = serving._masked_logits(x[::2], t[::2], k[::2],
+                                             p[::2])
     np.testing.assert_array_equal(np.asarray(masked[::2]),
                                   np.asarray(alone_masked))
     toks = llama.sample_logits(jax.random.PRNGKey(3), x, temperature=t,
@@ -189,7 +189,7 @@ def test_sample_slots_draws_what_slot_by_slot_drew(slots):
     args = (jnp.asarray(t, jnp.float32), jnp.asarray(k, jnp.int32),
             jnp.asarray(p, jnp.float32))
     want_keys, want = jax.vmap(_sample_slot)(keys, x, *args)
-    got_keys, got = jax.jit(llama._sample_slots)(keys, x, *args)
+    got_keys, got = jax.jit(serving._sample_slots)(keys, x, *args)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(got_keys),
                                   np.asarray(want_keys))
